@@ -6,8 +6,8 @@ treatment its sibling targets (ResNet 0.996x roofline, ViT 93% of
 device-time bound) received. This harness re-measures the MLM training
 step at the current tree, buckets every scheduled op by XLA provenance
 (the ``vit_phase_profile`` method), and quotes MFU from the analytic
-transformer FLOP count — the number the bench table cites
-(``artifacts/bench_r6_chip.json``).
+transformer FLOP count (never run on a chip: BERT MFU is not measured
+on the current code).
 
 Run: python examples/bert_phase_profile.py --model base --seq-len 128 \
          --batch-per-chip 128

@@ -16,7 +16,7 @@ intrinsic per-iteration cost" without measuring it. This probe pins it:
    the floor hypothesis is right, b8 throughput rises toward the
    roofline as k grows; if it's wrong, unrolling moves nothing.
 
-Writes ``artifacts/decode_ceiling_r6.json``: either b8 >= 70% of the
+Writes ``artifacts/decode_floor.json``: either b8 >= 70% of the
 roofline (unroll harvested the residual) or floor ~= residual (the
 hypothesis is pinned, not asserted).
 
@@ -99,7 +99,7 @@ def main() -> int:
                     "first customer: the batcher amortizes exactly the "
                     "per-iteration cost this probe pins) and record the "
                     "amortized rate beside the bare rows")
-    ap.add_argument("--out", default="artifacts/decode_ceiling_r6.json")
+    ap.add_argument("--out", default="artifacts/decode_floor.json")
     args = ap.parse_args()
 
     import jax

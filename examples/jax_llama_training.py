@@ -38,6 +38,54 @@ CONFIGS = {"tiny": LLAMA_TINY, "300m": LLAMA_300M,
            "1b": LLAMA_1B, "8b": LLAMA_8B}
 
 
+def build_train_step(model, tx, mesh, *, sp=1, s_local=None,
+                     chunked_loss=0):
+    """The jitted train step this script times — loss, gradients and
+    ``hvd.DistributedOptimizer`` update inside ``jax.shard_map`` over
+    ``mesh`` — as ``step(params, opt_state, ids) -> (params, opt_state,
+    loss)`` (params and state donated). ``chip_smoke.py`` builds its
+    Llama phase with this same function."""
+    if sp > 1:
+        def loss_fn(p, ids):
+            idx = lax.axis_index("seq")
+            positions = idx * s_local + jnp.arange(s_local)
+            logits = model.apply({"params": p}, ids, positions=positions)
+            return sp_causal_lm_loss(logits, ids, "seq")
+
+        def train_step(p, s, ids):
+            loss, grads = jax.value_and_grad(loss_fn)(p, ids)
+            # Each seq shard holds its contribution to d(global loss)/dp:
+            # sum over the axis; the optimizer then averages over data.
+            grads = jax.tree.map(lambda g: lax.psum(g, "seq"), grads)
+            updates, s = tx.update(grads, s, p)
+            return optax.apply_updates(p, updates), s, hvd.allreduce(loss)
+
+        in_specs = (P(), P(), P("data", "seq"))
+    else:
+        if chunked_loss:
+            def loss_fn(p, ids):
+                hidden = model.apply({"params": p}, ids, return_hidden=True)
+                return chunked_causal_lm_loss(
+                    hidden, p["lm_head"]["kernel"], ids,
+                    num_chunks=chunked_loss)
+        else:
+            def loss_fn(p, ids):
+                return causal_lm_loss(model.apply({"params": p}, ids), ids)
+
+        def train_step(p, s, ids):
+            loss, grads = jax.value_and_grad(loss_fn)(p, ids)
+            updates, s = tx.update(grads, s, p)
+            return optax.apply_updates(p, updates), s, hvd.allreduce(loss)
+
+        in_specs = (P(), P(), P("data"))
+
+    return jax.jit(jax.shard_map(
+        train_step, mesh=mesh,
+        in_specs=in_specs, out_specs=(P(), P(), P()),
+        check_vma=False,
+    ), donate_argnums=(0, 1))
+
+
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--model", choices=list(CONFIGS), default="tiny")
@@ -121,45 +169,8 @@ def main():
     tx = hvd.DistributedOptimizer(inner_tx, axis_name="data")
     opt_state = tx.init(params)
 
-    if sp > 1:
-        def loss_fn(p, ids):
-            idx = lax.axis_index("seq")
-            positions = idx * s_local + jnp.arange(s_local)
-            logits = model.apply({"params": p}, ids, positions=positions)
-            return sp_causal_lm_loss(logits, ids, "seq")
-
-        def train_step(p, s, ids):
-            loss, grads = jax.value_and_grad(loss_fn)(p, ids)
-            # Each seq shard holds its contribution to d(global loss)/dp:
-            # sum over the axis; the optimizer then averages over data.
-            grads = jax.tree.map(lambda g: lax.psum(g, "seq"), grads)
-            updates, s = tx.update(grads, s, p)
-            return optax.apply_updates(p, updates), s, hvd.allreduce(loss)
-
-        in_specs = (P(), P(), P("data", "seq"))
-    else:
-        if args.chunked_loss:
-            def loss_fn(p, ids):
-                hidden = model.apply({"params": p}, ids, return_hidden=True)
-                return chunked_causal_lm_loss(
-                    hidden, p["lm_head"]["kernel"], ids,
-                    num_chunks=args.chunked_loss)
-        else:
-            def loss_fn(p, ids):
-                return causal_lm_loss(model.apply({"params": p}, ids), ids)
-
-        def train_step(p, s, ids):
-            loss, grads = jax.value_and_grad(loss_fn)(p, ids)
-            updates, s = tx.update(grads, s, p)
-            return optax.apply_updates(p, updates), s, hvd.allreduce(loss)
-
-        in_specs = (P(), P(), P("data"))
-
-    step = jax.jit(jax.shard_map(
-        train_step, mesh=mesh,
-        in_specs=in_specs, out_specs=(P(), P(), P()),
-        check_vma=False,
-    ), donate_argnums=(0, 1))
+    step = build_train_step(model, tx, mesh, sp=sp, s_local=s_local,
+                            chunked_loss=args.chunked_loss)
 
     ids_s = jax.device_put(
         ids, hvd.parallel.data_sharding(mesh, *(("seq",) if sp > 1 else ())))

@@ -3,9 +3,8 @@
 Traces the ViT training step on the real chip and buckets every scheduled
 op's time into phases by XLA provenance — the same method that produced
 ``artifacts/moe_ceiling_r4.json`` (see ``examples/moe_phase_profile.py``).
-The per-phase table decides whether ViT-S/16's ~35% MFU hides another
-lever or is the configuration's structural ceiling
-(``artifacts/vit_ceiling_r5.json``).
+The per-phase table decides whether ViT-S/16's MFU hides another lever
+or is the configuration's structural ceiling.
 
 Run: python examples/vit_phase_profile.py --model s16 --batch-per-chip 64
 """

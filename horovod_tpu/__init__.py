@@ -19,7 +19,6 @@ Top-level surface mirrors ``import horovod.torch as hvd`` /
 
 __version__ = "0.1.0"
 
-from . import compat  # noqa: F401  (installs jax.shard_map alias; first)
 from .common.basics import (  # noqa: F401
     init,
     shutdown,

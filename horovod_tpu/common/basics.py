@@ -20,7 +20,6 @@ surface, but the heavy machinery differs by tier:
 from __future__ import annotations
 
 import atexit
-import os
 import threading
 from typing import Optional, Sequence
 
@@ -30,22 +29,6 @@ from . import retry
 from .config import Config
 from .topology import Topology, detect
 from .. import metrics
-
-_m = None
-
-
-def _init_metrics():
-    global _m
-    if _m is None:
-        from types import SimpleNamespace
-
-        _m = SimpleNamespace(
-            cpu_fallback=metrics.counter(
-                "hvd_init_cpu_fallback_total",
-                "HOROVOD_TPU_INIT_FALLBACK_CPU degradations to the CPU "
-                "dryrun backend."))
-    return _m
-
 
 class HorovodTpuState:
     """Python analogue of the reference ``HorovodGlobalState``
@@ -132,9 +115,9 @@ def _maybe_init_jax_distributed() -> None:
     per-tensor controller needed (the SPMD program itself is the negotiation,
     SURVEY.md §5).
 
-    Hardened (round-6 outage, artifacts/tpu_outage_r6.md): preflight-probed
-    and retried with exponential backoff under ``HOROVOD_TPU_INIT_RETRIES``/
-    ``_BACKOFF`` instead of wedging on the first dead coordinator."""
+    Preflight-probed and retried with exponential backoff under
+    ``HOROVOD_TPU_INIT_RETRIES``/``_BACKOFF`` instead of wedging on the
+    first dead coordinator."""
     coord = config_mod.spmd_coordinator()
     if not coord:
         return
@@ -146,13 +129,7 @@ def _maybe_init_jax_distributed() -> None:
             "are not; launch through horovodrun --spmd (or export all three)")
     import jax
 
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # older jax without the public probe
-        from jax._src import distributed as _dist
-
-        already = _dist.global_state.client is not None
-    if already:
+    if jax.distributed.is_initialized():
         return
     kwargs = {}
     raw_timeout = (config_mod.env_str("HOROVOD_START_TIMEOUT") or "").strip()
@@ -213,86 +190,42 @@ def _maybe_init_jax_distributed() -> None:
                      seed=rank, describe="jax.distributed.initialize")
 
 
-def _acquire_backend() -> bool:
+def _acquire_backend() -> None:
     """Force JAX backend (TPU runtime) acquisition under the init retry
-    policy, so a wedged/flaky backend init fails fast and retries instead
-    of hanging the rank forever (the round-6 failure mode).
+    policy, so a wedged or flaky backend init fails fast and retries
+    instead of hanging the rank forever.
 
-    Returns whether the backend is usable. False means NOTHING may touch
-    jax device APIs again this process — an abandoned wedged attempt may
-    still hold xla_bridge's backend lock, so any re-entry (including
-    topology's device probe) would hang unboundedly.
+    Raises :class:`~horovod_tpu.common.retry.RetryError` when every
+    attempt failed: a rank that cannot reach its devices must not carry
+    on — there is no CPU fallback and no zero-device mode. A rank that is
+    meant to run without an accelerator is told so up front
+    (``JAX_PLATFORMS=cpu``; the launcher exports it to every local rank
+    it does not bind to a chip).
 
-    With ``HOROVOD_TPU_INIT_FALLBACK_CPU=1`` an exhausted retry budget
-    degrades — loudly — to a CPU dryrun backend so the job can still run
-    parity/debug work while the pool is down."""
-    try:
-        import jax
-    except Exception:  # pragma: no cover - jax always present in this image
-        return False  # same tolerance as topology._device_counts
+    Each attempt runs on the ``hvd-deadline-call`` worker thread so a
+    hang inside native init surfaces as ``DeadlineExceeded`` instead of
+    blocking forever; initializing libtpu from that thread is sound
+    (checked on a v5e with libtpu 0.0.34 — ``chip_smoke.py``'s first
+    backend touch is this call)."""
+    import jax
 
     from .. import fault as fault_mod
 
-    # Bounded BY DEFAULT: the r6 outage was an init that hung rather than
-    # raised — with no deadline the retry/fallback machinery would never
-    # even engage. 300s is ~10x a healthy cold TPU init; 0 disables.
+    # Bounded BY DEFAULT: an init that hangs rather than raises would
+    # never engage the retry policy without a deadline. 300s is ~30x a
+    # healthy cold TPU init; 0 disables.
     per_attempt = config_mod._env_float("HOROVOD_TPU_INIT_TIMEOUT", 300.0)
 
     def _attempt():
         fault_mod.hook("init")
-        # device_count materializes the platform backend (the call that
-        # wedged in artifacts/tpu_outage_r6.md).
+        # device_count materializes the platform backend.
         return retry.run_with_deadline(
             jax.local_device_count, per_attempt, "jax backend init")
 
     attempts, backoff = retry.init_retry_env()
-    try:
-        retry.retry_call(_attempt, attempts=attempts, backoff=backoff,
-                         seed=config_mod.env_rank() or 0,
-                         describe="jax backend acquisition")
-        return True
-    except retry.RetryError as exc:
-        from .config import _env_bool
-
-        if _env_bool("HOROVOD_TPU_INIT_FALLBACK_CPU"):
-            if metrics.on():
-                _init_metrics().cpu_fallback.inc()
-            metrics.record_event("init_fallback_cpu", attempts=attempts,
-                                 error=str(exc.last)[:200])
-            logging.error(
-                "jax backend acquisition failed after %d attempts; "
-                "HOROVOD_TPU_INIT_FALLBACK_CPU=1 — DEGRADING TO THE CPU "
-                "DRYRUN BACKEND. This process will NOT use accelerators; "
-                "results are for parity/debugging only.", attempts)
-            os.environ["JAX_PLATFORMS"] = "cpu"
-            jax.config.update("jax_platforms", "cpu")
-            # The fallback itself must stay deadline-bounded: an abandoned
-            # wedged attempt may still hold xla_bridge's backend lock, and
-            # an unbounded call here would wedge the very path built to
-            # never wedge.
-            try:
-                retry.run_with_deadline(
-                    jax.local_device_count, per_attempt or 120.0,
-                    "CPU fallback backend init")
-                return True
-            except retry.DeadlineExceeded:
-                logging.error(
-                    "CPU fallback is unreachable too: the wedged init "
-                    "attempt still holds the JAX backend lock. Continuing "
-                    "on the host-only eager tier; jax device APIs are "
-                    "UNUSABLE in this process.")
-                return False
-        if isinstance(exc.last, fault_mod.FaultInjected):
-            raise  # injected wedges are test assertions: never swallow
-        # Bounded, loud, and non-fatal — the pre-hardening contract
-        # (topology._device_counts) tolerated a dead backend by reporting
-        # 0 devices; the eager host tier still works without accelerators.
-        logging.error(
-            "jax backend acquisition failed after %d bounded attempts "
-            "(%s); continuing WITHOUT accelerator devices — set "
-            "HOROVOD_TPU_INIT_FALLBACK_CPU=1 to degrade to a CPU dryrun "
-            "backend, or fix the TPU pool and relaunch", attempts, exc.last)
-        return False
+    retry.retry_call(_attempt, attempts=attempts, backoff=backoff,
+                     seed=config_mod.env_rank() or 0,
+                     describe="jax backend acquisition")
 
 
 def init(ranks: Optional[Sequence[int]] = None) -> None:
@@ -318,10 +251,8 @@ def init(ranks: Optional[Sequence[int]] = None) -> None:
 
         maybe_install_from_env()
         _maybe_init_jax_distributed()
-        backend_ok = _acquire_backend()
-        # After a failed acquisition the device probe must not re-enter
-        # jax (a wedged attempt may still hold the backend lock).
-        topology = detect(ranks, probe_devices=backend_ok)
+        _acquire_backend()
+        topology = detect(ranks)
         logging.set_rank(topology.rank)
         _state = HorovodTpuState(config, topology)
         if metrics.on():

@@ -1,10 +1,8 @@
 """Bounded retries with exponential backoff + jitter for wedgeable init.
 
-Round 6 lost an entire session to an un-retried, un-bounded TPU backend
-init (``artifacts/tpu_outage_r6.md``): every attempt hung inside native
-init until an external watchdog killed it. The init path must never be an
-infinite hang — it either succeeds, fails after a bounded number of
-attempts, or (opt-in) degrades to a CPU dryrun backend that logs loudly.
+A TPU backend init can hang inside native code instead of raising. The
+init path must never be an infinite hang: it either succeeds or raises
+after a bounded number of deadline-bounded attempts.
 
 Knobs (read by :func:`init_retry_env`):
 
@@ -14,7 +12,7 @@ Knobs (read by :func:`init_retry_env`):
   whole pod slice doesn't re-dial the coordinator in lockstep.
 * ``HOROVOD_TPU_INIT_TIMEOUT`` — per-attempt deadline seconds for
   :func:`run_with_deadline` (default 300s — bounded by default, because
-  the r6 outage hung rather than raised; 0 disables).
+  a wedged init hangs rather than raises; 0 disables).
 """
 
 from __future__ import annotations

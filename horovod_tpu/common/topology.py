@@ -81,16 +81,9 @@ def _device_counts() -> Tuple[int, int]:
         return 0, 0
 
 
-def detect(ranks: Optional[Sequence[int]] = None,
-           probe_devices: bool = True) -> Topology:
+def detect(ranks: Optional[Sequence[int]] = None) -> Topology:
     """Discover topology. ``ranks`` narrows the job to a subset, mirroring
     ``hvd.init(ranks)`` in the reference (``horovod/common/basics.py:29-55``).
-
-    ``probe_devices=False`` skips the JAX device-count probe entirely:
-    after backend acquisition failed its bounded retries (a wedged attempt
-    may still hold xla_bridge's backend lock), re-entering
-    ``jax.device_count()`` here would hang unboundedly — the caller
-    already knows there are no usable accelerators.
     """
     rank = _first_env_int(["HOROVOD_RANK", "OMPI_COMM_WORLD_RANK", "PMI_RANK"])
     size = _first_env_int(["HOROVOD_SIZE", "OMPI_COMM_WORLD_SIZE", "PMI_SIZE"])
@@ -102,8 +95,7 @@ def detect(ranks: Optional[Sequence[int]] = None,
             f"present (rank={rank}, size={size}); set both HOROVOD_RANK and "
             "HOROVOD_SIZE (or neither, to use the JAX process model)")
 
-    num_devices, local_num_devices = (
-        _device_counts() if probe_devices else (0, 0))
+    num_devices, local_num_devices = _device_counts()
 
     if rank is None:
         # No launcher env: fall back to the JAX process model.

@@ -110,8 +110,8 @@ class FaultInjected(RuntimeError):
 
 class InitWedged(FaultInjected):
     """Injected init failure (action "wedge"): the shape of a TPU backend
-    that hangs or errors K times before coming healthy (artifacts/
-    tpu_outage_r6.md) — retried by ``common/retry.py``."""
+    that hangs or errors K times before coming healthy — retried by
+    ``common/retry.py``."""
 
 
 @dataclasses.dataclass

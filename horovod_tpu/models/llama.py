@@ -614,7 +614,7 @@ def generate(model, variables, prompt_ids, max_new_tokens: int,
     ``unroll``: tokens decoded per ``lax.scan`` iteration (the loop body
     is replicated; the cache takes one in-place row write per token
     either way). >1 amortizes the fixed per-iteration while-loop cost
-    that dominates small-batch decode (``artifacts/decode_ceiling_r6``);
+    that dominates small-batch decode (``examples/decode_floor_probe.py``);
     identical tokens at any value.
 
     This is the inference counterpart of the training path the framework

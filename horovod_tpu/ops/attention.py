@@ -68,10 +68,18 @@ FLASH_DEFAULT_BLOCK_K = 1024
 
 
 def _auto_interpret() -> bool:
-    """Pallas interpreter mode off-TPU (hermetic CPU tests)."""
-    import jax as _jax
-    return _jax.default_backend() != "tpu"
-
+    """Compiled by Mosaic on ``tpu``, Pallas interpreter on ``cpu`` (the
+    hermetic tests). Any other backend is an error: silently interpreting
+    on a mis-named or unexpected platform would hide that the kernels
+    never reached the chip."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        "Pallas kernels here run compiled on 'tpu' or interpreted on "
+        f"'cpu'; jax.default_backend() is {backend!r}")
 
 
 def reference_attention(q, k, v, key_mask=None, causal=False,
@@ -562,8 +570,9 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
                     block_q: int = FLASH_DEFAULT_BLOCK_Q,
                     block_k: int = FLASH_DEFAULT_BLOCK_K,
                     interpret: Optional[bool] = None):
-    """Flash attention forward. ``interpret=None`` auto-selects Pallas
-    interpreter mode off-TPU (hermetic CPU tests run the same kernel).
+    """Flash attention forward. ``interpret=None`` compiles on ``tpu`` and
+    selects Pallas interpreter mode on ``cpu`` (the hermetic tests run the
+    same kernel); any other backend raises.
 
     ``causal`` with ``sq != sk`` follows the decode convention (matching
     ``reference_attention``): the sq query rows are the LAST sq positions
@@ -643,8 +652,6 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
                    else key_mask.astype(jnp.float32)),
                   causal, sm_scale, block_q, block_k, interpret,
                   key_mask is not None)
-
-
 
 
 def make_attention_fn(causal: bool = False, use_flash="auto",

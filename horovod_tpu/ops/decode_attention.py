@@ -22,7 +22,7 @@ bounded by HBM, not VMEM). FLOPs are ~2·L·D·H per program — noise next
 to the cache bytes — so memory-rate streaming IS the roofline.
 
 Used by ``horovod_tpu.models.llama._cached_attention`` for s == 1;
-interpret mode runs the same kernel off-TPU (hermetic CPU tests).
+interpret mode runs the same kernel on the CPU backend (hermetic tests).
 """
 
 from __future__ import annotations

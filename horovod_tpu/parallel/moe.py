@@ -113,8 +113,8 @@ def _route(probs: jax.Array, capacity: int, num_selected: int,
 # ---------------------------------------------------------------------------
 # Gather-only permutation (round 3): dispatch/combine and BOTH their
 # transposes run as row gathers. XLA's autodiff of a gather emits a
-# scatter-add, and TPU row scatters cost ~2.3x a gather (chip microbench
-# in artifacts/moe_dispatch_r3.json) — but a capacity slot is owned by at
+# scatter-add, and TPU row scatters cost ~2.3x a gather (round-3 chip
+# microbench) — but a capacity slot is owned by at
 # most ONE assignment, so every transpose is itself a gather through the
 # inverse slot->assignment map. The custom_vjps below encode that.
 

@@ -14,10 +14,15 @@ machinery exists; the launcher's jobs reduce to:
 Local ranks are direct children; remote hosts (``-H host:slots``) fan out
 over ssh with the env inlined (the reference's ``-x VAR`` passthrough,
 ``run/run.py:462-480``). On a TPU pod slice you typically run one process
-per host and let the SPMD tier drive all local chips; ``--bind-chips``
-instead partitions the host's chips among local ranks via
-``TPU_VISIBLE_DEVICES`` (one-chip-per-process, the reference's
-one-GPU-per-rank model).
+per host and let the SPMD tier drive all local chips.
+
+A chip belongs to one process at a time, so every rank's relation to the
+host's chips is decided here, never discovered by a failed init: a rank
+that is alone on its host keeps the platform it inherited (it owns every
+local chip); with ``--bind-chips`` local rank ``i`` owns exactly chip ``i``
+(one-chip-per-process, the reference's one-GPU-per-rank model — see
+:func:`chip_binding_env`); every other rank that shares a host is told it
+owns none (``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
@@ -88,6 +93,26 @@ def _is_local(host: str) -> bool:
     return host in ("localhost", "127.0.0.1", socket.gethostname())
 
 
+def chip_binding_env(local_rank: int) -> Dict[str, str]:
+    """Environment that makes libtpu give this process exactly one chip,
+    local chip ``local_rank``, as a complete one-chip topology of its own
+    (no cross-process slice is formed: the ranks talk through the eager
+    controller, not through ICI).
+
+    Established on a four-chip v5e host with libtpu 0.0.34: naming the
+    chip alone (``TPU_VISIBLE_CHIPS``, or the older ``TPU_VISIBLE_DEVICES``
+    with ``TPU_PROCESS_BOUNDS``) lets one rank in and fails the other three
+    on libtpu's multi-process lockfile; it is
+    ``TPU_CHIPS_PER_PROCESS_BOUNDS`` that declares the process a subset of
+    the host and allows several libtpu loads side by side."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(local_rank),
+        "TPU_VISIBLE_DEVICES": str(local_rank),    # the name before it
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 def build_rank_env(base: Dict[str, str], rank: int, size: int,
                    local_rank: int, local_size: int, cross_rank: int,
                    cross_size: int, controller_addr: str, secret: str,
@@ -146,8 +171,11 @@ def build_rank_env(base: Dict[str, str], rank: int, size: int,
     else:
         env["HOROVOD_CONTROLLER_ADDR"] = controller_addr
     if bind_chips:
-        env["TPU_VISIBLE_DEVICES"] = str(local_rank)
-        env["TPU_PROCESS_BOUNDS"] = f"1,1,1"
+        env.update(chip_binding_env(local_rank))
+    elif local_size > 1:
+        # Several unbound ranks share this host: none of them may take the
+        # chips (the first would win and the rest fail at init).
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -736,8 +764,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                         help="coordinator bind address host:port "
                              "(default: auto on rank-0 host)")
     parser.add_argument("--bind-chips", action="store_true",
-                        help="partition local TPU chips among local ranks via "
-                             "TPU_VISIBLE_DEVICES (one-chip-per-rank model)")
+                        help="give local rank i exactly local TPU chip i "
+                             "(one-chip-per-rank model); without it, ranks "
+                             "that share a host run with JAX_PLATFORMS=cpu")
     parser.add_argument("--spmd", action="store_true",
                         help="SPMD multi-host mode: ranks join the JAX "
                              "distributed runtime (one process per host, "

@@ -113,6 +113,9 @@ def async_result_entries(line: str, opcode: str, ents: List[tuple],
     return ents
 
 
+_INDEX_COMMENT_RE = re.compile(r"/\*index=\d+\*/")
+
+
 def collectives(compiled) -> List[Collective]:
     """Parse a ``jax`` compiled object (``jit(f).lower(...).compile()``)
     into its collective ops. Payload = the op's RESULT shape bytes (for
@@ -123,7 +126,12 @@ def collectives(compiled) -> List[Collective]:
     its own ring length."""
     out = []
     for line in compiled.as_text().splitlines():
+        # Tuples past five elements carry "/*index=5*/" position comments;
+        # their "=" would end the result-signature match below and a
+        # combined (many-operand) collective would go uncounted.
         s = line.strip()
+        if "/*index=" in s:
+            s = _INDEX_COMMENT_RE.sub("", s)
         # "%name = f32[...] all-reduce(...)" — opcode follows the result
         # signature; skip -start/-done pairs' duplicate (count -start).
         m = re.match(r"(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\(?[^=]*?)\s*"
