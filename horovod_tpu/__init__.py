@@ -19,6 +19,10 @@ Top-level surface mirrors ``import horovod.torch as hvd`` /
 
 __version__ = "0.1.0"
 
+import time as _time
+
+_IMPORT_START_NS = _time.perf_counter_ns()  # span "import": first line
+
 from .common.basics import (  # noqa: F401
     init,
     shutdown,
@@ -73,3 +77,6 @@ from .controller.bucket_scheduler import (  # noqa: F401
     partition_buckets,
     plan_from_compiled,
 )
+
+# Span "import": last line (see common/profiler.py; jax is most of it).
+profiler.record_span("import", _IMPORT_START_NS, _time.perf_counter_ns())

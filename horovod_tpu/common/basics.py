@@ -25,6 +25,7 @@ from typing import Optional, Sequence
 
 from . import config as config_mod
 from . import hvd_logging as logging
+from . import profiler
 from . import retry
 from .config import Config
 from .topology import Topology, detect
@@ -250,9 +251,22 @@ def init(ranks: Optional[Sequence[int]] = None) -> None:
         from ..run.watchdog import maybe_install_from_env
 
         maybe_install_from_env()
+        profiler.install_compile_listeners()
+        with profiler.span("init"):
+            _init_locked(config, ranks)
+
+
+def _init_locked(config: Config, ranks: Optional[Sequence[int]]) -> None:
+    """The body of :func:`init`, under ``_state_lock`` and the ``init``
+    span; its children say what a slow start was waiting for."""
+    global _state
+    with profiler.span("init.distributed"):
         _maybe_init_jax_distributed()
+    with profiler.span("init.backend"):
         _acquire_backend()
+    with profiler.span("init.topology"):
         topology = detect(ranks)
+    with profiler.span("init.controller"):
         logging.set_rank(topology.rank)
         _state = HorovodTpuState(config, topology)
         if metrics.on():

@@ -19,6 +19,23 @@ this module wires it up:
   env-var activation (``HOROVOD_PROFILE_DIR``).
 * ``annotate(name)`` / ``step(n)`` label host-side regions and training
   steps in the same trace.
+* The scope vocabulary (``SCOPE_*``, ``KERNEL_*``, :func:`collective_scope`)
+  is defined here and nowhere else: the names the program plants in the
+  compiled step, which a trace reader keys on.
+* ``span(name)`` / ``spans()`` keep a bounded in-memory log of what set-up
+  was doing (import, ``init`` and its children, mesh and placement, the
+  compile cache) on ``time.perf_counter_ns``; each span is also a
+  ``TraceAnnotation("hvd.<name>")``, so under an open profiler session
+  it lies on the device trace's clock too.
+* ``compile_events()`` keeps one record per jax monitoring event of the
+  compile path (outermost tracing, lowering, backend compile or cache
+  load, cache hits and misses) with the function's name: which program
+  compiled, and when. The listeners are registered once a process by ``hvd.init()``.
+
+None of this has a switch: scopes are compile-time metadata, spans and
+compile records are appends to a bounded list. "Tracing on" still means
+one thing, an open ``jax.profiler`` session (``HOROVOD_PROFILE_DIR`` or
+an explicit ``trace(log_dir)``).
 
 View traces with TensorBoard's profile plugin or Perfetto
 (``docs/timeline.md``).
@@ -26,18 +43,69 @@ View traces with TensorBoard's profile plugin or Perfetto
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
-from typing import Iterator, Optional
+import threading
+import time
+from typing import Iterator, NamedTuple, Optional
 
 from .config import env_str
 
 import jax
 
 __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
-           "named_scope", "PROFILE_DIR_ENV"]
+           "named_scope", "PROFILE_DIR_ENV",
+           "SCOPE_EXCHANGE", "SCOPE_UPDATE", "collective_scope",
+           "DECODE_PATHS", "decode_scope",
+           "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
+           "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
+           "Span", "span", "record_span", "spans", "spans_dropped",
+           "CompileEvent", "compile_events", "install_compile_listeners",
+           "COMPILE_EVENTS"]
 
 PROFILE_DIR_ENV = "HOROVOD_PROFILE_DIR"
+
+# ------------------------------------------------------- scope vocabulary
+# Device side: names that reach the compiled program (docs/timeline.md
+# lists them). Forward and backward carry no scope of ours: jax's name
+# stack marks them ``jvp(`` and ``transpose(``; flax names the modules.
+
+#: The gradient exchange of a step: every ``hvd.allreduce.<prefix>.<i>``
+#: of ``DistributedOptimizer`` / ``distributed_value_and_grad`` and
+#: compression's casts around them.
+SCOPE_EXCHANGE = "hvd.exchange"
+#: The wrapped optimizer's ``update`` inside ``DistributedOptimizer``.
+SCOPE_UPDATE = "hvd.update"
+
+#: ``name=`` of the Pallas kernels: what the Mosaic custom calls are
+#: called in the compiled program and the device trace.
+KERNEL_FLASH_FWD = "hvd_flash_fwd"
+KERNEL_FLASH_BWD_DQ = "hvd_flash_bwd_dq"
+KERNEL_FLASH_BWD_DKV = "hvd_flash_bwd_dkv"
+KERNEL_DECODE = "hvd_decode"
+KERNEL_PAGED_DECODE = "hvd_paged_decode"
+
+
+#: The decode attention paths of ``models/llama.py``, each under
+#: ``hvd.decode.<path>`` (``utils.comm_accounting.decode_path_markers``
+#: counts them in a compiled program).
+DECODE_PATHS = ("kernel_tp", "kernel", "einsum", "prefill", "paged_tp",
+                "paged")
+
+
+def decode_scope(path: str) -> str:
+    """``hvd.decode.<path>`` for one of :data:`DECODE_PATHS`."""
+    if path not in DECODE_PATHS:
+        raise ValueError(f"unknown decode path {path!r}")
+    return "hvd.decode." + path
+
+
+def collective_scope(opname: str, name: Optional[str] = None) -> str:
+    """``hvd.<op>[.<name>]``: the scope one traced collective runs under,
+    the name the eager timeline gives the same activity."""
+    return f"hvd.{opname}" + (f".{name}" if name else "")
+
 
 # Re-export: model code can label its own regions with the same mechanism
 # the collectives use; the labels land in HLO metadata (survive
@@ -94,3 +162,161 @@ def step(step_num: int):
     (``jax.profiler.StepTraceAnnotation``) — TensorBoard's profile
     plugin groups device activity by these."""
     return jax.profiler.StepTraceAnnotation("train", step_num=step_num)
+
+
+# ---------------------------------------------------------- the span log
+
+#: Prefix of a span's ``TraceAnnotation`` on the profiler's host plane.
+SPAN_PREFIX = "hvd."
+#: Spans kept; older ones are dropped and counted.
+MAX_SPANS = 4096
+
+
+class Span(NamedTuple):
+    """One finished region of host work, on ``time.perf_counter_ns``.
+    ``parent`` is the name of the span that was open on the same thread
+    when this one started, or ``None``."""
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[str]
+
+
+_log_lock = threading.Lock()
+_spans: collections.deque = collections.deque(maxlen=MAX_SPANS)
+_spans_dropped = 0
+_open = threading.local()
+
+
+def record_span(name: str, start_ns: int, end_ns: int,
+                parent: Optional[str] = None) -> None:
+    """Append a span that was timed by its caller (the package's import
+    stamps its own first and last line)."""
+    global _spans_dropped
+    with _log_lock:
+        if len(_spans) == _spans.maxlen:
+            _spans_dropped += 1
+        _spans.append(Span(name, start_ns, end_ns, parent))
+
+
+@contextlib.contextmanager
+def span(name: str) -> Iterator[None]:
+    """Record the enclosed block in the span log and, under an open
+    profiler session, as ``hvd.<name>`` on the trace's host plane::
+
+        with hvd.profiler.span("load_checkpoint"):
+            state = restore(path)
+
+    Costs two clock reads and a list append; no switch."""
+    stack = _open.__dict__.setdefault("stack", [])
+    parent = stack[-1] if stack else None
+    stack.append(name)
+    start = time.perf_counter_ns()
+    try:
+        with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+            yield
+    finally:
+        end = time.perf_counter_ns()
+        stack.pop()
+        record_span(name, start, end, parent)
+
+
+def spans() -> list:
+    """The span log, oldest first (at most :data:`MAX_SPANS`)."""
+    with _log_lock:
+        return list(_spans)
+
+
+def spans_dropped() -> int:
+    """Spans that fell off the log's old end since the process started."""
+    return _spans_dropped
+
+
+# ------------------------------------------------------- compile records
+
+_COMPILE_DURATIONS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+)
+_COMPILE_COUNTS = (
+    "/jax/compilation_cache/cache_hits",
+    "/jax/compilation_cache/cache_misses",
+)
+#: The jax monitoring events :func:`compile_events` keeps.
+COMPILE_EVENTS = _COMPILE_DURATIONS + _COMPILE_COUNTS
+#: Compile records kept; older ones are dropped.
+MAX_COMPILE_EVENTS = 4096
+
+
+class CompileEvent(NamedTuple):
+    """One jax monitoring event of the compile path. ``fun_name`` is the
+    jitted function's name where jax gives one (the three
+    ``/jax/core/compile`` durations: ``f`` while tracing, ``jit(f)`` from
+    lowering on), ``seconds`` is ``None`` for the cache-hit and cache-miss
+    counts, ``at_ns`` is when the event arrived (for a duration: when it
+    ended) on ``time.perf_counter_ns``. Tracing and lowering nest (an
+    inner ``jit`` inside the outer one's tracing, a kernel body traced
+    while it is lowered): only the outermost region of a thread is kept,
+    and its record holds the inner ones' time."""
+    event: str
+    fun_name: Optional[str]
+    seconds: Optional[float]
+    at_ns: int
+
+
+_compile_events: collections.deque = collections.deque(
+    maxlen=MAX_COMPILE_EVENTS)
+_listening = False
+# jax times these three as regions (a start, then a duration) and they
+# nest: every inner ``jit`` (each ``jnp`` function is one) is traced inside
+# the tracing of the outer one, and lowering a kernel traces its body.
+_COMPILE_REGIONS = _COMPILE_DURATIONS[:3]
+_region = threading.local()
+
+
+def _on_start(event: str, value: float, **kwargs) -> None:
+    # jax announces the start of each timed region as a scalar.
+    if event in _COMPILE_REGIONS:
+        _region.depth = getattr(_region, "depth", 0) + 1
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event not in _COMPILE_DURATIONS:
+        return
+    if event in _COMPILE_REGIONS:
+        # Thousands of inner records a step, all within the outermost
+        # region of this thread, which alone is kept.
+        _region.depth = max(getattr(_region, "depth", 1) - 1, 0)
+        if _region.depth:
+            return
+    _compile_events.append(CompileEvent(
+        event, kwargs.get("fun_name"), float(duration),
+        time.perf_counter_ns()))
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event in _COMPILE_COUNTS:
+        _compile_events.append(CompileEvent(
+            event, kwargs.get("fun_name"), None, time.perf_counter_ns()))
+
+
+def install_compile_listeners() -> None:
+    """Register the jax monitoring listeners behind
+    :func:`compile_events`, once a process (``hvd.init()`` calls this)."""
+    global _listening
+    with _log_lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_scalar_listener(_on_start)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
+
+
+def compile_events() -> list:
+    """Every compile-path event since ``hvd.init()``, oldest first. A
+    ``backend_compile_duration`` record stamped after the first step is a
+    recompilation: its ``fun_name`` says of what."""
+    return list(_compile_events)

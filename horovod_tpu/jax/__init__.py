@@ -22,7 +22,7 @@ from typing import Any, Callable, Optional
 import jax
 import optax
 
-from ..common import basics
+from ..common import basics, profiler
 from ..compression import Compression
 from .zero import zero_sharded_optimizer  # noqa: F401
 from .fsdp import (  # noqa: F401
@@ -118,10 +118,12 @@ def DistributedOptimizer(
         return optimizer.init(params)
 
     def update_fn(updates, state, params=None, **extra):
-        reduced = _allreduce_tree(updates, average=average,
-                                  axis_name=axis_name, name_prefix=name,
-                                  compression=compression)
-        return optimizer.update(reduced, state, params, **extra)
+        with jax.named_scope(profiler.SCOPE_EXCHANGE):
+            reduced = _allreduce_tree(updates, average=average,
+                                      axis_name=axis_name, name_prefix=name,
+                                      compression=compression)
+        with jax.named_scope(profiler.SCOPE_UPDATE):
+            return optimizer.update(reduced, state, params, **extra)
 
     tx = optax.GradientTransformation(init_fn, update_fn)
     if backward_passes_per_step > 1:
@@ -145,8 +147,10 @@ def distributed_value_and_grad(
 
     def wrapped(*args, **kwargs):
         value, grads = vag(*args, **kwargs)
-        grads = _allreduce_tree(grads, average=average, axis_name=axis_name,
-                                name_prefix="DistributedGrad")
+        with jax.named_scope(profiler.SCOPE_EXCHANGE):
+            grads = _allreduce_tree(grads, average=average,
+                                    axis_name=axis_name,
+                                    name_prefix="DistributedGrad")
         return value, grads
 
     return wrapped

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import profiler
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -276,7 +278,7 @@ def _cached_attention(q, k, v, cache, cache_index):
         tables = cache["tables"]
         if _DECODE_KERNEL and _DECODE_TP is not None:
             mesh, head_axis, batch_axis = _DECODE_TP
-            with jax.named_scope("hvd.decode.paged_tp"):
+            with jax.named_scope(profiler.decode_scope("paged_tp")):
                 ctx, k_pool, v_pool = sharded_paged_decode_step(
                     q, kc, vc, cache["k"], cache["v"], tables,
                     cache_index, hkv, mesh=mesh, head_axis=head_axis,
@@ -285,14 +287,14 @@ def _cached_attention(q, k, v, cache, cache_index):
             k_pool, v_pool = paged_cache_write(
                 cache["k"], cache["v"], kc, vc, tables, cache_index)
             if _DECODE_KERNEL:
-                with jax.named_scope("hvd.decode.paged"):
+                with jax.named_scope(profiler.decode_scope("paged")):
                     ctx = paged_decode_attention(
                         q, k_pool, v_pool, tables, cache_index, hkv,
                         sm_scale=scale)
             else:
                 # The gather-einsum fallback shares the einsum marker:
                 # it IS the einsum path, reading through the tables.
-                with jax.named_scope("hvd.decode.einsum"):
+                with jax.named_scope(profiler.decode_scope("einsum")):
                     ctx = paged_gather_attention(
                         q, k_pool, v_pool, tables, cache_index, hkv,
                         sm_scale=scale)
@@ -304,7 +306,7 @@ def _cached_attention(q, k, v, cache, cache_index):
         from ..ops.decode_attention import sharded_decode_step
 
         mesh, head_axis, batch_axis = _DECODE_TP
-        with jax.named_scope("hvd.decode.kernel_tp"):
+        with jax.named_scope(profiler.decode_scope("kernel_tp")):
             ctx, k_cache, v_cache = sharded_decode_step(
                 q, kc, vc, cache["k"], cache["v"], cache_index, hkv,
                 mesh=mesh, head_axis=head_axis, batch_axis=batch_axis,
@@ -318,7 +320,7 @@ def _cached_attention(q, k, v, cache, cache_index):
     if s == 1 and _DECODE_KERNEL:
         from ..ops.decode_attention import decode_attention
 
-        with jax.named_scope("hvd.decode.kernel"):
+        with jax.named_scope(profiler.decode_scope("kernel")):
             ctx = decode_attention(q, k_cache, v_cache, cache_index, hkv,
                                    sm_scale=scale)
         return ctx, {"k": k_cache, "v": v_cache}
@@ -329,7 +331,7 @@ def _cached_attention(q, k, v, cache, cache_index):
         # CACHE-DTYPE rows (kc/vc), so prefill sees exactly the values
         # every later decode step reads back — one semantics across
         # paths even when the cache dtype quantizes.
-        with jax.named_scope("hvd.decode.prefill"):
+        with jax.named_scope(profiler.decode_scope("prefill")):
             qg = q.reshape(b, s, hkv, group, d)
             logits = jnp.einsum("bshgd,blhd->bshgl", qg, kc).astype(
                 jnp.float32) * scale
@@ -341,7 +343,7 @@ def _cached_attention(q, k, v, cache, cache_index):
         return ctx.reshape(b, s, h, d), {"k": k_cache, "v": v_cache}
     # General path (einsum over the 4D view; also the s == 1 path under
     # exotic multi-device sharding — see _DECODE_KERNEL above).
-    with jax.named_scope("hvd.decode.einsum"):
+    with jax.named_scope(profiler.decode_scope("einsum")):
         qg = q.reshape(b, s, hkv, group, d)
         k4 = k_cache.reshape(b, window, hkv, d)
         v4 = v_cache.reshape(b, window, hkv, d)

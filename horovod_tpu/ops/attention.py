@@ -31,6 +31,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import profiler
+
 NEG_INF = -1e30
 
 
@@ -297,6 +299,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name=profiler.KERNEL_FLASH_FWD,
     )(qf, kf, vf, maskf)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
 
@@ -454,6 +457,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=profiler.KERNEL_FLASH_BWD_DQ,
     )(qf, kf, vf, maskf, dof, lse, delta)
 
     # GQA-native dkdv: grid rows are K/V heads (b*hkv), the query group is
@@ -500,6 +504,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name=profiler.KERNEL_FLASH_BWD_DKV,
     )(qf, kf, vf, maskf, dof, lse, delta)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..common import basics
+from ..common import basics, profiler
 from ..common.handles import Handle, HandleManager
 
 # Reduction op constants. The reference expresses Average as a client-side
@@ -81,9 +81,8 @@ def _traced_collective(tensor, axis_name, fn, opname: str = "collective",
     sharding annotations — and under single-process tracing (e.g. inside
     ``optax.MultiSteps``' ``lax.cond``) identity is the size-1 semantics."""
     ax = _resolve_axis(axis_name)
-    scope = f"hvd.{opname}" + (f".{name}" if name else "")
     try:
-        with jax.named_scope(scope):
+        with jax.named_scope(profiler.collective_scope(opname, name)):
             return fn(tensor, ax)
     except NameError:
         from ..common import hvd_logging as logging

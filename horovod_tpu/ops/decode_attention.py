@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from ..common import profiler
 from .attention import NEG_INF, _auto_interpret
 
 # Default L-tile: 2 * block_l * (Hkv*D) * 2 bytes of streamed K/V per
@@ -207,6 +208,7 @@ def decode_attention(q, k_cache, v_cache, cache_index, num_kv_heads,
             jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
         ],
         interpret=interpret,
+        name=profiler.KERNEL_DECODE,
     )(idx, w, k_cache, v_cache)
     # Normalize + transpose OUTSIDE the kernel: tiny (b, d, h) tensors,
     # no cache involvement ((1, h) -> (h, 1) is not Mosaic-legal).
@@ -356,6 +358,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
             jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
         ],
         interpret=interpret,
+        name=profiler.KERNEL_PAGED_DECODE,
     )(lens, tables, w, k_pool, v_pool)
     out = ctx_dh / jnp.maximum(l, 1e-30)
     return jnp.swapaxes(out, 1, 2).astype(q.dtype).reshape(b, 1, h, d)

@@ -21,6 +21,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..common import profiler
+
 DATA_AXIS = "data"
 
 _lock = threading.Lock()
@@ -32,6 +34,7 @@ def axis_size(axis_name: str) -> int:
     return jax.lax.psum(1, axis_name)
 
 
+@profiler.span("make_mesh")
 def make_mesh(
     axes: Optional[Mapping[str, int]] = None,
     devices: Optional[Sequence[jax.Device]] = None,
@@ -198,11 +201,13 @@ def shard_batch(tree, m: Optional[Mesh] = None):
     """
     m = m or mesh()
     sh = NamedSharding(m, PartitionSpec(DATA_AXIS))
-    return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh), tree)
+    with profiler.span("shard_batch"):
+        return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh), tree)
 
 
 def replicate(tree, m: Optional[Mesh] = None):
     """Replicate a pytree (params/optimizer state) across the mesh."""
     m = m or mesh()
     sh = NamedSharding(m, PartitionSpec())
-    return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh), tree)
+    with profiler.span("replicate"):
+        return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh), tree)

@@ -25,6 +25,8 @@ import dataclasses
 import re
 from typing import Dict, List
 
+from ..common import profiler
+
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
@@ -176,9 +178,8 @@ def payload_by_op(colls: List[Collective]) -> Dict[str, int]:
 #: Python-side record (``models.llama.LAST_DECODE_PATH`` is the cheap
 #: twin). The same labels show up as ``tf_op_name`` prefixes in profiler
 #: traces, so phase tables attribute attention time per path too.
-DECODE_PATH_MARKERS = ("hvd.decode.kernel_tp", "hvd.decode.kernel",
-                       "hvd.decode.einsum", "hvd.decode.prefill",
-                       "hvd.decode.paged_tp", "hvd.decode.paged")
+DECODE_PATH_MARKERS = tuple(profiler.decode_scope(path)
+                            for path in profiler.DECODE_PATHS)
 
 
 def decode_path_markers(compiled_or_text) -> Dict[str, int]:

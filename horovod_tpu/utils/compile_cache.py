@@ -9,19 +9,29 @@ never by the tests.
 
 The rule (one, so the cache can be placed from outside):
 
-* ``JAX_COMPILATION_CACHE_DIR`` set — do nothing. jax reads the variable
-  itself; no code sets another directory.
+* ``JAX_COMPILATION_CACHE_DIR`` set — leave the directory alone. jax
+  reads the variable itself; no code sets another directory.
 * unset — point jax at ``<checkout>/.jax_cache`` (git-ignored). The path
   is built from the checkout's location alone: a directory that moves
   between runs (``tempfile``, a pid, a timestamp) would never hit. The
   minimum-compile-time and minimum-size thresholds drop to zero so every
   program of a run is kept and a second run compiles nothing.
+
+Either way the cache's key includes the program's location metadata
+(``jax_compilation_cache_include_metadata_in_key``). jax leaves it out by
+default, and a program that differs from a cached one only in a
+``named_scope`` then gets the cached executable back *with the cached
+names*: a trace read by scope (``common/profiler.py``) would show another
+commit's. The price: two commits that differ only in the line numbers of
+traced code no longer share an entry; within one commit every run after
+the first stays warm.
 """
 
 from __future__ import annotations
 
 import os
 
+from ..common import profiler
 from ..common.config import env_str
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -36,14 +46,16 @@ def default_dir() -> str:
     return os.path.join(_CHECKOUT, ".jax_cache")
 
 
+@profiler.span("compile_cache.enable")
 def enable() -> str:
     """Turn the persistent compile cache on (see module docstring) and
     return the directory in use. Call before the first compilation."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = env_str(ENV_VAR)
     if placed:
         return placed
-    import jax
-
     path = default_dir()
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

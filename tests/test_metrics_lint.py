@@ -180,7 +180,10 @@ def test_trace_phase_names_fixed_vocabulary():
     — and every phase must actually be emitted somewhere."""
     from horovod_tpu.trace import ALL_PHASES
 
-    span_call = re.compile(r"\.span\(\s*\n?\s*[\"']([a-z_]+)[\"']")
+    # ``hvd.profiler.span`` is the SPMD tier's set-up log, another plane
+    # with names of its own (test_profiler_span_names_are_documented).
+    span_call = re.compile(
+        r"(?<!profiler)\.span\(\s*\n?\s*[\"']([a-z_]+)[\"']")
     found = []
     for path in _package_sources():
         with open(path) as f:
@@ -195,6 +198,23 @@ def test_trace_phase_names_fixed_vocabulary():
     assert {n for n, _ in found} == set(ALL_PHASES), (
         "a phase in the fixed vocabulary is never emitted: "
         f"{set(ALL_PHASES) - {n for n, _ in found}}")
+
+
+def test_profiler_span_names_are_documented():
+    """Every span the package plants through ``hvd.profiler.span`` /
+    ``record_span`` is listed in ``docs/timeline.md``, where an operator
+    reading ``hvd.profiler.spans()`` looks its name up."""
+    span_call = re.compile(
+        r"(?<!hvd\.)profiler\.(?:span|record_span)\(\s*[\"']([a-z_.]+)[\"']")
+    found = set()     # (``hvd.profiler.span(...)`` is a docstring's example)
+    for path in _package_sources():
+        with open(path) as f:
+            found.update(span_call.findall(f.read()))
+    assert {"import", "init", "init.backend", "make_mesh"} <= found
+    with open(os.path.join(REPO, "docs", "timeline.md")) as f:
+        documented = f.read()
+    missing = sorted(n for n in found if f"`{n}`" not in documented)
+    assert not missing, f"spans not in docs/timeline.md: {missing}"
 
 
 def test_no_import_time_registration():
