@@ -15,6 +15,11 @@ calls, in ONE process (a chip belongs to one process at a time):
   flash_one_tile     ops.attention's one-tile path at seq 512 against
                      reference_attention in float32: causal GQA at head
                      width 128, float32 with a key mask, causal sq != sk
+  window_and_experts ops.attention's streamed kernels under a causal band
+                     with a window (two blocks a side, causal GQA at head
+                     width 128) and parallel.moe.moe_apply_held with a
+                     share of the experts at SmallThinker's widths, each
+                     against its float32 reference, forward and gradients
   generate_llama300m models.llama.generate: contiguous decode kernel
   serve_llama300m    hvd.serving.serve: paged decode kernel, default
                      block_size
@@ -424,6 +429,143 @@ def phase_flash_one_tile(sz, rehearsal):
     return {"compile_s": compile_s, "run_s": run_s, "checks": checks}
 
 
+def phase_window_and_experts(sz, rehearsal):
+    """What PR 26 added to the step, off the benchmark's own shape: the
+    streamed flash kernels with ``window`` (blocks the band skips, blocks
+    its edges cross, blocks wholly inside) against ``reference_attention``
+    on float32 copies at the highest matmul precision, and the dropless
+    expert layer holding 4 of 16 experts (3 chosen a token, hidden 2560,
+    expert width 768) against every held expert applied densely in
+    float32. Both in bf16: a few ulps of bf16 of the largest value, and
+    never more than 2^-5 of it; the expert layer's count of what landed
+    here must be the count of chosen ids that are held."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops import attention
+    from horovod_tpu.ops.attention import flash_attention, reference_attention
+    from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
+
+    rng = np.random.RandomState(1)
+    checks, compile_s, run_s = [], 0.0, 0.0
+
+    def rand(*shape, scale=1.0, dtype=jnp.bfloat16):
+        return jnp.asarray(scale * rng.randn(*shape).astype(np.float32),
+                           dtype)
+
+    def timed(fn, *args):
+        nonlocal compile_s, run_s
+        t0 = _now()
+        jax.block_until_ready(fn(*args))
+        t1 = _now()
+        got = jax.block_until_ready(fn(*args))
+        t2 = _now()
+        run_s += t2 - t1
+        compile_s += max((t1 - t0) - (t2 - t1), 0.0)
+        return [np.asarray(x, np.float32) for x in jax.tree.leaves(got)]
+
+    def worst(got, want):
+        return max(float(np.max(np.abs(g - r)) / np.max(np.abs(r)))
+                   for g, r in zip(got, want))
+
+    tolerance = 2.0 ** -5
+
+    # -- the window: two default blocks a side, so the kernels stream.
+    seq = 256 if rehearsal else 2048
+    blocks = dict(block_q=64, block_k=128) if rehearsal else {}
+    window = seq // 2 + seq // 8        # crosses blocks off their borders
+    q, k, v, w = (rand(2, seq, 8, 128), rand(2, seq, 2, 128),
+                  rand(2, seq, 2, 128), rand(2, seq, 8, 128))
+
+    def attention_grads(attn, **kw):
+        def fn(q, k, v):
+            def loss(q, k, v):
+                out = attn(q, k, v, causal=True, window=window, **kw)
+                return jnp.sum(out.astype(jnp.float32)
+                               * w.astype(jnp.float32)), out
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (out,) + grads
+        return jax.jit(fn)
+
+    flash = attention_grads(flash_attention, **blocks)
+    names = set(re.findall(r"hvd_flash_\w+",
+                           flash.lower(q, k, v).as_text(debug_info=True)))
+    check(names == {"hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"}
+          and not attention._one_tile_path(
+              q, k, blocks.get("block_q", attention.FLASH_DEFAULT_BLOCK_Q),
+              blocks.get("block_k", attention.FLASH_DEFAULT_BLOCK_K)),
+          f"window: kernels {sorted(names)}, streamed by the shape rule")
+    with jax.default_matmul_precision("highest"):
+        want = timed(attention_grads(reference_attention),
+                     *(x.astype(jnp.float32) for x in (q, k, v)))
+    err = worst(timed(flash, q, k, v), want)
+    checks.append(check(
+        err <= tolerance,
+        f"window {window} of {seq}: out/dq/dk/dv within {err:.2e} of "
+        "max|reference|"))
+
+    # -- the expert layer with a share.
+    tokens, hidden, width = (512, 128, 96) if rehearsal else (4096, 2560, 768)
+    experts, held, chosen = 16, (4, 5, 6, 7), 3
+    x = rand(tokens, hidden)
+    logits = rand(tokens, experts, dtype=jnp.float32)
+    # Float32 weights that bf16 holds exactly: the layer's cast of them is
+    # then no rounding, and no gate changes sign between the two sides (a
+    # ReLU's gradient is not continuous there).
+    params = {name: rand(len(held), *shape, scale=shape[0] ** -0.5).astype(
+        jnp.float32) for name, shape in (("w_gate", (hidden, width)),
+                                         ("w_up", (hidden, width)),
+                                         ("w_down", (width, hidden)))}
+    target = rand(tokens, hidden)
+
+    def held_layer(params, x, logits):
+        def loss(params, x, logits):
+            y, load = moe_apply_held(grouped_gated_mlp, params, x, logits,
+                                     held, chosen)
+            return jnp.sum(y.astype(jnp.float32)
+                           * target.astype(jnp.float32)), (y, load)
+        (_, (y, load)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(params, x, logits)
+        return (y,) + grads, load
+
+    def dense_layer(params, x, logits):
+        def loss(params, x, logits):
+            top, ids = jax.lax.top_k(logits, chosen)
+            weights = jnp.zeros_like(logits).at[
+                jnp.arange(tokens)[:, None], ids].set(
+                jax.nn.softmax(top, axis=-1))[:, jnp.array(held)]
+            mid = jax.nn.relu(jnp.einsum(
+                "td,edf->etf", x, params["w_gate"])) * jnp.einsum(
+                "td,edf->etf", x, params["w_up"])
+            y = jnp.einsum("etd,te->td", jnp.einsum(
+                "etf,efd->etd", mid, params["w_down"]), weights)
+            return jnp.sum(y * target.astype(jnp.float32)), y
+        (_, y), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(params, x, logits)
+        return (y,) + grads
+
+    with jax.default_matmul_precision("highest"):
+        want = timed(jax.jit(dense_layer), params, x.astype(jnp.float32),
+                     logits)
+    *got, load = timed(jax.jit(held_layer), params, x, logits)
+    landed = int(np.isin(np.argsort(-np.asarray(logits), axis=-1)[:, :chosen],
+                         held).sum())
+    check(int(load.sum()) == landed,
+          f"experts: {int(load.sum())} assignments landed, {landed} chosen "
+          "ids are held")
+    err = worst(got, want)
+    checks.append(check(
+        err <= tolerance,
+        f"experts {held} of {experts}, {landed} of {tokens * chosen} "
+        f"assignments here: y/dw/dx/dlogits within {err:.2e} of "
+        "max|reference|"))
+    return {"compile_s": compile_s, "run_s": run_s, "checks": checks}
+
+
 def _lm_on_one_device(sz):
     """Model, variables and a prompt for the decode phases — on the
     default device (generate/serve are one-device paths; says which)."""
@@ -630,6 +772,7 @@ def main():
     for name, fn in (("train_resnet50", phase_train_resnet50),
                      ("train_llama300m", phase_train_llama),
                      ("flash_one_tile", phase_flash_one_tile),
+                     ("window_and_experts", phase_window_and_experts),
                      ("generate_llama300m", phase_generate),
                      ("serve_llama300m", phase_serve)):
         t0 = _now()

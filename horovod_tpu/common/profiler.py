@@ -57,6 +57,8 @@ import jax
 __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "named_scope", "PROFILE_DIR_ENV",
            "SCOPE_EXCHANGE", "SCOPE_UPDATE", "collective_scope",
+           "SCOPE_MOE_ROUTE", "SCOPE_MOE_DISPATCH", "SCOPE_MOE_EXPERTS",
+           "SCOPE_MOE_COMBINE",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
@@ -77,6 +79,17 @@ PROFILE_DIR_ENV = "HOROVOD_PROFILE_DIR"
 SCOPE_EXCHANGE = "hvd.exchange"
 #: The wrapped optimizer's ``update`` inside ``DistributedOptimizer``.
 SCOPE_UPDATE = "hvd.update"
+
+#: The phases of the dropless expert layer
+#: (``parallel/moe.py::moe_apply_held``), forward and backward alike:
+#: choosing each token's experts and their weights; sorting the
+#: assignments that land on this device by expert and gathering their
+#: rows; the grouped products of the experts held here; the weighted sum
+#: back into token order.
+SCOPE_MOE_ROUTE = "hvd.moe.route"
+SCOPE_MOE_DISPATCH = "hvd.moe.dispatch"
+SCOPE_MOE_EXPERTS = "hvd.moe.experts"
+SCOPE_MOE_COMBINE = "hvd.moe.combine"
 
 #: ``name=`` of the Pallas kernels: what the Mosaic custom calls are
 #: called in the compiled program and the device trace.

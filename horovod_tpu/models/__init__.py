@@ -34,6 +34,12 @@ from .moe_lm import (  # noqa: F401
     MoeLM,
 )
 from .mlp import MnistMLP  # noqa: F401
+from .smallthinker import (  # noqa: F401
+    SMALLTHINKER_21B,
+    SMALLTHINKER_TINY,
+    SmallThinkerConfig,
+    SmallThinkerLM,
+)
 from .resnet import (  # noqa: F401
     ResNet,
     ResNet50,

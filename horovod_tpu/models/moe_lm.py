@@ -13,6 +13,12 @@ expert-parallel substrate (``parallel/moe.py``):
   ``all_to_all`` over ICI (``moe_apply``). The routing (and therefore the
   numerics) is identical in both modes.
 
+One expert per device is this model's sharded mode, not the package's
+only one: ``parallel.moe.moe_apply_held`` is a dropless layer that is told
+which experts (several, any subset) a device holds, and
+``models/smallthinker.py`` is built on it. The capacity path here keeps
+its fixed ``[E, C, D]`` buffers because ``all_to_all`` needs them.
+
 The MLM/causal losses and non-MoE machinery are shared with the Llama
 family. Aux (load-balancing) losses from every MoE layer are summed into
 the ``"aux_loss"`` collection — fold ``sum(aux) * aux_weight`` into the

@@ -129,15 +129,29 @@ def _auto_interpret() -> bool:
         f"'cpu'; jax.default_backend() is {backend!r}")
 
 
+def _check_window(window, causal: bool, name: str) -> None:
+    if window is None:
+        return
+    if not causal:
+        raise ValueError(f"{name}: window={window} bounds the causal band "
+                         "from below and needs causal=True")
+    if window < 1:
+        raise ValueError(f"{name}: window must be at least 1, got {window}")
+
+
 def reference_attention(q, k, v, key_mask=None, causal=False,
-                        sm_scale: Optional[float] = None):
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None):
     """Plain XLA attention; also the backward-path recompute.
 
     Shapes: q (B, Sq, H, D); k/v (B, Sk, Hkv, D) with H % Hkv == 0
     (grouped-query attention: K/V repeat across each group of
-    H // Hkv query heads); key_mask (B, Sk) bool."""
+    H // Hkv query heads); key_mask (B, Sk) bool. ``window`` (causal
+    only): the query at position i sees the keys ``i - window < j <= i``,
+    itself and the ``window - 1`` before it."""
     d = q.shape[-1]
     _check_gqa_heads(q, k, v, "reference_attention")
+    _check_window(window, causal, "reference_attention")
     k, v = repeat_kv(q, k, v)
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -147,7 +161,10 @@ def reference_attention(q, k, v, key_mask=None, causal=False,
         sq, sk = q.shape[1], k.shape[1]
         qi = jnp.arange(sq)[:, None] + (sk - sq)
         ki = jnp.arange(sk)[None, :]
-        logits = jnp.where((ki <= qi)[None, None, :, :], logits, NEG_INF)
+        band = ki <= qi
+        if window is not None:
+            band = band & (ki > qi - window)
+        logits = jnp.where(band[None, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
 
@@ -157,30 +174,69 @@ def reference_attention(q, k, v, key_mask=None, causal=False,
 _STATE_LANES = 128
 
 
-def _allowed_mask(mask_ref, has_mask: bool, causal: bool, qb, kb,
-                  block_q: int, block_k: int, q_offset: int):
+def _allowed_mask(mask_ref, has_mask: bool, band: bool, qb, kb,
+                  block_q: int, block_k: int, q_offset: int,
+                  window: Optional[int] = None):
     """The (block_q, block_k) allowed-entry mask, or None when every entry
-    is allowed (no key mask given AND not causal) so the callers skip the
-    where/zeroing VPU passes entirely. ``has_mask`` is static — the
-    public entry knows at trace time whether a key mask was supplied."""
+    is allowed (no key mask given AND the block needs no ``band`` mask) so
+    the callers skip the where/zeroing VPU passes entirely. ``has_mask``
+    is static — the public entry knows at trace time whether a key mask
+    was supplied. ``band``: mask by the causal band, the diagonal above
+    and, with ``window``, the edge ``window`` keys below it."""
     allowed = None
     if has_mask:
         allowed = jnp.broadcast_to((mask_ref[0, 0] != 0)[None, :],
                                    (block_q, block_k))
-    if causal:
+    if band:
         q_pos = qb * block_q + q_offset + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         k_pos = kb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         tri = k_pos <= q_pos
+        if window is not None:
+            tri = tri & (k_pos > q_pos - window)
         allowed = tri if allowed is None else (allowed & tri)
     return allowed
+
+
+def _band_blocks(window: Optional[int], qb, kb, block_q: int, block_k: int,
+                 q_offset: int):
+    """Where the (qb, kb) block lies against the causal band: ``(live,
+    edge)``. ``live``: some entry is inside the band (a block wholly
+    above the diagonal, or wholly more than ``window`` keys below it,
+    touches no allowed entry and its compute is skipped; the DMA still
+    runs, grid fetches are static). ``edge``: an edge of the band crosses
+    the block, so its entries need the mask; a live block that no edge
+    crosses lies wholly inside and takes the body without the band's
+    mask."""
+    q_lo = qb * block_q + q_offset
+    q_hi = q_lo + block_q - 1
+    k_lo = kb * block_k
+    k_hi = k_lo + block_k - 1
+    live = k_lo <= q_hi
+    inside = k_hi <= q_lo
+    if window is not None:
+        live = live & (k_hi > q_lo - window)
+        inside = inside & (k_lo > q_hi - window)
+    return live, live & jnp.logical_not(inside)
+
+
+def _when_banded(causal: bool, window: Optional[int], qb, kb, block_q: int,
+                 block_k: int, q_offset: int, body):
+    """Run ``body(band)`` for this block: not at all outside the band,
+    with the band's mask where an edge crosses it, without it inside."""
+    if not causal:
+        body(False)
+        return
+    live, edge = _band_blocks(window, qb, kb, block_q, block_k, q_offset)
+    pl.when(edge)(lambda: body(True))
+    pl.when(live & jnp.logical_not(edge))(lambda: body(False))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *, block_k: int, sm_scale: float,
                   causal: bool, num_kb: int, block_q: int, q_offset: int,
-                  has_mask: bool):
+                  has_mask: bool, window: Optional[int] = None):
     # Grid (bh, qb, kb), kb innermost. Block shapes: q (1, block_q, d)
     # (constant across kb — fetched once), k/v (1, block_k, d) (a NEW tile
     # streams in from HBM each kb step), mask (1, 1, block_k). Running
@@ -197,14 +253,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: K blocks strictly above the diagonal touch no allowed entry;
-    # skip their compute entirely (the DMA still runs — grid fetches are
-    # static — but the MXU work, the dominant cost, is elided).
-    live = ((kb * block_k <= qb * block_q + block_q - 1 + q_offset)
-            if causal else True)
-
-    @pl.when(live)
-    def _body():
+    # Causal: K blocks wholly outside the band (above the diagonal, or
+    # more than ``window`` keys below it) are skipped, and only the blocks
+    # an edge of the band crosses build its mask: ``_when_banded``.
+    def _body(band):
         m = m_scr[:, :1]
         l = l_scr[:, :1]
         # MXU in the INPUT dtype with f32 accumulation: bf16 q/k run at
@@ -215,8 +267,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        allowed = _allowed_mask(mask_ref, has_mask, causal, qb, kb,
-                                block_q, block_k, q_offset)
+        allowed = _allowed_mask(mask_ref, has_mask, band, qb, kb,
+                                block_q, block_k, q_offset, window)
         if allowed is not None:
             s = jnp.where(allowed, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -236,6 +288,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, _body)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
@@ -355,7 +409,8 @@ _TN = ((0,), (0,))   # a.T @ b
 
 
 def _one_tile_scores(rows, cols, bias, causal: bool, q_axis: int,
-                     q_offset: int, sm_scale: float):
+                     q_offset: int, sm_scale: float,
+                     window: Optional[int] = None):
     """The masked, scaled f32 scores ``rows @ cols.T`` of one head, the
     queries along ``q_axis`` of the tile; the scale goes on the f32
     product as in the streamed kernels. ``bias`` is the key mask as one
@@ -370,13 +425,17 @@ def _one_tile_scores(rows, cols, bias, causal: bool, q_axis: int,
         q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                     q_axis)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        band = k_pos <= q_pos
+        if window is not None:
+            band = band & (k_pos > q_pos - window)
+        s = jnp.where(band, s, NEG_INF)
     return s
 
 
 def _one_tile_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                          sm_scale: float, causal: bool, q_offset: int,
-                         has_mask: bool, heads: int, group: int):
+                         has_mask: bool, heads: int, group: int,
+                         window: Optional[int] = None):
     # Blocks: q/o (heads*group, sq, d), k/v (heads, sk, d), bias (1, 1, sk),
     # lse (heads*group, 1, sq). Query head kh*group + j reads K/V head kh.
     # The tile is held TRANSPOSED, (sk, sq): the softmax statistics then
@@ -391,7 +450,7 @@ def _one_tile_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         k, v = k_ref[kh], v_ref[kh]
         for g in range(kh * group, (kh + 1) * group):
             s = _one_tile_scores(k, q_ref[g], bias, causal, 1, q_offset,
-                                 sm_scale)                       # (sk, sq)
+                                 sm_scale, window)               # (sk, sq)
             m = jnp.max(s, axis=0, keepdims=True)
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=0, keepdims=True)
@@ -406,13 +465,14 @@ def _one_tile_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
 
 
 def _one_tile_p_ds(q, k, v, do, bias, lse, delta, *, sm_scale: float,
-                   causal: bool, q_offset: int):
+                   causal: bool, q_offset: int,
+                   window: Optional[int] = None):
     """``(p, ds)`` of one head for the two backward kernels, both as f32
     TRANSPOSED (sk, sq) tiles like the forward's: ``lse`` and ``delta``
     are then the (1, sq) lane rows they are stored as and broadcast down
     the sublanes with no relayout. ``ds`` lacks the factor ``sm_scale``,
     which the callers put on their (rows, d) results."""
-    s = _one_tile_scores(k, q, bias, causal, 1, q_offset, sm_scale)
+    s = _one_tile_scores(k, q, bias, causal, 1, q_offset, sm_scale, window)
     # A query with no allowed key (lse == NEG_INF) must give p == 0: its
     # statistic flips sign so exp(s - lse) underflows.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
@@ -424,7 +484,8 @@ def _one_tile_p_ds(q, k, v, do, bias, lse, delta, *, sm_scale: float,
 def _one_tile_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                             delta_ref, dq_ref, *, sm_scale: float,
                             causal: bool, q_offset: int, has_mask: bool,
-                            heads: int, group: int):
+                            heads: int, group: int,
+                            window: Optional[int] = None):
     # Blocks as the forward's, with do like q and delta like lse.
     # dq = ds k contracts over the tile's ROWS in this orientation; written
     # as (k^T ds^T)^T Mosaic transposes the (sk, d) operand and the
@@ -435,7 +496,8 @@ def _one_tile_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         for g in range(kh * group, (kh + 1) * group):
             _, ds = _one_tile_p_ds(
                 q_ref[g], k, v, do_ref[g], bias, lse_ref[g], delta_ref[g],
-                sm_scale=sm_scale, causal=causal, q_offset=q_offset)
+                sm_scale=sm_scale, causal=causal, q_offset=q_offset,
+                window=window)
             dq = _dot(k, ds.astype(k.dtype), _TN)                # (d, sq)
             dq_ref[g] = (dq * sm_scale).T.astype(dq_ref.dtype)
 
@@ -443,7 +505,8 @@ def _one_tile_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 def _one_tile_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                              delta_ref, dk_ref, dv_ref, *, sm_scale: float,
                              causal: bool, q_offset: int, has_mask: bool,
-                             heads: int, group: int):
+                             heads: int, group: int,
+                             window: Optional[int] = None):
     # dv = p^T do and dk = ds^T q are plain products of the transposed
     # tile: nothing is transposed here. Both sum over the K/V head's query
     # group in registers and are written once per K/V head.
@@ -455,7 +518,8 @@ def _one_tile_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             q, do = q_ref[g], do_ref[g]
             p, ds = _one_tile_p_ds(
                 q, k, v, do, bias, lse_ref[g], delta_ref[g],
-                sm_scale=sm_scale, causal=causal, q_offset=q_offset)
+                sm_scale=sm_scale, causal=causal, q_offset=q_offset,
+                window=window)
             dv_g = _dot(p.astype(do.dtype), do, _NN)             # (sk, d)
             dk_g = _dot(ds.astype(q.dtype), q, _NN)              # (sk, d)
             dk = dk_g if dk is None else dk + dk_g
@@ -465,13 +529,15 @@ def _one_tile_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
 
 
 def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
-                   has_mask, interpret):
+                   has_mask, interpret, window=None):
     """One ``pallas_call`` of the one-tile path: a grid over K/V heads,
     ``heads`` a step, each with its ``h // hkv`` query heads. ``ins`` are
     ``(kind, array)`` pairs and ``outs`` kinds; the kind gives the block:
     "q" a query head's (sq, d) tile, "k" a K/V head's (sk, d) tile, "row"
     a query head's (1, sq) f32 statistic, "mask" a batch row's (1, sk)
-    additive key mask."""
+    additive key mask. ``window`` reaches the kernel only where it can
+    cut something (sk > window), so a call it cannot touch is the program
+    it was without one."""
     arrays = dict(ins)
     (bh, sq, d), (bhkv, sk, _) = arrays["q"].shape, arrays["k"].shape
     group = h // hkv
@@ -486,10 +552,12 @@ def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
         "k": jax.ShapeDtypeStruct((bhkv, sk, d), arrays["k"].dtype),
         "row": jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
     }
+    banded = ({"window": window}
+              if window is not None and window < sk else {})
     return pl.pallas_call(
         functools.partial(
             kernel, sm_scale=scale, causal=causal, q_offset=sk - sq,
-            has_mask=has_mask, heads=heads, group=group),
+            has_mask=has_mask, heads=heads, group=group, **banded),
         grid=(bhkv // heads,),
         in_specs=[specs[kind] for kind, _ in ins],
         out_specs=[specs[kind] for kind in outs],
@@ -506,7 +574,8 @@ def _mask_bias(maskf):
 
 
 def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
-                   interpret, has_mask: bool = True):
+                   interpret, has_mask: bool = True,
+                   window: Optional[int] = None):
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
@@ -524,7 +593,8 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             _one_tile_fwd_kernel, profiler.KERNEL_FLASH_FWD,
             [("q", qf), ("k", kf), ("k", vf), ("mask", _mask_bias(maskf))],
             ["q", "row"], heads=heads, h=h, hkv=hkv, scale=scale,
-            causal=causal, has_mask=has_mask, interpret=interpret)
+            causal=causal, has_mask=has_mask, interpret=interpret,
+            window=window)
         return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
     kv_row, mask_row = _gqa_index_maps(h, hkv)
     num_kb = sk // block_k
@@ -535,7 +605,8 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, block_k=block_k, sm_scale=scale,
                           causal=causal, num_kb=num_kb, block_q=block_q,
-                          q_offset=sk - sq, has_mask=has_mask),
+                          q_offset=sk - sq, has_mask=has_mask,
+                          window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -568,7 +639,8 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, block_k: int,
                          sm_scale: float, causal: bool, num_kb: int,
-                         block_q: int, q_offset: int, has_mask: bool):
+                         block_q: int, q_offset: int, has_mask: bool,
+                         window: Optional[int] = None):
     # Grid (bh, qb, kb), kb innermost: K/V tiles stream from HBM while
     # q/do/lse/delta stay resident. Recompute p block-by-block from q, k and
     # the saved lse; no S x S materialization (FA-2 backward, dq pass).
@@ -579,11 +651,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = ((kb * block_k <= qb * block_q + block_q - 1 + q_offset)
-            if causal else True)
-
-    @pl.when(live)
-    def _body():
+    def _body(band):
         lse = lse_ref[0, 0][:, None]          # (block_q, 1)
         delta = delta_ref[0, 0][:, None]      # (block_q, 1)
         # All dots in the INPUT dtype with f32 accumulation (see
@@ -592,8 +660,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        allowed = _allowed_mask(mask_ref, has_mask, causal, qb, kb,
-                                block_q, block_k, q_offset)
+        allowed = _allowed_mask(mask_ref, has_mask, band, qb, kb,
+                                block_q, block_k, q_offset, window)
         # Explicit zeroing (not exp of -inf): fully-masked rows keep p = 0,
         # so their gradients vanish as they must (out is identically 0).
         p = jnp.exp(s - lse)
@@ -607,6 +675,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, _body)
+
     @pl.when(kb == num_kb - 1)
     def _finalize():
         dq_ref[0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
@@ -616,7 +686,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                            delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                            block_q: int, sm_scale: float, causal: bool,
                            num_qb: int, block_k: int, q_offset: int,
-                           inner_steps: int, has_mask: bool):
+                           inner_steps: int, has_mask: bool,
+                           window: Optional[int] = None):
     # GQA-native grid (b*hkv, kb, t), t innermost sweeping the query GROUP
     # x q blocks (t = g * num_qb + qb): this program's K/V-head block stays
     # resident while Q/dO/lse/delta tiles stream from HBM for every query
@@ -634,11 +705,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = ((kb * block_k <= qb * block_q + block_q - 1 + q_offset)
-            if causal else True)
-
-    @pl.when(live)
-    def _body():
+    def _body(band):
         lse = lse_ref[0, 0][:, None]
         delta = delta_ref[0, 0][:, None]
         # All dots in the INPUT dtype with f32 accumulation (see
@@ -647,8 +714,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        allowed = _allowed_mask(mask_ref, has_mask, causal, qb, kb,
-                                block_q, block_k, q_offset)
+        allowed = _allowed_mask(mask_ref, has_mask, band, qb, kb,
+                                block_q, block_k, q_offset, window)
         p = jnp.exp(s - lse)
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
@@ -663,6 +730,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, _body)
+
     @pl.when(t == inner_steps - 1)
     def _finalize():
         dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
@@ -671,7 +740,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
 def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
                     block_q, block_k, interpret, dlse=None,
-                    has_mask: bool = True):
+                    has_mask: bool = True, window: Optional[int] = None):
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
@@ -702,7 +771,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         ins = [("q", qf), ("k", kf), ("k", vf), ("mask", _mask_bias(maskf)),
                ("q", dof), ("row", lse), ("row", delta)]
         static = dict(heads=heads, h=h, hkv=hkv, scale=scale, causal=causal,
-                      has_mask=has_mask, interpret=interpret)
+                      has_mask=has_mask, interpret=interpret, window=window)
         dq, = _one_tile_call(_one_tile_bwd_dq_kernel,
                              profiler.KERNEL_FLASH_BWD_DQ, ins, ["q"],
                              **static)
@@ -717,7 +786,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
                           sm_scale=scale, causal=causal, num_kb=num_kb,
                           block_q=block_q, q_offset=sk - sq,
-                          has_mask=has_mask),
+                          has_mask=has_mask, window=window),
         grid=(b * h, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -753,7 +822,8 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         functools.partial(_flash_bwd_dkdv_kernel, block_q=block_q,
                           sm_scale=scale, causal=causal, num_qb=num_qb,
                           block_k=block_k, q_offset=sk - sq,
-                          inner_steps=inner, has_mask=has_mask),
+                          inner_steps=inner, has_mask=has_mask,
+                          window=window),
         grid=(b * hkv, num_kb, inner),
         in_specs=[
             pl.BlockSpec((1, block_q, d),
@@ -791,23 +861,25 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
 # The mask rides as a *differentiable* float32 argument with a zero
 # cotangent: nondiff_argnums may not receive tracers (jit/shard_map callers
 # pass traced masks), so only the static config lives there.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, maskf, causal, sm_scale, block_q, block_k, interpret,
-           has_mask):
+           has_mask, window):
     out, _ = _flash_forward(q, k, v, maskf != 0, causal, sm_scale, block_q,
-                            block_k, interpret, has_mask=has_mask)
+                            block_k, interpret, has_mask=has_mask,
+                            window=window)
     return out
 
 
 def _flash_fwd_rule(q, k, v, maskf, causal, sm_scale, block_q, block_k,
-                    interpret, has_mask):
+                    interpret, has_mask, window):
     out, lse = _flash_forward(q, k, v, maskf != 0, causal, sm_scale, block_q,
-                              block_k, interpret, has_mask=has_mask)
+                              block_k, interpret, has_mask=has_mask,
+                              window=window)
     return out, (q, k, v, maskf, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, has_mask,
-                    res, g):
+                    window, res, g):
     q, k, v, maskf, out, lse = res
     from ..common.config import flash_xla_bwd
 
@@ -818,7 +890,8 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, has_mask,
         # compiled executables keep the backward they were traced with.
         def f(q, k, v):
             out = reference_attention(q, k, v, key_mask=maskf != 0,
-                                      causal=causal, sm_scale=sm_scale)
+                                      causal=causal, sm_scale=sm_scale,
+                                      window=window)
             # Match the flash forward exactly: rows with NO allowed key
             # emit zeros in the kernel, but reference_attention softmaxes
             # their constant NEG_INF logits into uniform probs (mean(v)).
@@ -829,7 +902,11 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, has_mask,
             allowed = (maskf != 0)[:, None, :]
             if causal:
                 qi = jnp.arange(sq)[:, None] + (sk - sq)
-                allowed = allowed & (jnp.arange(sk)[None, :] <= qi)[None]
+                ki = jnp.arange(sk)[None, :]
+                band = ki <= qi
+                if window is not None:
+                    band = band & (ki > qi - window)
+                allowed = allowed & band[None]
             row_valid = allowed.any(-1)  # (b, sq)
             return jnp.where(row_valid[:, :, None, None], out, 0.0)
 
@@ -838,7 +915,7 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, has_mask,
         return dq, dk, dv, jnp.zeros_like(maskf)
     dq, dk, dv = _flash_backward(q, k, v, maskf != 0, out, lse, g, causal,
                                  sm_scale, block_q, block_k, interpret,
-                                 has_mask=has_mask)
+                                 has_mask=has_mask, window=window)
     return dq, dk, dv, jnp.zeros_like(maskf)
 
 
@@ -849,7 +926,8 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
                     sm_scale: Optional[float] = None,
                     block_q: int = FLASH_DEFAULT_BLOCK_Q,
                     block_k: int = FLASH_DEFAULT_BLOCK_K,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Flash attention forward. ``interpret=None`` compiles on ``tpu`` and
     selects Pallas interpreter mode on ``cpu`` (the hermetic tests run the
     same kernel); any other backend raises.
@@ -859,6 +937,14 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
     of the key axis, i.e. query row i attends keys ``<= i + (sk - sq)``.
     For sq > sk, rows before key position 0 are fully masked and emit
     zeros (reference_attention degenerates to uniform probs there).
+
+    ``window`` (with ``causal``) bounds the band from below: the query at
+    position i sees the keys ``i - window < j <= i``. The streamed
+    kernels, forward and both backward, skip the blocks that lie wholly
+    outside the band and build its mask only in the blocks an edge of it
+    crosses (the diagonal, and the edge ``window`` keys below it); the
+    one-tile kernels take it as one more term of their mask, and only
+    where ``sk > window``. ``window=None`` is the plain causal band.
 
     Grouped-query attention is native: pass k/v with Hkv < H heads
     (H % Hkv == 0) and each group of H/Hkv query heads reads one K/V
@@ -883,6 +969,7 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
         interpret = _auto_interpret()
     b, sq, sk = k.shape[0], q.shape[1], k.shape[1]
     _check_gqa_heads(q, k, v, "flash_attention")
+    _check_window(window, causal, "flash_attention")
     # Awkward sequence lengths (e.g. ViT's 197 = 196 patches + CLS, a
     # PRIME) would make _fit_block degrade to pathological 1-row blocks.
     # Auto-pad to the next 128 multiple instead: padded keys are masked
@@ -923,7 +1010,7 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
             jnp.pad(k, ((0, 0), (0, skp - sk), (0, 0), (0, 0))),
             jnp.pad(v, ((0, 0), (0, skp - sk), (0, 0), (0, 0))),
             mask.astype(jnp.float32), causal, sm_scale, block_q, block_k,
-            interpret, True)
+            interpret, True, window)
         return out[:, :sq]
     # has_mask is static: with key_mask=None the kernels skip the mask
     # broadcast/where VPU passes entirely (the placeholder ones-mask
@@ -932,13 +1019,14 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
                   (jnp.ones((b, sk), jnp.float32) if key_mask is None
                    else key_mask.astype(jnp.float32)),
                   causal, sm_scale, block_q, block_k, interpret,
-                  key_mask is not None)
+                  key_mask is not None, window)
 
 
 def make_attention_fn(causal: bool = False, use_flash="auto",
                       block_q: int = FLASH_DEFAULT_BLOCK_Q,
                       block_k: int = FLASH_DEFAULT_BLOCK_K,
-                      sm_scale: Optional[float] = None):
+                      sm_scale: Optional[float] = None,
+                      window: Optional[int] = None):
     """Adapter for ``horovod_tpu.models.bert.SelfAttention(attention_fn=...)``
     — signature (q, k, v, mask) with mask of shape (B, Sk) or None.
 
@@ -947,11 +1035,14 @@ def make_attention_fn(causal: bool = False, use_flash="auto",
     (measured on v5e: BERT-base seq=128 runs 1240 vs 934 seq/s — the
     O(S^2) memory flash avoids is tiny there and the kernel overhead
     isn't); at long S flash's O(S) memory and blocking win. Pass
-    True/False to force.
+    True/False to force. ``window`` (with ``causal``) is handed to
+    whichever path runs: see :func:`flash_attention`.
 
     The returned fn carries ``supports_gqa = True``: both paths accept
     k/v with fewer (grouped) heads than q, so GQA models can skip the
     K/V repeat entirely (``LlamaAttention`` checks this attribute)."""
+
+    _check_window(window, causal, "make_attention_fn")
 
     def fn(q, k, v, mask):
         flash = use_flash
@@ -960,9 +1051,10 @@ def make_attention_fn(causal: bool = False, use_flash="auto",
         if flash:
             return flash_attention(q, k, v, key_mask=mask, causal=causal,
                                    sm_scale=sm_scale,
-                                   block_q=block_q, block_k=block_k)
+                                   block_q=block_q, block_k=block_k,
+                                   window=window)
         return reference_attention(q, k, v, key_mask=mask, causal=causal,
-                                   sm_scale=sm_scale)
+                                   sm_scale=sm_scale, window=window)
 
     fn.supports_gqa = True
     return fn

@@ -5,7 +5,13 @@ The reference implements data parallelism only (SURVEY.md §2.3); the mesh
 utilities here are its substrate plus the axes future strategies hang off."""
 
 from . import hierarchical, moe, pipeline, sequence  # noqa: F401
-from .moe import moe_apply, moe_apply_dense, switch_aux_loss  # noqa: F401
+from .moe import (  # noqa: F401
+    grouped_gated_mlp,
+    moe_apply,
+    moe_apply_dense,
+    moe_apply_held,
+    switch_aux_loss,
+)
 from .hierarchical import (  # noqa: F401
     hierarchical_allgather,
     hierarchical_allreduce,

@@ -19,15 +19,27 @@ assignments survive (scaled by ``k`` because top-k routing emits ``k*T``
 assignments in total); overflow tokens pass through with zero expert
 output (the standard residual-passthrough convention). The Switch load-balancing
 auxiliary loss is returned alongside the output.
+
+A second layer, below the capacity path, is dropless and is told which
+experts its device holds (:func:`moe_apply_held`): it routes over all the
+router's experts, sorts the assignments that land here by expert and runs
+grouped products over them (:func:`grouped_gated_mlp`), under the scopes
+``hvd.moe.route`` / ``.dispatch`` / ``.experts`` / ``.combine``. It is the
+share of an expert-parallel deployment one device computes between the two
+exchanges; the exchange is not in it. ``MoeLM`` keeps the capacity path:
+``all_to_all`` needs its fixed buffers, and a dropless exchange is queued
+(``ROADMAP.md`` R1).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..common import profiler
 from .mesh import axis_size
 
 
@@ -313,3 +325,139 @@ def moe_apply_dense(expert_fn: Callable[[Any, jax.Array], jax.Array],
     expert_out = jax.vmap(expert_fn)(stacked_params, expert_in)
     y = _gather_from_experts(expert_out, routing, idx)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over the experts a device HOLDS. The capacity path above
+# packs fixed [E, C, D] buffers because ``all_to_all`` needs them, keeps one
+# expert per device (or all of them) and drops what overflows. The layer
+# below is told which experts it holds, routes over all of them, and gives
+# the part of the layer's result that its own experts give: the share of an
+# expert-parallel deployment one device computes between the two exchanges.
+# Nothing here stands in for the other devices or for the exchange.
+
+
+@jax.custom_vjp
+def _take_token_rows(x, order, inverse):
+    """``[T, D]`` token rows -> ``[T*k, D]`` assignment rows in sorted
+    order: sorted row i is the token of assignment ``order[i]``, and
+    assignment ``t*k + j`` is token t's j-th choice. ``inverse`` is
+    ``order``'s inverse permutation."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _take_token_rows_fwd(x, order, inverse):
+    return _take_token_rows(x, order, inverse), (inverse, x.shape[0])
+
+
+def _take_token_rows_bwd(res, g):
+    inverse, tokens = res
+    # A permutation's transpose is the inverse permutation: a gather, not
+    # the scatter-add autodiff would emit; then each token's k rows add.
+    return g[inverse].reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_take_token_rows.defvjp(_take_token_rows_fwd, _take_token_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is known."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def grouped_gated_mlp(params: Any, rows: jax.Array, group_sizes: jax.Array,
+                      activation: Callable = jax.nn.relu) -> jax.Array:
+    """``w_down_e (act(w_gate_e h) * (w_up_e h))`` for rows sorted by
+    expert, ``group_sizes[e]`` of them for the e-th expert held: three
+    grouped products (``jax.lax.ragged_dot``, which the TPU compiler runs
+    tile by tile over the rows the groups cover and no further).
+    ``params``: ``w_gate`` / ``w_up`` ``[n, D, F]`` and ``w_down``
+    ``[n, F, D]``, cast to the rows' dtype here."""
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w.astype(a.dtype), group_sizes)
+
+    hidden = activation(grouped(rows, params["w_gate"])) \
+        * grouped(rows, params["w_up"])
+    return grouped(hidden, params["w_down"])
+
+
+def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
+                                       jax.Array],
+                   expert_params: Any,
+                   x: jax.Array,
+                   gate_logits: jax.Array,
+                   held: Sequence[int],
+                   num_selected: int) -> Tuple[jax.Array, jax.Array]:
+    """A dropless top-k expert layer on the device that holds the experts
+    ``held`` (ids into the router's ``gate_logits[T, E]``, static, in the
+    order of ``expert_params``' leading axis).
+
+    Every token chooses its ``num_selected`` largest logits over ALL ``E``
+    experts and weighs them by a softmax over the chosen logits (softmax
+    over all, top k, renormalised: the same numbers). The assignments
+    whose expert is held here are sorted by expert, their rows gathered,
+    ``expert_fn(expert_params, rows, group_sizes)`` applied (see
+    :func:`grouped_gated_mlp`), and the weighted results summed per token.
+    Returns ``(y, load)``: ``y[T, D]`` is the part of the layer's result
+    that the held experts give (all of it when every expert is held; the
+    parts of disjoint shares add up to it), ``load[len(held)]`` the
+    assignments each held expert received.
+
+    No capacity, no dropped assignment: the sorted buffer has room for
+    all ``T * num_selected`` assignments, the worst case of every token
+    choosing only experts held here, so the result is exact under any
+    imbalance. The rows past ``load.sum()`` belong to no group and are
+    held at zero on both sides of ``expert_fn``, forward and backward."""
+    tokens, _ = x.shape
+    num_experts = gate_logits.shape[-1]
+    held = tuple(int(e) for e in held)
+    if len(set(held)) != len(held) or not all(
+            0 <= e < num_experts for e in held):
+        raise ValueError(f"moe_apply_held: held={held} must be distinct "
+                         f"expert ids below {num_experts}")
+    n_held = len(held)
+    slot_of = np.full((num_experts,), n_held, np.int32)     # not here
+    slot_of[list(held)] = np.arange(n_held, dtype=np.int32)
+
+    with jax.named_scope(profiler.SCOPE_MOE_ROUTE):
+        top_logits, top_ids = jax.lax.top_k(
+            gate_logits.astype(jnp.float32), num_selected)      # [T, k]
+        weights = jax.nn.softmax(top_logits, axis=-1)
+        slots = jnp.asarray(slot_of)[top_ids]                   # [T, k]
+        landed = slots < n_held
+        weights = jnp.where(landed, weights, 0.0).astype(x.dtype)
+
+    with jax.named_scope(profiler.SCOPE_MOE_DISPATCH):
+        flat = slots.reshape(-1)                                # [T*k]
+        count = flat.shape[0]
+        # Held experts' assignments first, by expert, token order kept;
+        # the others (slot n_held) sort to the end.
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros((count,), jnp.int32).at[order].set(
+            jnp.arange(count, dtype=jnp.int32), unique_indices=True)
+        load = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(
+            jnp.int32)
+        live = (jnp.arange(count) < load.sum())[:, None]
+        rows = jnp.where(live, _take_token_rows(x, order, inverse), 0)
+
+    with jax.named_scope(profiler.SCOPE_MOE_EXPERTS):
+        out = expert_fn(expert_params, rows, load)
+
+    with jax.named_scope(profiler.SCOPE_MOE_COMBINE):
+        out = jnp.where(live, out, 0)
+        by_token = _permute_rows(out, inverse, order).reshape(
+            tokens, num_selected, -1)
+        y = jnp.einsum("tkd,tk->td", by_token, weights)
+    return y, load

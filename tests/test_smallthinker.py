@@ -1,0 +1,259 @@
+"""SmallThinker (``models/smallthinker.py``) and the dropless expert layer
+it forced (``parallel/moe.py::moe_apply_held``), at a tiny size on seeded
+weights: the model against the benchmark's plain float32 reference
+(``benchmarks/reference/smallthinker-21b-a3b.py``: no flax, no kernel, no
+grouped product, every held expert applied densely), whole and with a
+share of the experts; the parts the four shares give add up to the whole
+layer; nothing is dropped under a router forced onto one expert."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.models import (SMALLTHINKER_TINY, SmallThinkerLM,
+                                causal_lm_loss, chunked_causal_lm_loss)
+from horovod_tpu.models.smallthinker import SmallThinkerBlock
+from horovod_tpu.ops.attention import make_attention_fn
+from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ = 128       # the tiny window is 48: shorter than the sequence
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The benchmark's reference file, loaded by path (its name holds a
+    ``-``) with ``benchmarks`` on the path for its own import."""
+    import sys
+
+    bench = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "smallthinker_reference", os.path.join(
+                bench, "reference", "smallthinker-21b-a3b.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(bench)
+    return module
+
+
+def _config(held=None, **over):
+    return dataclasses.replace(SMALLTHINKER_TINY, dtype=jnp.float32,
+                               experts_held=held, **over)
+
+
+def _reference_config(cfg):
+    """The model's sizes under the keys the configuration file has."""
+    return {
+        "num_layers": cfg.num_layers, "rms_norm_eps": cfg.norm_eps,
+        "moe_num_active_primary_experts": cfg.num_selected,
+        "sliding_window_size": cfg.sliding_window,
+        "sliding_window_layout": list(cfg.window_layout),
+        "rope_layout": list(cfg.rope_layout), "rope_theta": cfg.rope_theta,
+        "deployment": {"experts_held": list(cfg.held())},
+    }
+
+
+def _share(params, held):
+    """``params`` of the model that holds every expert, cut to ``held``."""
+    out = jax.tree.map(lambda x: x, params)
+    for name in sorted(n for n in out if n.startswith("layer_")):
+        for w in ("w_gate", "w_up", "w_down"):
+            out[name][w] = {
+                "kernel": out[name][w]["kernel"][jnp.array(held)]}
+    return out
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    cfg = _config()
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
+                             cfg.vocab_size)
+    params = SmallThinkerLM(cfg).init(jax.random.PRNGKey(3), ids)["params"]
+    # Scales at which every path matters: a router that decides, experts
+    # and attention of the residual's own size.
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * (25.0 if "router" in str(path) else 3.0)
+        if x.ndim > 1 else x, params)
+    return ids, params
+
+
+@pytest.mark.parametrize("held", [None, (2, 3), (0, 5, 7)],
+                         ids=["all", "share-2-3", "share-0-5-7"])
+def test_loss_and_gradients_match_the_plain_reference(held, seeded,
+                                                      reference):
+    ids, params = seeded
+    cfg = _config(held)
+    params = params if held is None else _share(params, held)
+    model = SmallThinkerLM(cfg)
+
+    def loss(p):
+        return causal_lm_loss(model.apply({"params": p}, ids)[0], ids)
+
+    ours, grads = jax.jit(jax.value_and_grad(loss))(params)
+
+    def reference_loss(p):
+        total = sum(reference.sequence_nll_sum(
+            p, row, rnd=lambda a: a, config=_reference_config(cfg))
+            for row in ids)
+        return total / (ids.shape[0] * (ids.shape[1] - 1))
+
+    theirs, reference_grads = jax.jit(
+        jax.value_and_grad(reference_loss))(params)
+    np.testing.assert_allclose(ours, theirs, rtol=1e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree.leaves(reference_grads)):
+        # float32 through eight layers of weights scaled up: the loss
+        # agrees to 1e-5, a gradient to a part in a thousand of its leaf.
+        scale = float(jnp.max(jnp.abs(r))) + 1e-12
+        assert float(jnp.max(jnp.abs(g - r))) <= 3e-3 * scale, path
+
+
+def test_flash_kernels_remat_and_chunked_loss_change_nothing(seeded):
+    """The step the benchmark runs (flash attention by the program's own
+    rule, each block recomputed, the loss in chunks) against the plain
+    model: one function."""
+    ids, params = seeded
+    ids = jnp.concatenate([ids] * 4, axis=1)      # 512: four blocks a side
+    plain = SmallThinkerLM(_config(num_layers=4))   # one period
+    fast = SmallThinkerLM(
+        _config(num_layers=4, remat=True),
+        attention_fn=make_attention_fn(causal=True, use_flash=True,
+                                       block_q=128, block_k=128),
+        window_attention_fn=make_attention_fn(
+            causal=True, use_flash=True, block_q=128, block_k=128,
+            window=SMALLTHINKER_TINY.sliding_window))
+    params = {k: params[k] for k in sorted(params)
+              if k not in ("layer_4", "layer_5", "layer_6", "layer_7")}
+
+    def plain_loss(p):
+        return causal_lm_loss(plain.apply({"params": p}, ids)[0], ids)
+
+    def fast_loss(p):
+        hidden, _ = fast.apply({"params": p}, ids, return_hidden=True)
+        return chunked_causal_lm_loss(hidden, p["lm_head"]["kernel"], ids,
+                                      num_chunks=4)
+
+    a, ga = jax.jit(jax.value_and_grad(plain_loss))(params)
+    b, gb = jax.jit(jax.value_and_grad(fast_loss))(params)
+    np.testing.assert_allclose(a, b, rtol=1e-5)
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
+        np.testing.assert_allclose(x, y, rtol=0, atol=5e-3 * float(
+            jnp.max(jnp.abs(x)) + 1e-12))
+
+
+def test_parts_of_the_four_shares_add_up_to_the_whole_layer(seeded,
+                                                            reference):
+    """One layer: each share's output is ``a + (its experts' part)``, so
+    the four parts, with attention and the residual counted once, are the
+    whole-layer reference."""
+    ids, params = seeded
+    cfg = _config()
+    layer = params["layer_1"]           # a windowed, rotated layer
+    x = 3.0 * jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, cfg.dim))
+    window_fn = make_attention_fn(causal=True, use_flash=False,
+                                  window=cfg.sliding_window)
+
+    def block(held, p):
+        out, load = SmallThinkerBlock(
+            _config(held), rope=True, attention_fn=window_fn).apply(
+            {"params": p}, x)
+        return out[0], load
+
+    whole = reference._layer(lambda a: a, layer, x[0],
+                             _reference_config(cfg), True, True)
+    nothing_held = reference._layer(
+        lambda a: a, layer, x[0],
+        {**_reference_config(cfg), "deployment": {"experts_held": []}},
+        True, True)                     # a: attention and the residual
+    shares = [(0, 1), (2, 3), (4, 5), (6, 7)]
+    parts, landed = 0.0, 0
+    for held in shares:
+        out, load = block(held, _share({"layer_1": layer}, held)["layer_1"])
+        parts = parts + (out - nothing_held)
+        landed += int(load.sum())
+    assert landed == SEQ * cfg.num_selected     # every assignment, once
+    np.testing.assert_allclose(nothing_held + parts, whole, rtol=0,
+                               atol=2e-5 * float(jnp.max(jnp.abs(whole))))
+    # The same from the layer that holds all eight.
+    np.testing.assert_allclose(block(None, layer)[0], whole, rtol=0,
+                               atol=2e-5 * float(jnp.max(jnp.abs(whole))))
+
+
+def _experts(key, n, d=16, f=24):
+    ks = jax.random.split(key, 3)
+    return {"w_gate": jax.random.normal(ks[0], (n, d, f)),
+            "w_up": jax.random.normal(ks[1], (n, d, f)),
+            "w_down": jax.random.normal(ks[2], (n, f, d))}
+
+
+def _dense_experts(params, x, weights):
+    """Every expert on every token, weighted: the plain form."""
+    hidden = jax.nn.relu(jnp.einsum("td,edf->etf", x, params["w_gate"])) \
+        * jnp.einsum("td,edf->etf", x, params["w_up"])
+    return jnp.einsum("etd,te->td",
+                      jnp.einsum("etf,efd->etd", hidden, params["w_down"]),
+                      weights)
+
+
+@pytest.mark.parametrize("held", [(0, 1, 2, 3, 4, 5), (3,), (1, 4)],
+                         ids=["all", "the-one", "one-of-two"])
+def test_nothing_is_dropped_when_every_token_goes_to_one_expert(held):
+    """A router forced onto experts 3 and 1, in that order, for every
+    token: the capacity path would drop all but a buffer's worth; here
+    expert 3 gets all 64 rows and the result is exact."""
+    tokens, experts, k = 64, 6, 2
+    x = jax.random.normal(jax.random.PRNGKey(0), (tokens, 16))
+    logits = jnp.tile(jnp.array([-3.0, 4.0, -2.0, 5.0, -1.0, -4.0]),
+                      (tokens, 1))
+    params = _experts(jax.random.PRNGKey(1), experts)
+    mine = jax.tree.map(lambda w: w[jnp.array(held)], params)
+    y, load = moe_apply_held(grouped_gated_mlp, mine, x, logits, held, k)
+    chosen = jnp.zeros((tokens, experts)).at[:, jnp.array([3, 1])].set(
+        jax.nn.softmax(jnp.array([5.0, 4.0])))
+    here = jnp.zeros((experts,)).at[jnp.array(held)].set(1.0)
+    np.testing.assert_allclose(
+        y, _dense_experts(params, x, chosen * here), rtol=2e-5, atol=2e-5)
+    assert load.tolist() == [tokens if e in (1, 3) else 0 for e in held]
+
+
+def test_held_layer_gradients_match_the_dense_form():
+    tokens, experts, k, held = 48, 8, 3, (1, 2, 6)
+    x = jax.random.normal(jax.random.PRNGKey(2), (tokens, 16))
+    logits = 2.0 * jax.random.normal(jax.random.PRNGKey(3),
+                                     (tokens, experts))
+    params = _experts(jax.random.PRNGKey(4), experts)
+    mine = jax.tree.map(lambda w: w[jnp.array(held)], params)
+    target = jax.random.normal(jax.random.PRNGKey(5), (tokens, 16))
+
+    def ours(mine, x, logits):
+        y, _ = moe_apply_held(grouped_gated_mlp, mine, x, logits, held, k)
+        return jnp.sum(y * target)
+
+    def dense(mine, x, logits):
+        top, ids = jax.lax.top_k(logits, k)
+        weights = jnp.zeros_like(logits).at[
+            jnp.arange(tokens)[:, None], ids].set(jax.nn.softmax(top, -1))
+        return jnp.sum(_dense_experts(mine, x, weights[:, jnp.array(held)])
+                       * target)
+
+    got = jax.grad(ours, argnums=(0, 1, 2))(mine, x, logits)
+    want = jax.grad(dense, argnums=(0, 1, 2))(mine, x, logits)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+def test_held_ids_are_checked():
+    x, logits = jnp.zeros((4, 16)), jnp.zeros((4, 6))
+    params = _experts(jax.random.PRNGKey(0), 2)
+    with pytest.raises(ValueError, match="distinct expert ids"):
+        moe_apply_held(grouped_gated_mlp, params, x, logits, (1, 1), 2)
+    with pytest.raises(ValueError, match="distinct expert ids"):
+        moe_apply_held(grouped_gated_mlp, params, x, logits, (1, 6), 2)
