@@ -436,7 +436,9 @@ def phase_window_and_experts(sz, rehearsal):
     on float32 copies at the highest matmul precision, and the dropless
     expert layer holding 4 of 16 experts (3 chosen a token, hidden 2560,
     expert width 768) against every held expert applied densely in
-    float32. Both in bf16: a few ulps of bf16 of the largest value, and
+    float32, once as a seeded router spreads the tokens and once with
+    every token forced onto the held experts (PR 27: nothing dropped
+    where every sorted row belongs to a group). Both in bf16: a few ulps of bf16 of the largest value, and
     never more than 2^-5 of it; the expert layer's count of what landed
     here must be the count of chosen ids that are held."""
     import re
@@ -548,21 +550,26 @@ def phase_window_and_experts(sz, rehearsal):
             loss, argnums=(0, 1, 2), has_aux=True)(params, x, logits)
         return (y,) + grads
 
-    with jax.default_matmul_precision("highest"):
-        want = timed(jax.jit(dense_layer), params, x.astype(jnp.float32),
-                     logits)
-    *got, load = timed(jax.jit(held_layer), params, x, logits)
-    landed = int(np.isin(np.argsort(-np.asarray(logits), axis=-1)[:, :chosen],
-                         held).sum())
-    check(int(load.sum()) == landed,
-          f"experts: {int(load.sum())} assignments landed, {landed} chosen "
-          "ids are held")
-    err = worst(got, want)
-    checks.append(check(
-        err <= tolerance,
-        f"experts {held} of {experts}, {landed} of {tokens * chosen} "
-        f"assignments here: y/dw/dx/dlogits within {err:.2e} of "
-        "max|reference|"))
+    dense_layer, held_layer = jax.jit(dense_layer), jax.jit(held_layer)
+    # As the seeded router spreads them, a quarter lands here; with the
+    # held experts' logits raised every assignment does.
+    for what, logits in (("a router's spread", logits),
+                         ("every token forced here",
+                          logits.at[:, jnp.array(held)].add(20.0))):
+        with jax.default_matmul_precision("highest"):
+            want = timed(dense_layer, params, x.astype(jnp.float32), logits)
+        *got, load = timed(held_layer, params, x, logits)
+        landed = int(np.isin(np.argsort(
+            -np.asarray(logits), axis=-1)[:, :chosen], held).sum())
+        check(int(load.sum()) == landed,
+              f"experts: {int(load.sum())} assignments landed, {landed} "
+              "chosen ids are held")
+        err = worst(got, want)
+        checks.append(check(
+            err <= tolerance,
+            f"experts {held} of {experts}, {what}: {landed} of "
+            f"{tokens * chosen} assignments here: y/dw/dx/dlogits within "
+            f"{err:.2e} of max|reference|"))
     return {"compile_s": compile_s, "run_s": run_s, "checks": checks}
 
 

@@ -33,7 +33,7 @@ exchanges; the exchange is not in it. ``MoeLM`` keeps the capacity path:
 
 from __future__ import annotations
 
-from typing import Any, Callable, Sequence, Tuple
+from typing import Any, Callable, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -337,44 +337,96 @@ def moe_apply_dense(expert_fn: Callable[[Any, jax.Array], jax.Array],
 # Nothing here stands in for the other devices or for the exchange.
 
 
+#: The largest source, in bytes, from which a row gather runs at the speed
+#: of its output's writes: one the compiler can keep in the chip's 128 MiB
+#: on-chip memory (``S(1)`` on the source in the compiled text) beside what
+#: else lives there. From a source left in HBM every row costs five times
+#: as much whatever the order of the indices. Measured on the TPU v5e
+#: alone, bf16 rows 2560 wide, the step between 110 and 120 MiB
+#: (``PERF.md`` section 6, PR 27): on another generation measure it again,
+#: a block more than needed costs a slice and a concatenation of the block.
+_GATHER_SOURCE_BYTES = 96 * 2 ** 20
+
+
+def _gather_blocks(size: int, width: int) -> int:
+    """Into how many blocks of columns :func:`_gather_sum` splits a source
+    of ``size`` bytes whose rows are ``width`` wide: the fewest whole
+    128-lane blocks that bring each under ``_GATHER_SOURCE_BYTES``."""
+    lanes = width // 128 if width % 128 == 0 else 1
+    return next((n for n in range(1, lanes + 1) if lanes % n == 0
+                 and size <= n * _GATHER_SOURCE_BYTES), lanes)
+
+
+def _gather_sum(rows, place, scale):
+    """``y[t] = sum_j scale[j, t] * rows[place[j, t]]`` over the leading
+    axis of ``place`` / ``scale`` ``[k, T]``, added in float32, in
+    ``rows``' dtype. Where ``scale`` is 0 the row is not read for its
+    value (it may hold anything). ``rows`` is gathered in blocks of
+    columns small enough for the fast gather."""
+    blocks = _gather_blocks(rows.size * rows.dtype.itemsize, rows.shape[-1])
+    used = (scale != 0)[..., None]
+    return jnp.concatenate([
+        jnp.einsum("ktd,kt->td", jnp.where(used, block[place], 0), scale,
+                   preferred_element_type=jnp.float32).astype(rows.dtype)
+        for block in jnp.split(rows, blocks, axis=-1)], axis=-1)
+
+
+class _Places(NamedTuple):
+    """Where the sorted rows and the tokens' assignments find each other
+    (int32 / bool; assignment ``[j, t]``, flat ``j * T + t``, is token
+    t's j-th choice; the held experts' assignments sort first)."""
+    mine: jax.Array     # [k*T] the assignment of each sorted row
+    token: jax.Array    # [k*T] its token
+    live: jax.Array     # [k*T] whether the row belongs to a group
+    place: jax.Array    # [k, T] the sorted row of each assignment
+    here: jax.Array     # [k, T] whether the assignment landed here
+
+
 @jax.custom_vjp
-def _take_token_rows(x, order, inverse):
-    """``[T, D]`` token rows -> ``[T*k, D]`` assignment rows in sorted
-    order: sorted row i is the token of assignment ``order[i]``, and
-    assignment ``t*k + j`` is token t's j-th choice. ``inverse`` is
-    ``order``'s inverse permutation."""
-    return x[order // (order.shape[0] // x.shape[0])]
+def _rows_of_tokens(x, at):
+    """``[T, D]`` token rows -> the sorted rows: row i is token
+    ``at.token[i]``. Rows outside every group are read by no one."""
+    return x[at.token]
 
 
-def _take_token_rows_fwd(x, order, inverse):
-    return _take_token_rows(x, order, inverse), (inverse, x.shape[0])
+def _rows_of_tokens_fwd(x, at):
+    return x[at.token], at
 
 
-def _take_token_rows_bwd(res, g):
-    inverse, tokens = res
-    # A permutation's transpose is the inverse permutation: a gather, not
-    # the scatter-add autodiff would emit; then each token's k rows add.
-    return g[inverse].reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None
+def _rows_of_tokens_bwd(at, g):
+    # A token's rows add into it: as k gathers and a sum, not the
+    # scatter-add autodiff would emit (row by row, and over three times a
+    # gather's cost on the v5e).
+    return _gather_sum(g, at.place, at.here.astype(g.dtype)), None
 
 
-_take_token_rows.defvjp(_take_token_rows_fwd, _take_token_rows_bwd)
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is known."""
-    return x[perm]
+def _tokens_of_rows(out, weights, at):
+    """The weighted sum back into token order: ``y[t]`` adds
+    ``weights[j, t] * out[at.place[j, t]]`` over token t's assignments
+    that are ``at.here``."""
+    return _gather_sum(out, at.place, jnp.where(at.here, weights, 0))
 
 
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], inverse
+def _tokens_of_rows_fwd(out, weights, at):
+    return _tokens_of_rows(out, weights, at), (out, weights, at)
 
 
-def _permute_rows_bwd(inverse, g):
-    return g[inverse], None, None
+def _tokens_of_rows_bwd(res, dy):
+    out, weights, at = res
+    live = at.live[:, None]
+    g = dy[at.token]
+    d_out = jnp.where(live, g * weights.reshape(-1)[at.mine][:, None], 0)
+    d_row = jnp.sum(jnp.where(
+        live, g.astype(jnp.float32) * out.astype(jnp.float32), 0), axis=-1)
+    d_weights = jnp.where(at.here, d_row[at.place], 0).astype(weights.dtype)
+    return d_out, d_weights, None
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 
 def grouped_gated_mlp(params: Any, rows: jax.Array, group_sizes: jax.Array,
@@ -415,11 +467,15 @@ def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
     parts of disjoint shares add up to it), ``load[len(held)]`` the
     assignments each held expert received.
 
-    No capacity, no dropped assignment: the sorted buffer has room for
+    No capacity, no dropped assignment: the sorted rows have room for
     all ``T * num_selected`` assignments, the worst case of every token
     choosing only experts held here, so the result is exact under any
-    imbalance. The rows past ``load.sum()`` belong to no group and are
-    held at zero on both sides of ``expert_fn``, forward and backward."""
+    imbalance. ``expert_fn`` is given ``group_sizes`` that cover the
+    landed rows. The rows past them belong to no group: they hold the
+    token rows of assignments that landed elsewhere (not zeros), what
+    ``expert_fn`` returns for them is not read, and the gradient it is
+    handed for them is zero. So ``expert_fn`` works row by row: a row's
+    result hangs on that row and its expert's parameters alone."""
     tokens, _ = x.shape
     num_experts = gate_logits.shape[-1]
     held = tuple(int(e) for e in held)
@@ -436,28 +492,30 @@ def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
             gate_logits.astype(jnp.float32), num_selected)      # [T, k]
         weights = jax.nn.softmax(top_logits, axis=-1)
         slots = jnp.asarray(slot_of)[top_ids]                   # [T, k]
-        landed = slots < n_held
-        weights = jnp.where(landed, weights, 0.0).astype(x.dtype)
+        weights = jnp.where(slots < n_held, weights, 0.0).astype(x.dtype)
+        # Round-major: assignment j * T + t, so that a token's choices
+        # are a leading axis and never a 6-row tile.
+        weights = weights.T                                     # [k, T]
+        flat = slots.T.reshape(-1)                              # [k*T]
 
     with jax.named_scope(profiler.SCOPE_MOE_DISPATCH):
-        flat = slots.reshape(-1)                                # [T*k]
-        count = flat.shape[0]
         # Held experts' assignments first, by expert, token order kept;
         # the others (slot n_held) sort to the end.
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros((count,), jnp.int32).at[order].set(
-            jnp.arange(count, dtype=jnp.int32), unique_indices=True)
-        load = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(
-            jnp.int32)
-        live = (jnp.arange(count) < load.sum())[:, None]
-        rows = jnp.where(live, _take_token_rows(x, order, inverse), 0)
+        place = jnp.argsort(order).astype(jnp.int32).reshape(
+            num_selected, tokens)
+        load = jnp.sum(
+            flat[None, :] == jnp.arange(n_held, dtype=jnp.int32)[:, None],
+            axis=1, dtype=jnp.int32)
+        landed = jnp.sum(load)
+        at = _Places(mine=order, token=order % tokens,
+                     live=jnp.arange(order.shape[0]) < landed,
+                     place=place, here=place < landed)
+        rows = _rows_of_tokens(x, at)
 
     with jax.named_scope(profiler.SCOPE_MOE_EXPERTS):
         out = expert_fn(expert_params, rows, load)
 
     with jax.named_scope(profiler.SCOPE_MOE_COMBINE):
-        out = jnp.where(live, out, 0)
-        by_token = _permute_rows(out, inverse, order).reshape(
-            tokens, num_selected, -1)
-        y = jnp.einsum("tkd,tk->td", by_token, weights)
+        y = _tokens_of_rows(out, weights, at)
     return y, load
