@@ -18,7 +18,7 @@ scales with the model. Loopback understates the broadcast cost a real NIC
 would pay, so the recorded contrast is conservative.
 
 Writes the full record to ``--out`` (artifacts/elastic_restore_r15.json);
-the last stdout line is the JSON summary for the ``bench.py --full`` row,
+the last stdout line is the JSON summary,
 including the new ``hvd_elastic_restore_seconds`` histogram field.
 """
 

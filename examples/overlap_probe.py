@@ -29,7 +29,7 @@ A/B flags: ``--no-pipeline`` forces the serial engine
 last-bucket priority tag.
 
 Writes ``artifacts/overlap_r16.json`` via ``--out``; the last stdout
-line is a JSON summary for the ``bench.py --full`` row.
+line is a JSON summary.
 """
 
 import argparse

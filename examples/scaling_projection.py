@@ -6,8 +6,8 @@ The reference's north star is 90% scaling efficiency at 512 GPUs
 measure a 256-chip job, but every input of the efficiency function can
 be pinned individually:
 
-1. single-chip step time — measured on the v5e chip (bench.py / the
-   examples; values + commands recorded below);
+1. single-chip step time — measured on the v5e chip (values + commands
+   recorded below);
 2. gradient groups: payload bytes AND schedule placement — parsed from
    the REAL v5e compiler's scheduled HLO via a deviceless topology
    compile (``jax.experimental.topologies``, target v5e:2x4). The
@@ -45,9 +45,9 @@ from horovod_tpu.utils import overlap as ov
 from horovod_tpu.utils import scaling_model as sm
 
 # Single-chip rates measured in rounds 2-3 on one v5e of the installation
-# this repo was first written against (BENCH_r03.json; the builder's own
-# round-2 records were removed with that installation — the rates are
-# model INPUTS here, not claims about the current code).
+# this repo was first written against (its records were removed with it —
+# the rates are model INPUTS here, not claims about the current code; the
+# current code's rates are the ledger's, ``PERF_LEDGER.jsonl``).
 # step_time = batch / rate. The three CNNs are exactly
 # the reference's published scaling table (Inception V3 90%, ResNet 90%,
 # VGG-16 68% at 512 GPUs, docs/benchmarks.md:5-6) — the projection must
@@ -55,8 +55,8 @@ from horovod_tpu.utils import scaling_model as sm
 MEASURED = {
     "resnet50": {
         "rate": 2361.24, "unit": "img/s", "batch": 256,
-        "cmd": "python bench.py",
-        "source": "BENCH_r03.json",
+        "cmd": "the old installation's headline ResNet-50 run",
+        "source": "its round-3 record (removed)",
     },
     "inception3": {
         "rate": 1786.0, "unit": "img/s", "batch": 128,

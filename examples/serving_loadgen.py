@@ -24,10 +24,10 @@ Round 11 adds the production traffic shapes the fleet tier exists for:
 
 Prints one JSON record (tokens/sec, TTFT/TPOT p50/p99, block
 accounting incl. the paged-vs-contiguous peak comparison, the doctor's
-serving verdict) and writes it to ``--out`` — the serving bench rows
-(``bench.py --full``) run exactly this (``artifacts/serving_r9.json``,
-``artifacts/serving_r11.json``). The acceptance tests drive the same
-module in-process for the deterministic scheduling checks.
+serving verdict) and writes it to ``--out`` (``artifacts/serving_r9.json``
+and ``artifacts/serving_r11.json`` are two such records, both stamped
+``"substrate": "cpu"``). The acceptance tests drive the same module
+in-process for the deterministic scheduling checks.
 
 Run: python examples/serving_loadgen.py --model tiny --requests 320 \
          --seed 11 --rate 200 --prefix-share 8 --replicas 3 --chaos-kill

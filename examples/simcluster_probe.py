@@ -7,7 +7,7 @@ size, on 8–64 *logical* ranks multiplexed in this one process
 model-vs-measured check re-run at 8 and 32 ranks instead of its
 original 2-rank probe. Writes the full record (with the fitted
 control-plane calibration and per-size model residuals) to ``--out``
-and prints a one-line JSON summary for ``bench.py --full``.
+and prints a one-line JSON summary.
 
 Substrate honesty: loopback TCP, one shared GIL — these calibrate the
 coordinator's per-rank walk costs (recv + HMAC + dispatch per wire),

@@ -332,15 +332,6 @@ def cpu_ops() -> str:
     return os.environ.get("HOROVOD_CPU_OPS", "ring")
 
 
-def flash_xla_bwd() -> bool:
-    """``HOROVOD_FLASH_XLA_BWD``: trace-time escape hatch selecting the
-    rematerialized XLA backward for flash attention (O(S^2) memory).
-    Raw truthiness on purpose — the historical contract is "set to
-    anything non-empty", and both consumers (ops/attention.py,
-    parallel/sequence.py) must keep flipping together."""
-    return bool(os.environ.get("HOROVOD_FLASH_XLA_BWD"))
-
-
 def flight_recorder_path() -> Optional[str]:
     """``HOROVOD_FLIGHT_RECORDER``: crash-postmortem JSONL path (with
     ``{rank}``/``.rankN`` expansion applied by the recorder). None/blank

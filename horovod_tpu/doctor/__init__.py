@@ -99,9 +99,9 @@ def report(evidence: Optional[Evidence] = None) -> dict:
 
 
 def summary(rep: Optional[dict] = None) -> dict:
-    """Compact verdict for ``bench.py`` rows (the ``"health"`` field):
-    how many rules hit and the worst finding's hint. All-empty on a
-    healthy run — honest emptiness beats invented detail."""
+    """Compact verdict for a one-line record (``serving_loadgen``'s
+    ``"health"`` field): how many rules hit and the worst finding's hint.
+    All-empty on a healthy run — honest emptiness beats invented detail."""
     rep = rep if rep is not None else report()
     findings = rep.get("findings", [])
     worst = findings[0] if findings else None

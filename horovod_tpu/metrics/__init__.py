@@ -19,7 +19,7 @@ Three layers (see ``docs/metrics.md`` for the catalog and recipes):
 every hot-path instrumentation site reduces to ``if metrics.on():`` — a
 cached module-global boolean (re-read only on fork, like
 ``horovod_tpu.fault``) — and the registry stays empty. ``enable()``
-flips it programmatically (tests, ``bench.py``).
+flips it programmatically (tests, scripts).
 """
 
 from __future__ import annotations
@@ -740,7 +740,7 @@ def _counter_total(snap: Dict[str, dict], name: str) -> Optional[float]:
 
 
 def controller_health(snap: Optional[Dict[str, dict]] = None) -> dict:
-    """Compact controller-health summary (bench.py rows, dashboards):
+    """Compact controller-health summary (one-line records, dashboards):
     cycle-time p50/p99, fused bytes, response-cache hit rate. On a fresh
     registry — before the first controller cycle, or with any series
     missing (e.g. SPMD-only runs with no eager controller) — every key

@@ -41,8 +41,7 @@ environment variable selects it):
 Both keep the same conventions: products in the input dtype with f32
 accumulation, ``p`` cast to the V dtype for the MXU, fully-masked rows
 emit zeros (and zero gradients) and a finite ``lse``, a cotangent on
-``lse`` is a shift of ``delta``. Set ``HOROVOD_FLASH_XLA_BWD=1`` to fall
-back to the rematerialized XLA backward (read above both paths).
+``lse`` is a shift of ``delta``.
 
 What was measured where (``PERF.md`` sections 5 and 6 have the tables): on
 one TPU v5e, jax 0.9.0, B=64 H=12 S=512 D=64 bf16 with a key mask
@@ -881,38 +880,6 @@ def _flash_fwd_rule(q, k, v, maskf, causal, sm_scale, block_q, block_k,
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, has_mask,
                     window, res, g):
     q, k, v, maskf, out, lse = res
-    from ..common.config import flash_xla_bwd
-
-    if flash_xla_bwd():
-        # Escape hatch: rematerialized backward through the XLA reference
-        # path (materializes the S x S probs; O(S^2) memory). Read at trace
-        # time — set it before the train step is first compiled; already-
-        # compiled executables keep the backward they were traced with.
-        def f(q, k, v):
-            out = reference_attention(q, k, v, key_mask=maskf != 0,
-                                      causal=causal, sm_scale=sm_scale,
-                                      window=window)
-            # Match the flash forward exactly: rows with NO allowed key
-            # emit zeros in the kernel, but reference_attention softmaxes
-            # their constant NEG_INF logits into uniform probs (mean(v)).
-            # Differentiating the unzeroed form would leak those dead
-            # rows' cotangents into dv/dk. O(S^2) bools — this whole
-            # branch is the O(S^2) path already.
-            sq, sk = q.shape[1], k.shape[1]
-            allowed = (maskf != 0)[:, None, :]
-            if causal:
-                qi = jnp.arange(sq)[:, None] + (sk - sq)
-                ki = jnp.arange(sk)[None, :]
-                band = ki <= qi
-                if window is not None:
-                    band = band & (ki > qi - window)
-                allowed = allowed & band[None]
-            row_valid = allowed.any(-1)  # (b, sq)
-            return jnp.where(row_valid[:, :, None, None], out, 0.0)
-
-        _, vjp = jax.vjp(f, q, k, v)
-        dq, dk, dv = vjp(g)
-        return dq, dk, dv, jnp.zeros_like(maskf)
     dq, dk, dv = _flash_backward(q, k, v, maskf != 0, out, lse, g, causal,
                                  sm_scale, block_q, block_k, interpret,
                                  has_mask=has_mask, window=window)

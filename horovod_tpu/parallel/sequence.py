@@ -206,25 +206,6 @@ def _flash_block_pair_bwd(diag_causal, scale, res, cts):
     )
 
     q, maskf, k_blk, v_blk, out, lse = res
-    from ..common.config import flash_xla_bwd
-
-    if flash_xla_bwd():
-        # Same escape hatch as flash_attention's backward: rematerialize
-        # the (out, lse) pair densely and differentiate through XLA
-        # (O(S_local^2) memory; trace-time switch).
-        def dense_pair(q_, k_, v_):
-            pos = jnp.arange(q_.shape[1])
-            a, m, l = _block_attend(q_, k_, v_, scale, pos, pos,
-                                    diag_causal, maskf)
-            l_safe = jnp.maximum(l, 1e-30)
-            o = (a / l_safe).transpose(0, 2, 1, 3).astype(q_.dtype)
-            lse = (m + jnp.log(l_safe))[..., 0]
-            bh, hh, sh = lse.shape
-            return o, lse.reshape(bh * hh, 1, sh)
-
-        _, vjp = jax.vjp(dense_pair, q, k_blk, v_blk)
-        dq, dk, dv = vjp(cts)
-        return dq, None, dk, dv
     do, dlse = cts
     dq, dk, dv = _flash_backward(
         q, k_blk, v_blk, maskf, out, lse, do, diag_causal, scale,

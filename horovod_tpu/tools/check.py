@@ -16,8 +16,7 @@ One command, one exit code, one summary line per tool
 
 Exit 0 iff every tool is clean — the same set of gates tier-1 enforces,
 minus the pytest harness, so it runs in a couple of seconds before a
-push. ``--format json`` emits one machine-readable object (the
-``static_gates`` row in ``bench.py --full``).
+push. ``--format json`` emits one machine-readable object.
 """
 
 from __future__ import annotations
@@ -144,8 +143,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             if not res["ok"]:
                 kept["detail"] = res["detail"]
             out[name] = kept
-        # One line on purpose: the bench.py static_gates row reads the
-        # last JSON line of child stdout.
+        # One line on purpose: a caller reads the last JSON line of
+        # stdout.
         sys.stdout.write(json.dumps(out, sort_keys=True) + "\n")
         return 0 if ok else 1
 

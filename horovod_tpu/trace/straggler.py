@@ -186,9 +186,9 @@ def write_report(trace_dir: str, events: Optional[List[dict]] = None,
 
 
 def summary(snap: Optional[Dict[str, dict]] = None) -> dict:
-    """Compact straggler summary off the metrics registry (bench.py
-    rows): negotiation-slack p99 and the rank with the most straggler
-    cycles. Fields are None when no traced attribution ran."""
+    """Compact straggler summary off the metrics registry:
+    negotiation-slack p99 and the rank with the most straggler cycles.
+    Fields are None when no traced attribution ran."""
     snap = snap if snap is not None else metrics.snapshot()
     p99 = metrics.quantile(snap.get("hvd_negotiation_slack_seconds"), 0.99)
     worst_rank = None

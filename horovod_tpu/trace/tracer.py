@@ -151,10 +151,15 @@ class TraceWriter:
         out.append({"name": "trace_end", "ph": "M", "pid": self.rank,
                     "args": {"dropped_events": dropped,
                              "events": len(events)}})
-        with open(self._path, "w") as f:
+        # Written beside its name and moved onto it: a reader that waits
+        # for the file to exist (rank 0's merge at shutdown) never finds a
+        # half-written one.
+        tmp = self._path + ".tmp"
+        with open(tmp, "w") as f:
             for i, ev in enumerate(out):
                 f.write(("[\n" if i == 0 else ",\n") + json.dumps(ev))
             f.write("\n]\n")
+        os.replace(tmp, self._path)
         return self._path
 
     @property

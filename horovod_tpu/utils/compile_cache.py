@@ -3,9 +3,8 @@
 Every sealed chip call starts with no compiled code, and the ResNet-50
 step, the LM step, prefill and decode each take tens of seconds to
 compile. :func:`enable` is called by the scripts that run there
-(``chip_smoke.py``, ``bench.py``'s measurement children and, through
-``runpy``, the example mains they drive) — never by ``hvd.init()`` and
-never by the tests.
+(``chip_smoke.py``, ``benchmarks/run.py`` and the benchmark's tools) —
+never by ``hvd.init()`` and never by the tests.
 
 The rule (one, so the cache can be placed from outside):
 

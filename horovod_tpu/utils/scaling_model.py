@@ -8,8 +8,8 @@ reduction hides behind backward compute. This module computes the same
 function for a TPU pod from inputs that are each individually *measured*
 on the hardware we have:
 
-* ``step_time_s`` — single-chip step time (bench.py / examples, real
-  v5e chip);
+* ``step_time_s`` — single-chip step time (the ledger's cells / examples,
+  real v5e chip);
 * per-group gradient payloads and their **availability points** — parsed
   from the real v5e-compiled schedule (``utils.overlap``: the compiler
   emits one combined all-reduce per gradient group, placed where its

@@ -1,0 +1,76 @@
+"""What the SmallThinker test files share: the tiny configuration, the
+benchmark's plain reference loaded by path, and seeded weights at scales
+where every path matters."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.models import SMALLTHINKER_TINY, SmallThinkerLM
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ = 128       # the tiny window is 48: shorter than the sequence
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The benchmark's reference file, loaded by path (its name holds a
+    ``-``) with ``benchmarks`` on the path for its own import."""
+    import sys
+
+    bench = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "smallthinker_reference", os.path.join(
+                bench, "reference", "smallthinker-21b-a3b.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(bench)
+    return module
+
+
+def _config(held=None, **over):
+    return dataclasses.replace(SMALLTHINKER_TINY, dtype=jnp.float32,
+                               experts_held=held, **over)
+
+
+def _reference_config(cfg):
+    """The model's sizes under the keys the configuration file has."""
+    return {
+        "num_layers": cfg.num_layers, "rms_norm_eps": cfg.norm_eps,
+        "moe_num_active_primary_experts": cfg.num_selected,
+        "sliding_window_size": cfg.sliding_window,
+        "sliding_window_layout": list(cfg.window_layout),
+        "rope_layout": list(cfg.rope_layout), "rope_theta": cfg.rope_theta,
+        "deployment": {"experts_held": list(cfg.held())},
+    }
+
+
+def _share(params, held):
+    """``params`` of the model that holds every expert, cut to ``held``."""
+    out = jax.tree.map(lambda x: x, params)
+    for name in sorted(n for n in out if n.startswith("layer_")):
+        for w in ("w_gate", "w_up", "w_down"):
+            out[name][w] = {
+                "kernel": out[name][w]["kernel"][jnp.array(held)]}
+    return out
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    cfg = _config()
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
+                             cfg.vocab_size)
+    params = SmallThinkerLM(cfg).init(jax.random.PRNGKey(3), ids)["params"]
+    # Scales at which every path matters: a router that decides, experts
+    # and attention of the residual's own size.
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * (25.0 if "router" in str(path) else 3.0)
+        if x.ndim > 1 else x, params)
+    return ids, params

@@ -5,7 +5,10 @@ raise instead of being swallowed, the Pallas kernels refuse an unknown
 backend instead of silently interpreting, and the launcher tells every
 rank which devices are its own."""
 
+import functools
+import importlib.util
 import os
+import re
 import subprocess
 import sys
 
@@ -54,7 +57,7 @@ _CACHE_CODE = (
 # Parents that start chip-holding children import these; none may take
 # the chip itself.
 _IMPORT_CODE = (
-    "import horovod_tpu, horovod_tpu.run.launch, bench, __graft_entry__\n"
+    "import horovod_tpu, horovod_tpu.run.launch, __graft_entry__\n"
     "from jax._src import xla_bridge\n"
     "print(xla_bridge.backends_are_initialized())\n")
 
@@ -68,8 +71,8 @@ def test_compile_cache_left_alone_when_placed_from_outside(tmp_path):
     # jax read the variable itself; the helper set nothing.
     assert returned == configured == placed
     assert float(min_secs) == 1.0           # jax's own default, untouched
-    # ...and neither the helper nor importing the package, the launcher,
-    # bench or the graft entry initialized a backend.
+    # ...and neither the helper nor importing the package, the launcher
+    # or the graft entry initialized a backend.
     assert backends_up == "False"
 
 
@@ -139,3 +142,59 @@ def test_launcher_decides_every_ranks_devices():
     assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
                and e["TPU_PROCESS_BOUNDS"] == "1,1,1"
                and "JAX_PLATFORMS" not in e for e in bound)
+
+
+# The smoke's ResNet-50 step (``chip_smoke.build_resnet50_step``), lowered
+# and not compiled or run, on a mesh of 1 and of 4 virtual CPU devices at
+# the rehearsal's sizes: what the smoke asserts on the chip about placement
+# and about the exchange, as far as the lowered program shows it.
+@functools.lru_cache(maxsize=None)
+def _lowered_resnet_step(n):
+    import jax
+
+    from horovod_tpu.parallel import make_mesh, set_mesh
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    set_mesh(make_mesh(devices=jax.devices()[:n]))
+    step, state, (x, y), mesh = chip_smoke.build_resnet50_step(2, 32)
+    text = step.lower(*state, x, y).as_text(debug_info=True)
+    return text, state, (x, y), mesh
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_resnet_step_is_placed_as_the_smoke_asserts(n):
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    _, state, (x, y), mesh = _lowered_resnet_step(n)
+    devices = set(mesh.devices.flat)
+    assert mesh.axis_names == ("data",) and len(devices) == n
+    for leaf in jax.tree.leaves(state):
+        assert leaf.sharding.spec == P()
+        assert leaf.sharding.device_set == devices
+    for batch in (x, y):
+        assert batch.sharding.spec == P("data")
+        shards = batch.addressable_shards
+        assert len({s.device for s in shards}) == n
+        assert len({str(s.index) for s in shards}) == n
+        assert all(s.data.shape[0] * n == batch.shape[0] for s in shards)
+
+
+@pytest.mark.parametrize("n,group", [(1, "dense<0> : tensor<1x1xi64>"),
+                                     (4, "dense<[[0, 1, 2, 3]]>")])
+def test_resnet_step_lowers_with_its_exchange(n, group):
+    import jax
+
+    text, state, _, _ = _lowered_resnet_step(n)
+    # One all-reduce a gradient leaf, over every device of the mesh, each
+    # named from inside the optimizer's ``hvd.exchange`` scope.
+    leaves = len(jax.tree.leaves(state[0]))
+    reduces = re.findall(r'"stablehlo\.all_reduce"[^\n]*', text)
+    assert len(reduces) == leaves
+    assert all(f"replica_groups = {group}" in line for line in reduces)
+    scoped = re.findall(
+        r'loc\("(?:[^"]*/)?hvd\.exchange/hvd\.allreduce\.[^"/]*/psum"', text)
+    assert len(scoped) == leaves

@@ -163,16 +163,6 @@ def test_tp_decode_profile_smoke():
     assert '"token_parity_mismatches": 0' in out
 
 
-def test_scaling_efficiency_smoke():
-    out = _run([sys.executable, os.path.join(EX, "scaling_efficiency.py"),
-                "--model", "mlp", "--steps", "3", "--warmup", "1",
-                "--batch-per-chip", "8"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=2"})
-    assert '"metric": "scaling_efficiency"' in out
-    assert '"efficiency":' in out
-
-
 @pytest.mark.slow  # ~15 s; tensorflow_mnist_eager_two_ranks keeps the tf
 def test_tensorflow_mnist_two_ranks():  # 2-rank mnist path in tier-1
     # The tf.function path: allreduce rides a py_function node inside the

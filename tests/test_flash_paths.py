@@ -1,0 +1,226 @@
+"""The two paths through the flash kernels (one tile, streamed), each
+against the XLA reference, and the rule that chooses between them from
+shapes alone."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from attention_helpers import (B, D, H, KERNELS, PATHS, S,
+                               _assert_grads_close, _rand, _sq_loss,
+                               both_paths)
+from horovod_tpu.ops.attention import flash_attention, reference_attention
+
+
+@both_paths
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_paths_forward_and_grad(path, dtype, masked, causal):
+    dtype = jnp.dtype(dtype)
+    q, k, v = (_rand((B, S, H, D), 30 + i, dtype) for i in range(3))
+    mask = None
+    if masked:
+        mask_np = np.random.RandomState(33).rand(B, S) > 0.3
+        mask_np[:, 0] = True      # no fully-masked row, causal or not
+        mask = jnp.asarray(mask_np)
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, key_mask=mask, causal=causal, **PATHS[path])
+    ref = lambda q, k, v: reference_attention(  # noqa: E731
+        q, k, v, key_mask=mask, causal=causal)
+    out = flash(q, k, v)
+    assert out.dtype == dtype and out.shape == q.shape
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref(q, k, v), np.float32),
+        atol=2e-5 if f32 else 2e-2, rtol=1e-4 if f32 else 2e-2)
+    _assert_grads_close(flash, ref, q, k, v, 2e-3 if f32 else 5e-2)
+
+
+@both_paths
+@pytest.mark.parametrize("sq,sk", [(16, 64), (32, 64)])
+def test_flash_paths_causal_sq_ne_sk(path, sq, sk):
+    # Decode convention: the sq query rows are the LAST sq key positions.
+    q = _rand((B, sq, H, D), 40)
+    k, v = _rand((B, sk, H, D), 41), _rand((B, sk, H, D), 42)
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, **PATHS[path])
+    ref = lambda q, k, v: reference_attention(q, k, v, causal=True)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=1e-4)
+    _assert_grads_close(flash, ref, q, k, v, 2e-3)
+
+
+@both_paths
+@pytest.mark.parametrize("how", ["key_mask", "causal_sq_gt_sk"])
+def test_flash_paths_fully_masked_rows(path, how):
+    # Rows with no allowed key: zeros out, zero (finite) gradients, and
+    # the valid rows' gradients see nothing of them.
+    if how == "key_mask":
+        q, k, v = (_rand((B, S, H, D), 50 + i) for i in range(3))
+        mask_np = np.random.RandomState(53).rand(B, S) > 0.3
+        mask_np[0, :] = False
+        mask = jnp.asarray(mask_np)
+        dead = np.zeros((B, S), bool)
+        dead[0] = True
+        kw = dict(key_mask=mask)
+    else:
+        sq, sk = 64, 32
+        q = _rand((B, sq, H, D), 54)
+        k, v = _rand((B, sk, H, D), 55), _rand((B, sk, H, D), 56)
+        dead = np.broadcast_to(np.arange(sq) < sq - sk, (B, sq))
+        kw = dict(causal=True)
+    flash = lambda q, k, v: flash_attention(q, k, v, **kw, **PATHS[path])  # noqa: E731
+
+    def ref(q, k, v):
+        out = reference_attention(q, k, v, **kw)
+        return jnp.where(jnp.asarray(dead)[:, :, None, None], 0.0, out)
+
+    out = np.asarray(flash(q, k, v))
+    np.testing.assert_array_equal(out[dead], 0.0)
+    np.testing.assert_allclose(out, np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=1e-4)
+    gf = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(_sq_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    assert all(np.isfinite(np.asarray(g)).all() for g in gf)
+    np.testing.assert_array_equal(np.asarray(gf[0])[dead], 0.0)
+    if how == "key_mask":      # batch 0 has no live key at all
+        np.testing.assert_array_equal(np.asarray(gf[1])[0], 0.0)
+        np.testing.assert_array_equal(np.asarray(gf[2])[0], 0.0)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("path,blocks", [
+    ("one_tile", {}), ("streamed", {"block_q": 64, "block_k": 64})])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_paths_awkward_len_auto_pad(path, blocks, causal):
+    # ViT's 197 pads to 256: one tile at the defaults, four blocks a side
+    # at 64; the pad mask joins the caller's own either way.
+    s = 197
+    q, k, v = (_rand((B, s, H, D), 70 + i) for i in range(3))
+    mask = jnp.asarray(np.random.RandomState(73).rand(B, s) > 0.2
+                       ).at[:, 0].set(True)
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, key_mask=mask, causal=causal, **blocks)
+    ref = lambda q, k, v: reference_attention(  # noqa: E731
+        q, k, v, key_mask=mask, causal=causal)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=1e-4)
+    _assert_grads_close(flash, ref, q, k, v, 2e-3)
+
+
+@both_paths
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_paths_lse_out_and_dlse_in(path, causal):
+    # What ring attention leans on: the forward returns the row
+    # log-sum-exp, and the backward takes a cotangent on it (a shift of
+    # delta). Checked against jax's own vjp of a dense (out, lse) pair.
+    from horovod_tpu.ops.attention import (NEG_INF, _flash_backward,
+                                           _flash_forward)
+
+    blocks = PATHS[path] or {"block_q": 512, "block_k": 1024}
+    bq, bk = blocks["block_q"], blocks["block_k"]
+    b, s, h, d = 2, 32, 2, 8
+    q, k, v = (_rand((b, s, h, d), 80 + i) for i in range(3))
+    mask_np = np.random.RandomState(83).rand(b, s) > 0.3
+    mask_np[:, 0] = True
+    mask = jnp.asarray(mask_np)
+
+    def dense(q, k, v):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / d ** 0.5
+        allowed = mask[:, None, None, :]
+        if causal:
+            allowed = allowed & (jnp.arange(s)[None, :]
+                                 <= jnp.arange(s)[:, None])[None, None]
+        logits = jnp.where(allowed, logits, NEG_INF)
+        lse = jax.nn.logsumexp(logits, axis=-1)             # (b, h, s)
+        out = jnp.einsum("bhqk,bkhd->bqhd",
+                         jnp.exp(logits - lse[..., None]), v)
+        return out, lse.reshape(b * h, 1, s)
+
+    (ref_out, ref_lse), vjp = jax.vjp(dense, q, k, v)
+    out, lse = _flash_forward(q, k, v, mask, causal, None, bq, bk, True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               atol=2e-5, rtol=1e-5)
+    do, dlse = _rand(out.shape, 84), _rand(lse.shape, 85)
+    got = _flash_backward(q, k, v, mask, out, lse, do, causal, None, bq,
+                          bk, True, dlse=dlse)
+    for a, r in zip(got, vjp((do, dlse))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=2e-5, rtol=1e-3)
+
+
+def _kernel_grids(s, d=16, h=1, hkv=None, **kw):
+    """``{pallas_call name: rank of its grid}`` of a traced ``jax.grad``
+    of flash attention. Both paths call their kernels by the same three
+    names (the benchmark's per-kernel metrics read them); what tells them
+    apart is the grid: K/V heads alone on the one-tile path, (heads,
+    q blocks, k blocks) where the kernels stream."""
+    x = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, s, hkv or h, d), jnp.bfloat16)
+    m = jax.ShapeDtypeStruct((1, s), jnp.bool_)
+    f = jax.grad(lambda q, k, v, m: flash_attention(
+        q, k, v, key_mask=m, interpret=True, **kw).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                assert name not in found, name
+                found[name] = len(eqn.params["grid_mapping"].grid)
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                walk(inner)
+
+    walk(jax.make_jaxpr(f)(x, kv, kv, m).jaxpr)
+    return found
+
+
+ONE_TILE_GRIDS = dict.fromkeys(KERNELS, 1)
+STREAMED_GRIDS = dict.fromkeys(KERNELS, 3)
+
+
+@pytest.mark.parametrize("case,s,kw,grids", [
+    # BERT-base s512's own shape: one tile at the default blocks.
+    ("s512_defaults", 512, dict(d=64, h=2), ONE_TILE_GRIDS),
+    ("s512_causal_gqa", 512, dict(d=64, h=4, hkv=2, causal=True),
+     ONE_TILE_GRIDS),
+    ("s128_defaults", 128, {}, ONE_TILE_GRIDS),
+    # Past one default block a side the kernels stream, as before.
+    ("s2048_defaults", 2048, {}, STREAMED_GRIDS),
+    ("s1024_defaults", 1024, {}, STREAMED_GRIDS),     # two query blocks
+    ("s512_small_blocks", 512, dict(block_q=128, block_k=128),
+     STREAMED_GRIDS),
+    ("s512_one_side", 512, dict(block_k=256), STREAMED_GRIDS),
+    # One block a side by request, but the tile is past the VMEM rule.
+    ("s2048_one_block_declined", 2048,
+     dict(block_q=2048, block_k=2048), STREAMED_GRIDS),
+])
+def test_flash_path_is_chosen_from_shapes(case, s, kw, grids):
+    assert _kernel_grids(s, **kw) == grids
+
+
+def test_one_tile_rule():
+    from horovod_tpu.ops.attention import _one_tile_heads
+
+    # BERT-base s512, Llama-style GQA at 512, f32 tests: taken.
+    assert _one_tile_heads(512, 512, 64, 2, group=1, hkv=12) >= 1
+    assert _one_tile_heads(512, 512, 128, 2, group=4, hkv=8) >= 1
+    assert _one_tile_heads(512, 1024, 128, 4, group=1, hkv=8) >= 1
+    # Heads a step always divide the K/V heads (one mask row a step).
+    for hkv in (1, 2, 3, 8, 12):
+        n = _one_tile_heads(128, 128, 64, 2, group=1, hkv=hkv)
+        assert n >= 1 and hkv % n == 0
+    # Tiles whose f32 scores alone overrun scoped VMEM: declined.
+    assert _one_tile_heads(1024, 1024, 64, 2, group=1, hkv=8) == 0
+    assert _one_tile_heads(2048, 2048, 64, 2, group=1, hkv=8) == 0
+    # A query group too large to hold beside the tile: declined.
+    assert _one_tile_heads(512, 1024, 128, 4, group=32, hkv=1) == 0

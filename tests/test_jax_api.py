@@ -7,10 +7,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu.parallel import mesh
+from horovod_tpu.parallel import make_mesh, mesh, replicate, shard_batch
 
 N = 8
 
@@ -20,51 +21,100 @@ def _loss_fn(params, x, y):
     return jnp.mean((pred - y) ** 2)
 
 
-def test_distributed_optimizer_matches_single_device():
+# The main path as a table: ``hvd.DistributedOptimizer`` inside
+# ``jax.jit(jax.shard_map(...))`` on 4 virtual CPU devices against one
+# device on the global batch. ``tolerance`` is the largest gap allowed
+# between the two, as a part of the largest change a parameter made in
+# the two steps: float32 sums in another order without compression, a
+# gradient rounded to 11 (fp16) or 8 (bf16) significand bits before it is
+# summed over 4 devices with it, with margin for Adam's division.
+N_DEV = 4
+TOLERANCE = {"none": 1e-5, "fp16": 4e-3, "bf16": 2e-2}
+OPTIMIZERS = {
+    "sgd_momentum": lambda: optax.sgd(0.1, momentum=0.9),
+    "adamw": lambda: optax.adamw(1e-2),
+}
+
+
+def _mlp_loss(params, x, y):
+    h = jnp.tanh(x @ params["w1"] + params["b1"])
+    return jnp.mean((h @ params["w2"] + params["b2"] - y) ** 2)
+
+
+def _device_copies(leaf):
+    return [np.asarray(s.data).tobytes() for s in leaf.addressable_shards]
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+@pytest.mark.parametrize("optimizer", sorted(OPTIMIZERS))
+@pytest.mark.parametrize("compression", sorted(TOLERANCE))
+@pytest.mark.parametrize("average", [True, False])
+def test_distributed_optimizer_matches_single_device(average, compression,
+                                                     optimizer, passes):
     hvd.init()
-    key = jax.random.PRNGKey(0)
-    k1, k2, k3 = jax.random.split(key, 3)
-    x = jax.random.normal(k1, (N * 4, 3))
-    y = jax.random.normal(k2, (N * 4, 1))
-    params = {"w": jax.random.normal(k3, (3, 1)), "b": jnp.zeros((1,))}
+    m = make_mesh({"data": N_DEV}, devices=jax.devices()[:N_DEV])
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    params = {"w1": jax.random.normal(keys[0], (8, 16)) * 0.5,
+              "b1": jnp.zeros((16,)),
+              "w2": jax.random.normal(keys[1], (16, 2)) * 0.5,
+              "b2": jnp.zeros((2,))}
+    # One global batch a backward pass, two applied steps.
+    xs = jax.random.normal(keys[2], (2 * passes, N_DEV * 4, 8))
+    ys = jax.random.normal(keys[3], (2 * passes, N_DEV * 4, 2))
 
-    tx = hvd.DistributedOptimizer(optax.sgd(0.1), axis_name="data")
-    opt_state = tx.init(params)
+    tx = hvd.DistributedOptimizer(
+        OPTIMIZERS[optimizer](), axis_name="data", average=average,
+        compression=getattr(hvd.Compression, compression),
+        backward_passes_per_step=passes)
 
-    def train_step(params, opt_state, x, y):
-        grads = jax.grad(_loss_fn)(params, x, y)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
+    def train_step(p, opt_state, x, y):
+        grads = jax.grad(_mlp_loss)(p, x, y)
+        updates, opt_state = tx.update(grads, opt_state, p)
+        return optax.apply_updates(p, updates), opt_state
 
-    m = mesh()
-    sharded_step = jax.jit(
-        jax.shard_map(
-            train_step,
-            mesh=m,
-            in_specs=(P(), P(), P("data"), P("data")),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-    )
+    sharded_step = jax.jit(jax.shard_map(
+        train_step, mesh=m,
+        in_specs=(P(), P(), P("data"), P("data")), out_specs=(P(), P()),
+        check_vma=False))
 
-    # Single-device baseline: plain SGD on the full batch. Averaging
-    # per-shard grads across the mesh == full-batch gradient, so the two
-    # trajectories must match.
-    base_tx = optax.sgd(0.1)
-    base_state = base_tx.init(params)
-    base_params = params
+    # One device: the mean of the shards' gradients is the gradient on the
+    # global batch, their sum N_DEV times it.
+    base_tx = OPTIMIZERS[optimizer]()
+    if passes > 1:
+        base_tx = optax.MultiSteps(base_tx, every_k_schedule=passes)
+    scale = 1.0 if average else float(N_DEV)
 
-    for _ in range(5):
-        params, opt_state = sharded_step(params, opt_state, x, y)
-        g = jax.grad(_loss_fn)(base_params, x, y)
-        u, base_state = base_tx.update(g, base_state, base_params)
-        base_params = optax.apply_updates(base_params, u)
+    @jax.jit
+    def base_step(p, opt_state, x, y):
+        grads = jax.tree.map(lambda g: g * scale,
+                             jax.grad(_mlp_loss)(p, x, y))
+        updates, opt_state = base_tx.update(grads, opt_state, p)
+        return optax.apply_updates(p, updates), opt_state
 
-    for kname in params:
-        np.testing.assert_allclose(
-            np.asarray(params[kname]), np.asarray(base_params[kname]),
-            rtol=1e-5, atol=1e-6,
-        )
+    got = replicate((params, tx.init(params)), m)
+    want = (params, base_tx.init(params))
+    for x, y in zip(xs, ys):
+        got = sharded_step(*got, shard_batch(x, m), shard_batch(y, m))
+        want = base_step(*want, x, y)
+
+    for name in params:
+        moved = np.abs(np.asarray(want[0][name] - params[name])).max()
+        gap = np.abs(np.asarray(got[0][name]) - np.asarray(want[0][name]))
+        assert gap.max() <= TOLERANCE[compression] * moved, name
+    # What ``resnet50-dp4`` holds on the chip with limit 0: every device's
+    # copy of the parameters and of the optimizer's state, bit for bit.
+    got_params, got_state = got
+    if passes > 1:
+        # Between applied steps ``optax.MultiSteps`` accumulates each
+        # device's own gradients; after one it empties the accumulator by
+        # a product, which leaves zeros of either sign.
+        for leaf in jax.tree.leaves(got_state.acc_grads):
+            assert all(not np.asarray(s.data).any()
+                       for s in leaf.addressable_shards)
+        got_state = got_state._replace(acc_grads=None)
+    for leaf in jax.tree.leaves((got_params, got_state)):
+        copies = _device_copies(leaf)
+        assert len(copies) == N_DEV and len(set(copies)) == 1
 
 
 def test_distributed_value_and_grad():
@@ -118,8 +168,6 @@ def test_broadcast_parameters_single():
 def test_distributed_optimizer_compression_in_jit():
     """Under jit, Compression.bf16 casts the gradient before the psum (the
     collective moves bf16) and restores f32 afterwards."""
-    from horovod_tpu.parallel import make_mesh
-
     hvd.init()
     mesh = make_mesh({"data": 8})
     tx = hvd.DistributedOptimizer(optax.sgd(0.1), axis_name="data",

@@ -669,14 +669,23 @@ def test_live_drift_drive_fires_heals_and_persists(tmp_path, monkeypatch):
 def test_live_drift_twin_stays_silent(tmp_path, monkeypatch):
     """The undisturbed twin: same drive, no injected delay — the drift
     sentinel must stay silent across 20+ windows judged against the
-    run's own early calibration."""
+    run's own early calibration.
+
+    Every step carries the same 50 ms on rank 1's tick, in the windows
+    that calibrate and in the windows that are judged. At the sim's own
+    pace a cycle is under a millisecond and the whole calibration 20 ms:
+    one rank thread descheduled for a few milliseconds on a loaded host
+    doubled a window, which is the machine drifting and not the code
+    firing falsely. Against 50 ms a cycle the sentinel needs half a
+    second of lost time inside one horizon."""
+    pace = {1: 0.05}
     committed_path = tmp_path / "committed.json"
     step = 0
     cluster = SimCluster(ranks=4, elastic=True, protocheck=True)
     with cluster as c:
         for _ in range(4):
             for _ in range(3):
-                c.run_step([_spec(f"t.{step}")])
+                c.run_step([_spec(f"t.{step}")], delays=pace)
                 step += 1
             c.roll_window()
         committed_path.write_text(json.dumps(lc.get().refit()))
@@ -684,7 +693,7 @@ def test_live_drift_twin_stays_silent(tmp_path, monkeypatch):
                            str(committed_path))
         for window in range(20):
             for _ in range(3):
-                c.run_step([_spec(f"t.{step}")])
+                c.run_step([_spec(f"t.{step}")], delays=pace)
                 step += 1
             c.roll_window()
             drift = [f for f in c.doctor_report()["findings"]

@@ -159,8 +159,7 @@ def test_event_model_hand_cases():
 def test_artifact_inputs_pinned():
     """The shipped projection artifact's inputs must match what it claims:
     gradient payload == the real model's parameter bytes (cheap
-    eval_shape, no compile), measured rate == the driver's BENCH record,
-    efficiencies coherent."""
+    eval_shape, no compile), efficiencies coherent."""
     path = os.path.join(REPO, "artifacts", "scaling_projection_r4.json")
     d = json.load(open(path))
 
@@ -183,10 +182,6 @@ def test_artifact_inputs_pinned():
                 jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
                 deterministic=True))["params"],
     }
-    bench = json.load(open(os.path.join(REPO, "BENCH_r03.json")))
-    assert d["resnet50"]["measured_input"]["rate"] == \
-        bench["parsed"]["value"]
-
     for name, params in model_params.items():
         sec = d[name]
         pbytes = sum(int(np.prod(l.shape)) * l.dtype.itemsize

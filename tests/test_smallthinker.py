@@ -1,15 +1,9 @@
 """SmallThinker (``models/smallthinker.py``) and the dropless expert layer
 it forced (``parallel/moe.py::moe_apply_held``), at a tiny size on seeded
-weights: the model against the benchmark's plain float32 reference
-(``benchmarks/reference/smallthinker-21b-a3b.py``: no flax, no kernel, no
-grouped product, every held expert applied densely), whole and with a
-share of the experts; the parts the four shares give add up to the whole
-layer; nothing is dropped under a router forced onto one expert, nor
-whatever part of the assignments lands on a share."""
-
-import dataclasses
-import importlib.util
-import os
+weights (the model against the benchmark's plain float32 reference is
+``test_smallthinker_reference.py``): the parts the four shares give add
+up to the whole layer; nothing is dropped under a router forced onto one
+expert, nor whatever part of the assignments lands on a share."""
 
 import jax
 import jax.numpy as jnp
@@ -22,100 +16,8 @@ from horovod_tpu.models.smallthinker import SmallThinkerBlock
 from horovod_tpu.ops.attention import make_attention_fn
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SEQ = 128       # the tiny window is 48: shorter than the sequence
-
-
-@pytest.fixture(scope="module")
-def reference():
-    """The benchmark's reference file, loaded by path (its name holds a
-    ``-``) with ``benchmarks`` on the path for its own import."""
-    import sys
-
-    bench = os.path.join(ROOT, "benchmarks")
-    sys.path.insert(0, bench)
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "smallthinker_reference", os.path.join(
-                bench, "reference", "smallthinker-21b-a3b.py"))
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(bench)
-    return module
-
-
-def _config(held=None, **over):
-    return dataclasses.replace(SMALLTHINKER_TINY, dtype=jnp.float32,
-                               experts_held=held, **over)
-
-
-def _reference_config(cfg):
-    """The model's sizes under the keys the configuration file has."""
-    return {
-        "num_layers": cfg.num_layers, "rms_norm_eps": cfg.norm_eps,
-        "moe_num_active_primary_experts": cfg.num_selected,
-        "sliding_window_size": cfg.sliding_window,
-        "sliding_window_layout": list(cfg.window_layout),
-        "rope_layout": list(cfg.rope_layout), "rope_theta": cfg.rope_theta,
-        "deployment": {"experts_held": list(cfg.held())},
-    }
-
-
-def _share(params, held):
-    """``params`` of the model that holds every expert, cut to ``held``."""
-    out = jax.tree.map(lambda x: x, params)
-    for name in sorted(n for n in out if n.startswith("layer_")):
-        for w in ("w_gate", "w_up", "w_down"):
-            out[name][w] = {
-                "kernel": out[name][w]["kernel"][jnp.array(held)]}
-    return out
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    cfg = _config()
-    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
-                             cfg.vocab_size)
-    params = SmallThinkerLM(cfg).init(jax.random.PRNGKey(3), ids)["params"]
-    # Scales at which every path matters: a router that decides, experts
-    # and attention of the residual's own size.
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x * (25.0 if "router" in str(path) else 3.0)
-        if x.ndim > 1 else x, params)
-    return ids, params
-
-
-@pytest.mark.parametrize("held", [None, (2, 3), (0, 5, 7)],
-                         ids=["all", "share-2-3", "share-0-5-7"])
-def test_loss_and_gradients_match_the_plain_reference(held, seeded,
-                                                      reference):
-    ids, params = seeded
-    cfg = _config(held)
-    params = params if held is None else _share(params, held)
-    model = SmallThinkerLM(cfg)
-
-    def loss(p):
-        return causal_lm_loss(model.apply({"params": p}, ids)[0], ids)
-
-    ours, grads = jax.jit(jax.value_and_grad(loss))(params)
-
-    def reference_loss(p):
-        total = sum(reference.sequence_nll_sum(
-            p, row, rnd=lambda a: a, config=_reference_config(cfg))
-            for row in ids)
-        return total / (ids.shape[0] * (ids.shape[1] - 1))
-
-    theirs, reference_grads = jax.jit(
-        jax.value_and_grad(reference_loss))(params)
-    np.testing.assert_allclose(ours, theirs, rtol=1e-5)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), r in zip(flat, jax.tree.leaves(reference_grads)):
-        # float32 through eight layers of weights scaled up: the loss
-        # agrees to 1e-5, a gradient to a part in a thousand of its leaf.
-        scale = float(jnp.max(jnp.abs(r))) + 1e-12
-        assert float(jnp.max(jnp.abs(g - r))) <= 3e-3 * scale, path
+from smallthinker_helpers import (SEQ, _config, _reference_config,  # noqa: F401
+                                  _share, reference, seeded)
 
 
 def test_flash_kernels_remat_and_chunked_loss_change_nothing(seeded):

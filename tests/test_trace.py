@@ -296,7 +296,7 @@ def test_attribution_names_late_rank_and_feeds_metrics():
     assert cycles[("2",)] == 10
     [[_, slack]] = snap["hvd_negotiation_slack_seconds"]["values"]
     assert slack["count"] == 10
-    # bench.py's row summary reads the same registry.
+    # The compact summary reads the same registry.
     summary = hvd_trace.summary()
     assert summary["worst_rank"] == 2
     # The registry quantile interpolates inside log-spaced buckets:
