@@ -433,6 +433,8 @@ def phase_flash_one_tile(sz, rehearsal):
         ("causal-gqa-d128", jnp.bfloat16, 512, 512, 8, 2, 128, True, False),
         ("f32-mask-d64", jnp.float32, 512, 512, 4, 4, 64, False, True),
         ("causal-sq256-sk512", jnp.bfloat16, 256, 512, 4, 4, 64, True, True),
+        # BERT's heads: a grid step's block is four heads of the twelve.
+        ("mask-h12-d64", jnp.bfloat16, 512, 512, 12, 12, 64, False, True),
     ]
     rng = np.random.RandomState(0)
     batch = 2

@@ -15,28 +15,39 @@ environment variable selects it):
   are one block each and ``_one_tile_heads`` finds that the (sq, sk) tile
   fits scoped VMEM (at the default blocks: sq <= 512 and sk <= 1024;
   BERT-base at sequence 512, ViT's padded 197, a ring shard), nothing is
-  streamed. The grid runs over K/V heads only, a few a step, and every
-  kernel holds the score tile transposed, (sk, sq), so the row statistics
-  are the (1, sq) lane rows they are stored as. ``hvd_flash_fwd`` is a
-  plain softmax of the tile (scores, row max, ``exp``, row sum, ``p @ v``,
-  normalise; no scratch, no phases, no rescale) and writes ``o`` and the
-  row log-sum-exp. The backward keeps the streamed path's two kernels and
-  their names, each one pass over the tile with ``p = exp(s - lse)``,
-  ``dp = do v^T`` and ``ds`` rebuilt: ``hvd_flash_bwd_dq`` (``dq = ds k``)
-  and ``hvd_flash_bwd_dkv`` (``dv = p^T do``, ``dk = ds^T q``). Under GQA
-  a step covers a K/V head with its query group, so dk/dv are still
+  streamed. The kernels read and write the caller's arrays where XLA
+  keeps them: (B, S, H, D) is handed over as (B, H * D, S)
+  (``_heads_to_rows``: a reshape and a swap of the last two axes, both
+  bitcasts of the sequence-minor form XLA gives a projection), the grid
+  runs over batch rows and K/V heads, a few a step, the block index map
+  picks the step's band of rows and a head is a static slice of the
+  block, with its sequence on the lanes whatever the head width. No
+  transposed copy is made around a call. Every kernel holds the score
+  tile transposed, (sk, sq), so the row statistics are the (1, sq) lane
+  rows they are stored as, and with every operand as (d, s) the products
+  are plain (only k and, in the backward, v are transposed, (d, sk), once
+  a K/V head). ``hvd_flash_fwd`` is a plain softmax of the tile (scores,
+  row max, ``exp``, row sum, ``v^T p``, normalise; no scratch, no phases,
+  no rescale) and writes ``o`` and the row log-sum-exp. The backward
+  keeps the streamed path's two kernels and their names, each one pass
+  over the tile with ``p = exp(s - lse)``, ``dp = v do^T`` and ``ds``
+  rebuilt: ``hvd_flash_bwd_dq`` (``dq^T = k^T ds``) and
+  ``hvd_flash_bwd_dkv`` (``dv^T = do^T p^T``, ``dk^T = q^T ds^T``). Under
+  GQA a step covers a K/V head with its query group, so dk/dv are still
   written once per K/V head.
 * **Streamed** (every other shape, byte for byte as before): classic
-  FlashAttention-2 online-softmax blocking. The grid is (batch*heads,
-  q_blocks, k_blocks); Pallas streams one (block_k, d) K/V tile per
-  innermost grid step from HBM into VMEM (BlockSpec index_maps drive the
-  double-buffered DMA pipeline), so VMEM holds O(block_q*d + block_k*d),
-  not O(seq_k*d), and the ceiling on sequence length is HBM, not VMEM.
-  Running max / normalizer / output accumulate in VMEM scratch across the
-  innermost dimension (TPU grids execute sequentially). Backward is two
-  kernels (``hvd_flash_bwd_dq`` streaming K/V, ``hvd_flash_bwd_dkv``
-  streaming Q/dO), each rebuilding the probabilities from the saved
-  log-sum-exp instead of storing the S x S matrix.
+  FlashAttention-2 online-softmax blocking over heads folded into batch
+  (``_fold_heads``: a transposed copy each way, (B * H, S, D)). The grid
+  is (batch*heads, q_blocks, k_blocks); Pallas streams one (block_k, d)
+  K/V tile per innermost grid step from HBM into VMEM (BlockSpec
+  index_maps drive the double-buffered DMA pipeline), so VMEM holds
+  O(block_q*d + block_k*d), not O(seq_k*d), and the ceiling on sequence
+  length is HBM, not VMEM. Running max / normalizer / output accumulate
+  in VMEM scratch across the innermost dimension (TPU grids execute
+  sequentially). Backward is two kernels (``hvd_flash_bwd_dq`` streaming
+  K/V, ``hvd_flash_bwd_dkv`` streaming Q/dO), each rebuilding the
+  probabilities from the saved log-sum-exp instead of storing the S x S
+  matrix.
 
 Both keep the same conventions: products in the input dtype with f32
 accumulation, ``p`` cast to the V dtype for the MXU, fully-masked rows
@@ -55,7 +66,11 @@ two selects), not by the MXU: with the products removed a forward call still
 took 87% of its time, with the softmax removed 31%; with the mask as an
 additive row and the streaming state kept it took 2.02 ms, 2.5 times the
 one-tile forward. The one-tile kernels read 0.71 / 0.76 / 1.17 ms a call in
-that cell's trace (31.6 ms a step). One fused backward kernel (``p``, ``dp``
+that cell's trace (31.6 ms a step) while they took heads folded into batch,
+and 0.69 / 0.76 / 0.92 (28.4 ms a step; my chip run, PR 29) on the caller's
+arrays as (B, H * D, S); the 96 copies XLA made around the 36 calls for the
+fold, most of what PR 29 gained, were never kernel time (PERF.md
+section 6). One fused backward kernel (``p``, ``dp``
 and ``ds`` once for all three gradients) measured 1.15 ms a call there
 against the two kernels' 1.92; it is not what runs, because the benchmark's
 accepted per-kernel metrics read the two names in that cell (``PERF.md``
@@ -302,27 +317,53 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         lse_ref[0, 0] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
 
 
-def _fold_heads(q, k, v, key_mask):
-    """Fold heads into batch: q (B, Sq, H, D) -> (B*H, Sq, D) and k/v
-    (B, Sk, Hkv, D) -> (B*Hkv, Sk, D) contiguous MXU tiles, plus the mask
-    as (B, 1, Sk) int32 (TPU block shapes must tile (8,128) or equal the
-    array dims; the singleton row dim satisfies the equality escape).
-    Under GQA (Hkv < H) the K/V tiles are NOT repeated — the pallas
-    index_maps route each query head's grid row to its group's K/V row,
-    so the K/V HBM footprint stays at Hkv/H of the repeated form (DMA
-    traffic is unchanged: tiles are re-fetched per query-head row).
-    Shared by the forward and backward pallas_calls so their layouts
-    cannot drift apart."""
-    b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, d)
+def _heads_to_rows(x):
+    """(B, S, N, D) -> (B, N * D, S), what the one-tile kernels take: a
+    head is a band of D rows with its sequence on the lanes, which a block
+    index map picks, whatever D is. No copy is made where XLA already
+    keeps the array so, and around attention it does: the TPU compiler
+    writes a projection's (B, S, N, D) result sequence-minor
+    (``{1,3,2,0}`` in the compiled steps of both flash cells, head width
+    64 and 128) and wants the output projection's operand and the three
+    gradients the same way, so the reshape and the swap of the last two
+    axes compile to bitcasts
+    (``tests/benchmark/test_aot_flash_layout.py`` reads that in the
+    compiled BERT step). Handing the kernels (B, S, N * D) instead, heads
+    as lane bands, left one transposing copy an operand in that step."""
+    b, s, n, d = x.shape
+    return x.reshape(b, s, n * d).transpose(0, 2, 1)
+
+
+def _rows_to_heads(x, n: int):
+    b, nd, s = x.shape
+    return x.transpose(0, 2, 1).reshape(b, s, n, nd // n)
+
+
+def _fold_heads(x):
+    """(B, S, N, D) -> (B * N, S, D), what the streamed kernels take: heads
+    folded into batch by a transposed copy, each head's (S, D) rows
+    contiguous MXU tiles (``_heads_to_rows``' form lost to it there:
+    PERF.md section 6, PR 29). Under GQA (Hkv < H) the K/V tiles are NOT
+    repeated — the pallas index_maps route each query head's grid row to
+    its group's K/V row, so the K/V HBM footprint stays at Hkv/H of the
+    repeated form (DMA traffic is unchanged: tiles are re-fetched per
+    query-head row)."""
+    b, s, n, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
+
+
+def _unfold_heads(x, n: int):
+    _, s, d = x.shape
+    return x.reshape(-1, n, s, d).transpose(0, 2, 1, 3)
+
+
+def _mask_rows(key_mask, b: int, sk: int):
+    """The key mask as (B, 1, Sk) int32 (TPU block shapes must tile
+    (8,128) or equal the array dims; the singleton row dim satisfies the
+    equality escape); all ones where there is none."""
     if key_mask is None:
-        maskf = jnp.ones((b, 1, sk), dtype=jnp.int32)
-    else:
-        maskf = key_mask.astype(jnp.int32).reshape(b, 1, sk)
-    return qf, kf, vf, maskf
+        return jnp.ones((b, 1, sk), dtype=jnp.int32)
+    return key_mask.astype(jnp.int32).reshape(b, 1, sk)
 
 
 def _gqa_index_maps(h: int, hkv: int):
@@ -407,23 +448,29 @@ _NN = ((1,), (0,))   # a @ b
 _TN = ((0,), (0,))   # a.T @ b
 
 
-def _one_tile_scores(rows, cols, bias, causal: bool, q_axis: int,
-                     q_offset: int, sm_scale: float,
-                     window: Optional[int] = None):
-    """The masked, scaled f32 scores ``rows @ cols.T`` of one head, the
-    queries along ``q_axis`` of the tile; the scale goes on the f32
-    product as in the streamed kernels. ``bias`` is the key mask as one
-    additive vector (0 / NEG_INF) broadcast along the query axis: a masked
-    score becomes exactly NEG_INF (|s| is far below NEG_INF's last bit),
-    ``exp`` of it less a live row's max is exactly 0, and no select pass
-    over the tile is needed."""
-    s = _dot(rows, cols, _NT) * sm_scale
+def _head(ref, g: int, n: int):
+    """Head ``g`` of the ``n`` in a (1, n * d, s) block: its (d, s) band of
+    rows, a static sublane slice."""
+    d = ref.shape[1] // n
+    return ref.at[0, g * d:(g + 1) * d, :]
+
+
+def _one_tile_scores(k, qt, bias, causal: bool, q_offset: int,
+                     sm_scale: float, window: Optional[int] = None):
+    """The masked, scaled f32 scores of one head as the TRANSPOSED tile,
+    ``k @ qt`` (sk, sq), from k (sk, d) and the queries as they lie in
+    HBM, qt (d, sq); the scale goes on the f32 product as in the streamed
+    kernels. ``bias`` is the key mask as one additive (sk, 1) column
+    (0 / NEG_INF) broadcast along the query axis: a masked score becomes
+    exactly NEG_INF (|s| is far below NEG_INF's last bit), ``exp`` of it
+    less a live row's max is exactly 0, and no select pass over the tile
+    is needed."""
+    s = _dot(k, qt, _NN) * sm_scale
     if bias is not None:
         s = s + bias
     if causal:
-        q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                                    q_axis)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+        q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         band = k_pos <= q_pos
         if window is not None:
             band = band & (k_pos > q_pos - window)
@@ -435,48 +482,50 @@ def _one_tile_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                          sm_scale: float, causal: bool, q_offset: int,
                          has_mask: bool, heads: int, group: int,
                          window: Optional[int] = None):
-    # Blocks: q/o (heads*group, sq, d), k/v (heads, sk, d), bias (1, 1, sk),
-    # lse (heads*group, 1, sq). Query head kh*group + j reads K/V head kh.
+    # Blocks, every operand with its sequence on the lanes: q/o
+    # (1, heads*group*d, sq), k/v (1, heads*d, sk), a head the (d, s) band
+    # of rows ``_head`` slices; bias (1, 1, sk), lse (heads*group, 1, sq).
+    # Query head kh*group + j reads K/V head kh.
     # The tile is held TRANSPOSED, (sk, sq): the softmax statistics then
     # reduce over sublanes (plain VPU maxima and sums, no cross-lane
     # reduce of 512 rows) and come out as the (1, sq) lane rows lse is
-    # stored in, and the context product transposes v (sk, d) and the
-    # (d, sq) result, never a (sk, sq) tile. On the v5e this form ran the
-    # forward in 0.84 ms a call against 1.12 for the (sq, sk) form
-    # (PERF.md section 5).
+    # stored in, and with q, v and o as (d, s) the two products are plain:
+    # only k (d, sk) is transposed, once a K/V head, never a (sk, sq) tile.
     bias = bias_ref[0, 0][:, None] if has_mask else None        # (sk, 1)
+    n = heads * group
     for kh in range(heads):
-        k, v = k_ref[kh], v_ref[kh]
+        k, vt = _head(k_ref, kh, heads)[...].T, _head(v_ref, kh, heads)[...]
         for g in range(kh * group, (kh + 1) * group):
-            s = _one_tile_scores(k, q_ref[g], bias, causal, 1, q_offset,
-                                 sm_scale, window)               # (sk, sq)
+            s = _one_tile_scores(k, _head(q_ref, g, n)[...], bias, causal,
+                                 q_offset, sm_scale, window)     # (sk, sq)
             m = jnp.max(s, axis=0, keepdims=True)
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=0, keepdims=True)
             # A query with no allowed key has m == NEG_INF and p == 1
             # everywhere: it emits zeros, decided on the (1, sq) statistic.
             inv = jnp.where(m > NEG_INF / 2, 1.0 / l, 0.0)
-            acc = _dot(v, p.astype(v.dtype), _TN)                # (d, sq)
-            o_ref[g] = (acc * inv).T.astype(o_ref.dtype)
+            acc = _dot(vt, p.astype(vt.dtype), _NN)              # (d, sq)
+            _head(o_ref, g, n)[...] = (acc * inv).astype(o_ref.dtype)
             # Such a query's lse is NEG_INF + log(sk) == NEG_INF in f32:
             # finite, as the streaming kernel's.
             lse_ref[g] = m + jnp.log(l)
 
 
-def _one_tile_p_ds(q, k, v, do, bias, lse, delta, *, sm_scale: float,
+def _one_tile_p_ds(qt, k, v, dot, bias, lse, delta, *, sm_scale: float,
                    causal: bool, q_offset: int,
                    window: Optional[int] = None):
     """``(p, ds)`` of one head for the two backward kernels, both as f32
-    TRANSPOSED (sk, sq) tiles like the forward's: ``lse`` and ``delta``
-    are then the (1, sq) lane rows they are stored as and broadcast down
-    the sublanes with no relayout. ``ds`` lacks the factor ``sm_scale``,
-    which the callers put on their (rows, d) results."""
-    s = _one_tile_scores(k, q, bias, causal, 1, q_offset, sm_scale, window)
+    TRANSPOSED (sk, sq) tiles like the forward's, from k and v as (sk, d)
+    and q and do as (d, sq): ``lse`` and ``delta`` are then the (1, sq)
+    lane rows they are stored as and broadcast down the sublanes with no
+    relayout. ``ds`` lacks the factor ``sm_scale``, which the callers put
+    on their (d, rows) results."""
+    s = _one_tile_scores(k, qt, bias, causal, q_offset, sm_scale, window)
     # A query with no allowed key (lse == NEG_INF) must give p == 0: its
     # statistic flips sign so exp(s - lse) underflows.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
     p = jnp.exp(s - lse)
-    dp = _dot(v, do, _NT)                                        # (sk, sq)
+    dp = _dot(v, dot, _NN)                                       # (sk, sq)
     return p, p * (dp - delta)
 
 
@@ -486,19 +535,20 @@ def _one_tile_bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                             heads: int, group: int,
                             window: Optional[int] = None):
     # Blocks as the forward's, with do like q and delta like lse.
-    # dq = ds k contracts over the tile's ROWS in this orientation; written
-    # as (k^T ds^T)^T Mosaic transposes the (sk, d) operand and the
-    # (d, sq) result, not the tile (as the forward's context product).
+    # dq^T = k^T ds^T is a plain product of k as it lies in HBM, (d, sk),
+    # and the transposed tile.
     bias = bias_ref[0, 0][:, None] if has_mask else None        # (sk, 1)
+    n = heads * group
     for kh in range(heads):
-        k, v = k_ref[kh], v_ref[kh]
+        kt = _head(k_ref, kh, heads)[...]
+        k, v = kt.T, _head(v_ref, kh, heads)[...].T
         for g in range(kh * group, (kh + 1) * group):
             _, ds = _one_tile_p_ds(
-                q_ref[g], k, v, do_ref[g], bias, lse_ref[g], delta_ref[g],
-                sm_scale=sm_scale, causal=causal, q_offset=q_offset,
-                window=window)
-            dq = _dot(k, ds.astype(k.dtype), _TN)                # (d, sq)
-            dq_ref[g] = (dq * sm_scale).T.astype(dq_ref.dtype)
+                _head(q_ref, g, n)[...], k, v, _head(do_ref, g, n)[...],
+                bias, lse_ref[g], delta_ref[g], sm_scale=sm_scale,
+                causal=causal, q_offset=q_offset, window=window)
+            dq = _dot(kt, ds.astype(kt.dtype), _NN)              # (d, sq)
+            _head(dq_ref, g, n)[...] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _one_tile_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
@@ -506,50 +556,54 @@ def _one_tile_bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                              causal: bool, q_offset: int, has_mask: bool,
                              heads: int, group: int,
                              window: Optional[int] = None):
-    # dv = p^T do and dk = ds^T q are plain products of the transposed
-    # tile: nothing is transposed here. Both sum over the K/V head's query
-    # group in registers and are written once per K/V head.
+    # dv^T = do^T p and dk^T = q^T ds contract the query axis, the lanes
+    # of both operands: no tile is transposed. Both sum over the K/V
+    # head's query group in registers and are written once per K/V head.
     bias = bias_ref[0, 0][:, None] if has_mask else None        # (sk, 1)
+    n = heads * group
     for kh in range(heads):
-        k, v = k_ref[kh], v_ref[kh]
+        k, v = _head(k_ref, kh, heads)[...].T, _head(v_ref, kh, heads)[...].T
         dk = dv = None
         for g in range(kh * group, (kh + 1) * group):
-            q, do = q_ref[g], do_ref[g]
+            qt, dot = _head(q_ref, g, n)[...], _head(do_ref, g, n)[...]
             p, ds = _one_tile_p_ds(
-                q, k, v, do, bias, lse_ref[g], delta_ref[g],
+                qt, k, v, dot, bias, lse_ref[g], delta_ref[g],
                 sm_scale=sm_scale, causal=causal, q_offset=q_offset,
                 window=window)
-            dv_g = _dot(p.astype(do.dtype), do, _NN)             # (sk, d)
-            dk_g = _dot(ds.astype(q.dtype), q, _NN)              # (sk, d)
+            dv_g = _dot(dot, p.astype(dot.dtype), _NT)           # (d, sk)
+            dk_g = _dot(qt, ds.astype(qt.dtype), _NT)            # (d, sk)
             dk = dk_g if dk is None else dk + dk_g
             dv = dv_g if dv is None else dv + dv_g
-        dk_ref[kh] = (dk * sm_scale).astype(dk_ref.dtype)
-        dv_ref[kh] = dv.astype(dv_ref.dtype)
+        _head(dk_ref, kh, heads)[...] = (dk * sm_scale).astype(dk_ref.dtype)
+        _head(dv_ref, kh, heads)[...] = dv.astype(dv_ref.dtype)
 
 
 def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
                    has_mask, interpret, window=None):
-    """One ``pallas_call`` of the one-tile path: a grid over K/V heads,
+    """One ``pallas_call`` of the one-tile path over (B, heads * d, S)
+    arrays (``_heads_to_rows``): a grid over batch rows and K/V heads,
     ``heads`` a step, each with its ``h // hkv`` query heads. ``ins`` are
     ``(kind, array)`` pairs and ``outs`` kinds; the kind gives the block:
-    "q" a query head's (sq, d) tile, "k" a K/V head's (sk, d) tile, "row"
-    a query head's (1, sq) f32 statistic, "mask" a batch row's (1, sk)
-    additive key mask. ``window`` reaches the kernel only where it can
-    cut something (sk > window), so a call it cannot touch is the program
-    it was without one."""
+    "q" the step's query heads, a (heads * group * d, sq) band of rows;
+    "k" its K/V heads, a (heads * d, sk) band; "row" a query head's
+    (1, sq) f32 statistic, of a (B * H, 1, sq) array; "mask" a batch
+    row's (1, sk) additive key mask. ``window`` reaches the kernel only
+    where it can cut something (sk > window), so a call it cannot touch
+    is the program it was without one."""
     arrays = dict(ins)
-    (bh, sq, d), (bhkv, sk, _) = arrays["q"].shape, arrays["k"].shape
-    group = h // hkv
+    (b, hd, sq), (_, kvd, sk) = arrays["q"].shape, arrays["k"].shape
+    group, steps = h // hkv, hkv // heads
     specs = {
-        "q": pl.BlockSpec((heads * group, sq, d), lambda i: (i, 0, 0)),
-        "k": pl.BlockSpec((heads, sk, d), lambda i: (i, 0, 0)),
-        "row": pl.BlockSpec((heads * group, 1, sq), lambda i: (i, 0, 0)),
-        "mask": pl.BlockSpec((1, 1, sk), lambda i: (i * heads // hkv, 0, 0)),
+        "q": pl.BlockSpec((1, hd // steps, sq), lambda n, i: (n, i, 0)),
+        "k": pl.BlockSpec((1, kvd // steps, sk), lambda n, i: (n, i, 0)),
+        "row": pl.BlockSpec((heads * group, 1, sq),
+                            lambda n, i: (n * steps + i, 0, 0)),
+        "mask": pl.BlockSpec((1, 1, sk), lambda n, i: (n, 0, 0)),
     }
     shapes = {
-        "q": jax.ShapeDtypeStruct((bh, sq, d), arrays["q"].dtype),
-        "k": jax.ShapeDtypeStruct((bhkv, sk, d), arrays["k"].dtype),
-        "row": jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+        "q": jax.ShapeDtypeStruct((b, hd, sq), arrays["q"].dtype),
+        "k": jax.ShapeDtypeStruct((b, kvd, sk), arrays["k"].dtype),
+        "row": jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
     }
     banded = ({"window": window}
               if window is not None and window < sk else {})
@@ -557,7 +611,7 @@ def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
         functools.partial(
             kernel, sm_scale=scale, causal=causal, q_offset=sk - sq,
             has_mask=has_mask, heads=heads, group=group, **banded),
-        grid=(bhkv // heads,),
+        grid=(b, steps),
         in_specs=[specs[kind] for kind, _ in ins],
         out_specs=[specs[kind] for kind in outs],
         out_shape=[shapes[kind] for kind in outs],
@@ -567,7 +621,7 @@ def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
 
 
 def _mask_bias(maskf):
-    """``_fold_heads``' (B, 1, Sk) int mask as the additive f32 row the
+    """``_mask_rows``' (B, 1, Sk) int mask as the additive f32 row the
     one-tile kernels take: 0 where the key is allowed, NEG_INF where not."""
     return jnp.where(maskf != 0, 0.0, NEG_INF).astype(jnp.float32)
 
@@ -585,16 +639,18 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             f"flash_attention: seq lengths ({sq},{sk}) must be divisible by "
             f"blocks ({block_q},{block_k}); pad to a block multiple")
 
-    qf, kf, vf, maskf = _fold_heads(q, k, v, key_mask)
+    maskf = _mask_rows(key_mask, b, sk)
     heads = _one_tile_path(q, k, block_q, block_k)
     if heads:
         out, lse = _one_tile_call(
             _one_tile_fwd_kernel, profiler.KERNEL_FLASH_FWD,
-            [("q", qf), ("k", kf), ("k", vf), ("mask", _mask_bias(maskf))],
+            [("q", _heads_to_rows(q)), ("k", _heads_to_rows(k)),
+             ("k", _heads_to_rows(v)), ("mask", _mask_bias(maskf))],
             ["q", "row"], heads=heads, h=h, hkv=hkv, scale=scale,
             causal=causal, has_mask=has_mask, interpret=interpret,
             window=window)
-        return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
+        return _rows_to_heads(out, h), lse
+    qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     kv_row, mask_row = _gqa_index_maps(h, hkv)
     num_kb = sk // block_k
     # kb innermost: K/V tiles stream HBM→VMEM one per step; q block and the
@@ -632,7 +688,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
         interpret=interpret,
         name=profiler.KERNEL_FLASH_FWD,
     )(qf, kf, vf, maskf)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
+    return _unfold_heads(out, h), lse
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
@@ -746,14 +802,12 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
 
-    qf, kf, vf, maskf = _fold_heads(q, k, v, key_mask)
-    kv_row, mask_row = _gqa_index_maps(h, hkv)
-    dof = g.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    outf = out.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+    maskf = _mask_rows(key_mask, b, sk)
     # delta_i = sum_d dO_i O_i — the softmax-normalizer correction term;
-    # cheap elementwise XLA, fused into the surrounding graph.
-    delta = jnp.sum(dof.astype(jnp.float32) * outf.astype(jnp.float32),
-                    axis=-1).reshape(b * h, 1, sq)
+    # cheap elementwise XLA on the caller's arrays, fused into the
+    # surrounding graph; the kernels take it as lse's (B*H, 1, Sq) rows.
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1).reshape(b * h, 1, sq)
     if dlse is not None:
         # A cotangent on the lse output (ring attention's cross-block
         # merge differentiates through it) is EXACTLY a shift of delta:
@@ -762,13 +816,11 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         # since d lse_i / d s_ij = p_ij. dv is unaffected.
         delta = delta - dlse.reshape(b * h, 1, sq).astype(jnp.float32)
 
-    def unfold(x, heads_):
-        return x.reshape(b, heads_, x.shape[1], d).transpose(0, 2, 1, 3)
-
     heads = _one_tile_path(q, k, block_q, block_k)
     if heads:
-        ins = [("q", qf), ("k", kf), ("k", vf), ("mask", _mask_bias(maskf)),
-               ("q", dof), ("row", lse), ("row", delta)]
+        ins = [("q", _heads_to_rows(q)), ("k", _heads_to_rows(k)),
+               ("k", _heads_to_rows(v)), ("mask", _mask_bias(maskf)),
+               ("q", _heads_to_rows(g)), ("row", lse), ("row", delta)]
         static = dict(heads=heads, h=h, hkv=hkv, scale=scale, causal=causal,
                       has_mask=has_mask, interpret=interpret, window=window)
         dq, = _one_tile_call(_one_tile_bwd_dq_kernel,
@@ -777,8 +829,11 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         dk, dv = _one_tile_call(_one_tile_bwd_dkv_kernel,
                                 profiler.KERNEL_FLASH_BWD_DKV, ins,
                                 ["k", "k"], **static)
-        return unfold(dq, h), unfold(dk, hkv), unfold(dv, hkv)
+        return (_rows_to_heads(dq, h), _rows_to_heads(dk, hkv),
+                _rows_to_heads(dv, hkv))
 
+    qf, kf, vf, dof = (_fold_heads(x) for x in (q, k, v, g))
+    kv_row, mask_row = _gqa_index_maps(h, hkv)
     num_kb = sk // block_k
     num_qb = sq // block_q
     dq = pl.pallas_call(
@@ -854,7 +909,8 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         name=profiler.KERNEL_FLASH_BWD_DKV,
     )(qf, kf, vf, maskf, dof, lse, delta)
 
-    return unfold(dq, h), unfold(dk, hkv), unfold(dv, hkv)
+    return (_unfold_heads(dq, h), _unfold_heads(dk, hkv),
+            _unfold_heads(dv, hkv))
 
 
 # The mask rides as a *differentiable* float32 argument with a zero
@@ -922,7 +978,9 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
     group — dk/dv are written once per K/V head (Hkv/H the HBM
     writes), never materialized at full H. Streaming DMA traffic for
     K/V tiles is unchanged: each query head still reads its group's
-    tiles.
+    tiles. On the one-tile path a grid step's block is a band of K/V
+    heads beside the band of their query groups, both cut from the
+    caller's arrays by the index maps (``_heads_to_rows``).
 
     ``block_q``/``block_k`` set the VMEM working set AND the HBM→VMEM
     streaming granule: per grid step one (block_k, d) K and V tile is DMAed

@@ -1,0 +1,126 @@
+"""Where the flash kernels find a head in the caller's arrays (PR 29).
+
+The one-tile kernels take (B, heads * d, S): a grid step's heads are a
+band of rows which the block index map picks, a head is a static slice
+of the block. The streamed kernels take heads folded into batch. Every
+head layout a step can be handed (a proper part of the head axis, the
+whole axis, one head, a query group, narrow and wide heads) is checked
+on both against the XLA reference, forward and all three gradients
+(``lse`` out and ``dlse`` in: ``test_flash_paths.py``); and the program
+the one-tile path hands XLA holds no 4-D transpose."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from attention_helpers import PATHS, _assert_grads_close, _rand, both_paths
+from horovod_tpu.ops.attention import (_one_tile_path, flash_attention,
+                                       reference_attention)
+
+B, S = 2, 32
+
+# name: (query heads, K/V heads, head width)
+HEADS = {
+    "h12_d64": (12, 12, 64),        # BERT: a step's band is 4 heads of 12
+    "h2_d64": (2, 2, 64),           # the whole axis in one step
+    "h1_d64": (1, 1, 64),           # one head
+    "gqa8_2_d64": (8, 2, 64),       # a query group of 4 over each K/V head
+    "gqa28_4_d128": (28, 4, 128),   # SmallThinker: a group of 7 (streams)
+    "h2_d32": (2, 2, 32),
+    "h2_d256": (2, 2, 256),
+}
+heads = pytest.mark.parametrize("heads", sorted(HEADS))
+
+
+def _qkv(name, dtype, sq=S, sk=S, seed=0):
+    h, hkv, d = HEADS[name]
+    return (_rand((B, sq, h, d), seed, dtype),
+            _rand((B, sk, hkv, d), seed + 1, dtype),
+            _rand((B, sk, hkv, d), seed + 2, dtype))
+
+
+def _key_mask(sk, seed):
+    mask = np.random.RandomState(seed).rand(B, sk) > 0.3
+    mask[:, 0] = True      # no fully-masked row, causal or not
+    return jnp.asarray(mask)
+
+
+def _check(flash, ref, q, k, v):
+    f32 = q.dtype == jnp.float32
+    out = flash(q, k, v)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref(q, k, v), np.float32),
+        atol=2e-5 if f32 else 2e-2, rtol=1e-4 if f32 else 2e-2)
+    _assert_grads_close(flash, ref, q, k, v, 2e-3 if f32 else 5e-2)
+
+
+@both_paths
+@heads
+@pytest.mark.parametrize("how,dtype", [("key_mask", "bfloat16"),
+                                       ("causal", "float32")])
+def test_flash_head_layouts_forward_and_grad(path, heads, how, dtype):
+    q, k, v = _qkv(heads, jnp.dtype(dtype))
+    kw = (dict(key_mask=_key_mask(S, 5)) if how == "key_mask"
+          else dict(causal=True))
+    _check(lambda q, k, v: flash_attention(q, k, v, **kw, **PATHS[path]),
+           lambda q, k, v: reference_attention(q, k, v, **kw), q, k, v)
+
+
+@both_paths
+@pytest.mark.parametrize("heads,how", [
+    (heads, how)
+    for heads in ("h12_d64", "gqa8_2_d64", "gqa28_4_d128")
+    for how in ("plain", "causal_sq_ne_sk", "window")
+] + [("h12_d64", "pad_197")])
+def test_flash_head_layouts_shapes_of_the_band(path, heads, how):
+    sq, sk, kw = {
+        "plain": (S, S, {}),
+        "causal_sq_ne_sk": (16, S, dict(causal=True)),       # decode rows
+        "window": (S, S, dict(causal=True, window=12)),
+        "pad_197": (197, 197, dict(key_mask=_key_mask(197, 7))),
+    }[how]
+    blocks = PATHS[path]
+    if how == "pad_197" and blocks:     # 197 pads to 256: four blocks
+        blocks = dict(block_q=64, block_k=64)
+    q, k, v = _qkv(heads, jnp.float32, sq, sk, seed=10)
+    _check(lambda q, k, v: flash_attention(q, k, v, **kw, **blocks),
+           lambda q, k, v: reference_attention(q, k, v, **kw), q, k, v)
+
+
+def _transposed_ranks(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "transpose":
+            found.append(len(eqn.invars[0].aval.shape))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _transposed_ranks(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("case,b,s,h,hkv,d,masked", [
+    ("bert-base-s512-dp1", 64, 512, 12, 12, 64, True),
+    ("vit_padded_197", 8, 256, 12, 12, 64, True),
+    ("decoder_gqa_d128", 4, 512, 8, 2, 128, False),
+])
+def test_one_tile_program_transposes_no_4d_operand(case, b, s, h, hkv, d,
+                                                   masked):
+    # What the fold was: (B, S, H, D) -> (B, H, S, D), a copy XLA had to
+    # make around every call (eight a BERT layer). The one-tile path
+    # reshapes to (B, S, H * D), a bitcast, and swaps the last two axes,
+    # which XLA folds into the layout it already keeps the array in
+    # (tests/benchmark/test_aot_flash_layout.py holds that).
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16)
+    m = jax.ShapeDtypeStruct((b, s), jnp.bool_)
+    assert _one_tile_path(q, kv, s, s)
+
+    def attend(q, k, v, m):
+        return flash_attention(q, k, v, key_mask=m if masked else None,
+                               causal=not masked, interpret=True)
+
+    grad = jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+    for fn in (attend, grad):
+        ranks = _transposed_ranks(jax.make_jaxpr(fn)(q, kv, kv, m).jaxpr, [])
+        assert ranks and max(ranks) == 3, (case, ranks)

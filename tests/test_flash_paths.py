@@ -116,22 +116,31 @@ def test_flash_paths_awkward_len_auto_pad(path, blocks, causal):
 
 @both_paths
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_paths_lse_out_and_dlse_in(path, causal):
+@pytest.mark.parametrize("h,hkv,d", [
+    (2, 2, 8),
+    (12, 12, 64),     # one tile: a step's block is 4 heads of the 12
+    (8, 2, 64),       # a query group of 4 over each K/V head
+])
+def test_flash_paths_lse_out_and_dlse_in(path, causal, h, hkv, d):
     # What ring attention leans on: the forward returns the row
     # log-sum-exp, and the backward takes a cotangent on it (a shift of
-    # delta). Checked against jax's own vjp of a dense (out, lse) pair.
+    # delta); both stay (B * H, 1, S) rows whatever layout the kernels
+    # take their operands in. Checked against jax's own vjp of a dense
+    # (out, lse) pair.
     from horovod_tpu.ops.attention import (NEG_INF, _flash_backward,
-                                           _flash_forward)
+                                           _flash_forward, repeat_kv)
 
     blocks = PATHS[path] or {"block_q": 512, "block_k": 1024}
     bq, bk = blocks["block_q"], blocks["block_k"]
-    b, s, h, d = 2, 32, 2, 8
-    q, k, v = (_rand((b, s, h, d), 80 + i) for i in range(3))
+    b, s = 2, 32
+    q = _rand((b, s, h, d), 80)
+    k, v = _rand((b, s, hkv, d), 81), _rand((b, s, hkv, d), 82)
     mask_np = np.random.RandomState(83).rand(b, s) > 0.3
     mask_np[:, 0] = True
     mask = jnp.asarray(mask_np)
 
     def dense(q, k, v):
+        k, v = repeat_kv(q, k, v)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / d ** 0.5
         allowed = mask[:, None, None, :]
         if causal:
@@ -153,6 +162,7 @@ def test_flash_paths_lse_out_and_dlse_in(path, causal):
     got = _flash_backward(q, k, v, mask, out, lse, do, causal, None, bq,
                           bk, True, dlse=dlse)
     for a, r in zip(got, vjp((do, dlse))):
+        assert a.shape == r.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    atol=2e-5, rtol=1e-3)
 
@@ -161,7 +171,7 @@ def _kernel_grids(s, d=16, h=1, hkv=None, **kw):
     """``{pallas_call name: rank of its grid}`` of a traced ``jax.grad``
     of flash attention. Both paths call their kernels by the same three
     names (the benchmark's per-kernel metrics read them); what tells them
-    apart is the grid: K/V heads alone on the one-tile path, (heads,
+    apart is the grid: (batch, K/V heads) on the one-tile path, (heads,
     q blocks, k blocks) where the kernels stream."""
     x = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, s, hkv or h, d), jnp.bfloat16)
@@ -184,7 +194,7 @@ def _kernel_grids(s, d=16, h=1, hkv=None, **kw):
     return found
 
 
-ONE_TILE_GRIDS = dict.fromkeys(KERNELS, 1)
+ONE_TILE_GRIDS = dict.fromkeys(KERNELS, 2)
 STREAMED_GRIDS = dict.fromkeys(KERNELS, 3)
 
 
