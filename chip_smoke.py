@@ -20,6 +20,10 @@ calls, in ONE process (a chip belongs to one process at a time):
                      width 128) and parallel.moe.moe_apply_held with a
                      share of the experts at SmallThinker's widths, each
                      against its float32 reference, forward and gradients
+  linear_attention   ops.linear_attention's chunked gated delta rule at
+                     Olmo-Hybrid's head widths (15 heads, keys 96, values
+                     192, chunks of 64) against the token-by-token
+                     recurrence in float32, forward and all five gradients
   generate_llama300m models.llama.generate: contiguous decode kernel
   serve_llama300m    hvd.serving.serve: paged decode kernel, default
                      block_size
@@ -120,6 +124,28 @@ def _lower_and_compile(step, *args):
     t1 = _now()
     compiled = lowered.compile()
     return compiled, t1 - t0, _now() - t1
+
+
+class _Stopwatch:
+    """Calls a jitted function twice and keeps the seconds: the second
+    call is ``run_s``, the first minus the second ``compile_s``. Returns
+    the second call's leaves as float32 numpy arrays."""
+
+    def __init__(self):
+        self.compile_s = self.run_s = 0.0
+
+    def __call__(self, fn, *args):
+        import jax
+        import numpy as np
+
+        t0 = _now()
+        jax.block_until_ready(fn(*args))
+        t1 = _now()
+        got = jax.block_until_ready(fn(*args))
+        t2 = _now()
+        self.run_s += t2 - t1
+        self.compile_s += max((t1 - t0) - (t2 - t1), 0.0)
+        return [np.asarray(x, np.float32) for x in jax.tree.leaves(got)]
 
 
 def _rel_close(a, b, tol):
@@ -525,22 +551,11 @@ def phase_window_and_experts(sz, rehearsal):
     from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
 
     rng = np.random.RandomState(1)
-    checks, compile_s, run_s = [], 0.0, 0.0
+    checks, timed = [], _Stopwatch()
 
     def rand(*shape, scale=1.0, dtype=jnp.bfloat16):
         return jnp.asarray(scale * rng.randn(*shape).astype(np.float32),
                            dtype)
-
-    def timed(fn, *args):
-        nonlocal compile_s, run_s
-        t0 = _now()
-        jax.block_until_ready(fn(*args))
-        t1 = _now()
-        got = jax.block_until_ready(fn(*args))
-        t2 = _now()
-        run_s += t2 - t1
-        compile_s += max((t1 - t0) - (t2 - t1), 0.0)
-        return [np.asarray(x, np.float32) for x in jax.tree.leaves(got)]
 
     def worst(got, want):
         return max(float(np.max(np.abs(g - r)) / np.max(np.abs(r)))
@@ -643,7 +658,68 @@ def phase_window_and_experts(sz, rehearsal):
             f"experts {held} of {experts}, {what}: {landed} of "
             f"{tokens * chosen} assignments here: y/dw/dx/dlogits within "
             f"{err:.2e} of max|reference|"))
-    return {"compile_s": compile_s, "run_s": run_s, "checks": checks}
+    return {"compile_s": timed.compile_s, "run_s": timed.run_s,
+            "checks": checks}
+
+
+def phase_linear_attention(sz, rehearsal):
+    """What PR 30 added to the step, off the benchmark's own shape: the
+    chunked gated delta rule (``ops.linear_attention.gated_delta_rule``)
+    at the published head widths, 15 heads with keys 96 and values 192
+    wide, in bf16 with chunks of 64, against
+    ``reference_gated_delta_rule`` (the recurrence token by token) on
+    float32 copies at the highest matmul precision: the outputs, the
+    state after the last token and the gradients of q, k, v, g and beta.
+    q and k enter as a layer hands them over (L2-normed, q scaled by
+    d_k^-1/2), the decays between e^-4 and 1, beta in (0, 2). bf16
+    operands through the chunk's triangular system, its writes and the
+    state: a few ulps of bf16 of the largest value, and never more than
+    2^-5 of it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops import linear_attention as la
+
+    rng = np.random.RandomState(2)
+    seq, heads, d_k, d_v = (160, 3, 96, 192) if rehearsal \
+        else (2048, 15, 96, 192)
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32))
+
+    q = (la.l2_normalize(rand(1, seq, heads, d_k)) * d_k ** -0.5).astype(
+        jnp.bfloat16)
+    k = la.l2_normalize(rand(1, seq, heads, d_k)).astype(jnp.bfloat16)
+    v = rand(1, seq, heads, d_v).astype(jnp.bfloat16)
+    g = -jnp.exp(jnp.asarray(rng.uniform(
+        -6.0, 1.4, (1, seq, heads)).astype(np.float32)))
+    beta = 2.0 * jax.nn.sigmoid(2.0 * rand(1, seq, heads))
+    target = rand(1, seq, heads, d_v)
+
+    def with_gradients(rule):
+        def fn(q, k, v, g, beta):
+            def loss(*args):
+                o, state = rule(*args, output_final_state=True)
+                return jnp.sum(o.astype(jnp.float32) * target), (o, state)
+            (_, (o, state)), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(q, k, v, g, beta)
+            return (o, state) + grads
+        return jax.jit(fn)
+
+    timed = _Stopwatch()
+    want = timed(with_gradients(la.reference_gated_delta_rule),
+                 *(x.astype(jnp.float32) for x in (q, k, v)), g, beta)
+    got = timed(with_gradients(la.gated_delta_rule), q, k, v, g, beta)
+    errs = [float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+            for a, b in zip(got, want)]
+    check(max(errs) <= 2.0 ** -5,
+          "linear attention: o/state/dq/dk/dv/dg/dbeta within "
+          + "/".join(f"{e:.1e}" for e in errs) + " of max|reference|")
+    return dict(compile_s=timed.compile_s, run_s=timed.run_s, checks=[
+        f"chunked delta rule, {heads} heads {d_k}/{d_v} wide over {seq} "
+        f"tokens in chunks of 64: o/state/dq/dk/dv/dg/dbeta within "
+        f"{max(errs):.2e} of max|reference|"])
 
 
 def _lm_on_one_device(sz):
@@ -853,6 +929,7 @@ def main():
                      ("train_llama300m", phase_train_llama),
                      ("flash_one_tile", phase_flash_one_tile),
                      ("window_and_experts", phase_window_and_experts),
+                     ("linear_attention", phase_linear_attention),
                      ("generate_llama300m", phase_generate),
                      ("serve_llama300m", phase_serve)):
         t0 = _now()

@@ -59,6 +59,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "SCOPE_EXCHANGE", "SCOPE_UPDATE", "collective_scope",
            "SCOPE_MOE_ROUTE", "SCOPE_MOE_DISPATCH", "SCOPE_MOE_EXPERTS",
            "SCOPE_MOE_COMBINE",
+           "SCOPE_LINATTN_CONV", "SCOPE_LINATTN_SCAN", "SCOPE_LINATTN_GATE",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
@@ -90,6 +91,17 @@ SCOPE_MOE_ROUTE = "hvd.moe.route"
 SCOPE_MOE_DISPATCH = "hvd.moe.dispatch"
 SCOPE_MOE_EXPERTS = "hvd.moe.experts"
 SCOPE_MOE_COMBINE = "hvd.moe.combine"
+
+#: The parts of a gated delta-rule layer (``models/olmo_hybrid.py``
+#: ``LinearAttentionMixer`` over ``ops/linear_attention.py``), forward and
+#: backward alike: the three causal convolutions with their SiLU and the
+#: q/k L2 norms; everything of ``gated_delta_rule`` (the chunk-local
+#: products and triangular system, the scan over chunks, the outputs); the
+#: gated per-head norm. The projections around them carry no scope, as
+#: attention's carry none.
+SCOPE_LINATTN_CONV = "hvd.linattn.conv"
+SCOPE_LINATTN_SCAN = "hvd.linattn.scan"
+SCOPE_LINATTN_GATE = "hvd.linattn.gate"
 
 #: ``name=`` of the Pallas kernels: what the Mosaic custom calls are
 #: called in the compiled program and the device trace.

@@ -34,6 +34,11 @@ from .moe_lm import (  # noqa: F401
     MoeLM,
 )
 from .mlp import MnistMLP  # noqa: F401
+from .olmo_hybrid import (  # noqa: F401
+    OLMO_HYBRID_TINY,
+    OlmoHybridConfig,
+    OlmoHybridLM,
+)
 from .smallthinker import (  # noqa: F401
     SMALLTHINKER_21B,
     SMALLTHINKER_TINY,
