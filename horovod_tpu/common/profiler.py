@@ -60,6 +60,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "SCOPE_MOE_ROUTE", "SCOPE_MOE_DISPATCH", "SCOPE_MOE_EXPERTS",
            "SCOPE_MOE_COMBINE",
            "SCOPE_LINATTN_CONV", "SCOPE_LINATTN_SCAN", "SCOPE_LINATTN_GATE",
+           "SCOPE_LOSS_HEAD",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
@@ -102,6 +103,11 @@ SCOPE_MOE_COMBINE = "hvd.moe.combine"
 SCOPE_LINATTN_CONV = "hvd.linattn.conv"
 SCOPE_LINATTN_SCAN = "hvd.linattn.scan"
 SCOPE_LINATTN_GATE = "hvd.linattn.gate"
+
+#: Everything of ``models.chunked_causal_lm_loss``: the sweep over the
+#: sequence's chunks (one ``while``) that applies the head and computes
+#: the loss and, when differentiated, both of its gradients.
+SCOPE_LOSS_HEAD = "hvd.loss.head"
 
 #: ``name=`` of the Pallas kernels: what the Mosaic custom calls are
 #: called in the compiled program and the device trace.

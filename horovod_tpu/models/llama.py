@@ -779,45 +779,117 @@ def causal_lm_loss(logits, input_ids):
     return token_nll(logits[:, :-1], input_ids[:, 1:]).mean()
 
 
+def _loss_chunks(hidden, head_kernel, input_ids, num_chunks):
+    """The operands of one sweep over the sequence's chunks, chunk-major:
+    hidden states (n, B, c, D), shifted targets (n, B, c) and the head
+    kernel in ``hidden``'s dtype."""
+    b, s, d = hidden.shape
+    c = s // num_chunks
+    # Shifted targets over the FULL sequence; the final position has no
+    # next token — it wraps to a garbage value and is masked out.
+    targets = jnp.concatenate([input_ids[:, 1:], input_ids[:, :1]], axis=1)
+    h = hidden.reshape(b, num_chunks, c, d).transpose(1, 0, 2, 3)
+    t = targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
+    return h, t, head_kernel.astype(hidden.dtype)
+
+
+def _mean_nll(nll, b, s):
+    """The mean over every position but each sequence's last, of the
+    chunk-major (n, B, c) per-token nll."""
+    return nll.transpose(1, 0, 2).reshape(b, s)[:, :-1].mean()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_loss(hidden, head_kernel, input_ids, num_chunks):
+    # The call nobody differentiates: the loss alone, one product a chunk.
+    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
+        b, s, _ = hidden.shape
+        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks)
+        # Same matmul dtype as the in-model lm_head (MXU f32 accumulate).
+        nll = jax.lax.map(lambda args: token_nll(args[0] @ w, args[1]),
+                          (h, t))
+        return _mean_nll(nll, b, s)
+
+
+def _chunked_loss_fwd(hidden, head_kernel, input_ids, num_chunks):
+    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
+        b, s, d = hidden.shape
+        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks)
+        vocab = w.shape[1]
+        # d(mean)/d(nll) of every position: 0 at each sequence's last.
+        scale = (jnp.arange(s) < s - 1).astype(jnp.float32) / (b * (s - 1))
+        scale = scale.reshape(num_chunks, 1, s // num_chunks)
+
+        def chunk(dw, args):
+            h_c, t_c, scale_c = args
+            logits = h_c @ w
+            # token_nll's sweep (the gather before the upcast), with the
+            # softmax's gradient taken while the logits are in hand.
+            z = logits.astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(z, axis=-1)
+            target_logit = jnp.take_along_axis(
+                logits, t_c[..., None], axis=-1)[..., 0].astype(jnp.float32)
+            onehot = jnp.arange(vocab) == t_c[..., None]
+            dlogits = ((jnp.exp(z - lse[..., None]) - onehot)
+                       * scale_c[..., None]).astype(logits.dtype)
+            dh_c = jnp.einsum("bcv,dv->bcd", dlogits, w)
+            dw = dw + jnp.einsum("bcd,bcv->dv", h_c, dlogits,
+                                 preferred_element_type=jnp.float32)
+            return dw, (lse - target_logit, dh_c)
+
+        dw, (nll, dh) = jax.lax.scan(
+            chunk, jnp.zeros((d, vocab), jnp.float32), (h, t, scale))
+        dh = dh.transpose(1, 0, 2, 3).reshape(b, s, d)
+        return _mean_nll(nll, b, s), (dh, dw.astype(head_kernel.dtype))
+
+
+def _chunked_loss_bwd(num_chunks, residuals, g):
+    del num_chunks
+    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
+        dh, dw = residuals
+        return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
+                (g * dw.astype(jnp.float32)).astype(dw.dtype), None)
+
+
+_chunked_loss.defvjp(_chunked_loss_fwd, _chunked_loss_bwd)
+
+
 def chunked_causal_lm_loss(hidden, head_kernel, input_ids,
                            num_chunks: int = 8):
     """:func:`causal_lm_loss` with the lm_head fused in, applied one
-    sequence chunk at a time under ``jax.checkpoint``: the full (B, S, V)
-    logits — and, in the backward pass, their same-sized cotangent — never
-    exist; peak extra HBM is O(B * S/num_chunks * V). At Llama-300M
-    S=16384 that's the ~2 GiB that makes single-chip training fit where
-    the fused-head path OOMs.
+    sequence chunk at a time in ONE sweep (a ``lax.scan``, one ``while``
+    of the compiled step): the full (B, S, V) logits — and their
+    same-sized cotangent — never exist; peak extra HBM is
+    O(B * S/num_chunks * V). At Llama-300M S=16384 that's the ~2 GiB that
+    makes single-chip training fit where the fused-head path OOMs.
 
     ``hidden``: final-norm hidden states from
     ``model.apply(..., return_hidden=True)``, shape (B, S, dim);
     ``head_kernel``: ``params["lm_head"]["kernel"]`` (dim, V).
     The LOSS matches ``causal_lm_loss`` on the full logits exactly (each
     logit row is the same dot product; the mean is reassembled exactly).
-    Head/hidden GRADIENTS agree up to bf16 rounding at chunk boundaries:
-    each chunk's dW partial quantizes to bf16 before the cross-chunk sum,
-    where the fused head quantizes once (measured ~0.7% grad-norm delta —
-    bf16-training noise level)."""
-    b, s, d = hidden.shape
+
+    Differentiated (a ``jax.custom_vjp``), the same sweep also computes
+    both GRADIENTS: the loss ends the step, so with a chunk's logits in
+    hand ``dlogits = (softmax - onehot) / count`` is known (zero at each
+    sequence's last position) and the chunk does its three
+    vocabulary-wide products at once — ``h_c @ w``, ``dlogits @ w.T``,
+    ``h_c.T @ dlogits`` — in the operands' dtype with float32
+    accumulation; nothing is recomputed. Stored for the backward rule,
+    which only multiplies them by the incoming scalar: ``dh`` (B, S, dim)
+    in ``hidden``'s dtype and ``dW`` (dim, V), summed over the chunks in
+    float32 and rounded once to ``head_kernel``'s dtype. Against autodiff
+    of ``causal_lm_loss`` on the full logits both agree to 1e-6 in
+    float32 and, under bf16, to the rounding of the logits' cotangent
+    (grad-norm deltas under 1%, ``tests/test_llama.py``). Called
+    undifferentiated it computes the loss alone. Forward mode
+    (``jax.jvp``) is not defined."""
+    s = hidden.shape[1]
     if s % num_chunks:
         raise ValueError(
             f"chunked_causal_lm_loss: seq len {s} must be divisible by "
             f"num_chunks {num_chunks}")
-    c = s // num_chunks
-    # Shifted targets over the FULL sequence; the final position has no
-    # next token — it wraps to a garbage value and is masked out below.
-    targets = jnp.concatenate([input_ids[:, 1:], input_ids[:, :1]], axis=1)
-    h = hidden.reshape(b, num_chunks, c, d).transpose(1, 0, 2, 3)
-    t = targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
-    w = head_kernel.astype(hidden.dtype)
-
-    @jax.checkpoint
-    def chunk_nll(h_c, t_c):
-        # Same matmul dtype as the in-model lm_head (MXU f32 accumulate).
-        return token_nll(h_c @ w, t_c)
-
-    nll = jax.lax.map(lambda args: chunk_nll(*args), (h, t))
-    nll = nll.transpose(1, 0, 2).reshape(b, s)
-    return nll[:, :-1].mean()
+    return _chunked_loss(hidden, head_kernel, input_ids, num_chunks)
 
 
 def sp_causal_lm_loss(logits, input_ids, axis_name: str):

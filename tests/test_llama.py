@@ -3,8 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from horovod_tpu.models import LLAMA_TINY, LlamaLM, causal_lm_loss
+from horovod_tpu.models import (LLAMA_TINY, LlamaLM, causal_lm_loss,
+                                chunked_causal_lm_loss)
 
 
 def _ids(shape, seed=0):
@@ -269,8 +271,6 @@ def test_remat_matches_no_remat():
 
 
 def test_chunked_loss_matches_full():
-    from horovod_tpu.models import chunked_causal_lm_loss
-
     model = LlamaLM(LLAMA_TINY)
     ids = _ids((2, 16))
     variables = model.init(jax.random.PRNGKey(0), ids)
@@ -287,9 +287,10 @@ def test_chunked_loss_matches_full():
     l1, g1 = jax.value_and_grad(chunked)(variables["params"])
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
 
-    # Gradients agree up to bf16 rounding at chunk boundaries (per-chunk
-    # dW partials quantize before the cross-chunk sum — see the loss
-    # docstring), so compare leaf-wise grad-norm ratios, not elements.
+    # Gradients agree up to the bf16 rounding of the logits' cotangent
+    # (the chunked head rounds softmax - onehot once, autodiff of the full
+    # logits rounds its two terms apart — see the loss docstring), so
+    # compare leaf-wise grad-norm ratios, not elements.
     def close_in_norm(a, b):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
         denom = max(np.linalg.norm(a), 1e-12)
@@ -300,15 +301,142 @@ def test_chunked_loss_matches_full():
 
 
 def test_chunked_loss_rejects_indivisible():
-    import pytest
-
-    from horovod_tpu.models import chunked_causal_lm_loss
-
     hidden = jnp.zeros((1, 10, LLAMA_TINY.dim), jnp.bfloat16)
     kernel = jnp.zeros((LLAMA_TINY.dim, LLAMA_TINY.vocab_size))
     with pytest.raises(ValueError, match="divisible"):
         chunked_causal_lm_loss(hidden, kernel, jnp.zeros((1, 10), jnp.int32),
                                num_chunks=3)
+
+
+def _head_problem(batch, dtype, kernel_dtype=jnp.float32, seq=16):
+    """Hidden states, a head kernel and ids at LLAMA_TINY's widths."""
+    k_h, k_w = jax.random.split(jax.random.PRNGKey(3))
+    hidden = jax.random.normal(
+        k_h, (batch, seq, LLAMA_TINY.dim), jnp.float32).astype(dtype)
+    kernel = (0.2 * jax.random.normal(
+        k_w, (LLAMA_TINY.dim, LLAMA_TINY.vocab_size),
+        jnp.float32)).astype(kernel_dtype)
+    return hidden, kernel, _ids((batch, seq), seed=batch)
+
+
+def _full_head_loss(ids):
+    return lambda h, w: causal_lm_loss(h @ w.astype(h.dtype), ids)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / max(np.linalg.norm(a), 1e-12)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("num_chunks", [1, 2, 8])
+def test_chunked_loss_and_both_gradients_match_autodiff_of_full_logits(
+        num_chunks, batch, dtype, tol):
+    hidden, kernel, ids = _head_problem(batch, dtype)
+    l0, g0 = jax.value_and_grad(_full_head_loss(ids), argnums=(0, 1))(
+        hidden, kernel)
+    l1, g1 = jax.value_and_grad(
+        lambda h, w: chunked_causal_lm_loss(h, w, ids, num_chunks=num_chunks),
+        argnums=(0, 1))(hidden, kernel)
+    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
+    # Differentiated or not, the same loss to the bit.
+    assert float(l1) == float(
+        chunked_causal_lm_loss(hidden, kernel, ids, num_chunks=num_chunks))
+    for a, b in zip(g0, g1):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert _rel(a, b) < tol, (_rel(a, b), tol)
+
+
+def test_chunked_loss_bf16_gradients_do_not_hang_on_the_chunk_count():
+    # dW is summed over the chunks in float32 and rounded once: what is
+    # left against float32 autodiff is the rounding of the logits and of
+    # their cotangent, the same at every chunk count (the sum of bf16
+    # partials this replaces grew with it).
+    hidden, kernel, ids = _head_problem(2, jnp.bfloat16)
+    _, exact = jax.value_and_grad(_full_head_loss(ids), argnums=(0, 1))(
+        hidden.astype(jnp.float32), kernel)
+    gaps = []
+    for num_chunks in (1, 8):
+        got = jax.grad(
+            lambda h, w: chunked_causal_lm_loss(
+                h, w, ids, num_chunks=num_chunks), argnums=(0, 1))(
+                    hidden, kernel)
+        gaps.append([_rel(a, b) for a, b in zip(exact, got)])
+    assert max(gaps[0] + gaps[1]) < 1e-2, gaps
+    np.testing.assert_allclose(gaps[0], gaps[1], rtol=0.05)
+
+
+@pytest.mark.parametrize("wrap", [
+    lambda loss, h, w: 3.0 * loss(h, w),
+    lambda loss, h, w: loss(h, w) + 0.5 * (h.astype(jnp.float32) ** 2).sum()
+    + (w ** 2).sum(),
+    lambda loss, h, w: loss(h, w) * loss(h, 2.0 * w),
+], ids=["scaled", "in_a_sum", "twice"])
+def test_chunked_loss_takes_a_cotangent_that_is_not_one(wrap):
+    hidden, kernel, ids = _head_problem(2, jnp.float32)
+
+    def chunked(h, w):
+        return chunked_causal_lm_loss(h, w, ids, num_chunks=4)
+
+    g0 = jax.grad(lambda h, w: wrap(_full_head_loss(ids), h, w),
+                  argnums=(0, 1))(hidden, kernel)
+    g1 = jax.grad(lambda h, w: wrap(chunked, h, w),
+                  argnums=(0, 1))(hidden, kernel)
+    for a, b in zip(g0, g1):
+        assert _rel(a, b) < 1e-6, _rel(a, b)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_chunked_loss_last_position_gets_no_gradient(dtype):
+    hidden, kernel, ids = _head_problem(2, dtype)
+    dh = jax.grad(lambda h: chunked_causal_lm_loss(
+        h, kernel, ids, num_chunks=4))(hidden)
+    assert dh.dtype == dtype
+    assert not np.asarray(dh[:, -1], np.float32).any()
+    assert np.asarray(dh[:, :-1], np.float32).any(axis=-1).all()
+
+
+@pytest.mark.parametrize("kernel_dtype", [jnp.float32, jnp.bfloat16])
+def test_chunked_loss_kernel_gradient_comes_back_in_the_kernels_dtype(
+        kernel_dtype):
+    hidden, kernel, ids = _head_problem(2, jnp.bfloat16, kernel_dtype)
+    dw = jax.grad(lambda w: chunked_causal_lm_loss(
+        hidden, w, ids, num_chunks=4))(kernel)
+    assert dw.dtype == kernel_dtype and dw.shape == kernel.shape
+    want = jax.grad(lambda w: _full_head_loss(ids)(hidden, w))(kernel)
+    assert _rel(want, dw) < 2e-2
+
+
+def _vocab_wide_products(jaxpr):
+    """dot_generals with a vocabulary-wide operand or result, counted
+    through every nested jaxpr, with the loops they stand in."""
+    vocab = LLAMA_TINY.vocab_size
+    loops = products = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            loops += 1
+        if eqn.primitive.name == "dot_general" and any(
+                vocab in v.aval.shape for v in eqn.invars + eqn.outvars):
+            products += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            inner = _vocab_wide_products(sub)
+            loops, products = loops + inner[0], products + inner[1]
+    return loops, products
+
+
+def test_chunked_loss_one_loop_one_product_a_chunk_three_when_differentiated():
+    hidden, kernel, ids = _head_problem(2, jnp.bfloat16)
+
+    def chunked(h, w):
+        return chunked_causal_lm_loss(h, w, ids, num_chunks=4)
+
+    # The loop's body is traced once, so a count is products a chunk.
+    assert _vocab_wide_products(
+        jax.make_jaxpr(chunked)(hidden, kernel).jaxpr) == (1, 1)
+    assert _vocab_wide_products(jax.make_jaxpr(jax.value_and_grad(
+        chunked, argnums=(0, 1)))(hidden, kernel).jaxpr) == (1, 3)
 
 
 def test_tensor_parallel_specs_match_data_parallel():
