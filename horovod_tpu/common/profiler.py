@@ -58,7 +58,8 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "named_scope", "PROFILE_DIR_ENV",
            "SCOPE_EXCHANGE", "SCOPE_UPDATE", "collective_scope",
            "SCOPE_MOE_ROUTE", "SCOPE_MOE_DISPATCH", "SCOPE_MOE_EXPERTS",
-           "SCOPE_MOE_COMBINE",
+           "SCOPE_MOE_COMBINE", "SCOPE_MOE_SHARED",
+           "SCOPE_ATTN_FULL", "SCOPE_ATTN_WINDOW", "SCOPE_ATTN_POINTWISE",
            "SCOPE_LINATTN_CONV", "SCOPE_LINATTN_SCAN", "SCOPE_LINATTN_GATE",
            "SCOPE_LOSS_HEAD",
            "DECODE_PATHS", "decode_scope",
@@ -92,6 +93,20 @@ SCOPE_MOE_ROUTE = "hvd.moe.route"
 SCOPE_MOE_DISPATCH = "hvd.moe.dispatch"
 SCOPE_MOE_EXPERTS = "hvd.moe.experts"
 SCOPE_MOE_COMBINE = "hvd.moe.combine"
+#: The shared expert every token passes beside the routed ones
+#: (``models/laguna.py``): a dense gated MLP, outside ``moe_apply_held``.
+SCOPE_MOE_SHARED = "hvd.moe.shared"
+
+#: The attention of a model whose layers differ in kind
+#: (``models/laguna.py``), forward and backward alike: the attention call
+#: itself (scores, softmax, context: the flash kernels where they run, so
+#: that a kernel's time can be told apart by the kind of its layer) of a
+#: layer that sees every earlier key, and of one that sees a window of
+#: them; and the pointwise passes around it, the rotary embedding of q and
+#: k and the per-head gate on the context. The projections carry no scope.
+SCOPE_ATTN_FULL = "hvd.attn.full"
+SCOPE_ATTN_WINDOW = "hvd.attn.window"
+SCOPE_ATTN_POINTWISE = "hvd.attn.pointwise"
 
 #: The parts of a gated delta-rule layer (``models/olmo_hybrid.py``
 #: ``LinearAttentionMixer`` over ``ops/linear_attention.py``), forward and

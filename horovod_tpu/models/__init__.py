@@ -27,6 +27,12 @@ from .llama import (  # noqa: F401
     token_nll,
 )
 from .inception import InceptionV3  # noqa: F401
+from .laguna import (  # noqa: F401
+    LAGUNA_TINY,
+    LAGUNA_XS2,
+    LagunaConfig,
+    LagunaLM,
+)
 from .moe_lm import (  # noqa: F401
     MOE_SMALL,
     MOE_TINY,

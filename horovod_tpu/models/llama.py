@@ -82,7 +82,8 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(self.dtype)
 
 
-def rotary_embedding(x, theta: float, positions=None):
+def rotary_embedding(x, theta: float, positions=None, rotary_dim=None,
+                     inv_freq=None, scale=None):
     """Apply RoPE to (B, S, H, D). ``positions`` are the GLOBAL token
     positions of the rows — defaults to 0..S-1. Shape (S,) rotates every
     batch row alike (training, whole-batch decode); shape (B, S) gives
@@ -90,10 +91,23 @@ def rotary_embedding(x, theta: float, positions=None):
     batches mix sequences at heterogeneous decode positions). Under
     sequence parallelism each shard must pass its own global offsets
     (e.g. ``axis_index * S_local + arange(S_local)``) or every shard
-    would rotate as if it held the sequence start."""
+    would rotate as if it held the sequence start.
+
+    The defaults rotate the whole head at ``theta ** (-i / half)``. A
+    partial rotary embedding gives ``rotary_dim`` < D: the first
+    ``rotary_dim`` entries of each head are rotated (rotate-half inside
+    them), the rest pass through. ``inv_freq`` (``rotary_dim // 2``
+    numbers) takes the place of the frequencies ``theta`` gives, for a
+    scaled embedding whose frequencies are blended (YaRN:
+    ``models/laguna.py``); ``scale`` multiplies cos and sin (YaRN's
+    attention factor)."""
     b, s, h, d = x.shape
-    half = d // 2
-    freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    rotated = d if rotary_dim is None else rotary_dim
+    half = rotated // 2
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    else:
+        freqs = np.asarray(inv_freq, np.float32)
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.float32)
     # Angles/cos/sin in f32 (positional phase must not quantize: at
@@ -103,15 +117,20 @@ def rotary_embedding(x, theta: float, positions=None):
     # over (B, S, H, D) this replaces was ~8% of the Llama-300M step
     # (XProf round 3).
     angles = positions.astype(jnp.float32)[..., :, None] * freqs
-    if angles.ndim == 2:                               # (S, half)
-        cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
-        sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
-    else:                                              # (B, S, half)
-        cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-        sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    # (S, half) rotates every batch row alike, (B, S, half) each its own.
+    at = (None, slice(None), None) if angles.ndim == 2 \
+        else (slice(None), slice(None), None)
+
+    def table(fn):
+        values = fn(angles) if scale is None else fn(angles) * scale
+        return values[at].astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
+    x1, x2 = x[..., :half], x[..., half:rotated]
+    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if rotated < d:
+        parts.append(x[..., rotated:])
+    return jnp.concatenate(parts, axis=-1)
 
 
 class LlamaAttention(nn.Module):

@@ -1,0 +1,103 @@
+"""What the Laguna test files share: the tiny configuration, the
+benchmark's plain reference loaded by path, and seeded weights at scales
+where every path matters."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.models import LAGUNA_TINY, LagunaLM
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# The tiny window is 48 and YaRN's original context 32: both shorter than
+# the sequence.
+SEQ = 128
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The benchmark's reference file, loaded by path (its name holds a
+    ``-`` and a ``.``) with ``benchmarks`` on the path for its own
+    import."""
+    import sys
+
+    bench = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "laguna_reference", os.path.join(
+                bench, "reference", "laguna-xs.2.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(bench)
+    return module
+
+
+def _config(held=None, **over):
+    return dataclasses.replace(LAGUNA_TINY, dtype=jnp.float32,
+                               experts_held=held, **over)
+
+
+def _rope_parameters(spec):
+    """A ``RotarySpec`` under the keys the configuration file has."""
+    group = {"rope_theta": spec.theta,
+             "partial_rotary_factor": spec.fraction,
+             "rope_type": "default" if spec.yarn_factor is None else "yarn"}
+    if spec.yarn_factor is not None:
+        group.update(
+            factor=spec.yarn_factor, beta_fast=spec.beta_fast,
+            beta_slow=spec.beta_slow,
+            original_max_position_embeddings=spec.original_positions,
+            attention_factor=spec.attention_factor)
+    return group
+
+
+def _reference_config(cfg):
+    """The model's sizes under the keys the configuration file has."""
+    return {
+        "num_layers": cfg.num_layers, "rms_norm_eps": cfg.norm_eps,
+        "head_dim": cfg.head_dim,
+        "layer_types": list(cfg.layer_types),
+        "mlp_layer_types": list(cfg.mlp_layer_types),
+        "sliding_window": cfg.sliding_window,
+        "num_experts_per_tok": cfg.num_selected,
+        "moe_routed_scaling_factor": cfg.routed_scale,
+        "rope_parameters": {
+            "full_attention": _rope_parameters(cfg.full_rotary),
+            "sliding_attention": _rope_parameters(cfg.sliding_rotary)},
+        "deployment": {"experts_held": list(cfg.held())},
+    }
+
+
+def _share(params, held):
+    """``params`` of the model that holds every routed expert, cut to
+    ``held``; what every chip holds alike is left whole."""
+    out = jax.tree.map(lambda x: x, params)
+    for name in sorted(n for n in out if n.startswith("layer_")):
+        for w in ("w_gate", "w_up", "w_down"):
+            if w in out[name]:
+                out[name][w] = {
+                    "kernel": out[name][w]["kernel"][
+                        jnp.array(held, jnp.int32)]}
+    return out
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    cfg = _config()
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
+                             cfg.vocab_size)
+    params = LagunaLM(cfg).init(jax.random.PRNGKey(3), ids)["params"]
+    # Scales at which every path matters: a router that decides, a gate
+    # that is not one half everywhere, experts and attention of the
+    # residual's own size.
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * (25.0 if "router" in str(path)
+                             or "wg" in str(path) else 3.0)
+        if x.ndim > 1 else x, params)
+    return ids, params

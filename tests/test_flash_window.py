@@ -51,6 +51,34 @@ def test_flash_window_matches_reference_causal_gqa(path, window, dtype):
     _assert_grads_close(flash, ref, q, k, v, 2e-3 if f32 else 5e-2)
 
 
+@pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 256)])
+def test_streamed_kernels_at_a_window_below_the_key_block(block_q, block_k):
+    """The regime of a window-512 layer under the default 512 x 1024
+    blocks (PR 32's cell), scaled down: window 128 below a key block of
+    256, 8 query heads over 2, at 1024 positions. A query block sees one
+    or two of the four key tiles; the others are fetched and skipped.
+    Forward and both backward kernels against the XLA reference."""
+    q = _rand((1, 1024, 8, 32), 60)
+    k, v = (_rand((1, 1024, 2, 32), 61 + i) for i in range(2))
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=128, block_q=block_q, block_k=block_k)
+    ref = lambda q, k, v: reference_attention(  # noqa: E731
+        q, k, v, causal=True, window=128)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=1e-4)
+    _assert_grads_close(flash, ref, q, k, v, 2e-3)
+    # Streamed, not one tile; and of a query block's key tiles at most
+    # two are live.
+    from horovod_tpu.ops.attention import _band_blocks, _one_tile_path
+
+    assert not _one_tile_path(q, k, block_q, block_k)
+    for qb in range(1024 // block_q):
+        live = [bool(_band_blocks(128, qb, kb, block_q, block_k, 0)[0])
+                for kb in range(1024 // block_k)]
+        assert 1 <= sum(live) <= 2
+
+
 def test_reference_window_is_the_band_written_out():
     # window 3 at 6 positions, by hand: row i averages v over i-2..i.
     v = jnp.arange(6, dtype=jnp.float32).reshape(1, 6, 1, 1)
