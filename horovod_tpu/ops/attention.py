@@ -35,19 +35,26 @@ environment variable selects it):
   ``hvd_flash_bwd_dkv`` (``dv^T = do^T p^T``, ``dk^T = q^T ds^T``). Under
   GQA a step covers a K/V head with its query group, so dk/dv are still
   written once per K/V head.
-* **Streamed** (every other shape, byte for byte as before): classic
-  FlashAttention-2 online-softmax blocking over heads folded into batch
-  (``_fold_heads``: a transposed copy each way, (B * H, S, D)). The grid
-  is (batch*heads, q_blocks, k_blocks); Pallas streams one (block_k, d)
-  K/V tile per innermost grid step from HBM into VMEM (BlockSpec
-  index_maps drive the double-buffered DMA pipeline), so VMEM holds
-  O(block_q*d + block_k*d), not O(seq_k*d), and the ceiling on sequence
-  length is HBM, not VMEM. Running max / normalizer / output accumulate
-  in VMEM scratch across the innermost dimension (TPU grids execute
-  sequentially). Backward is two kernels (``hvd_flash_bwd_dq`` streaming
-  K/V, ``hvd_flash_bwd_dkv`` streaming Q/dO), each rebuilding the
-  probabilities from the saved log-sum-exp instead of storing the S x S
-  matrix.
+* **Streamed** (every other shape): classic FlashAttention-2
+  online-softmax blocking over heads folded into batch (``_fold_heads``:
+  a transposed copy each way, (B * H, S, D)). The grid is (batch*heads,
+  q_blocks, the key blocks of a query block's band); Pallas streams one
+  (block_k, d) K/V tile per innermost grid step from HBM into VMEM
+  (BlockSpec index_maps drive the double-buffered DMA pipeline), so VMEM
+  holds O(block_q*d + block_k*d), not O(seq_k*d), and the ceiling on
+  sequence length is HBM, not VMEM. Running max / normalizer / output
+  accumulate in VMEM scratch across the innermost dimension (TPU grids
+  execute sequentially). Backward is two kernels (``hvd_flash_bwd_dq``
+  streaming K/V, ``hvd_flash_bwd_dkv`` streaming Q/dO), each rebuilding
+  the probabilities from the saved log-sum-exp instead of storing the
+  S x S matrix. **The inner axis counts the blocks of the band, not of
+  the sequence** (``_band_grid``, from ``causal``, ``window`` and the
+  shapes): without ``causal`` it is every block; with it the index maps
+  follow the band and clamp at its end, so no tile above the diagonal is
+  copied in; with a ``window`` the axis is also only as long as the band
+  is wide (2 of 8 key blocks at window 512 under the default blocks, 5 of
+  8 at window 4096, sequence 8192). ``_BandAxis.walk`` counts what a
+  head's sweep makes of steps, live steps and copies.
 
 Both keep the same conventions: products in the input dtype with f32
 accumulation, ``p`` cast to the V dtype for the MXU, fully-masked rows
@@ -82,7 +89,7 @@ streamed path at 2048+ has no benchmark cell yet.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -218,11 +225,13 @@ def _band_blocks(window: Optional[int], qb, kb, block_q: int, block_k: int,
     """Where the (qb, kb) block lies against the causal band: ``(live,
     edge)``. ``live``: some entry is inside the band (a block wholly
     above the diagonal, or wholly more than ``window`` keys below it,
-    touches no allowed entry and its compute is skipped; the DMA still
-    runs, grid fetches are static). ``edge``: an edge of the band crosses
-    the block, so its entries need the mask; a live block that no edge
-    crosses lies wholly inside and takes the body without the band's
-    mask."""
+    touches no allowed entry: no body runs for it, and ``_band_grid``
+    keeps its tiles out of the grid's fetches). ``edge``: an edge of the
+    band crosses the block, so its entries need the mask; a live block
+    that no edge crosses lies wholly inside and takes the body without
+    the band's mask. The one definition of both: ``_band_grid``'s first
+    and last live block are this function's, solved for one index
+    (``tests/test_flash_window.py`` walks every block of both)."""
     q_lo = qb * block_q + q_offset
     q_hi = q_lo + block_q - 1
     k_lo = kb * block_k
@@ -235,41 +244,169 @@ def _band_blocks(window: Optional[int], qb, kb, block_q: int, block_k: int,
     return live, live & jnp.logical_not(inside)
 
 
+def _block_index_op(static, traced):
+    """``min`` or ``max`` of two block indices: Python ints (a static
+    walk of the grid) or traced (an index map, a ``program_id``)."""
+    def op(a, b):
+        both = isinstance(a, int) and isinstance(b, int)
+        return static(a, b) if both else traced(a, b)
+    return op
+
+
+_lower = _block_index_op(min, jnp.minimum)
+_upper = _block_index_op(max, jnp.maximum)
+
+
+class _BandAxis(NamedTuple):
+    """The inner axis of a streamed grid: for the block ``o`` of the
+    outer sequence axis, the inner blocks ``first(o) .. last(o)`` are
+    the live ones (none where ``last(o) < first(o)``: a query block
+    before key 0, a key block below every query's window) and the axis
+    is ``extent`` steps long, the widest band of any ``o`` and at least
+    one step. ``banded`` is False without ``causal``: the axis is then
+    the whole inner sequence and a step is its block."""
+    first: Callable
+    last: Callable
+    extent: int
+    outer: int
+    inner: int
+    banded: bool
+
+    def block(self, o, j):
+        """Step ``j`` of the outer block ``o``: ``(block, tile, ended)``.
+        ``block = first(o) + j`` is what the kernel hands
+        ``_band_blocks``; ``ended``, ``block > last(o)``, says the step
+        lies past the band's end and runs no body (``_band_blocks``
+        would say as much of a block above the diagonal, but not of one
+        past the sequence's end); ``tile`` is what the index maps name:
+        ``block`` clamped into the band, so a step past the band's end
+        names the tile the step before it named and Pallas issues no
+        copy. Integer arithmetic on ``o`` and ``j``, Python or traced."""
+        if not self.banded:
+            return j, j, False
+        first, last = self.first(o), self.last(o)
+        block = first + j
+        return block, _lower(block, _upper(last, first)), block > last
+
+    def tile(self, o, j):
+        """``block``'s ``tile`` alone, for an index map."""
+        return self.block(o, j)[1]
+
+    def walk(self, group: int = 1):
+        """The static counter: ``(steps, live, tiles)`` of one head's
+        sweep of this axis beside ``outer * inner * group``, what the
+        grid over the whole sequence made of all three. ``steps``: grid
+        steps; ``live``: those that run a body; ``tiles``: those that
+        name another tile than the step before them, i.e. the copies
+        Pallas issues. ``group``: the query heads a K/V head's sweep
+        covers (dk/dv: ``t = g * extent + j``)."""
+        live = tiles = 0
+        before = None
+        for o in range(self.outer):
+            for g in range(group):
+                for j in range(self.extent):
+                    _, tile, ended = self.block(o, j)
+                    live += not ended
+                    tiles += (g, tile) != before
+                    before = (g, tile)
+        return self.outer * group * self.extent, live, tiles
+
+
+def _band_grid(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+               window: Optional[int]):
+    """The two inner axes of the streamed grids, from static shapes:
+    ``(keys, queries)``. ``keys``: the key blocks of a query block's
+    band, for the forward and dq grids ``(b*h, sq/block_q,
+    keys.extent)``; ``queries``: the query blocks of a key block's band,
+    for dk/dv's ``(b*hkv, sk/block_k, group * queries.extent)``. Both
+    are ``_band_blocks``' ``live`` solved for one index, with
+    ``q_offset = sk - sq``:
+
+    * ``k_lo <= q_hi``: ``kb <= q_hi // block_k`` and
+      ``qb >= (k_lo - q_offset) // block_q``;
+    * ``k_hi > q_lo - window``: ``kb >= (q_lo - window + 1) // block_k``
+      and ``qb <= (k_hi + window - 1 - q_offset) // block_q``;
+
+    each held inside the sequence (``//`` floors, here and on traced
+    values, so a bound below zero stays below ``first``). Not causal:
+    every block of the sequence, each its own tile. Causal without a
+    window: the axis stays as long as the sequence (the last query block
+    needs every key block, key block 0 every query block) and the clamp
+    of ``_BandAxis.block`` alone stops the fetch of the tiles above the
+    diagonal. With a window the axis is as long as the band is wide."""
+    num_qb, num_kb, q_offset = sq // block_q, sk // block_k, sk - sq
+
+    def first_k(qb):
+        if window is None:
+            return 0
+        return _upper(qb * block_q + q_offset - window + 1, 0) // block_k
+
+    def last_k(qb):
+        return (qb * block_q + q_offset + block_q - 1) // block_k
+
+    def first_q(kb):
+        return _upper(kb * block_k - q_offset, 0) // block_q
+
+    def last_q(kb):
+        if window is None:
+            return num_qb - 1
+        return _lower((kb * block_k + block_k + window - 2 - q_offset)
+                      // block_q, num_qb - 1)
+
+    def axis(first, last, outer, inner):
+        if not causal:
+            return _BandAxis(lambda o: 0, lambda o: inner - 1, inner, outer,
+                             inner, False)
+        extent = max([last(o) - first(o) + 1 for o in range(outer)] + [1])
+        return _BandAxis(first, last, extent, outer, inner, True)
+
+    return (axis(first_k, last_k, num_qb, num_kb),
+            axis(first_q, last_q, num_kb, num_qb))
+
+
 def _when_banded(causal: bool, window: Optional[int], qb, kb, block_q: int,
-                 block_k: int, q_offset: int, body):
-    """Run ``body(band)`` for this block: not at all outside the band,
-    with the band's mask where an edge crosses it, without it inside."""
+                 block_k: int, q_offset: int, ended, body):
+    """Run ``body(band)`` for this block: not at all outside the band or
+    where the step has ``ended`` (``_BandAxis.block``), with the band's
+    mask where an edge crosses it, without it inside."""
     if not causal:
         body(False)
         return
     live, edge = _band_blocks(window, qb, kb, block_q, block_k, q_offset)
+    going = jnp.logical_not(ended)
+    live, edge = live & going, edge & going
     pl.when(edge)(lambda: body(True))
     pl.when(live & jnp.logical_not(edge))(lambda: body(False))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *, block_k: int, sm_scale: float,
-                  causal: bool, num_kb: int, block_q: int, q_offset: int,
+                  causal: bool, keys: _BandAxis, block_q: int, q_offset: int,
                   has_mask: bool, window: Optional[int] = None):
-    # Grid (bh, qb, kb), kb innermost. Block shapes: q (1, block_q, d)
-    # (constant across kb — fetched once), k/v (1, block_k, d) (a NEW tile
-    # streams in from HBM each kb step), mask (1, 1, block_k). Running
-    # softmax state persists in VMEM scratch across the kb loop.
+    # Grid (bh, qb, j), j innermost over the key blocks of the query
+    # block's band (``_band_grid``): step j is key block ``kb = first(qb)
+    # + j``. Block shapes: q (1, block_q, d) (constant across j — fetched
+    # once), k/v (1, block_k, d) (a NEW tile streams in from HBM each step
+    # inside the band, none past its end: the index maps clamp), mask
+    # (1, 1, block_k). Running softmax state persists in VMEM scratch
+    # across the j loop.
     # ``q_offset = sk - sq``: under the decode convention the sq query rows
     # are the LAST sq positions of the sk-long key axis, so query row i sits
     # on the causal diagonal at key column i + q_offset (matches
     # reference_attention's ``qi = arange(sq) + (sk - sq)``).
-    qb, kb = pl.program_id(1), pl.program_id(2)
+    qb, j = pl.program_id(1), pl.program_id(2)
+    kb, _, ended = keys.block(qb, j)
 
-    @pl.when(kb == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: K blocks wholly outside the band (above the diagonal, or
-    # more than ``window`` keys below it) are skipped, and only the blocks
+    # Causal: a step past the band's end runs no body, and only the blocks
     # an edge of the band crosses build its mask: ``_when_banded``.
+    # A query block with no live key (rows before key 0 when sq > sk)
+    # still meets j == 0 and the last step, and writes its zeros.
     def _body(band):
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -303,9 +440,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, _body)
+    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, ended,
+                 _body)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(j == keys.extent - 1)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -652,25 +790,26 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
         return _rows_to_heads(out, h), lse
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     kv_row, mask_row = _gqa_index_maps(h, hkv)
-    num_kb = sk // block_k
-    # kb innermost: K/V tiles stream HBM→VMEM one per step; q block and the
-    # o/lse output blocks are revisited (their index_maps ignore kb), so
-    # they stay VMEM-resident across the whole kb sweep.
-    grid = (b * h, sq // block_q, num_kb)
+    keys, _ = _band_grid(sq, sk, block_q, block_k, causal, window)
+    # The band's key blocks innermost: K/V tiles stream HBM→VMEM one per
+    # step inside the band; q block and the o/lse output blocks are
+    # revisited (their index_maps ignore j), so they stay VMEM-resident
+    # across the whole sweep.
+    grid = (b * h, sq // block_q, keys.extent)
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, block_k=block_k, sm_scale=scale,
-                          causal=causal, num_kb=num_kb, block_q=block_q,
+                          causal=causal, keys=keys, block_q=block_q,
                           q_offset=sk - sq, has_mask=has_mask,
                           window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j: (kv_row(bh), j, 0)),
+                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j: (kv_row(bh), j, 0)),
+                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, i, j: (mask_row(bh), 0, j)),
+                         lambda bh, i, j: (mask_row(bh), 0, keys.tile(i, j))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -693,16 +832,18 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, block_k: int,
-                         sm_scale: float, causal: bool, num_kb: int,
+                         sm_scale: float, causal: bool, keys: _BandAxis,
                          block_q: int, q_offset: int, has_mask: bool,
                          window: Optional[int] = None):
-    # Grid (bh, qb, kb), kb innermost: K/V tiles stream from HBM while
-    # q/do/lse/delta stay resident. Recompute p block-by-block from q, k and
-    # the saved lse; no S x S materialization (FA-2 backward, dq pass).
+    # Grid (bh, qb, j) as the forward's, j innermost over the band's key
+    # blocks: K/V tiles stream from HBM while q/do/lse/delta stay resident.
+    # Recompute p block-by-block from q, k and the saved lse; no S x S
+    # materialization (FA-2 backward, dq pass).
     # q_offset: see _flash_kernel — decode-convention diagonal shift.
-    qb, kb = pl.program_id(1), pl.program_id(2)
+    qb, j = pl.program_id(1), pl.program_id(2)
+    kb, _, ended = keys.block(qb, j)
 
-    @pl.when(kb == 0)
+    @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -730,9 +871,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, _body)
+    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, ended,
+                 _body)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(j == keys.extent - 1)
     def _finalize():
         dq_ref[0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
 
@@ -740,20 +882,24 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                            delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                            block_q: int, sm_scale: float, causal: bool,
-                           num_qb: int, block_k: int, q_offset: int,
+                           queries: _BandAxis, block_k: int, q_offset: int,
                            inner_steps: int, has_mask: bool,
                            window: Optional[int] = None):
     # GQA-native grid (b*hkv, kb, t), t innermost sweeping the query GROUP
-    # x q blocks (t = g * num_qb + qb): this program's K/V-head block stays
-    # resident while Q/dO/lse/delta tiles stream from HBM for every query
-    # head in the group, and dk/dv accumulate in VMEM scratch across the
-    # whole sweep — the K/V-head gradient is written ONCE per (b*hkv, kb),
-    # i.e. Hkv/H of the HBM writes of a per-query-head grid, with no
-    # full-H partial in HBM and no XLA group-sum afterwards. MHA is the
-    # group == 1 case (inner_steps == num_qb).
+    # x the query blocks of the key block's band (``_band_grid``;
+    # t = g * extent + j, query block ``qb = first(kb) + j``): this
+    # program's K/V-head block stays resident while Q/dO/lse/delta tiles
+    # stream from HBM for every query head in the group (none past the
+    # band's end: the index maps clamp), and dk/dv accumulate in VMEM
+    # scratch across the whole sweep — the K/V-head gradient is written
+    # ONCE per (b*hkv, kb), i.e. Hkv/H of the HBM writes of a
+    # per-query-head grid, with no full-H partial in HBM and no XLA
+    # group-sum afterwards. MHA is the group == 1 case (inner_steps ==
+    # extent). A key block no query sees (sq < sk under a window) runs no
+    # body and writes its zeros.
     # q_offset: see _flash_kernel — decode-convention diagonal shift.
     kb, t = pl.program_id(1), pl.program_id(2)
-    qb = t % num_qb
+    qb, _, ended = queries.block(kb, t % queries.extent)
 
     @pl.when(t == 0)
     def _init():
@@ -785,7 +931,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, _body)
+    _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, ended,
+                 _body)
 
     @pl.when(t == inner_steps - 1)
     def _finalize():
@@ -834,22 +981,21 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
 
     qf, kf, vf, dof = (_fold_heads(x) for x in (q, k, v, g))
     kv_row, mask_row = _gqa_index_maps(h, hkv)
-    num_kb = sk // block_k
-    num_qb = sq // block_q
+    keys, queries = _band_grid(sq, sk, block_q, block_k, causal, window)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                          sm_scale=scale, causal=causal, num_kb=num_kb,
+                          sm_scale=scale, causal=causal, keys=keys,
                           block_q=block_q, q_offset=sk - sq,
                           has_mask=has_mask, window=window),
-        grid=(b * h, num_qb, num_kb),
+        grid=(b * h, sq // block_q, keys.extent),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j: (kv_row(bh), j, 0)),
+                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j: (kv_row(bh), j, 0)),
+                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, i, j: (mask_row(bh), 0, j)),
+                         lambda bh, i, j: (mask_row(bh), 0, keys.tile(i, j))),
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
@@ -862,36 +1008,40 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
     )(qf, kf, vf, maskf, dof, lse, delta)
 
     # GQA-native dkdv: grid rows are K/V heads (b*hkv), the query group is
-    # swept in-kernel (t = g * num_qb + qb, innermost), so dk/dv come out
-    # at (b*hkv, sk, d) directly — no full-H partials in HBM, no XLA
-    # group-sum. Q/dO/lse/delta index maps route the t step to query head
-    # kvh * group + t // num_qb (group-contiguous, matching repeat_kv).
+    # swept in-kernel (t = g * extent + j, innermost, over the query blocks
+    # of the key block's band), so dk/dv come out at (b*hkv, sk, d)
+    # directly — no full-H partials in HBM, no XLA group-sum. Q/dO/lse/delta
+    # index maps route the t step to query head kvh * group + t // extent
+    # (group-contiguous, matching repeat_kv) and to the band's query tile.
     group = h // hkv
-    inner = group * num_qb
+    inner = group * queries.extent
 
     def q_row(bh, t):
-        return (bh // hkv) * h + (bh % hkv) * group + t // num_qb
+        return (bh // hkv) * h + (bh % hkv) * group + t // queries.extent
+
+    def q_tile(j, t):
+        return queries.tile(j, t % queries.extent)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, block_q=block_q,
-                          sm_scale=scale, causal=causal, num_qb=num_qb,
+                          sm_scale=scale, causal=causal, queries=queries,
                           block_k=block_k, q_offset=sk - sq,
                           inner_steps=inner, has_mask=has_mask,
                           window=window),
-        grid=(b * hkv, num_kb, inner),
+        grid=(b * hkv, sk // block_k, inner),
         in_specs=[
             pl.BlockSpec((1, block_q, d),
-                         lambda bh, j, t: (q_row(bh, t), t % num_qb, 0)),
+                         lambda bh, j, t: (q_row(bh, t), q_tile(j, t), 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh, j, t: (bh // hkv, 0, j)),
             pl.BlockSpec((1, block_q, d),
-                         lambda bh, j, t: (q_row(bh, t), t % num_qb, 0)),
+                         lambda bh, j, t: (q_row(bh, t), q_tile(j, t), 0)),
             pl.BlockSpec((1, 1, block_q),
-                         lambda bh, j, t: (q_row(bh, t), 0, t % num_qb)),
+                         lambda bh, j, t: (q_row(bh, t), 0, q_tile(j, t))),
             pl.BlockSpec((1, 1, block_q),
-                         lambda bh, j, t: (q_row(bh, t), 0, t % num_qb)),
+                         lambda bh, j, t: (q_row(bh, t), 0, q_tile(j, t))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
@@ -963,11 +1113,15 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
 
     ``window`` (with ``causal``) bounds the band from below: the query at
     position i sees the keys ``i - window < j <= i``. The streamed
-    kernels, forward and both backward, skip the blocks that lie wholly
-    outside the band and build its mask only in the blocks an edge of it
-    crosses (the diagonal, and the edge ``window`` keys below it); the
-    one-tile kernels take it as one more term of their mask, and only
-    where ``sk > window``. ``window=None`` is the plain causal band.
+    kernels, forward and both backward, walk only the blocks of the band
+    (``_band_grid``: the grid's inner axis is as long as the band is
+    wide, and the blocks outside it are neither computed nor fetched) and
+    build its mask only in the blocks an edge of it crosses (the
+    diagonal, and the edge ``window`` keys below it); the one-tile
+    kernels take it as one more term of their mask, and only where
+    ``sk > window``. ``window=None`` is the plain causal band: the axis
+    is as long as the sequence, and the tiles above the diagonal are not
+    fetched.
 
     Grouped-query attention is native: pass k/v with Hkv < H heads
     (H % Hkv == 0) and each group of H/Hkv query heads reads one K/V
@@ -977,14 +1131,15 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
     accumulates each K/V head's gradient in VMEM across its query
     group — dk/dv are written once per K/V head (Hkv/H the HBM
     writes), never materialized at full H. Streaming DMA traffic for
-    K/V tiles is unchanged: each query head still reads its group's
-    tiles. On the one-tile path a grid step's block is a band of K/V
+    K/V tiles is unchanged by the grouping: each query head still reads
+    its group's tiles (those of its band, under ``causal``). On the
+    one-tile path a grid step's block is a band of K/V
     heads beside the band of their query groups, both cut from the
     caller's arrays by the index maps (``_heads_to_rows``).
 
     ``block_q``/``block_k`` set the VMEM working set AND the HBM→VMEM
-    streaming granule: per grid step one (block_k, d) K and V tile is DMAed
-    in (double-buffered by Pallas), so peak VMEM is
+    streaming granule: per grid step inside the band one (block_k, d) K
+    and V tile is DMAed in (double-buffered by Pallas), so peak VMEM is
     O(block_q*d + 2*block_k*d) independent of sequence length — S is bounded
     by HBM, not VMEM. Both are clamped/halved to divide the sequence
     length. Where that leaves one block a side and the tile fits VMEM

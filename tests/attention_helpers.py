@@ -43,6 +43,24 @@ def _assert_grads_close(fn, ref, q, k, v, tol):
         assert np.abs(a - b).max() / (np.abs(b).max() + 1e-6) < tol
 
 
+def kernel_grids(fn, *args):
+    """``{pallas_call name: its grid}`` of ``fn`` traced on ``args``
+    (arrays or ``jax.ShapeDtypeStruct``s); a name met twice fails."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                assert name not in found, name
+                found[name] = tuple(eqn.params["grid_mapping"].grid)
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
 # Both paths call their kernels by these names (the benchmark's per-kernel
 # metrics read them).
 KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")

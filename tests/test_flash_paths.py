@@ -9,7 +9,7 @@ import pytest
 
 from attention_helpers import (B, D, H, KERNELS, PATHS, S,
                                _assert_grads_close, _rand, _sq_loss,
-                               both_paths)
+                               both_paths, kernel_grids)
 from horovod_tpu.ops.attention import flash_attention, reference_attention
 
 
@@ -179,19 +179,8 @@ def _kernel_grids(s, d=16, h=1, hkv=None, **kw):
     f = jax.grad(lambda q, k, v, m: flash_attention(
         q, k, v, key_mask=m, interpret=True, **kw).astype(
             jnp.float32).sum(), argnums=(0, 1, 2))
-    found = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                name = eqn.params["name"]
-                assert name not in found, name
-                found[name] = len(eqn.params["grid_mapping"].grid)
-            for inner in jax.core.jaxprs_in_params(eqn.params):
-                walk(inner)
-
-    walk(jax.make_jaxpr(f)(x, kv, kv, m).jaxpr)
-    return found
+    grids = kernel_grids(f, x, kv, kv, m)
+    return {name: len(grids[name]) for name in sorted(grids)}
 
 
 ONE_TILE_GRIDS = dict.fromkeys(KERNELS, 2)
