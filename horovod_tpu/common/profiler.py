@@ -23,19 +23,23 @@ this module wires it up:
   is defined here and nowhere else: the names the program plants in the
   compiled step, which a trace reader keys on.
 * ``span(name)`` / ``spans()`` keep a bounded in-memory log of what set-up
-  was doing (import, ``init`` and its children, mesh and placement, the
-  compile cache) on ``time.perf_counter_ns``; each span is also a
+  was doing (import, ``init`` and its children, mesh and placement) on
+  ``time.perf_counter_ns``; each span is also a
   ``TraceAnnotation("hvd.<name>")``, so under an open profiler session
   it lies on the device trace's clock too.
 * ``compile_events()`` keeps one record per jax monitoring event of the
   compile path (outermost tracing, lowering, backend compile or cache
   load, cache hits and misses) with the function's name: which program
   compiled, and when. The listeners are registered once a process by ``hvd.init()``.
+* ``exchanges()`` keeps one record per gradient exchange the program
+  traced (``DistributedOptimizer``, ``distributed_value_and_grad``): how
+  many leaves, their bytes as held and on the wire, the axis and its
+  size. It is written while the step is traced, never while it runs.
 
-None of this has a switch: scopes are compile-time metadata, spans and
-compile records are appends to a bounded list. "Tracing on" still means
-one thing, an open ``jax.profiler`` session (``HOROVOD_PROFILE_DIR`` or
-an explicit ``trace(log_dir)``).
+None of this has a switch: scopes are compile-time metadata, spans,
+compile records and exchange records are appends to a bounded list.
+"Tracing on" still means one thing, an open ``jax.profiler`` session
+(``HOROVOD_PROFILE_DIR`` or an explicit ``trace(log_dir)``).
 
 View traces with TensorBoard's profile plugin or Perfetto
 (``docs/timeline.md``).
@@ -67,7 +71,8 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
            "Span", "span", "record_span", "spans", "spans_dropped",
            "CompileEvent", "compile_events", "install_compile_listeners",
-           "COMPILE_EVENTS"]
+           "COMPILE_EVENTS",
+           "ExchangeRecord", "record_exchange", "exchanges"]
 
 PROFILE_DIR_ENV = "HOROVOD_PROFILE_DIR"
 
@@ -366,3 +371,67 @@ def compile_events() -> list:
     ``backend_compile_duration`` record stamped after the first step is a
     recompilation: its ``fun_name`` says of what."""
     return list(_compile_events)
+
+
+# ------------------------------------------------------ exchange records
+
+#: Exchange records kept; older ones are dropped.
+MAX_EXCHANGES = 4096
+
+
+class ExchangeRecord(NamedTuple):
+    """What one traced gradient exchange asked to be reduced: the jit
+    tier's counterpart of the eager timeline's per-tensor lines. One
+    record per call of the exchange on traced values (once per traced
+    program, not once per step). ``prefix`` names the caller as its
+    ``hvd.allreduce.<prefix>.<i>`` scopes do; ``axis_size`` is ``None``
+    where the axis is not bound (plain ``jit``): every collective then
+    falls back to the identity and the step exchanges nothing.
+    ``bytes_asked`` counts the leaves as the gradient holds them,
+    ``bytes_wire`` as the ``psum`` carries them (after compression's
+    cast), ``wire_dtypes`` the latter by dtype name. ``at_ns`` is on
+    ``time.perf_counter_ns``; ``parent`` is the span open on the thread,
+    as a :class:`Span`'s."""
+    prefix: str
+    axis: str
+    axis_size: Optional[int]
+    leaves: int
+    bytes_asked: int
+    bytes_wire: int
+    wire_dtypes: dict
+    average: bool
+    at_ns: int
+    parent: Optional[str]
+
+
+_exchanges: collections.deque = collections.deque(maxlen=MAX_EXCHANGES)
+
+
+def _nbytes(leaf) -> int:
+    return int(leaf.size) * leaf.dtype.itemsize
+
+
+def record_exchange(prefix: str, axis: str, axis_size: Optional[int],
+                    asked, wire, average: bool) -> None:
+    """Append the record of one traced exchange. ``asked`` and ``wire``
+    are its leaves as the gradient holds them and as they go on the wire
+    (tracers do: only shapes and dtypes are read)."""
+    wire_dtypes = {}
+    for leaf in wire:
+        name = str(leaf.dtype)
+        wire_dtypes[name] = wire_dtypes.get(name, 0) + _nbytes(leaf)
+    stack = getattr(_open, "stack", None)
+    record = ExchangeRecord(
+        prefix, axis, axis_size, len(asked), sum(map(_nbytes, asked)),
+        sum(wire_dtypes.values()), wire_dtypes, bool(average),
+        time.perf_counter_ns(), stack[-1] if stack else None)
+    with _log_lock:
+        _exchanges.append(record)
+
+
+def exchanges() -> list:
+    """Every traced exchange since the process started, oldest first.
+    After the first step ``exchanges()[-1]`` says what every step
+    exchanges; ``axis_size is None`` says that it exchanges nothing."""
+    with _log_lock:
+        return list(_exchanges)

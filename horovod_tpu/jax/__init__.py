@@ -61,16 +61,17 @@ def _allreduce_tree(tree, average: bool, axis_name: Optional[str],
         # the axis is actually bound (shard_map): on the pjit-style
         # identity fallback the round-trip would truncate gradients for
         # zero wire savings.
-        compress_traced = compression is not None
-        if compress_traced:
-            try:
-                jax.lax.axis_index(C._resolve_axis(axis_name))
-            except NameError:
-                compress_traced = False
-        reduced = []
+        axis = C._resolve_axis(axis_name)
+        try:
+            axis_size = jax.lax.axis_size(axis)
+        except NameError:
+            axis_size = None
+        compress_traced = compression is not None and axis_size is not None
+        wire, reduced = [], []
         for i, g in enumerate(leaves):
             if compress_traced:
                 g, ctx = compression.compress(g)
+            wire.append(g)
             # Named like the eager tier names its timeline activities:
             # the hvd.allreduce.<prefix>.<i> scope lands in HLO metadata
             # and profiler traces (see common/profiler.py).
@@ -79,6 +80,9 @@ def _allreduce_tree(tree, average: bool, axis_name: Optional[str],
             if compress_traced:
                 r = compression.decompress(r, ctx)
             reduced.append(r)
+        # What this exchange asked for, kept while the step is traced.
+        profiler.record_exchange(name_prefix, axis, axis_size, leaves, wire,
+                                 average)
         return jax.tree_util.tree_unflatten(treedef, reduced)
     st = basics.state()
     if st.topology.size == 1:

@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import os
 
-from ..common import profiler
 from ..common.config import env_str
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -45,7 +44,6 @@ def default_dir() -> str:
     return os.path.join(_CHECKOUT, ".jax_cache")
 
 
-@profiler.span("compile_cache.enable")
 def enable() -> str:
     """Turn the persistent compile cache on (see module docstring) and
     return the directory in use. Call before the first compilation."""

@@ -5,6 +5,7 @@ trace wrappers must be env-gated no-ops when unconfigured."""
 
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -299,6 +300,118 @@ def test_distributed_value_and_grad_exchange_scope_in_hlo():
     assert paths and all("hvd.exchange/" in p for p in paths)
 
 
+# ------------------------------------------------- exchange records
+
+EXCHANGE_DEVICES = 4
+EXCHANGE_PARAMS = {"w": (4, 4), "b": (4,)}      # 20 float32: 80 bytes
+
+
+def _traced_exchange(wrap, bound=True):
+    """Lower (never run) a step that exchanges ``EXCHANGE_PARAMS``'
+    gradients through ``wrap(loss, p, x)``; the records it left."""
+    mesh = make_mesh({"data": EXCHANGE_DEVICES},
+                     devices=jax.devices()[:EXCHANGE_DEVICES])
+    params = {k: jnp.ones(shape) for k, shape in sorted(EXCHANGE_PARAMS.items())}
+    x = jnp.ones((EXCHANGE_DEVICES, 4))
+
+    def loss(p, x):
+        return ((x @ p["w"] + p["b"]) ** 2).mean()
+
+    def body(p, x):
+        return wrap(loss, p, x)
+
+    if bound:
+        body = jax.shard_map(body, mesh=mesh, in_specs=(P(), P("data")),
+                             out_specs=P(), check_vma=False)
+    mark = len(hvd.profiler.exchanges())
+    before = time.perf_counter_ns()
+    jax.jit(body).lower(params, x)
+    records = hvd.profiler.exchanges()[mark:]
+    assert all(before <= r.at_ns <= time.perf_counter_ns() for r in records)
+    return records
+
+
+def _optimizer_wrap(**options):
+    tx = hvd.DistributedOptimizer(optax.sgd(0.1), axis_name="data",
+                                  **options)
+
+    def wrap(loss, p, x):
+        u, _ = tx.update(jax.grad(loss)(p, x), tx.init(p), p)
+        return sum(a.sum() for a in jax.tree.leaves(
+            optax.apply_updates(p, u)))
+
+    return wrap
+
+
+def test_exchange_record_under_shard_map():
+    [r] = _traced_exchange(_optimizer_wrap())
+    assert r == hvd.profiler.ExchangeRecord(
+        prefix="DistributedOptimizer", axis="data",
+        axis_size=EXCHANGE_DEVICES, leaves=2, bytes_asked=80, bytes_wire=80,
+        wire_dtypes={"float32": 80}, average=True, at_ns=r.at_ns,
+        parent=None)
+    # The optimizer's ``name=`` is the record's prefix, as it is the
+    # per-leaf scopes'; a sum is told from a mean.
+    [named] = _traced_exchange(_optimizer_wrap(name="Outer", average=False))
+    assert (named.prefix, named.average) == ("Outer", False)
+
+
+def test_exchange_record_counts_the_wire_under_compression():
+    [r] = _traced_exchange(_optimizer_wrap(
+        compression=hvd.Compression.bf16))
+    assert (r.leaves, r.bytes_asked, r.bytes_wire) == (2, 80, 40)
+    assert r.wire_dtypes == {"bfloat16": 40}
+    [r] = _traced_exchange(_optimizer_wrap(
+        compression=hvd.Compression.fp16))
+    assert r.wire_dtypes == {"float16": 40}
+
+
+def test_exchange_record_says_when_the_axis_is_unbound():
+    """Plain ``jit``: every collective falls back to the identity. The
+    record is the one place that says so; nothing is cast for a wire that
+    is not there."""
+    [r] = _traced_exchange(
+        _optimizer_wrap(compression=hvd.Compression.bf16), bound=False)
+    assert r.axis == "data" and r.axis_size is None
+    assert (r.leaves, r.bytes_asked, r.bytes_wire) == (2, 80, 80)
+    assert r.wire_dtypes == {"float32": 80}
+
+
+def test_exchange_record_of_distributed_value_and_grad():
+    def wrap(loss, p, x):
+        value, g = hvd.distributed_value_and_grad(
+            loss, axis_name="data")(p, x)
+        return value + sum(a.sum() for a in jax.tree.leaves(g))
+
+    [r] = _traced_exchange(wrap)
+    assert (r.prefix, r.axis_size, r.leaves, r.bytes_wire) == (
+        "DistributedGrad", EXCHANGE_DEVICES, 2, 80)
+
+
+def test_exchange_record_names_the_open_span_and_none_is_left_eagerly():
+    with hvd.profiler.span("build_step"):
+        [r] = _traced_exchange(_optimizer_wrap())
+    assert r.parent == "build_step"
+    # The eager branch (concrete leaves) keeps its timeline: no record.
+    hvd.init()
+    tx = hvd.DistributedOptimizer(optax.sgd(0.1))
+    params = {k: jnp.ones(shape) for k, shape in sorted(EXCHANGE_PARAMS.items())}
+    mark = len(hvd.profiler.exchanges())
+    tx.update(params, tx.init(params), params)
+    assert hvd.profiler.exchanges()[mark:] == []
+
+
+def test_exchange_log_is_bounded(monkeypatch):
+    import collections
+
+    from horovod_tpu.common import profiler
+
+    monkeypatch.setattr(profiler, "_exchanges", collections.deque(maxlen=2))
+    for _ in range(3):
+        _traced_exchange(_optimizer_wrap())
+    assert len(profiler.exchanges()) == 2
+
+
 def _flash_text(which, **blocks):
     """``blocks`` empty: S=128 is one tile at the default blocks; explicit
     small blocks stream. Both paths call their kernels by the same names."""
@@ -431,5 +544,6 @@ def test_compile_cache_cannot_hand_back_another_commits_names(
         assert "true_scope_b" in fresh and "true_scope_a" not in fresh
     assert counts[1] > counts[0]    # its own entry, not the other's
     assert counts[2] == counts[1]   # and warm from then on
-    assert any(s.name == "compile_cache.enable"
-               for s in hvd.profiler.spans())
+    # It plants no span: nothing read the one it had (PR 34).
+    assert not any(s.name == "compile_cache.enable"
+                   for s in hvd.profiler.spans())
