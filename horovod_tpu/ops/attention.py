@@ -54,7 +54,16 @@ environment variable selects it):
   copied in; with a ``window`` the axis is also only as long as the band
   is wide (2 of 8 key blocks at window 512 under the default blocks, 5 of
   8 at window 4096, sequence 8192). ``_BandAxis.walk`` counts what a
-  head's sweep makes of steps, live steps and copies.
+  head's sweep makes of steps, live steps and copies, ``_band_pairs``
+  the pairs its live tiles compute beside the pairs of the band. **The
+  blocks are fitted to the sequence and, in the backward, to the band**:
+  ``block_q`` / ``block_k`` are upper bounds, halved until they divide
+  the sequence (``_fit_block``), and dq and dk/dv halve the key block
+  further while its half still covers the window (``_fit_band``: 512 at
+  window 512, where 1024 computed three times the band's pairs; window
+  4096 and plain causal keep 1024). The forward keeps the sequence's key
+  block, because its step pays for the row statistics whatever the key
+  block's width.
 
 Both keep the same conventions: products in the input dtype with f32
 accumulation, ``p`` cast to the V dtype for the MXU, fully-masked rows
@@ -128,9 +137,11 @@ FLASH_AUTO_MIN_SEQ = 512
 # sk <= 1024) takes the one-tile path, measured at S=512 (module
 # docstring). Longer ones stream in these tiles: (512, 1024) was the best
 # of a sweep at B=4 S=2048 H=8 D=64 bf16 causal on the installation before
-# this one (block_q=1024 overran the 16 MiB of scoped VMEM there); that
-# sweep has not been repeated on the current code, and no benchmark cell
-# runs the streamed path yet (PERF.md section 7).
+# this one (block_q=1024 overran the 16 MiB of scoped VMEM there). On the
+# current code, at B=2 S=8192 D=128 bf16 (my chip runs, PR 37): plain
+# causal 48/8 heads takes 17.56 / 17.67 / 22.35 ms a call (fwd / dq / dkv)
+# at (512, 1024) and 30.18 / 18.95 / 27.32 at (512, 512); under a window
+# of 512 the backward kernels' key block is ``_fit_band``'s.
 FLASH_DEFAULT_BLOCK_Q = 512
 FLASH_DEFAULT_BLOCK_K = 1024
 
@@ -190,9 +201,11 @@ def reference_attention(q, k, v, key_mask=None, causal=False,
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
 
 
-# Lane width of the m/l scratch accumulators. TPU VMEM wants a 128-wide
-# trailing dim; the running max/normalizer live column-broadcast across it.
-_STATE_LANES = 128
+# The lanes of a vector register: TPU VMEM wants a 128-wide trailing dim.
+# The m/l scratch accumulators are this wide (the running max/normalizer
+# live column-broadcast across it), and no block is fitted to a trailing
+# width that is not a multiple of it (``_fit_band``).
+_LANES = 128
 
 
 def _allowed_mask(mask_ref, has_mask: bool, band: bool, qb, kb,
@@ -231,7 +244,7 @@ def _band_blocks(window: Optional[int], qb, kb, block_q: int, block_k: int,
     that no edge crosses lies wholly inside and takes the body without
     the band's mask. The one definition of both: ``_band_grid``'s first
     and last live block are this function's, solved for one index
-    (``tests/test_flash_window.py`` walks every block of both)."""
+    (``tests/test_flash_band.py`` walks every block of both)."""
     q_lo = qb * block_q + q_offset
     q_hi = q_lo + block_q - 1
     k_lo = kb * block_k
@@ -362,6 +375,25 @@ def _band_grid(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
 
     return (axis(first_k, last_k, num_qb, num_kb),
             axis(first_q, last_q, num_kb, num_qb))
+
+
+def _band_pairs(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+                window: Optional[int]):
+    """The static counter's other half, beside ``_BandAxis.walk``:
+    ``(computed, band)`` of one head's sweep. ``computed``: the (query,
+    key) pairs its live tiles compute, each tile whole; ``band``: the
+    pairs the band holds, which is what the mathematics needs. Their
+    ratio is what ``_fit_band`` trades against the number of steps. The
+    same for the kernels that share the blocks: their live tiles are the
+    same set."""
+    keys, _ = _band_grid(sq, sk, block_q, block_k, causal, window)
+    if not causal:
+        band = sq * sk
+    else:
+        band = sum(
+            max(min(p, sk - 1) - max(p - (window or sk) + 1, 0) + 1, 0)
+            for p in range(sk - sq, sk))
+    return keys.walk()[1] * block_q * block_k, band
 
 
 def _when_banded(causal: bool, window: Optional[int], qb, kb, block_q: int,
@@ -527,6 +559,39 @@ def _fit_block(block: int, seq: int) -> int:
     while block > 1 and seq % block:
         block //= 2
     return max(block, 1)
+
+
+def _fit_band(block_k: int, causal: bool, window: Optional[int]) -> int:
+    """The sequence-fitted key block of a streamed *backward* call,
+    fitted to the band as well. Under a ``window`` a query block's band
+    is ``block_q + window - 1`` keys wide whatever the key block, and
+    every key tile it touches is computed whole: a key block wider than
+    the window computes mostly pairs the mask then throws away (window
+    512 under 512 x 1024 at sequence 8192: 2.97 times the band's pairs,
+    ``_band_pairs``). So the key block is halved while its half still
+    covers the window and is whole ``_LANES`` (the key block is the lane
+    axis of the key mask's (1, 1, block_k) block): the smallest halving
+    that is no narrower than the window, 512 there (2.00 times). A half
+    of a block that divides the sequence divides it too. A window at or
+    above the key block, plain causal and no band at all keep their
+    block; the query block is the sequence's in every case. From
+    ``causal``, ``window`` and the block alone, and only after
+    ``_one_tile_path`` has compared the sequence-fitted blocks with the
+    sequence: a call that fits one tile is one tile whatever its window.
+
+    ``_flash_backward`` takes it for dq and dk/dv, whose steps cost what
+    their tile's pairs cost. ``_flash_forward`` keeps the sequence's key
+    block: its step also pays for the online softmax's row statistics
+    (the cross-lane max and sum of ``block_q`` rows, the rescale of
+    ``m``, ``l`` and the accumulator), about as much as a 512 x 512
+    tile's pairs and no less under a narrower key block, so more, smaller
+    key steps lose there what the pairs gain (measured at each halving,
+    forward, dq and dk/dv apart: ``PERF.md`` section 6, PR 37). The two
+    share nothing that hangs on a block: ``out`` and ``lse`` are rows."""
+    if causal and window is not None:
+        while block_k % (2 * _LANES) == 0 and block_k // 2 >= window:
+            block_k //= 2
+    return block_k
 
 
 # ------------------------------------------------------- the one-tile path
@@ -820,8 +885,8 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _STATE_LANES), jnp.float32),
-            pltpu.VMEM((block_q, _STATE_LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
@@ -981,6 +1046,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
 
     qf, kf, vf, dof = (_fold_heads(x) for x in (q, k, v, g))
     kv_row, mask_row = _gqa_index_maps(h, hkv)
+    block_k = _fit_band(block_k, causal, window)
     keys, queries = _band_grid(sq, sk, block_q, block_k, causal, window)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
@@ -1141,10 +1207,16 @@ def flash_attention(q, k, v, key_mask=None, causal: bool = False,
     streaming granule: per grid step inside the band one (block_k, d) K
     and V tile is DMAed in (double-buffered by Pallas), so peak VMEM is
     O(block_q*d + 2*block_k*d) independent of sequence length — S is bounded
-    by HBM, not VMEM. Both are clamped/halved to divide the sequence
-    length. Where that leaves one block a side and the tile fits VMEM
-    (``_one_tile_heads``; sq <= 512 and sk <= 1024 at the defaults) the
-    one-tile kernels run instead and nothing streams: module docstring."""
+    by HBM, not VMEM. Both are upper bounds, clamped/halved to divide the
+    sequence length. Where that leaves one block a side and the tile fits
+    VMEM (``_one_tile_heads``; sq <= 512 and sk <= 1024 at the defaults)
+    the one-tile kernels run instead and nothing streams: module
+    docstring. Where the kernels stream under a ``window``, the backward
+    kernels' key block is fitted to the band as well: halved while its
+    half still covers the window and is whole 128 lanes (``_fit_band``),
+    so a window narrower than the key block costs dq and dk/dv tiles as
+    wide as the window, not as the block; the forward's blocks are the
+    sequence's."""
     if interpret is None:
         interpret = _auto_interpret()
     b, sq, sk = k.shape[0], q.shape[1], k.shape[1]

@@ -193,6 +193,10 @@ STREAMED_GRIDS = dict.fromkeys(KERNELS, 3)
     ("s512_causal_gqa", 512, dict(d=64, h=4, hkv=2, causal=True),
      ONE_TILE_GRIDS),
     ("s128_defaults", 128, {}, ONE_TILE_GRIDS),
+    # A window below sk cuts the tile, not the path: the band's fit
+    # (PR 37) comes after the path is chosen, in the streamed backward.
+    ("s512_window_below_sk", 512,
+     dict(d=64, h=4, hkv=2, causal=True, window=128), ONE_TILE_GRIDS),
     # Past one default block a side the kernels stream, as before.
     ("s2048_defaults", 2048, {}, STREAMED_GRIDS),
     ("s1024_defaults", 1024, {}, STREAMED_GRIDS),     # two query blocks
@@ -205,6 +209,51 @@ STREAMED_GRIDS = dict.fromkeys(KERNELS, 3)
 ])
 def test_flash_path_is_chosen_from_shapes(case, s, kw, grids):
     assert _kernel_grids(s, **kw) == grids
+
+
+def _grad_grids(sq, sk, h, hkv, **kw):
+    q = jax.ShapeDtypeStruct((1, sq, h, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, sk, hkv, 128), jnp.bfloat16)
+    return kernel_grids(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True, **kw).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+
+
+@pytest.mark.parametrize("window", [None, 64, 256, 1000])
+def test_one_tile_neighbours_keep_their_path_under_a_window(window):
+    # The largest tile of the default blocks, 512 queries on 1024 keys,
+    # with a query group: ``_one_tile_path`` compares the sequence-fitted
+    # blocks with (sq, sk), so no window narrower than the key block
+    # turns the call into a streamed one, forward or backward.
+    grids = _grad_grids(512, 1024, 4, 2, window=window)
+    assert {name: len(grids[name]) for name in sorted(grids)} == \
+        ONE_TILE_GRIDS
+
+
+@pytest.mark.parametrize("sq,sk,window,block_k", [
+    (2048, 2048, 512, 512),         # Laguna's sliding layers, shorter
+    (2048, 2048, 100, 128),
+    (1024, 4096, 256, 256),         # the decode convention
+    (2048, 2048, 1024, 1024),       # a window of the key block's width
+    (2048, 2048, None, 1024),
+])
+def test_streamed_kernels_take_their_blocks_from_one_place(sq, sk, window,
+                                                           block_k):
+    # Each kernel's grid is the band's at its blocks. The forward's are
+    # the defaults fitted to the sequence, (heads, sq / 512, key steps);
+    # dq and dk/dv agree on theirs, the same with the key block fitted to
+    # the band as well: dq (heads, sq / 512, key steps), dk/dv (K/V heads,
+    # sk / block_k, group x query steps).
+    from horovod_tpu.ops.attention import _band_grid, _fit_band
+
+    assert _fit_band(1024, True, window) == block_k
+    keys, _ = _band_grid(sq, sk, 512, 1024, True, window)
+    fit_keys, fit_queries = _band_grid(sq, sk, 512, block_k, True, window)
+    assert _grad_grids(sq, sk, 8, 2, window=window) == {
+        "hvd_flash_fwd": (8, sq // 512, keys.extent),
+        "hvd_flash_bwd_dq": (8, sq // 512, fit_keys.extent),
+        "hvd_flash_bwd_dkv": (2, sk // block_k, 4 * fit_queries.extent),
+    }
 
 
 def test_one_tile_rule():
