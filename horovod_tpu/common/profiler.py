@@ -65,6 +65,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "SCOPE_MOE_COMBINE", "SCOPE_MOE_SHARED",
            "SCOPE_ATTN_FULL", "SCOPE_ATTN_WINDOW", "SCOPE_ATTN_POINTWISE",
            "SCOPE_LINATTN_CONV", "SCOPE_LINATTN_SCAN", "SCOPE_LINATTN_GATE",
+           "SCOPE_SHORTCONV", "SCOPE_SHORTCONV_POINTWISE",
            "SCOPE_LOSS_HEAD",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
@@ -123,6 +124,14 @@ SCOPE_ATTN_POINTWISE = "hvd.attn.pointwise"
 SCOPE_LINATTN_CONV = "hvd.linattn.conv"
 SCOPE_LINATTN_SCAN = "hvd.linattn.scan"
 SCOPE_LINATTN_GATE = "hvd.linattn.gate"
+
+#: The gated short-convolution mixer (``models/lfm2.py``
+#: ``ShortConvMixer``), forward and backward alike: the whole mixer, both
+#: projections inside; and inside it the pointwise part between them, the
+#: two gates and the causal convolution
+#: (``ops/linear_attention.causal_conv``).
+SCOPE_SHORTCONV = "hvd.shortconv"
+SCOPE_SHORTCONV_POINTWISE = "hvd.shortconv.pointwise"
 
 #: Everything of ``models.chunked_causal_lm_loss``: the sweep over the
 #: sequence's chunks (one ``while``) that applies the head and computes

@@ -33,6 +33,12 @@ from .laguna import (  # noqa: F401
     LagunaConfig,
     LagunaLM,
 )
+from .lfm2 import (  # noqa: F401
+    LFM2_24B_A2B,
+    LFM2_TINY,
+    Lfm2Config,
+    Lfm2LM,
+)
 from .moe_lm import (  # noqa: F401
     MOE_SMALL,
     MOE_TINY,

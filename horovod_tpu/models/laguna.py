@@ -45,7 +45,8 @@ import numpy as np
 
 from ..common import profiler
 from ..ops.attention import make_attention_fn
-from ..parallel.moe import grouped_gated_mlp, moe_apply_held
+from ..parallel.moe import (grouped_gated_mlp, moe_apply_held,
+                            softmax_top_k)
 from .llama import RMSNorm, rotary_embedding
 from .smallthinker import _Kernel
 
@@ -264,7 +265,8 @@ class LagunaBlock(nn.Module):
         }
         routed, load = moe_apply_held(
             functools.partial(grouped_gated_mlp, activation=jax.nn.silu),
-            experts, rows, logits, held, cfg.num_selected)
+            experts, rows, logits, held, cfg.num_selected,
+            route=softmax_top_k)
         return a + shared + cfg.routed_scale * routed.reshape(b, s, d), load
 
 

@@ -1,0 +1,277 @@
+"""LFM2: a causal expert LM whose mixers are mostly short convolutions.
+
+The architecture of ``LiquidAI/LFM2-24B-A2B`` (``model_type``
+``lfm2_moe``; 24B parameters, 2B active; widths from its public
+``config.json``), beside ``SmallThinkerLM``, ``LagunaLM`` and
+``OlmoHybridLM`` and built from their parts (``RMSNorm``,
+``rotary_embedding``, ``causal_conv``, ``moe_apply_held``). The block is
+``h = x + Mixer(norm(x))``, ``y = h + FFN(norm(h))``. What sets it apart:
+
+* **Three mixers in four are gated short convolutions** (``layer_types``,
+  period [conv, conv, attention, conv]): ``[B, C, u] = split3(z W_in)``,
+  ``v = conv3(B * u)`` (causal, depthwise, 3 taps, no bias and no
+  activation), ``out = (C * v) W_out``: a gate on either side of a
+  convolution that sees two tokens back.
+* **The fourth is grouped-query attention** with an RMSNorm over each q
+  and k head (one scale of the head's width, shared by the heads) BEFORE
+  the rotary embedding, which rotates the whole head; 32 query heads over
+  8, width ``dim / heads`` = 64.
+* **The first ``num_dense_layers`` FFNs are dense** (SiLU-gated); the
+  others route: scores ``s = sigmoid(z W_r)`` in float32, the chosen set
+  is the 4 largest of ``s + b`` with ``b`` one float an expert that
+  enters THE CHOICE ONLY (``use_expert_bias``: what an
+  auxiliary-loss-free balancing rule moves; here a leaf that takes no
+  gradient, see below), the weights are the chosen experts' own scores
+  over their sum plus 1e-6 (``norm_topk_prob``; the published
+  ``routed_scaling_factor`` is 1); no shared expert.
+* **The head is the embedding**: ``logits = x E^T``; with
+  ``return_hidden`` the caller hands
+  ``chunked_causal_lm_loss`` the embedding transposed, and the
+  embedding's gradient is the sum of the lookup's and the head's.
+
+**The bias** is the leaf ``layer_i/expert_bias/kernel`` ``[num_experts]``
+in ``params``: the routing rule reads it under ``stop_gradient``, so its
+gradient is exactly zero, and a training step must leave it out of weight
+decay (``decay_mask``). The update that trains it by the experts' loads
+is not in this file.
+
+**The experts held.** ``experts_held`` names the routed experts whose
+weights this device has (``SmallThinkerConfig.experts_held``): a sparse
+block routes over all ``num_experts`` and adds the part the held experts
+give; mixers, router, bias and the dense layers are whole on every
+device. The routed parts of disjoint shares, with everything else counted
+once, add up to the whole layer (``tests/test_lfm2.py``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Callable, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..common import profiler
+from ..ops.attention import make_attention_fn
+from ..ops.linear_attention import causal_conv
+from ..parallel.moe import grouped_gated_mlp, moe_apply_held, sigmoid_top_k
+from .laguna import GatedMLP
+from .llama import RMSNorm, rotary_embedding
+from .olmo_hybrid import _Leaf
+from .smallthinker import _Kernel
+
+CONV, FULL = "conv", "full_attention"
+_PERIOD = (CONV, CONV, FULL, CONV)
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2Config:
+    vocab_size: int = 65536
+    dim: int = 2048
+    num_layers: int = 40
+    # One entry a layer: its mixer's kind.
+    layer_types: Tuple[str, ...] = _PERIOD * 10
+    # The first ``num_dense_layers`` FFNs are dense, the others route.
+    num_dense_layers: int = 2
+    num_heads: int = 32              # the head's width is dim / num_heads
+    num_kv_heads: int = 8
+    rope_theta: float = 1e6
+    conv_taps: int = 3
+    mlp_hidden: int = 11776          # the dense layers'
+    num_experts: int = 64            # the router's width
+    num_selected: int = 4
+    expert_hidden: int = 1536
+    # Routed expert ids whose weights this device holds; None = all.
+    experts_held: Optional[Tuple[int, ...]] = None
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    # jax.checkpoint each block in the backward pass (LlamaConfig.remat).
+    remat: bool = False
+
+    @property
+    def head_dim(self) -> int:
+        return self.dim // self.num_heads
+
+    def held(self) -> Tuple[int, ...]:
+        return (tuple(range(self.num_experts)) if self.experts_held is None
+                else tuple(self.experts_held))
+
+
+LFM2_24B_A2B = Lfm2Config()
+# A leading dense layer and one period after it, 2 of 8 experts a token.
+LFM2_TINY = Lfm2Config(
+    vocab_size=512, dim=64, num_layers=5,
+    layer_types=(CONV, FULL, CONV, CONV, CONV), num_dense_layers=1,
+    num_heads=2, num_kv_heads=1, mlp_hidden=160, num_experts=8,
+    num_selected=2, expert_hidden=48)
+
+
+def _taps_init(key, shape, dtype=jnp.float32):
+    """Uniform within 1/sqrt(taps) of zero: a depthwise convolution's
+    default in the framework the model was published in."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def gated_short_conv(b_gate, c_gate, u, taps):
+    """``C * conv(B * u)``: the pointwise part of the short-convolution
+    mixer, (B, S, dim) arrays and ``taps`` (K, dim). The convolution is
+    ``ops.linear_attention.causal_conv`` with no activation."""
+    return c_gate * causal_conv(b_gate * u, taps)
+
+
+def decay_mask(params):
+    """``params``-shaped tree of bools for an optimizer's weight decay:
+    False on every ``expert_bias`` leaf, which no step trains."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: not any(
+            getattr(k, "key", None) == "expert_bias" for k in path), params)
+
+
+class ShortConvMixer(nn.Module):
+    """``[B, C, u] = split3(z W_in)``; ``out = (C * conv(B * u)) W_out``,
+    no bias anywhere. All of it under ``hvd.shortconv``, the part between
+    the projections under ``hvd.shortconv.pointwise``."""
+    config: Lfm2Config
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        dense = lambda features, name: nn.Dense(  # noqa: E731
+            features, use_bias=False, dtype=cfg.dtype,
+            param_dtype=jnp.float32, name=name)
+        with jax.named_scope(profiler.SCOPE_SHORTCONV):
+            b_gate, c_gate, u = jnp.split(
+                dense(3 * cfg.dim, "in_proj")(x), 3, axis=-1)
+            taps = _Leaf("kernel", (cfg.conv_taps, cfg.dim), _taps_init,
+                         name="taps")()
+            with jax.named_scope(profiler.SCOPE_SHORTCONV_POINTWISE):
+                y = gated_short_conv(b_gate, c_gate, u, taps)
+            return dense(cfg.dim, "out_proj")(y)
+
+
+class Lfm2Attention(nn.Module):
+    """Causal grouped-query attention: RMSNorm over each q and k head,
+    then the rotary embedding over the whole head. ``attention_fn(q, k,
+    v, None)`` carries the band and runs under ``hvd.attn.full``."""
+    config: Lfm2Config
+    attention_fn: Callable
+
+    @nn.compact
+    def __call__(self, x, positions=None):
+        cfg = self.config
+        dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
+            features=(heads, cfg.head_dim), axis=-1, use_bias=False,
+            dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
+        q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(
+            dense(cfg.num_heads, "wq")(x))
+        k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(
+            dense(cfg.num_kv_heads, "wk")(x))
+        v = dense(cfg.num_kv_heads, "wv")(x)
+        q = rotary_embedding(q, cfg.rope_theta, positions)
+        k = rotary_embedding(k, cfg.rope_theta, positions)
+        with jax.named_scope(profiler.SCOPE_ATTN_FULL):
+            ctx = self.attention_fn(q, k, v, None)
+        return nn.DenseGeneral(features=cfg.dim, axis=(-2, -1),
+                               use_bias=False, dtype=cfg.dtype,
+                               param_dtype=jnp.float32, name="wo")(ctx)
+
+
+class Lfm2Block(nn.Module):
+    """``h = x + Mixer(norm(x))``; ``out = h + F(norm(h))``, ``F`` the
+    dense MLP or the chosen routed experts held here. Returns ``(out,
+    load)``, ``load`` the assignments each held expert received (``None``
+    from a dense layer)."""
+    config: Lfm2Config
+    kind: str           # the mixer's: CONV or FULL
+    sparse: bool
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x, positions=None):
+        cfg = self.config
+        b, s, d = x.shape
+        z = RMSNorm(cfg.norm_eps, cfg.dtype, name="operator_norm")(x)
+        if self.kind == CONV:
+            mixed = ShortConvMixer(cfg, name="conv")(z)
+        elif self.kind == FULL:
+            mixed = Lfm2Attention(cfg, self.attention_fn,
+                                  name="attention")(z, positions)
+        else:
+            raise ValueError(f"Lfm2Block: layer type {self.kind!r} is "
+                             f"neither {CONV!r} nor {FULL!r}")
+        h = x + mixed
+        z = RMSNorm(cfg.norm_eps, cfg.dtype, name="ffn_norm")(h)
+        if not self.sparse:
+            return h + GatedMLP(cfg.mlp_hidden, cfg.dtype, name="mlp")(z), \
+                None
+        held = cfg.held()
+        rows = z.reshape(b * s, d)
+        # The router in float32: which experts a token gets is decided on
+        # small differences between scores.
+        logits = rows.astype(jnp.float32) @ _Kernel(
+            (d, cfg.num_experts), name="router")()
+        bias = _Leaf("kernel", (cfg.num_experts,),
+                     nn.initializers.normal(0.01), name="expert_bias")()
+        experts = {
+            "w_gate": _Kernel((len(held), d, cfg.expert_hidden),
+                              name="w_gate")(),
+            "w_up": _Kernel((len(held), d, cfg.expert_hidden),
+                            name="w_up")(),
+            "w_down": _Kernel((len(held), cfg.expert_hidden, d),
+                              name="w_down")(),
+        }
+        routed, load = moe_apply_held(
+            functools.partial(grouped_gated_mlp, activation=jax.nn.silu),
+            experts, rows, logits, held, cfg.num_selected,
+            route=sigmoid_top_k(bias))
+        return h + routed.reshape(b, s, d), load
+
+
+class Lfm2LM(nn.Module):
+    """Token embedding, the blocks, a final RMSNorm and the head, which
+    is the embedding again.
+
+    ``attention_fn(q, k, v, mask)`` serves the attention layers; the
+    default is the plain XLA softmax. On the chip pass
+    ``make_attention_fn(causal=True)``, whose own shape rule picks the
+    kernels.
+
+    Returns ``(logits, load)``, or with ``return_hidden`` ``(hidden,
+    load)`` for ``chunked_causal_lm_loss``, whose ``head_kernel`` is then
+    ``params["tok_embeddings"]["embedding"].T``; ``load[sparse layer,
+    held expert]`` counts the assignments each held expert received (a
+    dense layer has no row)."""
+    config: Lfm2Config
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, input_ids, positions=None, return_hidden=False):
+        cfg = self.config
+        if len(cfg.layer_types) < cfg.num_layers:
+            raise ValueError("Lfm2LM: layer_types needs an entry for each "
+                             f"of {cfg.num_layers} layers")
+        attention_fn = self.attention_fn or make_attention_fn(
+            causal=True, use_flash=False)
+        embed = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
+                         name="tok_embeddings")
+        x = embed(input_ids).astype(cfg.dtype)
+        block_cls = nn.remat(Lfm2Block) if cfg.remat else Lfm2Block
+        loads = []
+        for i, kind in enumerate(cfg.layer_types[:cfg.num_layers]):
+            sparse = i >= cfg.num_dense_layers
+            x, load = block_cls(
+                cfg, kind=kind, sparse=sparse,
+                attention_fn=attention_fn if kind == FULL else None,
+                name=f"layer_{i}")(x, positions)
+            if sparse:
+                loads.append(load)
+        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        load = jnp.stack(loads) if loads else jnp.zeros(
+            (0, len(cfg.held())), jnp.int32)
+        if return_hidden:
+            return x, load
+        return jnp.einsum("bsd,vd->bsv", x, embed.embedding.astype(
+            cfg.dtype)), load

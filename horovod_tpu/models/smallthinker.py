@@ -40,7 +40,8 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..ops.attention import make_attention_fn
-from ..parallel.moe import grouped_gated_mlp, moe_apply_held
+from ..parallel.moe import (grouped_gated_mlp, moe_apply_held,
+                            softmax_top_k)
 from .llama import RMSNorm, rotary_embedding
 
 _PERIOD = (0, 1, 1, 1)      # global without RoPE, then three window layers
@@ -153,7 +154,7 @@ class SmallThinkerBlock(nn.Module):
         }
         y, load = moe_apply_held(grouped_gated_mlp, experts,
                                  h.reshape(b * s, d), logits, held,
-                                 cfg.num_selected)
+                                 cfg.num_selected, route=softmax_top_k)
         return a + y.reshape(b, s, d), load
 
 

@@ -62,7 +62,8 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["gated_delta_rule", "reference_gated_delta_rule",
-           "causal_conv_silu", "gated_head_norm", "l2_normalize"]
+           "causal_conv", "causal_conv_silu", "gated_head_norm",
+           "l2_normalize"]
 
 
 def _dot(spec, a, b, dtype):
@@ -230,13 +231,16 @@ def reference_gated_delta_rule(q, k, v, g, beta,
     return (o, final) if output_final_state else o
 
 
-def causal_conv_silu(x, w):
-    """``silu`` of a causal depthwise convolution along the sequence:
-    ``x`` ``(B, S, C)``, ``w`` ``(K, C)``; channel ``c`` of token ``t`` is
-    ``silu(sum_i w[i, c] x[t - (K - 1) + i, c])``, tokens before the
-    sequence read as zero, no bias. The last tap is the token's own: no
-    later token is seen. Float32 inside, ``x``'s type out; the backward
-    pass keeps ``x`` alone and recomputes the float32 sums."""
+def causal_conv(x, w, activation=None):
+    """A causal depthwise convolution along the sequence: ``x``
+    ``(B, S, C)``, ``w`` ``(K, C)``; channel ``c`` of token ``t`` is
+    ``sum_i w[i, c] x[t - (K - 1) + i, c]``, tokens before the sequence
+    read as zero, no bias. The last tap is the token's own: no later token
+    is seen. ``activation`` (float32 -> float32, pointwise) is the
+    caller's and is applied to the sums; ``None`` leaves them as they are
+    (the gated short convolution of ``models/lfm2.py``). Float32 inside,
+    ``x``'s type out; the backward pass keeps ``x`` alone and recomputes
+    the float32 sums."""
     taps, s = w.shape[0], x.shape[1]
 
     @jax.checkpoint
@@ -245,9 +249,16 @@ def causal_conv_silu(x, w):
                          ((0, 0), (taps - 1, 0), (0, 0)))
         y = sum(padded[:, i:i + s] * w[i].astype(jnp.float32)
                 for i in range(taps))
-        return jax.nn.silu(y).astype(x.dtype)
+        return (y if activation is None else activation(y)).astype(x.dtype)
 
     return conv(x, w)
+
+
+def causal_conv_silu(x, w):
+    """``silu`` of :func:`causal_conv`: channel ``c`` of token ``t`` is
+    ``silu(sum_i w[i, c] x[t - (K - 1) + i, c])`` (the gated delta-rule
+    layer's convolutions, ``models/olmo_hybrid.py``)."""
+    return causal_conv(x, w, jax.nn.silu)
 
 
 def l2_normalize(x, eps: float = 1e-6):

@@ -10,6 +10,8 @@ from .moe import (  # noqa: F401
     moe_apply,
     moe_apply_dense,
     moe_apply_held,
+    sigmoid_top_k,
+    softmax_top_k,
     switch_aux_loss,
 )
 from .hierarchical import (  # noqa: F401

@@ -23,7 +23,9 @@ auxiliary loss is returned alongside the output.
 A second layer, below the capacity path, is dropless and is told which
 experts its device holds (:func:`moe_apply_held`): it routes over all the
 router's experts, sorts the assignments that land here by expert and runs
-grouped products over them (:func:`grouped_gated_mlp`), under the scopes
+grouped products over them (:func:`grouped_gated_mlp`), by the routing
+rule its model gives (:func:`softmax_top_k`, :func:`sigmoid_top_k`), under
+the scopes
 ``hvd.moe.route`` / ``.dispatch`` / ``.experts`` / ``.combine``. It is the
 share of an expert-parallel deployment one device computes between the two
 exchanges; the exchange is not in it. ``MoeLM`` keeps the capacity path:
@@ -445,20 +447,70 @@ def grouped_gated_mlp(params: Any, rows: jax.Array, group_sizes: jax.Array,
     return grouped(hidden, params["w_down"])
 
 
+def softmax_top_k(gate_logits: jax.Array, num_selected: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The routing rule of :func:`moe_apply_held`'s first models: a token
+    chooses its ``num_selected`` largest logits and weighs them by a
+    softmax over the chosen logits (softmax over all, top k, renormalised:
+    the same numbers). ``gate_logits`` ``[T, E]`` float32; returns
+    ``(top_ids, weights)``, both ``[T, k]``."""
+    top_logits, top_ids = jax.lax.top_k(gate_logits, num_selected)
+    return top_ids, jax.nn.softmax(top_logits, axis=-1)
+
+
+#: In the normalisation of :func:`sigmoid_top_k`'s weights.
+_WEIGHT_SUM_EPS = 1e-6
+
+
+def sigmoid_top_k(bias: jax.Array) -> Callable:
+    """A routing rule for :func:`moe_apply_held` whose choice is made on
+    one array and whose weights are taken from another: the scores are
+    ``s = sigmoid(gate_logits)``; a token chooses the ``num_selected``
+    largest of ``s + bias`` (``bias`` ``[E]``, one float an expert, as an
+    auxiliary-loss-free balancing rule keeps one: it enters the CHOICE
+    only, takes no gradient and gives none); the weights are the chosen
+    experts' own ``s`` over their sum plus 1e-6."""
+    def rule(gate_logits, num_selected):
+        scores = jax.nn.sigmoid(gate_logits)
+        _, top_ids = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(scores.dtype)),
+            num_selected)
+        # A chosen expert's own score, as a select over the router's
+        # width: pointwise forward and backward, no gather along the
+        # minor axis and no scatter-add behind it.
+        chosen = top_ids[..., None] == jnp.arange(
+            scores.shape[-1], dtype=top_ids.dtype)              # [T, k, E]
+        weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0),
+                          axis=-1)
+        return top_ids, weights / (
+            jnp.sum(weights, axis=-1, keepdims=True) + _WEIGHT_SUM_EPS)
+
+    return rule
+
+
 def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
                                        jax.Array],
                    expert_params: Any,
                    x: jax.Array,
                    gate_logits: jax.Array,
                    held: Sequence[int],
-                   num_selected: int) -> Tuple[jax.Array, jax.Array]:
+                   num_selected: int,
+                   route: Callable[[jax.Array, int],
+                                   Tuple[jax.Array, jax.Array]]
+                   = softmax_top_k) -> Tuple[jax.Array, jax.Array]:
     """A dropless top-k expert layer on the device that holds the experts
     ``held`` (ids into the router's ``gate_logits[T, E]``, static, in the
     order of ``expert_params``' leading axis).
 
-    Every token chooses its ``num_selected`` largest logits over ALL ``E``
-    experts and weighs them by a softmax over the chosen logits (softmax
-    over all, top k, renormalised: the same numbers). The assignments
+    Every token chooses ``num_selected`` of ALL ``E`` experts and weighs
+    them by the model's routing rule: ``route(gate_logits in float32,
+    num_selected) -> (top_ids, weights)``, both ``[T, k]``, traced under
+    ``hvd.moe.route``. :func:`softmax_top_k` (the default: the largest
+    logits, a softmax over them) and :func:`sigmoid_top_k` (sigmoid
+    scores, a bias an expert in the choice only, weights normalised by
+    their sum) are the two the models here give; the rule is the only
+    thing that differs between them, and dispatch, experts and combine
+    are one code. The assignments
     whose expert is held here are sorted by expert, their rows gathered,
     ``expert_fn(expert_params, rows, group_sizes)`` applied (see
     :func:`grouped_gated_mlp`), and the weighted results summed per token.
@@ -488,9 +540,8 @@ def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
     slot_of[list(held)] = np.arange(n_held, dtype=np.int32)
 
     with jax.named_scope(profiler.SCOPE_MOE_ROUTE):
-        top_logits, top_ids = jax.lax.top_k(
-            gate_logits.astype(jnp.float32), num_selected)      # [T, k]
-        weights = jax.nn.softmax(top_logits, axis=-1)
+        top_ids, weights = route(gate_logits.astype(jnp.float32),
+                                 num_selected)                  # [T, k]
         slots = jnp.asarray(slot_of)[top_ids]                   # [T, k]
         weights = jnp.where(slots < n_held, weights, 0.0).astype(x.dtype)
         # Round-major: assignment j * T + t, so that a token's choices

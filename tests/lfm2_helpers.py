@@ -1,0 +1,96 @@
+"""What the LFM2 test files share: the tiny configuration, the
+benchmark's plain reference loaded by path, and seeded weights at scales
+where every path matters."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.models import LFM2_TINY, Lfm2LM
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ = 96
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The benchmark's reference file, loaded by path (its name holds
+    ``-`` and ``.``) with ``benchmarks`` on the path for its own
+    import."""
+    import sys
+
+    bench = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "lfm2_reference", os.path.join(
+                bench, "reference", "lfm2-24b-a2b.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(bench)
+    return module
+
+
+def _config(held=None, **over):
+    return dataclasses.replace(LFM2_TINY, dtype=jnp.float32,
+                               experts_held=held, **over)
+
+
+def _reference_config(cfg, **optimizer):
+    """The model's sizes under the keys the configuration file has; the
+    layers run are the first ``num_layers`` of ``layer_types``."""
+    return {
+        "num_layers": cfg.num_layers, "norm_eps": cfg.norm_eps,
+        "num_dense_layers": cfg.num_dense_layers,
+        "layer_types": list(cfg.layer_types),
+        "num_experts_per_tok": cfg.num_selected,
+        "routed_scaling_factor": 1,
+        "rope_parameters": {"rope_theta": cfg.rope_theta,
+                            "rope_type": "default"},
+        "deployment": {"experts_held": list(cfg.held()),
+                       "layers_run": list(range(cfg.num_layers))},
+        "optimizer": optimizer,
+    }
+
+
+def _share(params, held):
+    """``params`` of the model that holds every routed expert, cut to
+    ``held``; what every chip holds alike is left whole."""
+    out = jax.tree.map(lambda x: x, params)
+    for name in sorted(n for n in out if n.startswith("layer_")):
+        for w in ("w_gate", "w_up", "w_down"):
+            if w in out[name]:
+                out[name][w] = {
+                    "kernel": out[name][w]["kernel"][
+                        jnp.array(held, jnp.int32)]}
+    return out
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    cfg = _config()
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
+                             cfg.vocab_size)
+    params = jax.jit(Lfm2LM(cfg).init)(jax.random.PRNGKey(3), ids)["params"]
+
+    # Scales at which every path matters: a router that decides, a bias
+    # that moves the choice for some tokens and not for all, mixers and
+    # experts of the residual's own size (a convolution mixer is cubic in
+    # its in-projection, which flax draws at 1/sqrt(width): left as
+    # drawn).
+    def scaled(path, x):
+        names = {str(getattr(k, "key", k)) for k in path}
+        if "router" in names:
+            return x * 25.0
+        if "expert_bias" in names:
+            return x * 10.0
+        if names & {"in_proj", "out_proj"}:
+            return x
+        return x * 3.0 if x.ndim > 1 and "taps" not in names else x
+
+    return ids, jax.tree_util.tree_map_with_path(scaled, params)

@@ -1,0 +1,218 @@
+"""LFM2 (``models/lfm2.py``) and what it forced of ``moe_apply_held``
+and the causal convolution, at a tiny size on seeded weights: the model
+against the benchmark's plain float32 reference through three AdamW steps
+(loss, first gradient, the parameters after); the convolution sees no
+later token and is three shifted sums; the bias changes the chosen set
+and not the weights, takes a zero gradient and is left by the optimizer
+as it was; the tied matrix's gradient is the sum of its two paths; the
+routed parts of the eight disjoint shares add up to the uncut reference's
+layer."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from horovod_tpu.models import (Lfm2LM, causal_lm_loss,
+                                chunked_causal_lm_loss)
+from horovod_tpu.models.lfm2 import (CONV, Lfm2Block, decay_mask,
+                                     gated_short_conv)
+from horovod_tpu.ops.linear_attention import causal_conv, causal_conv_silu
+from horovod_tpu.parallel.moe import sigmoid_top_k, softmax_top_k
+from lfm2_helpers import (SEQ, _config, _reference_config, _share,  # noqa: F401
+                          reference, seeded)
+
+OPTIMIZER = dict(learning_rate=1e-3, b1=0.9, b2=0.999, eps=1e-8,
+                 weight_decay=0.1)
+
+
+def test_three_adamw_steps_match_the_plain_reference(seeded, reference):
+    """Each step's loss, the first gradient and the parameters after
+    three steps, with a share of the experts held (the layer that holds
+    all eight is the last test's), both mixers, the dense layer, the QK
+    norms, the bias and the tied head on; the bias comes out bit for bit
+    as it went in, on both sides."""
+    ids, params = seeded
+    held = (0, 5, 7)
+    cfg = _config(held)
+    assert {"conv", "full_attention"} == set(cfg.layer_types)
+    params = _share(params, held)
+    model = Lfm2LM(cfg)
+    tx = optax.adamw(mask=decay_mask, **OPTIMIZER)
+
+    def loss(p):
+        return causal_lm_loss(model.apply({"params": p}, ids)[0], ids)
+
+    @jax.jit
+    def step(p, opt_state):
+        value, grads = jax.value_and_grad(loss)(p)
+        updates, opt_state = tx.update(grads, opt_state, p)
+        return optax.apply_updates(p, updates), opt_state, value, grads
+
+    ours, opt_state, losses, first = params, tx.init(params), [], None
+    for _ in range(3):
+        ours, opt_state, value, grads = step(ours, opt_state)
+        losses.append(float(value))
+        first = grads if first is None else first
+    their_losses, their_first, theirs = reference.follow(
+        params, [(np.asarray(row)[None],) for row in ids], 3,
+        _reference_config(cfg, **OPTIMIZER))
+    # One replica a sequence: Horovod's mean of the replicas' means.
+    np.testing.assert_allclose(
+        losses, [np.mean(step) for step in their_losses], rtol=2e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    for (path, start), g, r, a, b in zip(
+            flat, *map(jax.tree.leaves, (first, their_first, ours, theirs))):
+        name = jax.tree_util.keystr(path)
+        # float32 through five layers of weights scaled up: a gradient
+        # agrees to a part in a thousand of its leaf.
+        scale = float(np.max(np.abs(r))) + 1e-12
+        assert float(jnp.max(jnp.abs(g - r))) <= 3e-3 * scale, name
+        if "expert_bias" in name:
+            assert not np.any(np.asarray(g)) and not np.any(r)
+            np.testing.assert_array_equal(a, start)
+            np.testing.assert_array_equal(b, start)
+            continue
+        moved = float(np.max(np.abs(np.asarray(b) - np.asarray(start))))
+        assert moved > 0, name
+        assert float(jnp.max(jnp.abs(a - b))) <= 0.05 * moved, name
+
+
+def test_the_convolution_sees_no_later_token_and_is_three_shifted_sums(
+        reference):
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 6))
+    taps = jax.random.normal(jax.random.PRNGKey(1), (3, 6))
+    got = causal_conv(x, taps)
+    for row, want in zip(got, x):
+        np.testing.assert_allclose(
+            row, reference._short_conv(lambda a: a, want, taps), atol=1e-6)
+    # Token 17 moves tokens 17, 18, 19 and nothing before or after them.
+    moved = np.any(np.asarray(
+        causal_conv(x.at[:, 17].add(1.0), taps) != got), axis=(0, 2))
+    assert np.flatnonzero(moved).tolist() == [17, 18, 19]
+    # The activation is the caller's: Olmo-Hybrid's is the SiLU of these
+    # sums, the mixer's none, with a gate on either side.
+    np.testing.assert_allclose(causal_conv_silu(x, taps),
+                               jax.nn.silu(got), atol=1e-6)
+    b_gate, c_gate = x[:, ::-1], x * 0.5
+    np.testing.assert_allclose(gated_short_conv(b_gate, c_gate, x, taps),
+                               c_gate * causal_conv(b_gate * x, taps))
+    grads = jax.grad(lambda x, w: jnp.sum(causal_conv(x, w) ** 2), (0, 1))(
+        x, taps)
+    want = jax.grad(lambda x, w: sum(jnp.sum(reference._short_conv(
+        lambda a: a, row, w) ** 2) for row in x), (0, 1))(x, taps)
+    for g, w in zip(grads, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+def test_the_bias_enters_the_choice_and_not_the_weights():
+    logits = 1.5 * jax.random.normal(jax.random.PRNGKey(2), (512, 16))
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(3), (16,))
+    scores = jax.nn.sigmoid(logits)
+    ids, weights = sigmoid_top_k(bias)(logits, 4)
+    plain_ids, plain_weights = sigmoid_top_k(0.0 * bias)(logits, 4)
+    # The chosen set is the 4 largest of s + b ...
+    np.testing.assert_array_equal(
+        np.sort(ids, -1), np.sort(jax.lax.top_k(scores + bias, 4)[1], -1))
+    changed = np.any(np.sort(ids, -1) != np.sort(plain_ids, -1), axis=-1)
+    assert 0.2 < changed.mean() < 0.95
+    # ... and the weights the chosen experts' own scores over their sum
+    # plus 1e-6: they add up to 1 / (1 + 1e-6 / sum).
+    own = np.take_along_axis(np.asarray(scores), np.asarray(ids), -1)
+    total = own.sum(-1, keepdims=True)
+    np.testing.assert_allclose(weights, own / (total + 1e-6), rtol=1e-6)
+    np.testing.assert_allclose(weights.sum(-1),
+                               1.0 / (1.0 + 1e-6 / total[:, 0]), rtol=1e-6)
+    # Where the bias changed nothing in the choice, it changed nothing
+    # (expert by expert: the order inside a set is the choice's).
+    def by_expert(ids, weights):
+        dense = np.zeros(logits.shape, np.float32)
+        np.put_along_axis(dense, np.asarray(ids), np.asarray(weights), -1)
+        return dense
+
+    np.testing.assert_allclose(
+        by_expert(ids, weights)[~changed],
+        by_expert(plain_ids, plain_weights)[~changed], rtol=1e-6)
+
+    def through(logits, bias):
+        return jnp.sum(sigmoid_top_k(bias)(logits, 4)[1] ** 2)
+
+    d_logits, d_bias = jax.grad(through, (0, 1))(logits, bias)
+    assert not np.any(np.asarray(d_bias)) and np.any(np.asarray(d_logits))
+    # The softmax rule is the one the held layer had inside it.
+    top_logits, top_ids = jax.lax.top_k(logits, 4)
+    old_ids, old_weights = softmax_top_k(logits, 4)
+    np.testing.assert_array_equal(old_ids, top_ids)
+    np.testing.assert_array_equal(old_weights,
+                                  jax.nn.softmax(top_logits, axis=-1))
+
+
+def test_the_tied_matrix_gradient_is_the_sum_of_its_two_paths(seeded):
+    """The step the benchmark runs (each block recomputed, the loss in
+    chunks with the embedding transposed as the head) against the plain
+    model: one function; and the embedding's gradient is the lookup's
+    plus the head's."""
+    ids, params = seeded
+    model = Lfm2LM(_config(remat=True))
+
+    def chunked(p, head):
+        hidden, _ = model.apply({"params": p}, ids, return_hidden=True)
+        return chunked_causal_lm_loss(hidden, head.T, ids, num_chunks=4)
+
+    def plain(p):
+        return causal_lm_loss(
+            Lfm2LM(_config()).apply({"params": p}, ids)[0], ids)
+
+    table = params["tok_embeddings"]["embedding"]
+    value, (by_lookup, by_head) = jax.jit(jax.value_and_grad(
+        chunked, (0, 1)))(params, table)
+    want_value, want = jax.jit(jax.value_and_grad(plain))(params)
+    np.testing.assert_allclose(value, want_value, rtol=1e-5)
+    lookup = by_lookup["tok_embeddings"]["embedding"]
+    tied = want["tok_embeddings"]["embedding"]
+    scale = float(jnp.max(jnp.abs(tied)))
+    for part in (lookup, by_head):
+        assert float(jnp.max(jnp.abs(part))) > 0.01 * scale
+    np.testing.assert_allclose(lookup + by_head, tied, rtol=0,
+                               atol=2e-5 * scale)
+    assert "lm_head" not in params
+
+
+def test_routed_parts_of_the_eight_shares_add_up_to_the_whole_layer(
+        seeded, reference):
+    """A sparse layer: a share's output is ``h + (its experts' part)``,
+    so the routed parts of the eight disjoint shares (one expert each),
+    with the mixer and the residual counted once, are the uncut
+    reference's layer."""
+    ids, params = seeded
+    cfg = _config()
+    p = params["layer_2"]
+    x = 3.0 * jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, cfg.dim))
+
+    def block(held, p):
+        out, load = jax.jit(lambda p, x: Lfm2Block(
+            _config(held), kind=CONV, sparse=True).apply({"params": p}, x))(
+            p, x)
+        return out[0], load
+
+    rcfg = _reference_config(cfg)
+    whole = reference._layer(lambda a: a, p, x[0], rcfg, CONV, True)
+    # The mixer and the residual: what every chip adds.
+    alike = reference._layer(
+        lambda a: a, _share({"layer_2": p}, ())["layer_2"], x[0],
+        {**rcfg, "deployment": {"experts_held": []}}, CONV, True)
+    parts, landed = 0.0, 0
+    for expert in range(cfg.num_experts):
+        out, load = block((expert,),
+                          _share({"layer_2": p}, (expert,))["layer_2"])
+        parts = parts + (out - alike)
+        landed += int(load.sum())
+    assert landed == SEQ * cfg.num_selected     # every assignment, once
+    scale = float(jnp.max(jnp.abs(whole)))
+    # The routed parts are far above the tolerance they are added up to.
+    assert float(jnp.max(jnp.abs(parts))) > 100 * 2e-5 * scale
+    np.testing.assert_allclose(alike + parts, whole, rtol=0,
+                               atol=2e-5 * scale)
+    # The same from the layer that holds all eight.
+    np.testing.assert_allclose(block(None, p)[0], whole, rtol=0,
+                               atol=2e-5 * scale)
