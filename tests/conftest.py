@@ -35,3 +35,13 @@ def _fresh_state():
 
     hvd.shutdown()
     reset_mesh()
+
+
+@pytest.fixture(scope="module")
+def reference_results():
+    """What the XLA reference gave for a kernel case of this file, keyed
+    by the case and not by the kernel path that asked
+    (``attention_helpers.assert_matches_reference``). It outlives
+    ``_fresh_state``'s shutdown: it holds arrays only, nothing of ``hvd``
+    or the mesh registry."""
+    return {}

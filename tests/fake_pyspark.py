@@ -79,7 +79,7 @@ class _RDD:
         errors = []
         for idx, payload_path, result_path, proc in procs:
             try:
-                out, _ = proc.communicate(timeout=240)
+                out, _ = proc.communicate(timeout=180)
                 if proc.returncode != 0:
                     errors.append(
                         f"partition {idx}: exit {proc.returncode}:\n{out}")

@@ -92,7 +92,7 @@ def seeded():
     cfg = _config()
     ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
                              cfg.vocab_size)
-    params = LagunaLM(cfg).init(jax.random.PRNGKey(3), ids)["params"]
+    params = jax.jit(LagunaLM(cfg).init)(jax.random.PRNGKey(3), ids)["params"]
     # Scales at which every path matters: a router that decides, a gate
     # that is not one half everywhere, experts and attention of the
     # residual's own size.

@@ -448,6 +448,11 @@ def _elastic_summary(steps):
     if hvd.rank() == 0:
         print("METRICS_SNAPSHOT " + _json.dumps(hvd.metrics.snapshot()),
               flush=True)
+    # Nobody leaves before rank 0 has printed. A member that exits has
+    # LEFT: rank 0's controller then re-forms, logs that into the middle
+    # of the snapshot's line and bumps the epoch gauge the parent reads
+    # (both seen with every core busy: CHANGES.md, PR 39).
+    hvd.allgather_object(None, name="el.printed")
 
 
 def _elastic_train(target_size, min_epoch=2, settle_steps=10,

@@ -67,7 +67,8 @@ def seeded():
     cfg = _config()
     ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
                              cfg.vocab_size)
-    params = SmallThinkerLM(cfg).init(jax.random.PRNGKey(3), ids)["params"]
+    params = jax.jit(SmallThinkerLM(cfg).init)(jax.random.PRNGKey(3),
+                                               ids)["params"]
     # Scales at which every path matters: a router that decides, experts
     # and attention of the residual's own size.
     params = jax.tree_util.tree_map_with_path(

@@ -6,10 +6,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from attention_helpers import (PATHS, _assert_grads_close, _rand, _sq_loss,
+from attention_helpers import (PATHS, _rand, assert_matches_reference,
                                both_paths)
-from horovod_tpu.ops.attention import (flash_attention, make_attention_fn,
-                                       reference_attention)
+from horovod_tpu.ops import attention
+from model_helpers import jit_apply, jit_init
+
+# One compiled program a call (test_flash_attention.py has the reason).
+flash_attention = jax.jit(attention.flash_attention, static_argnames=(
+    "causal", "block_q", "block_k"))
+reference_attention = jax.jit(attention.reference_attention,
+                              static_argnames=("causal",))
 
 
 class TestGroupedQueryAttention:
@@ -52,8 +58,8 @@ class TestGroupedQueryAttention:
         flash = lambda q, k, v: flash_attention(  # noqa: E731
             q, k, v, causal=True, block_q=16, block_k=16)
         ref = lambda q, k, v: reference_attention(q, k, v, causal=True)  # noqa: E731
-        g0 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-        g1 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        g0 = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+        g1 = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
         # dk/dv include the group sum over each K/V head's query heads.
         for a, b in zip(g0, g1):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -76,8 +82,8 @@ class TestGroupedQueryAttention:
         flash = lambda q, k, v: flash_attention(  # noqa: E731
             q, k, v, causal=True, block_q=8, block_k=8)
         ref = lambda q, k, v: reference_attention(q, k, v, causal=True)  # noqa: E731
-        g0 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-        g1 = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        g0 = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+        g1 = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g0, g1):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=2e-4)
@@ -99,11 +105,11 @@ class TestGroupedQueryAttention:
             return reference_attention(q, k, v, key_mask=mask, causal=True)
 
         repeat_model = LlamaLM(LLAMA_TINY, attention_fn=repeat_path_fn)
-        variables = repeat_model.init(jax.random.PRNGKey(0), ids)
+        variables = jit_init(repeat_model, ids)
         gqa_model = LlamaLM(LLAMA_TINY, attention_fn=make_attention_fn(
             causal=True, use_flash=True, block_q=16, block_k=16))
-        out_repeat = repeat_model.apply(variables, ids)
-        out_gqa = gqa_model.apply(variables, ids)
+        out_repeat = jit_apply(repeat_model)(variables, ids)
+        out_gqa = jit_apply(gqa_model)(variables, ids)
         np.testing.assert_allclose(np.asarray(out_repeat, np.float32),
                                    np.asarray(out_gqa, np.float32),
                                    atol=5e-2, rtol=5e-2)
@@ -111,7 +117,7 @@ class TestGroupedQueryAttention:
 
 @both_paths
 @pytest.mark.parametrize("causal,sq", [(False, 32), (True, 32), (True, 16)])
-def test_flash_paths_gqa(path, causal, sq):
+def test_flash_paths_gqa(path, causal, sq, reference_results):
     # Hkv < H: dk/dv come out at Hkv heads, each the sum over its query
     # group; with sq != sk also the decode-convention diagonal.
     b, sk, h, hkv, d = 2, 32, 4, 2, 8
@@ -123,8 +129,9 @@ def test_flash_paths_gqa(path, causal, sq):
         q, k, v, key_mask=mask, causal=causal, **PATHS[path])
     ref = lambda q, k, v: reference_attention(  # noqa: E731
         q, k, v, key_mask=mask, causal=causal)
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(ref(q, k, v)), atol=1e-5)
-    dq, dk, dv = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    case = ("paths_gqa", causal, sq)
+    out, (dq, dk, dv) = assert_matches_reference(
+        flash, ref, q, k, v, shared=(reference_results, case))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(reference_results[case][0]), atol=1e-5)
     assert dq.shape == q.shape and dk.shape == k.shape == dv.shape
-    _assert_grads_close(flash, ref, q, k, v, 2e-3)

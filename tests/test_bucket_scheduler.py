@@ -8,7 +8,6 @@ vs unbucketed allreduce results are the same bytes)."""
 import hashlib
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -30,8 +29,6 @@ from horovod_tpu.utils.scaling_model import (
     predicted_bucket_events,
 )
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 
 
 # ------------------------------------------------------------- partitioner
@@ -368,7 +365,7 @@ def test_tuned_bucket_rides_synced_cycle_reply_to_every_rank():
 
 # ------------------------------------------- mp acceptance (bit identity)
 
-from mp_harness import free_port as _free_port  # noqa: E402
+from mp_harness import run_script_ranks  # noqa: E402
 
 
 def test_bucketed_vs_unbucketed_bit_identical():
@@ -380,34 +377,8 @@ def test_bucketed_vs_unbucketed_bit_identical():
 
     if bindings.load() is None:
         pytest.skip("native core unavailable (no toolchain)")
-    addrs = ",".join(f"127.0.0.1:{_free_port()}" for _ in range(2))
-    procs = []
-    for rank in range(2):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["JAX_PLATFORMS"] = "cpu"
-        env["HOROVOD_CYCLE_TIME"] = "1"
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.abspath(__file__), "bucket_bitident",
-             str(rank), "2", addrs],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True))
-    results = []
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(timeout=120)
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            raise AssertionError(f"rank {rank} hung")
-        assert proc.returncode == 0, (
-            f"rank {rank} failed (exit {proc.returncode}):\n{out}")
-        payload = None
-        for line in out.splitlines():
-            if line.startswith("RESULT "):
-                payload = json.loads(line[len("RESULT "):])
-        assert payload is not None, f"no RESULT in:\n{out}"
-        results.append(payload)
+    results = run_script_ranks(os.path.abspath(__file__), "bucket_bitident",
+                               2, timeout=120)
     for res in results:
         assert res["bucketed"] == res["unbucketed"], (
             "bucketed and unbucketed allreduce results differ bitwise")

@@ -2,14 +2,23 @@
 reference softmax — the kernel that frees the KV cache from the XLA
 layout/update trade-off (artifacts/decode_ceiling_r5.json)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.ops.decode_attention import decode_attention
+from horovod_tpu.ops.decode_attention import \
+    decode_attention as _decode_attention
 
+# One compiled program a shape, for the kernel and for the reference: the
+# cache index is traced, as it is in generate()'s decode scan, so the
+# cases of one shape share theirs.
+decode_attention = jax.jit(_decode_attention, static_argnums=4,
+                           static_argnames="block_l")
 
+@functools.partial(jax.jit, static_argnums=4)
 def _reference(q, k_cache, v_cache, cache_index, hkv):
     b, s, h, d = q.shape
     L = k_cache.shape[1]
@@ -173,9 +182,9 @@ def test_sharded_decode_step_matches_reference(data_par, model_par,
     kc = jnp.asarray(rng.randn(b, L, hkv * d).astype(np.float32)) * 0.4
     vc = jnp.asarray(rng.randn(b, L, hkv * d).astype(np.float32)) * 0.4
     mesh = _tp_mesh(data_par, model_par)
-    out, k2, v2 = sharded_decode_step(q, kn, vn, kc, vc, idx, hkv,
-                                      mesh=mesh, head_axis="model",
-                                      batch_axis=batch_axis)
+    out, k2, v2 = jax.jit(functools.partial(
+        sharded_decode_step, num_kv_heads=hkv, mesh=mesh, head_axis="model",
+        batch_axis=batch_axis))(q, kn, vn, kc, vc, idx)
     k_ref = kc.at[:, idx].set(kn.reshape(b, hkv * d))
     v_ref = vc.at[:, idx].set(vn.reshape(b, hkv * d))
     np.testing.assert_allclose(np.asarray(k2), np.asarray(k_ref),
@@ -233,12 +242,15 @@ def test_sharded_decode_step_validation():
 # --- classifier: replicated / heads-sharded / exotic dispatch -------------
 
 
-def _tiny_tp_setup(mesh=None, axis="model"):
+@pytest.fixture(scope="module")
+def tiny_tp():
+    """``(cfg, model, variables, prompt)`` of the tiny float32 Llama the
+    classifier and TP tests share, initialised once (one compiled init).
+    It outlives ``_fresh_state``: a config, a flax module and arrays on
+    device 0, nothing of ``hvd`` or the mesh registry; no test writes to
+    the tree it is handed."""
     import dataclasses
 
-    from jax.sharding import NamedSharding
-
-    from horovod_tpu.models import llama_tp_param_specs
     from horovod_tpu.models.llama import LLAMA_TINY, LlamaLM
 
     cfg = dataclasses.replace(LLAMA_TINY, dtype=jnp.float32)
@@ -246,31 +258,37 @@ def _tiny_tp_setup(mesh=None, axis="model"):
     prompt = jnp.asarray(
         np.random.RandomState(3).randint(0, cfg.vocab_size, (4, 5)),
         jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt)
-    if mesh is None:
-        return cfg, model, variables, prompt
+    return cfg, model, jax.jit(model.init)(jax.random.PRNGKey(0),
+                                           prompt), prompt
+
+
+def _tp_sharded(variables, mesh, axis="model"):
+    from jax.sharding import NamedSharding
+
+    from horovod_tpu.models import llama_tp_param_specs
+
     specs = llama_tp_param_specs(variables["params"], axis=axis)
-    sharded = {"params": jax.tree.map(
+    return {"params": jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         variables["params"], specs)}
-    return cfg, model, sharded, prompt
 
 
-def test_classifier_replicated():
+def test_classifier_replicated(tiny_tp):
     from horovod_tpu.models import classify_decode_sharding
 
-    cfg, _, variables, prompt = _tiny_tp_setup()
+    cfg, _, variables, prompt = tiny_tp
     info = classify_decode_sharding(variables, prompt, cfg.num_kv_heads)
     assert info.path == "kernel"
 
 
-def test_classifier_heads_sharded_tp():
+def test_classifier_heads_sharded_tp(tiny_tp):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.models import classify_decode_sharding
 
     mesh = _tp_mesh(2, 2)
-    cfg, _, sharded, prompt = _tiny_tp_setup(mesh)
+    cfg, _, variables, prompt = tiny_tp
+    sharded = _tp_sharded(variables, mesh)
     info = classify_decode_sharding(sharded, prompt, cfg.num_kv_heads)
     assert info.path == "kernel_tp"
     assert info.head_axis == "model" and info.batch_axis is None
@@ -281,26 +299,26 @@ def test_classifier_heads_sharded_tp():
     assert info.path == "kernel_tp" and info.batch_axis == "data"
 
 
-def test_classifier_exotic_falls_back_to_einsum():
+def test_classifier_exotic_falls_back_to_einsum(tiny_tp):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.models import classify_decode_sharding
 
     mesh = _tp_mesh(2, 2)
-    cfg, _, sharded, prompt = _tiny_tp_setup(mesh)
+    cfg, _, variables, prompt = tiny_tp
+    sharded = _tp_sharded(variables, mesh)
 
     # Uneven head split: tp=4 mesh axis on the H=4 wq heads while Hkv=2
     # can't split 4 ways (wk/wv stay replicated on the same mesh).
     mesh4 = _tp_mesh(1, 4)
-    cfg4, _, vars4, _ = _tiny_tp_setup()
     repl4 = jax.tree_util.tree_map(
-        lambda x: jax.device_put(x, NamedSharding(mesh4, P())), vars4)
+        lambda x: jax.device_put(x, NamedSharding(mesh4, P())), variables)
     wq4 = repl4["params"]["layer_0"]["attention"]["wq"]["kernel"]
     repl4["params"]["layer_0"]["attention"]["wq"]["kernel"] = \
         jax.device_put(
             jax.device_get(wq4),
             NamedSharding(mesh4, P(None, "model", None)))
-    info = classify_decode_sharding(repl4, prompt, cfg4.num_kv_heads)
+    info = classify_decode_sharding(repl4, prompt, cfg.num_kv_heads)
     assert info.path == "einsum" and "uneven" in info.reason
 
     # Sequence-sharded prompt (the cache would shard on seq): exotic.
@@ -318,7 +336,7 @@ def test_classifier_exotic_falls_back_to_einsum():
     assert info.path == "einsum"
 
 
-def test_generate_tp_rides_shard_mapped_kernel():
+def test_generate_tp_rides_shard_mapped_kernel(tiny_tp):
     # The CPU-mesh parity pin for the tentpole: generate() under Megatron
     # TP specs must (a) emit the SAME greedy tokens as the replicated
     # single-device run and (b) actually trace the shard_mapped Pallas
@@ -332,11 +350,11 @@ def test_generate_tp_rides_shard_mapped_kernel():
     from horovod_tpu.utils.comm_accounting import decode_path_markers
 
     mesh = _tp_mesh(2, 2)
-    cfg, model, variables, prompt = _tiny_tp_setup()
+    cfg, model, variables, prompt = tiny_tp
     base = generate(model, variables, prompt, max_new_tokens=5)
     assert llama_mod.LAST_DECODE_PATH.path == "kernel"
 
-    _, _, sharded, _ = _tiny_tp_setup(mesh)
+    sharded = _tp_sharded(variables, mesh)
     prompt_sh = jax.device_put(prompt, NamedSharding(mesh, P("data")))
     with mesh:
         tp = generate(model, sharded, prompt_sh, max_new_tokens=5)

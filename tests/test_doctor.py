@@ -8,7 +8,6 @@ naming rank 1 via BOTH the live rank-0 endpoint and the offline
 
 import json
 import os
-import subprocess
 import sys
 import urllib.error
 import urllib.request
@@ -17,6 +16,7 @@ import numpy as np  # noqa: F401  (parity with the other mp test modules)
 import pytest
 
 from mp_harness import free_port as _free_port
+from mp_harness import run_cmd
 from mp_harness import run_ranks as _run_ranks
 
 from horovod_tpu import doctor, metrics
@@ -24,8 +24,6 @@ from horovod_tpu.doctor import Evidence, diagnose
 from horovod_tpu.doctor import rules as doctor_rules
 from horovod_tpu.metrics import MetricsRegistry
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 
 
 @pytest.fixture(autouse=True)
@@ -494,11 +492,9 @@ def test_evidence_from_artifacts_empty_dir(tmp_path):
 
 
 def _run_cli(args, timeout=120):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    return subprocess.run(
+    return run_cmd(
         [sys.executable, "-m", "horovod_tpu.tools.doctor"] + args,
-        env=env, capture_output=True, text=True, timeout=timeout)
+        timeout=timeout)
 
 
 def test_tools_doctor_cli_json_text_and_exit_codes(tmp_path):
@@ -561,7 +557,7 @@ def test_delay_chaos_doctor_names_rank1_live_and_offline(tmp_path):
     shutdown left behind (straggler-report evidence)."""
     trace_dir = tmp_path / "trace"
     port = _free_port()
-    outs = _run_ranks("doctor", size=3, timeout=240.0, extra_env={
+    outs = _run_ranks("doctor", size=3, extra_env={
         "HOROVOD_TRACE_DIR": str(trace_dir),
         "HOROVOD_METRICS_PORT": str(port),
         "HOROVOD_METRICS_PUSH_CYCLES": "5",

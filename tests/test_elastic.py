@@ -17,10 +17,13 @@ import pytest
 
 from mp_harness import (
     assert_protocheck_clean,
+    child_env,
     counter_by_label,
+    finish,
     free_port,
     launch_rank,
     protocheck_env,
+    run_cmd,
     run_ranks,
 )
 
@@ -37,8 +40,6 @@ from horovod_tpu.fault import FaultPlan, FaultRule
 from horovod_tpu.metrics import MetricsRegistry
 from horovod_tpu.utils.checkpoint import _write_atomically, latest_checkpoint
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 
 SECRET = b"x" * 32
 
@@ -437,11 +438,8 @@ else:
     raise AssertionError("empty State() must be rejected")
 print("STATE_OK")
 """
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    res = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=120)
+    env = child_env()
+    res = run_cmd([sys.executable, "-c", code], timeout=120, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "STATE_OK" in res.stdout
 
@@ -525,21 +523,9 @@ def test_elastic_join_admits_third_rank():
         procs.append(launch_rank(
             "elastic_join", 2, 3, addr,
             extra_env={**base, "HOROVOD_ELASTIC_JOIN": "1"}))
-        deadline = time.monotonic() + 120.0
-        outputs = []
-        for rank, proc in enumerate(procs):
-            try:
-                out, _ = proc.communicate(
-                    timeout=max(1.0, deadline - time.monotonic()))
-            except subprocess.TimeoutExpired:
-                for p in procs:
-                    p.kill()
-                raise AssertionError(f"elastic_join: rank {rank} hung")
-            outputs.append(out)
-        for rank, proc in enumerate(procs):
-            assert proc.returncode == 0, (
-                f"elastic_join: rank {rank} failed:\n{outputs[rank]}")
-            assert "ELASTIC size=3" in outputs[rank], outputs[rank]
+        outputs = finish(procs, 120.0, "elastic_join")
+        for out in outputs:
+            assert "ELASTIC size=3" in out, out
         assert assert_protocheck_clean(pc_dir, "elastic_join") == 3
     snap = _rank0_snapshot(outputs)
     transitions = _counter_by_label(snap,
@@ -564,20 +550,8 @@ def test_elastic_parked_joiner_at_max_ranks_does_not_livelock():
     joiner = launch_rank("elastic_parked", 2, 3, addr,
                          extra_env={**base, "HOROVOD_ELASTIC_JOIN": "1"})
     try:
-        outputs = []
-        for rank, proc in enumerate(procs):
-            try:
-                out, _ = proc.communicate(timeout=120)
-            except subprocess.TimeoutExpired:
-                for p in procs:
-                    p.kill()
-                raise AssertionError(f"elastic_parked: rank {rank} hung")
-            outputs.append(out)
-        for rank, proc in enumerate(procs):
-            assert proc.returncode == 0, (
-                f"elastic_parked: rank {rank} failed:\n{outputs[rank]}")
-            assert "PARKED_OK size=2 epoch=1" in outputs[rank], \
-                outputs[rank]
+        for out in finish(procs, 120.0, "elastic_parked"):
+            assert "PARKED_OK size=2 epoch=1" in out, out
         # The members' wires (and the coordinator's parked-joiner wire,
         # heartbeats only) stayed on-spec the whole time.
         assert_protocheck_clean(pc_dir, "elastic_parked", require=2)
@@ -671,14 +645,12 @@ def test_elastic_launcher_respawns_dead_worker(tmp_path):
         "print(f'rank {hvd.rank()} done size={hvd.size()} '\n"
         "      f'epoch={hvd.elastic.epoch()}', flush=True)\n"
         "hvd.shutdown()\n")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     env["HOROVOD_CYCLE_TIME"] = "1"
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2", "--elastic",
          sys.executable, str(script)],
-        env=env, capture_output=True, text=True, timeout=180)
+        timeout=180, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "respawning its slot as an elastic joiner" in res.stderr, \
         res.stderr

@@ -1,30 +1,24 @@
 """Example smoke runs — the reference CI does the same for its examples
-(.buildkite/gen-pipeline.sh:101-133)."""
+(.buildkite/gen-pipeline.sh:101-133). This file: the JAX examples on the
+data axis. Its neighbours: ``test_examples_encoders.py`` (BERT and ViT),
+``test_examples_parallel.py`` (the other axes),
+``test_examples_llama.py``, ``test_examples_decode.py`` and
+``test_examples_fsdp.py`` (the decoder's scripts: training, decoding,
+sharded), ``test_examples_{torch,tensorflow,mxnet}.py`` (the other
+frameworks). ``--dist loadfile`` starts the files with the most
+tests first and the suite's last long file has four: files of two to four
+launches fill the other workers while it runs, a file of twenty would be a
+sixth of the wall on its own (``ROADMAP.md`` D9)."""
 
 import os
-import subprocess
 import sys
 
 import pytest
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
+from mp_harness import REPO
+from mp_harness import run_example as _run
+
 EX = os.path.join(REPO, "examples")
-
-
-def _run(cmd, timeout=300, extra_env=None, expect_failure=False):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    env["HOROVOD_CYCLE_TIME"] = "1"
-    env.update(extra_env or {})
-    res = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=timeout, cwd=REPO)
-    if expect_failure:
-        assert res.returncode != 0, res.stdout + res.stderr
-        return res.stderr
-    assert res.returncode == 0, res.stdout + res.stderr
-    return res.stdout
 
 
 def test_jax_mnist_smoke():
@@ -33,60 +27,11 @@ def test_jax_mnist_smoke():
     assert "epoch 0" in out
 
 
-def test_torch_mnist_two_ranks():
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "torch_mnist.py"),
-                "--epochs", "1", "--batch-size", "128"])
-    assert "epoch 0" in out
-
-
-def test_ring_attention_example_smoke():
-    out = _run([sys.executable,
-                os.path.join(EX, "jax_long_context_ring_attention.py"),
-                "--seq-len", "64", "--heads", "2", "--head-dim", "8"])
-    assert "ring attention" in out
-
-
-def test_bert_example_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_bert_pretraining.py"),
-                "--model", "tiny", "--seq-len", "32", "--batch-size", "1",
-                "--num-iters", "2"])
-    assert "sequences/sec" in out
-
-
 def test_word2vec_example_smoke():
     out = _run([sys.executable, os.path.join(EX, "jax_word2vec.py"),
                 "--steps", "50", "--batch-size", "256",
                 "--vocab-size", "2000", "--embedding-dim", "32"])
     assert "pairs/sec" in out
-
-
-@pytest.mark.slow  # ~14 s; test_word2vec_example_smoke keeps the
-def test_tensorflow_word2vec_two_ranks():  # word2vec path in tier-1
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "tensorflow_word2vec.py"),
-                "--steps", "10", "--batch-size", "64",
-                "--vocab-size", "500", "--embedding-dim", "16"])
-    # The embedding gradient must ride the sparse IndexedSlices path while
-    # the dense projection gradient rides the dense allreduce path.
-    assert "embedding grad: IndexedSlices" in out
-    assert "proj grad: EagerTensor" in out
-
-
-@pytest.mark.slow  # ~11 s; spark coverage stays in test_spark{,_e2e}.py
-def test_keras_spark_rossmann_fallback_path():
-    # pyspark is absent in this image; the example's in-process path still
-    # runs the full feature-engineering + entity-embedding pipeline.
-    out = _run([sys.executable, os.path.join(EX, "keras_spark_rossmann.py"),
-                "--epochs", "1", "--rows", "1024"])
-    assert "final exp_rmspe=" in out
-
-
-def test_mxnet_example_two_ranks():
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "mxnet_mnist.py"),
-                "--epochs", "1"])
-    assert "epoch 0" in out
 
 
 @pytest.mark.slow  # ~40 s: two full example launches (train + resume)
@@ -105,166 +50,6 @@ def test_imagenet_resnet50_checkpoint_resume(tmp_path):
     assert "resumed" in out and "ckpt_2" in out
 
 
-def test_llama_generation_example_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_llama_generation.py"),
-                "--model", "tiny", "--prompt-len", "8",
-                "--max-new-tokens", "8", "--batch-size", "2"])
-    assert "decode tokens/sec" in out
-
-
-def test_vit_example_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_vit_training.py"),
-                "--model", "tiny", "--batch-per-chip", "2", "--steps", "4",
-                "--warmup-steps", "1"],
-               extra_env={
-                   "XLA_FLAGS": "--xla_force_host_platform_device_count=2"})
-    assert "vit-tiny" in out and "img/sec" in out
-
-
-def test_moe_example_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_moe_training.py"),
-                "--steps", "15", "--tokens-per-device", "128",
-                "--d-model", "16", "--d-hidden", "32"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=4"})
-    assert "tokens/sec through" in out
-
-
-def test_pipeline_example_smoke():
-    out = _run([sys.executable,
-                os.path.join(EX, "jax_pipeline_parallel.py"),
-                "--steps", "10", "--microbatches", "8",
-                "--microbatch-size", "4", "--features", "32"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=4"})
-    assert "samples/sec through" in out
-
-
-def test_pipeline_example_1f1b_smoke():
-    out = _run([sys.executable,
-                os.path.join(EX, "jax_pipeline_parallel.py"),
-                "--steps", "10", "--microbatches", "8",
-                "--microbatch-size", "4", "--features", "32",
-                "--schedule", "1f1b"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=4"})
-    assert "samples/sec through" in out
-
-
-def test_tp_decode_profile_smoke():
-    # The round-6 serving path proof: the harness must classify the TP
-    # mesh as kernel_tp, find ONLY kernel_tp markers in the lowered
-    # step, and match the single-device greedy tokens exactly (f32).
-    out = _run([sys.executable, os.path.join(EX, "tp_decode_profile.py"),
-                "--model", "tiny", "--tp", "2", "--batch-size", "4",
-                "--prompt-len", "8", "--max-new-tokens", "8",
-                "--force-host-devices", "4", "--f32"], timeout=420)
-    assert '"path": "kernel_tp"' in out
-    assert '"token_parity_mismatches": 0' in out
-
-
-@pytest.mark.slow  # ~15 s; tensorflow_mnist_eager_two_ranks keeps the tf
-def test_tensorflow_mnist_two_ranks():  # 2-rank mnist path in tier-1
-    # The tf.function path: allreduce rides a py_function node inside the
-    # traced step.
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "tensorflow_mnist.py"),
-                "--epochs", "1", "--batch-size", "256"])
-    assert "epoch 0" in out
-
-
-def test_tensorflow_mnist_eager_two_ranks():
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "tensorflow_mnist_eager.py"),
-                "--steps", "5", "--batch-size", "32"])
-    assert "step 0" in out
-
-
-def test_tensorflow_keras_mnist_two_ranks(tmp_path):
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "tensorflow_keras_mnist.py"),
-                "--epochs", "1", "--batch-size", "256",
-                "--model-dir", str(tmp_path)])
-    assert "final: acc=" in out
-
-
-@pytest.mark.slow  # ~14 s; tensorflow_keras_mnist_two_ranks keeps the
-def test_keras_mnist_advanced_two_ranks():  # keras 2-rank path in tier-1
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, os.path.join(EX, "keras_mnist_advanced.py"),
-                "--epochs", "2", "--batch-size", "256",
-                "--warmup-epochs", "1"])
-    assert "final: acc=" in out
-
-
-@pytest.mark.slow  # ~24 s (two launches); torch_mnist_two_ranks keeps
-def test_torch_imagenet_resnet50_two_ranks_resume(tmp_path):  # torch 2-rank
-    fmt = str(tmp_path / "checkpoint-{epoch}.pth.tar")
-    script = os.path.join(EX, "torch_imagenet_resnet50.py")
-    args = ["--steps-per-epoch", "2", "--batch-size", "2", "--image-size",
-            "32", "--num-classes", "10", "--checkpoint-format", fmt]
-    _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-          sys.executable, script, "--epochs", "1"] + args)
-    assert os.path.exists(fmt.format(epoch=1))
-    # Second run resumes past epoch 0 from the rank-0 checkpoint.
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable, script, "--epochs", "2"] + args)
-    assert "epoch 1" in out and "epoch 0:" not in out
-
-
-@pytest.mark.slow  # ~65 s: 2-rank keras ResNet-50 train + resume
-def test_keras_imagenet_resnet50_two_ranks(tmp_path):
-    fmt = str(tmp_path / "ck-{epoch}.keras")
-    base = [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-            sys.executable,
-            os.path.join(EX, "keras_imagenet_resnet50.py"),
-            "--steps-per-epoch", "2", "--batch-size", "2",
-            "--image-size", "32", "--num-classes", "10",
-            "--checkpoint-format", fmt]
-    out = _run(base + ["--epochs", "1"])
-    assert "final:" in out
-    # Rank 0 wrote a FULL .keras checkpoint (optimizer state included).
-    assert os.path.exists(fmt.format(epoch=1))
-    # Second run resumes: rank 0 restores epoch 1 through hvd.load_model
-    # (optimizer re-wrapped in DistributedOptimizer, reference
-    # examples/keras_imagenet_resnet50.py:100-104) and only epoch 2 trains.
-    out = _run(base + ["--epochs", "2"])
-    assert "Epoch 2/2" in out
-    assert "Epoch 1/2" not in out
-    assert "final:" in out
-
-
-def test_mxnet_imagenet_resnet50_two_ranks():
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable,
-                os.path.join(EX, "mxnet_imagenet_resnet50.py"),
-                "--epochs", "1", "--steps-per-epoch", "2",
-                "--batch-size", "4", "--image-size", "16",
-                "--num-classes", "10"])
-    assert "epoch 0" in out
-
-
-@pytest.mark.slow  # ~22 s model build; torch_synthetic_benchmark keeps
-def test_tensorflow_synthetic_benchmark_two_ranks():  # the bench path
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable,
-                os.path.join(EX, "tensorflow_synthetic_benchmark.py"),
-                "--model", "MobileNetV2", "--batch-size", "4",
-                "--image-size", "32", "--num-classes", "10",
-                "--num-warmup-batches", "1", "--num-batches-per-iter", "2",
-                "--num-iters", "2"])
-    assert "Total img/sec on 2 worker(s):" in out
-
-
-def test_torch_synthetic_benchmark_two_ranks():
-    out = _run([sys.executable, "-m", "horovod_tpu.run", "-np", "2",
-                sys.executable,
-                os.path.join(EX, "torch_synthetic_benchmark.py"),
-                "--num-iters", "2", "--num-warmup", "1",
-                "--batch-size", "8", "--image-size", "32"])
-    assert "total img/sec on 2 ranks" in out
-
-
 def test_flash_benchmark_smoke():
     out = _run([sys.executable,
                 os.path.join(EX, "flash_attention_benchmark.py"),
@@ -274,70 +59,10 @@ def test_flash_benchmark_smoke():
     assert '"metric": "flash_fwd_ms"' in out
 
 
-def test_llama_fsdp_smoke():
-    out = _run([sys.executable, os.path.join(EX,
-                                             "jax_llama_fsdp_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--num-iters", "2"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=8"})
-    assert "tokens/sec" in out
-    assert "param shard fraction=1/8" in out
-
-
-def test_llama_fsdp_tp_hybrid_smoke():
-    out = _run([sys.executable, os.path.join(EX,
-                                             "jax_llama_fsdp_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--num-iters", "2",
-                "--tensor-parallel", "2"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=8"})
-    assert "dp=4 tp=2" in out
-
-
-def test_llama_seq_parallel_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_llama_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--batch-size", "1",
-                "--num-iters", "2", "--seq-parallel", "4"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=4"})
-    assert "tokens/sec" in out
-
-
-def test_llama_remat_chunked_loss_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_llama_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--batch-size", "1",
-                "--num-iters", "2", "--remat", "--chunked-loss", "4"])
-    assert "tokens/sec" in out
-
-
-def test_llama_chunked_loss_rejects_seq_parallel():
-    err = _run([sys.executable, os.path.join(EX, "jax_llama_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--seq-parallel", "4",
-                "--chunked-loss", "4"],
-               extra_env={"XLA_FLAGS":
-                          "--xla_force_host_platform_device_count=4"},
-               expect_failure=True)
-    assert "chunked-loss" in err
-
-
 @pytest.mark.slow  # ~30 s/family: large-model compiles on CPU
 @pytest.mark.parametrize("model,size", [("vgg16", "64"), ("inception3", "96")])
 def test_jax_synthetic_benchmark_model_families(model, size):
     out = _run([sys.executable, os.path.join(EX, "jax_synthetic_benchmark.py"),
                 "--model", model, "--batch-size", "2", "--num-iters", "2",
-                "--num-batches", "1", "--image-size", size], timeout=560)
+                "--num-batches", "1", "--image-size", size])
     assert "Img/sec per chip" in out
-
-
-def test_jax_moe_lm_training_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_moe_lm_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--batch-size", "1",
-                "--num-iters", "2"])
-    assert "tokens/sec" in out
-
-
-def test_llama_adafactor_smoke():
-    out = _run([sys.executable, os.path.join(EX, "jax_llama_training.py"),
-                "--model", "tiny", "--seq-len", "64", "--batch-size", "1",
-                "--num-iters", "2", "--optimizer", "adafactor"])
-    assert "tokens/sec" in out

@@ -12,7 +12,6 @@ import json
 import logging as pylogging
 import os
 import socket
-import subprocess
 import sys
 import threading
 import time
@@ -22,10 +21,10 @@ import pytest
 
 from horovod_tpu import fault
 from horovod_tpu.fault.plan import FaultInjected, FaultPlan, InitWedged
+from mp_harness import child_env, run_cmd, run_ranks
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
-WORKER = os.path.join(HERE, "mp_worker.py")
 
 
 @pytest.fixture(autouse=True)
@@ -388,14 +387,11 @@ def test_run_with_deadline():
 
 
 def _init_subprocess(extra_env, code=None):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     env.update(extra_env)
     code = code or ("import horovod_tpu as hvd; hvd.init(); "
                     "print('init-ok', hvd.size())")
-    return subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=180)
+    return run_cmd([sys.executable, "-c", code], timeout=180, env=env)
 
 
 def test_wedged_init_recovers_within_retry_budget():
@@ -429,73 +425,20 @@ def test_wedged_init_exhausted_budget_fails_loudly():
 
 def _run_chaos(scenario, plan, size=2, timeout=90.0, extra_env=None,
                expect_killed=()):
-    """Spawn ranks like tests/test_multiprocess.run_ranks, with a shared
-    seeded fault plan; returns (outputs, returncodes). Every chaos run
+    """``mp_harness.run_ranks`` with a shared seeded fault plan; returns
+    the ranks' outputs. Every chaos run
     also runs under the wire-protocol conformance monitor
     (HOROVOD_PROTOCHECK=1) and asserts zero recorded violations — the
     kill/drop chaos suite doubles as a conformance suite."""
-    import shutil
-    import tempfile
-
-    from mp_harness import assert_protocheck_clean, protocheck_env
-
-    def free_port():
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-        s.close()
-        return port
-
-    addr = f"127.0.0.1:{free_port()}"
-    pc_dir = tempfile.mkdtemp(prefix="hvd-protocheck-")
-    procs = []
-    try:
-        for rank in range(size):
-            env = dict(os.environ)
-            env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-            env["JAX_PLATFORMS"] = "cpu"
-            env.update({
-                "HOROVOD_RANK": str(rank),
-                "HOROVOD_SIZE": str(size),
-                "HOROVOD_LOCAL_RANK": str(rank),
-                "HOROVOD_LOCAL_SIZE": str(size),
-                "HOROVOD_CONTROLLER_ADDR": addr,
-                "HOROVOD_ENGINE": "python",  # fault hooks live in the python
-                "HOROVOD_CYCLE_TIME": "1",   # controller's star control plane
-                "HOROVOD_FAULT_PLAN": json.dumps(plan),
-                "HOROVOD_STALL_CHECK_TIME_SECONDS": "5",
-            })
-            env.update(protocheck_env(pc_dir))
-            env.update(extra_env or {})
-            procs.append(subprocess.Popen(
-                [sys.executable, WORKER, scenario], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-        deadline = time.monotonic() + timeout
-        outputs = []
-        for rank, proc in enumerate(procs):
-            try:
-                out, _ = proc.communicate(
-                    timeout=max(1.0, deadline - time.monotonic()))
-            except subprocess.TimeoutExpired:
-                for p in procs:
-                    p.kill()
-                raise AssertionError(
-                    f"chaos {scenario}: rank {rank} hung past the timeout")
-            outputs.append(out)
-        for rank in expect_killed:
-            assert procs[rank].returncode == -9, (
-                f"rank {rank} expected SIGKILL, got {procs[rank].returncode}"
-                f":\n{outputs[rank]}")
-        for rank, proc in enumerate(procs):
-            if rank not in expect_killed:
-                assert proc.returncode == 0, (
-                    f"chaos {scenario}: rank {rank} failed "
-                    f"(exit {proc.returncode}):\n{outputs[rank]}")
-        assert_protocheck_clean(pc_dir, context=f"chaos {scenario}",
-                                require=1)
-        return outputs
-    finally:
-        shutil.rmtree(pc_dir, ignore_errors=True)
+    env = {
+        # The fault hooks live in the python controller's star control
+        # plane (run_ranks' engine).
+        "HOROVOD_FAULT_PLAN": json.dumps(plan),
+        "HOROVOD_STALL_CHECK_TIME_SECONDS": "5",
+    }
+    env.update(extra_env or {})
+    return run_ranks(scenario, size, timeout, extra_env=env,
+                     allowed_exit={rank: (-9,) for rank in expect_killed})
 
 
 def test_worker_death_mid_allreduce_aborts_survivors_descriptively():
@@ -580,9 +523,7 @@ def test_wedged_init_then_supervised_restart_end_to_end(tmp_path):
         "hvd.shutdown()\n")
     path = tmp_path / "train.py"
     path.write_text(script)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     # The wedge applies only while HOROVOD_RESTART_EPOCH=0 via a wrapper
     # that injects the plan conditionally.
     wrapper = tmp_path / "wrapped.py"
@@ -595,11 +536,11 @@ def test_wedged_init_then_supervised_restart_end_to_end(tmp_path):
         f"runpy.run_path({str(path)!r}, run_name='__main__')\n")
     env["HOROVOD_TPU_INIT_RETRIES"] = "2"
     env["HOROVOD_TPU_INIT_BACKOFF"] = "0.05"
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "1",
          "--max-restarts", "2", "--restart-backoff", "0.1",
          sys.executable, str(wrapper)],
-        env=env, capture_output=True, text=True, timeout=300, cwd=REPO)
+        timeout=180, env=env, cwd=REPO)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "epoch 1 up" in res.stdout
     assert "restarting (attempt 1/2)" in res.stderr

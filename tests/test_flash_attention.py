@@ -7,9 +7,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from attention_helpers import B, S, H, D, _qkv
-from horovod_tpu.ops.attention import (flash_attention, make_attention_fn,
-                                       reference_attention)
+from attention_helpers import B, S, H, D, _qkv, out_and_grads
+from horovod_tpu.ops import attention
+from horovod_tpu.ops.attention import make_attention_fn
+from model_helpers import jit_apply, jit_init
+
+# One compiled program a call: eagerly, the interpreter dispatches every
+# operation of a kernel as a program of its own (attention_helpers.
+# out_and_grads has the same reason).
+flash_attention = jax.jit(attention.flash_attention, static_argnames=(
+    "causal", "block_q", "block_k", "window"))
+reference_attention = jax.jit(attention.reference_attention,
+                              static_argnames=("causal", "window"))
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -38,15 +47,10 @@ def test_flash_causal_sq_ne_sk(sq, sk, grad):
                                    atol=2e-5, rtol=1e-4)
         return
 
-    def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=True,
-                                block_q=16, block_k=16) ** 2).sum()
-
-    def loss_ref(q, k, v):
-        return (reference_attention(q, k, v, causal=True) ** 2).sum()
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    _, gf = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16), q, k, v)
+    _, gr = out_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=True), q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)
@@ -90,8 +94,8 @@ def test_flash_causal_sq_gt_sk_grads():
         valid = (jnp.arange(sq) >= sq - sk)[None, :, None, None]
         return jnp.where(valid, out, 0.0).sum()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     np.testing.assert_array_equal(np.asarray(gf[0])[:, :sq - sk], 0.0)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -109,21 +113,14 @@ def test_flash_bf16_matches_reference(causal):
     mk = lambda: jnp.asarray(  # noqa: E731
         rng.randn(B, S, H, D).astype(np.float32) * 0.3, jnp.bfloat16)
     q, k, v = mk(), mk(), mk()
-    ref = reference_attention(q, k, v, causal=causal).astype(jnp.float32)
-    out = flash_attention(q, k, v, causal=causal,
-                          block_q=16, block_k=16).astype(jnp.float32)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    out, gf = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=16, block_k=16), q, k, v)
+    ref, gr = out_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal), q, k, v)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
                                atol=2e-2, rtol=2e-2)
-
-    def loss(fn):
-        return lambda q, k, v: (
-            fn(q, k, v).astype(jnp.float32) ** 2).sum()
-
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, causal=causal, block_q=16, block_k=16)
-    refa = lambda q, k, v: reference_attention(q, k, v, causal=causal)  # noqa: E731
-    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss(refa), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         a = np.asarray(a, np.float32)
         b = np.asarray(b, np.float32)
@@ -143,15 +140,10 @@ def test_flash_key_mask():
 def test_flash_gradient():
     q, k, v = _qkv(3)
 
-    def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=True,
-                                block_q=16, block_k=16) ** 2).sum()
-
-    def loss_ref(q, k, v):
-        return (reference_attention(q, k, v, causal=True) ** 2).sum()
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    _, gf = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16), q, k, v)
+    _, gr = out_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=True), q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)
@@ -183,15 +175,10 @@ def test_flash_gradient_with_mask():
     mask_np[0, :] = False
     mask = jnp.asarray(mask_np)
 
-    def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, key_mask=mask,
-                                block_q=16, block_k=16) ** 2).sum()
-
-    def loss_ref(q, k, v):
-        return (reference_attention(q, k, v, key_mask=mask) ** 2).sum()
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    _, gf = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, key_mask=mask, block_q=16, block_k=16), q, k, v)
+    _, gr = out_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, key_mask=mask), q, k, v)
     for a, b in zip(gf, gr):
         a, b = np.asarray(a), np.asarray(b)
         assert np.isfinite(a).all()
@@ -231,17 +218,12 @@ def test_flash_awkward_seq_auto_pad_grads_and_mask():
     rng = np.random.RandomState(7)
     mask = jnp.asarray(rng.rand(B, s) > 0.2)
 
-    def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, key_mask=mask) ** 2).sum()
-
-    def loss_ref(q, k, v):
-        return (reference_attention(q, k, v, key_mask=mask) ** 2).sum()
-
-    np.testing.assert_allclose(
-        float(loss_flash(q, k, v)), float(loss_ref(q, k, v)),
-        rtol=1e-4)
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    out, gf = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, key_mask=mask), q, k, v)
+    ref, gr = out_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, key_mask=mask), q, k, v)
+    np.testing.assert_allclose(float((out ** 2).sum()),
+                               float((ref ** 2).sum()), rtol=1e-4)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)
@@ -259,21 +241,26 @@ def test_flash_long_context_32k():
     mk = lambda: jnp.asarray(rng.randn(b, s, h, d).astype(np.float32)) * 0.3
     q, k, v = mk(), mk(), mk()
 
-    out = flash_attention(q, k, v, causal=True, block_q=2048, block_k=2048)
+    out = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=2048, block_k=2048))(q, k, v)
 
     chunk = 2048
-    for start in range(0, s, chunk * 4):  # spot-check 1/4 of the chunks
-        qc = q[:, start:start + chunk]
+
+    @jax.jit    # eagerly, each 256 MB temporary is a buffer of its own
+    def reference_rows(start):
+        qc = jax.lax.dynamic_slice_in_dim(q, start, chunk, axis=1)
         logits = jnp.einsum("bqhd,bkhd->bhqk", qc, k).astype(jnp.float32)
         logits = logits / (d ** 0.5)
         ki = jnp.arange(s)[None, :]
         qi = (start + jnp.arange(chunk))[:, None]
         logits = jnp.where((ki <= qi)[None, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
-        ref_c = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+
+    for start in range(0, s, chunk * 4):  # spot-check 1/4 of the chunks
         np.testing.assert_allclose(
-            np.asarray(out[:, start:start + chunk]), np.asarray(ref_c),
-            atol=2e-5, rtol=1e-4)
+            np.asarray(out[:, start:start + chunk]),
+            np.asarray(reference_rows(start)), atol=2e-5, rtol=1e-4)
 
 
 def test_bert_with_flash_attention():
@@ -283,12 +270,12 @@ def test_bert_with_flash_attention():
     cfg = BERT_TINY
     ids = jnp.ones((1, 32), jnp.int32)
     model_ref = BertEncoder(cfg)
-    variables = model_ref.init(jax.random.PRNGKey(0), ids, deterministic=True)
-    out_ref = model_ref.apply(variables, ids, deterministic=True)
+    variables = jit_init(model_ref, ids, deterministic=True)
+    out_ref = jit_apply(model_ref, deterministic=True)(variables, ids)
 
     model_flash = BertEncoder(
         cfg, attention_fn=make_attention_fn(use_flash=True, block_q=16,
                                        block_k=16))
-    out_flash = model_flash.apply(variables, ids, deterministic=True)
+    out_flash = jit_apply(model_flash, deterministic=True)(variables, ids)
     np.testing.assert_allclose(np.asarray(out_flash), np.asarray(out_ref),
                                atol=5e-2, rtol=5e-2)

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from attention_helpers import PATHS, _assert_grads_close, _rand, both_paths
+from attention_helpers import (PATHS, _rand, assert_matches_reference,
+                               both_paths)
 from horovod_tpu.ops.attention import (_one_tile_path, flash_attention,
                                        reference_attention)
 
@@ -46,26 +47,19 @@ def _key_mask(sk, seed):
     return jnp.asarray(mask)
 
 
-def _check(flash, ref, q, k, v):
-    f32 = q.dtype == jnp.float32
-    out = flash(q, k, v)
-    assert out.dtype == q.dtype and out.shape == q.shape
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref(q, k, v), np.float32),
-        atol=2e-5 if f32 else 2e-2, rtol=1e-4 if f32 else 2e-2)
-    _assert_grads_close(flash, ref, q, k, v, 2e-3 if f32 else 5e-2)
-
-
 @both_paths
 @heads
 @pytest.mark.parametrize("how,dtype", [("key_mask", "bfloat16"),
                                        ("causal", "float32")])
-def test_flash_head_layouts_forward_and_grad(path, heads, how, dtype):
+def test_flash_head_layouts_forward_and_grad(path, heads, how, dtype,
+                                             reference_results):
     q, k, v = _qkv(heads, jnp.dtype(dtype))
     kw = (dict(key_mask=_key_mask(S, 5)) if how == "key_mask"
           else dict(causal=True))
-    _check(lambda q, k, v: flash_attention(q, k, v, **kw, **PATHS[path]),
-           lambda q, k, v: reference_attention(q, k, v, **kw), q, k, v)
+    assert_matches_reference(
+        lambda q, k, v: flash_attention(q, k, v, **kw, **PATHS[path]),
+        lambda q, k, v: reference_attention(q, k, v, **kw), q, k, v,
+        shared=(reference_results, (heads, how, dtype)))
 
 
 @both_paths
@@ -74,7 +68,8 @@ def test_flash_head_layouts_forward_and_grad(path, heads, how, dtype):
     for heads in ("h12_d64", "gqa8_2_d64", "gqa28_4_d128")
     for how in ("plain", "causal_sq_ne_sk", "window")
 ] + [("h12_d64", "pad_197")])
-def test_flash_head_layouts_shapes_of_the_band(path, heads, how):
+def test_flash_head_layouts_shapes_of_the_band(path, heads, how,
+                                               reference_results):
     sq, sk, kw = {
         "plain": (S, S, {}),
         "causal_sq_ne_sk": (16, S, dict(causal=True)),       # decode rows
@@ -85,8 +80,10 @@ def test_flash_head_layouts_shapes_of_the_band(path, heads, how):
     if how == "pad_197" and blocks:     # 197 pads to 256: four blocks
         blocks = dict(block_q=64, block_k=64)
     q, k, v = _qkv(heads, jnp.float32, sq, sk, seed=10)
-    _check(lambda q, k, v: flash_attention(q, k, v, **kw, **blocks),
-           lambda q, k, v: reference_attention(q, k, v, **kw), q, k, v)
+    assert_matches_reference(
+        lambda q, k, v: flash_attention(q, k, v, **kw, **blocks),
+        lambda q, k, v: reference_attention(q, k, v, **kw), q, k, v,
+        shared=(reference_results, (heads, how)))
 
 
 def _transposed_ranks(jaxpr, found):

@@ -7,9 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from attention_helpers import (B, D, H, KERNELS, PATHS, S,
-                               _assert_grads_close, _rand, _sq_loss,
-                               both_paths, kernel_grids)
+from attention_helpers import (B, D, H, KERNELS, PATHS, S, _rand,
+                               assert_matches_reference, both_paths,
+                               kernel_grids, out_and_grads)
 from horovod_tpu.ops.attention import flash_attention, reference_attention
 
 
@@ -17,7 +17,8 @@ from horovod_tpu.ops.attention import flash_attention, reference_attention
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("masked", [False, True])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_paths_forward_and_grad(path, dtype, masked, causal):
+def test_flash_paths_forward_and_grad(path, dtype, masked, causal,
+                                      reference_results):
     dtype = jnp.dtype(dtype)
     q, k, v = (_rand((B, S, H, D), 30 + i, dtype) for i in range(3))
     mask = None
@@ -25,32 +26,25 @@ def test_flash_paths_forward_and_grad(path, dtype, masked, causal):
         mask_np = np.random.RandomState(33).rand(B, S) > 0.3
         mask_np[:, 0] = True      # no fully-masked row, causal or not
         mask = jnp.asarray(mask_np)
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, key_mask=mask, causal=causal, **PATHS[path])
-    ref = lambda q, k, v: reference_attention(  # noqa: E731
-        q, k, v, key_mask=mask, causal=causal)
-    out = flash(q, k, v)
-    assert out.dtype == dtype and out.shape == q.shape
-    f32 = dtype == jnp.float32
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref(q, k, v), np.float32),
-        atol=2e-5 if f32 else 2e-2, rtol=1e-4 if f32 else 2e-2)
-    _assert_grads_close(flash, ref, q, k, v, 2e-3 if f32 else 5e-2)
+    assert_matches_reference(
+        lambda q, k, v: flash_attention(q, k, v, key_mask=mask,
+                                        causal=causal, **PATHS[path]),
+        lambda q, k, v: reference_attention(q, k, v, key_mask=mask,
+                                            causal=causal),
+        q, k, v, shared=(reference_results, (dtype, masked, causal)))
 
 
 @both_paths
 @pytest.mark.parametrize("sq,sk", [(16, 64), (32, 64)])
-def test_flash_paths_causal_sq_ne_sk(path, sq, sk):
+def test_flash_paths_causal_sq_ne_sk(path, sq, sk, reference_results):
     # Decode convention: the sq query rows are the LAST sq key positions.
     q = _rand((B, sq, H, D), 40)
     k, v = _rand((B, sk, H, D), 41), _rand((B, sk, H, D), 42)
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, causal=True, **PATHS[path])
-    ref = lambda q, k, v: reference_attention(q, k, v, causal=True)  # noqa: E731
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(ref(q, k, v)),
-                               atol=2e-5, rtol=1e-4)
-    _assert_grads_close(flash, ref, q, k, v, 2e-3)
+    assert_matches_reference(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        **PATHS[path]),
+        lambda q, k, v: reference_attention(q, k, v, causal=True),
+        q, k, v, shared=(reference_results, ("sq_ne_sk", sq, sk)))
 
 
 @both_paths
@@ -78,12 +72,11 @@ def test_flash_paths_fully_masked_rows(path, how):
         out = reference_attention(q, k, v, **kw)
         return jnp.where(jnp.asarray(dead)[:, :, None, None], 0.0, out)
 
-    out = np.asarray(flash(q, k, v))
+    out, gf = out_and_grads(flash, q, k, v)
+    want, gr = out_and_grads(ref, q, k, v)
+    out = np.asarray(out)
     np.testing.assert_array_equal(out[dead], 0.0)
-    np.testing.assert_allclose(out, np.asarray(ref(q, k, v)),
-                               atol=2e-5, rtol=1e-4)
-    gf = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(_sq_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(out, np.asarray(want), atol=2e-5, rtol=1e-4)
     assert all(np.isfinite(np.asarray(g)).all() for g in gf)
     np.testing.assert_array_equal(np.asarray(gf[0])[dead], 0.0)
     if how == "key_mask":      # batch 0 has no live key at all
@@ -97,21 +90,20 @@ def test_flash_paths_fully_masked_rows(path, how):
 @pytest.mark.parametrize("path,blocks", [
     ("one_tile", {}), ("streamed", {"block_q": 64, "block_k": 64})])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_paths_awkward_len_auto_pad(path, blocks, causal):
+def test_flash_paths_awkward_len_auto_pad(path, blocks, causal,
+                                          reference_results):
     # ViT's 197 pads to 256: one tile at the defaults, four blocks a side
     # at 64; the pad mask joins the caller's own either way.
     s = 197
     q, k, v = (_rand((B, s, H, D), 70 + i) for i in range(3))
     mask = jnp.asarray(np.random.RandomState(73).rand(B, s) > 0.2
                        ).at[:, 0].set(True)
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, key_mask=mask, causal=causal, **blocks)
-    ref = lambda q, k, v: reference_attention(  # noqa: E731
-        q, k, v, key_mask=mask, causal=causal)
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(ref(q, k, v)),
-                               atol=2e-5, rtol=1e-4)
-    _assert_grads_close(flash, ref, q, k, v, 2e-3)
+    assert_matches_reference(
+        lambda q, k, v: flash_attention(q, k, v, key_mask=mask,
+                                        causal=causal, **blocks),
+        lambda q, k, v: reference_attention(q, k, v, key_mask=mask,
+                                            causal=causal),
+        q, k, v, shared=(reference_results, ("pad_197", causal)))
 
 
 @both_paths
@@ -121,7 +113,8 @@ def test_flash_paths_awkward_len_auto_pad(path, blocks, causal):
     (12, 12, 64),     # one tile: a step's block is 4 heads of the 12
     (8, 2, 64),       # a query group of 4 over each K/V head
 ])
-def test_flash_paths_lse_out_and_dlse_in(path, causal, h, hkv, d):
+def test_flash_paths_lse_out_and_dlse_in(path, causal, h, hkv, d,
+                                         reference_results):
     # What ring attention leans on: the forward returns the row
     # log-sum-exp, and the backward takes a cotangent on it (a shift of
     # delta); both stay (B * H, 1, S) rows whatever layout the kernels
@@ -152,16 +145,31 @@ def test_flash_paths_lse_out_and_dlse_in(path, causal, h, hkv, d):
                          jnp.exp(logits - lse[..., None]), v)
         return out, lse.reshape(b * h, 1, s)
 
-    (ref_out, ref_lse), vjp = jax.vjp(dense, q, k, v)
-    out, lse = _flash_forward(q, k, v, mask, causal, None, bq, bk, True)
+    do, dlse = _rand(q.shape, 84), _rand((b * h, 1, s), 85)
+
+    @jax.jit
+    def kernels(q, k, v, do, dlse):
+        out, lse = _flash_forward(q, k, v, mask, causal, None, bq, bk, True)
+        return out, lse, _flash_backward(q, k, v, mask, out, lse, do,
+                                         causal, None, bq, bk, True,
+                                         dlse=dlse)
+
+    @jax.jit
+    def exact(q, k, v, do, dlse):
+        (out, lse), vjp = jax.vjp(dense, q, k, v)
+        return out, lse, vjp((do, dlse))
+
+    case = ("lse", causal, h, hkv, d)
+    if case not in reference_results:
+        reference_results[case] = exact(q, k, v, do, dlse)
+    ref_out, ref_lse, ref_grads = reference_results[case]
+    out, lse, got = kernels(q, k, v, do, dlse)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
                                atol=2e-5, rtol=1e-4)
+    assert lse.shape == ref_lse.shape
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                atol=2e-5, rtol=1e-5)
-    do, dlse = _rand(out.shape, 84), _rand(lse.shape, 85)
-    got = _flash_backward(q, k, v, mask, out, lse, do, causal, None, bq,
-                          bk, True, dlse=dlse)
-    for a, r in zip(got, vjp((do, dlse))):
+    for a, r in zip(got, ref_grads):
         assert a.shape == r.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    atol=2e-5, rtol=1e-3)

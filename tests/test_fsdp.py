@@ -133,7 +133,7 @@ def _llama_setup():
     batch, seq = N_DEV, 32
     ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (batch, seq)),
                       jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids[:1])["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids[:1])["params"]
     return model, params, ids
 
 

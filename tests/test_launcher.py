@@ -2,12 +2,12 @@
 (.buildkite/gen-pipeline.sh:101-133); same here via `python -m horovod_tpu.run`."""
 
 import os
-import subprocess
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from mp_harness import run_cmd
+from mp_harness import run_launcher as _run_launcher
 
 SCRIPT = (
     "import os; os.environ.setdefault('JAX_PLATFORMS','cpu');"
@@ -19,16 +19,6 @@ SCRIPT = (
     "assert np.allclose(out, expected), out;"
     "print(f'rank {hvd.rank()} of {hvd.size()} ok'); hvd.shutdown()"
 )
-
-
-def _run_launcher(args, timeout=180):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    env["HOROVOD_CYCLE_TIME"] = "1"
-    return subprocess.run(
-        [sys.executable, "-m", "horovod_tpu.run"] + args,
-        env=env, capture_output=True, text=True, timeout=timeout)
 
 
 def test_launch_np2():
@@ -151,7 +141,7 @@ def test_restart_resumes_from_latest_checkpoint(tmp_path):
         "hvd.shutdown()\n")
     res = _run_launcher(["-np", "1", "--max-restarts", "1",
                          "--restart-backoff", "0.05",
-                         sys.executable, str(script)], timeout=300)
+                         sys.executable, str(script)])
     assert res.returncode == 0, res.stdout + res.stderr
     assert "resumed step=5 epoch=1" in res.stdout
 
@@ -237,9 +227,9 @@ def test_nic_ring_probe_three_hosts():
     import horovod_tpu.run.task_fn as task_fn_module
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     with open(task_fn_module.__file__) as script:
-        proc = subprocess.run(
-            [sys.executable, "-", "2", addr], stdin=script,
-            env=env, capture_output=True, text=True, timeout=120)
+        proc = run_cmd(
+            [sys.executable, "-", "2", addr],
+            timeout=120, stdin=script, env=env)
     for t in threads:
         t.join(timeout=60)
     driver.close()

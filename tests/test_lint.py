@@ -19,8 +19,6 @@ Four layers (docs/static-analysis.md):
 
 import json
 import os
-import socket
-import subprocess
 import sys
 import threading
 
@@ -38,6 +36,7 @@ from horovod_tpu.analysis import (
 )
 from horovod_tpu.analysis.lockorder import LockGraph, TrackedLock, make_lock
 from horovod_tpu.analysis.rules import ALL_RULES
+from mp_harness import LAUNCH_LIMIT, child_env, run_cmd, run_ranks
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -313,24 +312,20 @@ def test_cli_json_and_exit_codes(tmp_path):
     bad = tmp_path / "pkgdir" / "bad.py"
     bad.parent.mkdir()
     bad.write_text(_fixture("hvd005_bad.py"))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     base = [sys.executable, "-m", "horovod_tpu.tools.lint",
             str(bad.parent), "--format", "json"]
-    res = subprocess.run(base + ["--baseline", "none"], env=env,
-                         capture_output=True, text=True, timeout=300)
+    res = run_cmd(base + ["--baseline", "none"], timeout=180, env=env)
     assert res.returncode == 1, res.stdout + res.stderr
     payload = json.loads(res.stdout)
     assert {f["rule"] for f in payload["findings"]} == {"HVD005"}
     # Grandfather them; the same invocation now exits 0.
     bl = str(tmp_path / "bl.json")
-    res = subprocess.run(base + ["--write-baseline", "--baseline", bl],
-                         env=env, capture_output=True, text=True,
-                         timeout=300)
+    res = run_cmd(
+        base + ["--write-baseline", "--baseline", bl],
+        timeout=180, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
-    res = subprocess.run(base + ["--baseline", bl], env=env,
-                         capture_output=True, text=True, timeout=300)
+    res = run_cmd(base + ["--baseline", bl], timeout=180, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
 
 
@@ -344,20 +339,16 @@ def test_fix_autofixes_mechanical_rules_idempotently(tmp_path):
     f2.write_text(_fixture("hvd002_bad.py"))
     f5 = pkg / "threads.py"
     f5.write_text(_fixture("hvd005_bad.py"))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     cmd = [sys.executable, "-m", "horovod_tpu.tools.lint", str(pkg),
            "--fix", "--select", "HVD002,HVD005", "--baseline", "none"]
-    res = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=300)
+    res = run_cmd(cmd, timeout=180, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "applied" in res.stdout
     once = f2.read_text(), f5.read_text()
     assert "sorted(ticks.items())" in once[0]
     assert 'name="hvd-worker"' in once[1] and "daemon=True" in once[1]
-    res = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=300)
+    res = run_cmd(cmd, timeout=180, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "applied 0 fix(es)" in res.stdout
     assert (f2.read_text(), f5.read_text()) == once  # twice == once
@@ -457,13 +448,11 @@ def test_cli_refuses_partial_rewrite_of_default_baseline(tmp_path):
     entries; the CLI must refuse (exit 2, usage error) and leave the
     checked-in file untouched."""
     before = open(BASELINE).read()
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    res = subprocess.run(
+    env = child_env()
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.tools.lint",
          "--select", "HVD004", "--write-baseline"],
-        env=env, capture_output=True, text=True, timeout=300)
+        timeout=180, env=env)
     assert res.returncode == 2, res.stdout + res.stderr
     assert "full default scan" in res.stderr
     assert open(BASELINE).read() == before
@@ -595,50 +584,20 @@ def test_write_graph_artifact(tmp_path, monkeypatch):
 # 5. 3-rank acceptance: real controller under HOROVOD_LOCKCHECK=1
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
 def test_lockcheck_three_rank_run_produces_acyclic_graph(tmp_path):
     """Acceptance criterion: a 3-rank eager job under
     ``HOROVOD_LOCKCHECK=1`` completes and every rank writes a valid
     ``lockgraph.json`` with no cycles. Telemetry + rank-0 timeline are
     on so the run exercises the real nested acquisitions (the
     timeline-emit-under-pids-lock path the detector exists to watch)."""
-    addr = f"127.0.0.1:{_free_port()}"
     size = 3
     out = str(tmp_path / "lockgraph.json")
-    procs = []
-    for rank in range(size):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.update({
-            "JAX_PLATFORMS": "cpu",
-            "HOROVOD_CYCLE_TIME": "1",
-            "HOROVOD_RANK": str(rank),
-            "HOROVOD_SIZE": str(size),
-            "HOROVOD_LOCAL_RANK": str(rank),
-            "HOROVOD_LOCAL_SIZE": str(size),
-            "HOROVOD_CONTROLLER_ADDR": addr,
-            "HOROVOD_ENGINE": "python",
-            "HOROVOD_LOCKCHECK": "1",
-            "HOROVOD_LOCKCHECK_OUTPUT": out,
-            "HOROVOD_METRICS": "1",
-        })
-        if rank == 0:
-            env["HOROVOD_TIMELINE"] = str(tmp_path / "tl.json")
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "mp_worker.py"), "allreduce"],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True))
-    for rank, proc in enumerate(procs):
-        stdout, _ = proc.communicate(timeout=120)
-        assert proc.returncode == 0, (
-            f"rank {rank} failed under lockcheck:\n{stdout[-3000:]}")
+    run_ranks("allreduce", size, timeout=120, extra_env={
+        "HOROVOD_LOCKCHECK": "1",
+        "HOROVOD_LOCKCHECK_OUTPUT": out,
+        "HOROVOD_METRICS": "1",
+    }, per_rank_env={0: {"HOROVOD_TIMELINE": str(tmp_path / "tl.json")}},
+        protocheck=False)
     edges_seen = 0
     reports = []
     for rank in range(size):
@@ -663,3 +622,83 @@ def test_lockcheck_three_rank_run_produces_acyclic_graph(tmp_path):
     assert join["superset"], (
         "runtime lock edges missing from the static graph: "
         f"{join['uncovered_runtime_edges']}")
+
+
+# ------------------------------------------------ the suite's own launches
+
+# What starts a process: of ``subprocess`` and of ``os`` the calls that
+# fork, spawn, exec or shell out.
+_LAUNCHERS = {
+    "subprocess": ("run", "Popen", "call", "check_call", "check_output",
+                   "getoutput", "getstatusoutput"),
+    "os": ("system", "popen", "fork", "forkpty", "posix_spawn",
+           "posix_spawnp", "startfile"),
+    "multiprocessing": ("Process", "Pool"),
+}
+
+
+def _launch_findings(source, path):
+    """What ``tests/mp_harness.py`` asks of a test file, as findings: no
+    process started but through the harness, and no literal ``timeout=``
+    above ``LAUNCH_LIMIT`` in a call or a default. The suite runs under
+    one clock (``timeout 1470``); a launch's limit is a hang's price."""
+    import ast
+
+    found = []
+    for node in ast.walk(ast.parse(source, path)):
+        if isinstance(node, ast.Call):
+            fn = node.func
+            owner = (fn.value.id if isinstance(fn, ast.Attribute)
+                     and isinstance(fn.value, ast.Name) else None)
+            if owner and (fn.attr in _LAUNCHERS.get(owner, ()) or (
+                    owner == "os" and fn.attr.startswith(("spawn", "exec")))):
+                found.append(f"{path}:{node.lineno}: {owner}.{fn.attr}() "
+                             "starts a process outside tests/mp_harness.py")
+            limits = [(kw.value, node.lineno) for kw in node.keywords
+                      if kw.arg == "timeout"]
+        elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            args = node.args
+            named = list(zip((args.posonlyargs + args.args)[::-1],
+                             args.defaults[::-1]))
+            named += [(a, d) for a, d in zip(args.kwonlyargs,
+                                             args.kw_defaults) if d]
+            limits = [(d, node.lineno) for a, d in named
+                      if a.arg == "timeout"]
+        else:
+            continue
+        for value, line in limits:
+            if (isinstance(value, ast.Constant)
+                    and isinstance(value.value, (int, float))
+                    and value.value > LAUNCH_LIMIT):
+                found.append(f"{path}:{line}: timeout={value.value} is "
+                             f"above the {LAUNCH_LIMIT:.0f} s a hang may "
+                             "cost")
+    return found
+
+
+@pytest.mark.parametrize("source,findings", [
+    ("import subprocess\nsubprocess.run(['x'], timeout=560)\n", 2),
+    ("import subprocess as sp, os\nos.system('x')\nos.execv('x', [])\n", 2),
+    ("def _run(cmd, timeout=300):\n    return run_cmd(cmd, timeout)\n", 1),
+    ("def _run(cmd, *, timeout=420.0):\n    pass\n", 1),
+    ("run_ranks('x', timeout=240.0)\nhandle.result(timeout=181)\n", 2),
+    ("run_cmd(cmd, timeout=180)\nrun_ranks('x', timeout=limit * 4)\n"
+     "body = 'subprocess.Popen([1])'\nos.path.join('a')\n", 0),
+], ids=["subprocess_run_and_its_limit", "os_system_and_exec",
+        "default_limit", "keyword_only_default", "keyword_limits", "clean"])
+def test_launch_rule_finds_what_it_names(source, findings):
+    assert len(_launch_findings(source, "t.py")) == findings
+
+
+def test_tests_launch_processes_only_through_the_harness():
+    """Every ``tests/*.py`` outside ``tests/benchmark/`` (the benchmark's
+    own, not this gate's) but the harness itself and the workers it
+    starts."""
+    exempt = {"mp_harness.py", "mp_worker.py", "spmd_worker.py",
+              "fake_pyspark.py"}
+    found = []
+    for name in sorted(os.listdir(HERE)):
+        if name.endswith(".py") and name not in exempt:
+            with open(os.path.join(HERE, name), encoding="utf-8") as f:
+                found += _launch_findings(f.read(), f"tests/{name}")
+    assert not found, "\n".join(found)

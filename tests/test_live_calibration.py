@@ -649,14 +649,24 @@ def test_live_drift_drive_fires_heals_and_persists(tmp_path, monkeypatch):
         assert drifted.negotiation_per_rank_s >= 2 * baseline_slope
 
         # Heal phase: the delay is gone; healthy windows displace the
-        # whole horizon (8 windows) and the finding clears.
-        for _ in range(9):
+        # whole horizon (8 windows) and the finding clears. On an idle box
+        # that is the ninth window. The baseline above is this box's speed
+        # in the second it was taken, so a box that six other workers
+        # slow down for a while reads drifted until it is itself again
+        # (the flap under load: CHANGES.md, PR 39): what is asserted is
+        # that the finding clears, and 40 windows bound the wait.
+        for window in range(40):
             for _ in range(4):
                 c.run_step([_spec(f"s.{step}")])
                 step += 1
             c.roll_window()
-        assert not [f for f in c.doctor_report()["findings"]
-                    if f["rule"] == "calibration_drift"]
+            if window >= 8 and not [
+                    f for f in c.doctor_report()["findings"]
+                    if f["rule"] == "calibration_drift"]:
+                break
+        else:
+            raise AssertionError(
+                "calibration_drift still reported after 40 healthy windows")
         # Rank-0 shutdown persists the final (healed) re-fit too.
     final = json.loads((live_dir / "capacity_live.json").read_text())
     assert final["source"] == "live"

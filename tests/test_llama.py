@@ -7,6 +7,18 @@ import pytest
 
 from horovod_tpu.models import (LLAMA_TINY, LlamaLM, causal_lm_loss,
                                 chunked_causal_lm_loss)
+from model_helpers import jit_apply, jit_init
+
+
+@pytest.fixture(scope="module")
+def variables():
+    """LLAMA_TINY's variables, initialised once for the file: the
+    parameters are float32 and depend on neither the shape of the ids they
+    are traced with nor the config's compute dtype (``PRNGKey(0)``, as
+    each test used to draw them for itself). It outlives
+    ``_fresh_state``: arrays on device 0, nothing of ``hvd`` or the mesh
+    registry; no test writes to the tree."""
+    return jit_init(LlamaLM(LLAMA_TINY), _ids((2, 16)))
 
 
 def _ids(shape, seed=0):
@@ -15,74 +27,70 @@ def _ids(shape, seed=0):
         jnp.int32)
 
 
-def test_forward_and_loss():
+def test_forward_and_loss(variables):
     model = LlamaLM(LLAMA_TINY)
     ids = _ids((2, 16))
-    variables = model.init(jax.random.PRNGKey(0), ids)
-    logits = model.apply(variables, ids)
+    logits = jit_apply(model)(variables, ids)
     assert logits.shape == (2, 16, LLAMA_TINY.vocab_size)
     loss = causal_lm_loss(logits, ids)
     assert 0.5 * np.log(LLAMA_TINY.vocab_size) < float(loss) < \
         2 * np.log(LLAMA_TINY.vocab_size)
 
 
-def test_head_dtype_knob():
+def test_head_dtype_knob(variables):
     # Default: logits in the model compute dtype (bf16). head_dtype=f32
     # opts raw-logit consumers back into full precision (advisor round-2).
     import dataclasses
 
     ids = _ids((1, 8))
     model = LlamaLM(LLAMA_TINY)
-    variables = model.init(jax.random.PRNGKey(0), ids)
-    assert model.apply(variables, ids).dtype == LLAMA_TINY.dtype
+    assert jit_apply(model)(variables, ids).dtype == LLAMA_TINY.dtype
     f32_model = LlamaLM(
         dataclasses.replace(LLAMA_TINY, head_dtype=jnp.float32))
-    assert f32_model.apply(variables, ids).dtype == jnp.float32
+    assert jit_apply(f32_model)(variables, ids).dtype == jnp.float32
 
 
-def test_causality():
+def test_causality(variables):
     model = LlamaLM(LLAMA_TINY)
     ids = _ids((1, 12))
-    variables = model.init(jax.random.PRNGKey(0), ids)
-    out1 = model.apply(variables, ids)
+    forward = jit_apply(model)
+    out1 = forward(variables, ids)
     ids2 = ids.at[0, 8].set((int(ids[0, 8]) + 1) % LLAMA_TINY.vocab_size)
-    out2 = model.apply(variables, ids2)
+    out2 = forward(variables, ids2)
     # Positions before 8 must be unchanged; position 8 must change.
     np.testing.assert_allclose(np.asarray(out1[0, :8]),
                                np.asarray(out2[0, :8]), atol=1e-4)
     assert not np.allclose(np.asarray(out1[0, 8]), np.asarray(out2[0, 8]))
 
 
-def test_gradients_flow():
+def test_gradients_flow(variables):
     model = LlamaLM(LLAMA_TINY)
     ids = _ids((2, 8))
-    variables = model.init(jax.random.PRNGKey(0), ids)
 
     def loss_fn(params):
         return causal_lm_loss(model.apply({"params": params}, ids), ids)
 
-    grads = jax.grad(loss_fn)(variables["params"])
+    grads = jax.jit(jax.grad(loss_fn))(variables["params"])
     leaves = jax.tree_util.tree_leaves(grads)
     assert all(np.isfinite(np.asarray(l)).all() for l in leaves)
     assert any(float(jnp.abs(l).max()) > 0 for l in leaves)
 
 
-def test_flash_attention_seam():
+def test_flash_attention_seam(variables):
     from horovod_tpu.ops.attention import make_attention_fn
 
     cfg = LLAMA_TINY
     ids = _ids((1, 32))
     ref_model = LlamaLM(cfg)
-    variables = ref_model.init(jax.random.PRNGKey(0), ids)
-    out_ref = ref_model.apply(variables, ids)
+    out_ref = jit_apply(ref_model)(variables, ids)
     flash_model = LlamaLM(cfg, attention_fn=make_attention_fn(
         causal=True, use_flash=True, block_q=16, block_k=16))
-    out_flash = flash_model.apply(variables, ids)
+    out_flash = jit_apply(flash_model)(variables, ids)
     np.testing.assert_allclose(np.asarray(out_flash), np.asarray(out_ref),
                                atol=5e-2, rtol=5e-2)
 
 
-def test_sequence_parallel_ring_attention():
+def test_sequence_parallel_ring_attention(variables):
     """Long-context integration: LlamaLM runs inside a sequence-sharded
     shard_map with ring attention plugged into the attention_fn seam and
     GLOBAL RoPE positions per shard — output must match the single-device
@@ -97,8 +105,7 @@ def test_sequence_parallel_ring_attention():
     s = 64
     ids = _ids((2, s), seed=3)
     ref_model = LlamaLM(cfg)
-    variables = ref_model.init(jax.random.PRNGKey(0), ids)
-    ref = ref_model.apply(variables, ids)
+    ref = jit_apply(ref_model)(variables, ids)
 
     sp_model = LlamaLM(cfg, attention_fn=lambda q, k, v, m: ring_attention(
         q, k, v, axis_name="seq", causal=True))
@@ -119,7 +126,7 @@ def test_sequence_parallel_ring_attention():
                                atol=5e-2, rtol=5e-2)
 
 
-def test_sequence_parallel_rope_positions_matter():
+def test_sequence_parallel_rope_positions_matter(variables):
     """Without global positions the sharded model must NOT match —
     guarding against silently-local RoPE (every shard rotating as if it
     held the sequence start)."""
@@ -133,8 +140,7 @@ def test_sequence_parallel_rope_positions_matter():
     s = 64
     ids = _ids((2, s), seed=4)
     ref_model = LlamaLM(cfg)
-    variables = ref_model.init(jax.random.PRNGKey(0), ids)
-    ref = np.asarray(ref_model.apply(variables, ids), np.float32)
+    ref = np.asarray(jit_apply(ref_model)(variables, ids), np.float32)
 
     sp_model = LlamaLM(cfg, attention_fn=lambda q, k, v, m: ring_attention(
         q, k, v, axis_name="seq", causal=True))
@@ -168,7 +174,7 @@ def test_sp_causal_lm_loss_matches_single_device():
     np.testing.assert_allclose(float(sp), float(full), rtol=1e-6)
 
 
-def test_sequence_parallel_ulysses():
+def test_sequence_parallel_ulysses(variables):
     """Ulysses all-to-all SP through the same seam: heads split over the
     axis, full-sequence attention per shard, global RoPE positions."""
     from jax.sharding import PartitionSpec as P
@@ -181,8 +187,7 @@ def test_sequence_parallel_ulysses():
     s = 64
     ids = _ids((2, s), seed=5)
     ref_model = LlamaLM(cfg)
-    variables = ref_model.init(jax.random.PRNGKey(0), ids)
-    ref = ref_model.apply(variables, ids)
+    ref = jit_apply(ref_model)(variables, ids)
 
     sp_model = LlamaLM(cfg, attention_fn=lambda q, k, v, m:
                        ulysses_attention(q, k, v, axis_name="seq",
@@ -204,7 +209,7 @@ def test_sequence_parallel_ulysses():
                                atol=5e-2, rtol=5e-2)
 
 
-def test_sequence_parallel_ring_zigzag():
+def test_sequence_parallel_ring_zigzag(variables):
     """Zigzag-layout SP: ids and RoPE positions both follow the zigzag
     shard order (zigzag_positions), output unshards to match the
     single-device model."""
@@ -223,8 +228,7 @@ def test_sequence_parallel_ring_zigzag():
     s = 64
     ids = _ids((2, s), seed=6)
     ref_model = LlamaLM(cfg)
-    variables = ref_model.init(jax.random.PRNGKey(0), ids)
-    ref = ref_model.apply(variables, ids)
+    ref = jit_apply(ref_model)(variables, ids)
 
     sp_model = LlamaLM(cfg, attention_fn=lambda q, k, v, m: ring_attention(
         q, k, v, axis_name="seq", causal=True, layout="zigzag"))
@@ -247,13 +251,15 @@ def test_sequence_parallel_ring_zigzag():
                                atol=1e-1, rtol=5e-2)
 
 
-def test_remat_matches_no_remat():
+def test_remat_matches_no_remat(variables):
     import dataclasses
 
     ids = _ids((2, 16))
-    base = LlamaLM(LLAMA_TINY)
-    remat = LlamaLM(dataclasses.replace(LLAMA_TINY, remat=True))
-    variables = base.init(jax.random.PRNGKey(0), ids)
+    # float32: under jit the two programs fuse differently, and in
+    # bfloat16 that alone moves the gradients by their rounding.
+    cfg = dataclasses.replace(LLAMA_TINY, dtype=jnp.float32)
+    base = LlamaLM(cfg)
+    remat = LlamaLM(dataclasses.replace(cfg, remat=True))
 
     def loss_fn(model):
         def f(params):
@@ -262,18 +268,17 @@ def test_remat_matches_no_remat():
 
     # Same params apply in both: remat only changes WHEN activations are
     # (re)computed, never the math.
-    l0, g0 = jax.value_and_grad(loss_fn(base))(variables["params"])
-    l1, g1 = jax.value_and_grad(loss_fn(remat))(variables["params"])
+    l0, g0 = jax.jit(jax.value_and_grad(loss_fn(base)))(variables["params"])
+    l1, g1 = jax.jit(jax.value_and_grad(loss_fn(remat)))(variables["params"])
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
         g0, g1)
 
 
-def test_chunked_loss_matches_full():
+def test_chunked_loss_matches_full(variables):
     model = LlamaLM(LLAMA_TINY)
     ids = _ids((2, 16))
-    variables = model.init(jax.random.PRNGKey(0), ids)
 
     def full(params):
         return causal_lm_loss(model.apply({"params": params}, ids), ids)
@@ -283,8 +288,8 @@ def test_chunked_loss_matches_full():
         return chunked_causal_lm_loss(
             hidden, params["lm_head"]["kernel"], ids, num_chunks=4)
 
-    l0, g0 = jax.value_and_grad(full)(variables["params"])
-    l1, g1 = jax.value_and_grad(chunked)(variables["params"])
+    l0, g0 = jax.jit(jax.value_and_grad(full))(variables["params"])
+    l1, g1 = jax.jit(jax.value_and_grad(chunked))(variables["params"])
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
 
     # Gradients agree up to the bf16 rounding of the logits' cotangent
@@ -439,7 +444,7 @@ def test_chunked_loss_one_loop_one_product_a_chunk_three_when_differentiated():
         chunked, argnums=(0, 1)))(hidden, kernel).jaxpr) == (1, 3)
 
 
-def test_tensor_parallel_specs_match_data_parallel():
+def test_tensor_parallel_specs_match_data_parallel(variables):
     """Megatron-style TP via GSPMD: device_put params with
     llama_tp_param_specs over a (data, model) mesh, jit the train step,
     and the loss trajectory must match the fully-replicated run (XLA
@@ -453,7 +458,7 @@ def test_tensor_parallel_specs_match_data_parallel():
     cfg = LLAMA_TINY  # heads 4, kv 2, ffn 128, vocab 512: all divide tp=2
     model = LlamaLM(cfg)
     ids = _ids((8, 16))  # batch divides both dp=8 and dp=4
-    params0 = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params0 = variables["params"]
     tx = optax.adam(1e-2)
 
     def loss_fn(p, ids):
@@ -504,7 +509,7 @@ def test_tensor_parallel_specs_match_data_parallel():
     assert tp_losses[-1] < tp_losses[0]
 
 
-def test_kv_cache_decode_matches_full_forward():
+def test_kv_cache_decode_matches_full_forward(variables):
     # Greedy decoding through the static-shape KV cache must reproduce the
     # no-cache path exactly: token-by-token full forwards over the growing
     # sequence pick the same argmax at every step. f32 so numerics can't
@@ -516,7 +521,6 @@ def test_kv_cache_decode_matches_full_forward():
     cfg = dataclasses.replace(LLAMA_TINY, dtype=jnp.float32)
     model = LlamaLM(cfg)
     prompt = _ids((2, 5), seed=3)
-    variables = model.init(jax.random.PRNGKey(0), prompt)
 
     n_new = 6
     out = generate(model, variables, prompt, max_new_tokens=n_new)
@@ -524,14 +528,15 @@ def test_kv_cache_decode_matches_full_forward():
     np.testing.assert_array_equal(np.asarray(out[:, :5]), np.asarray(prompt))
 
     seq = prompt
+    forward = jit_apply(model)      # one program a length
     for _ in range(n_new):
-        logits = model.apply(variables, seq)
+        logits = forward(variables, seq)
         nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1)
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(seq))
 
 
-def test_kv_cache_logits_match_full_forward():
+def test_kv_cache_logits_match_full_forward(variables):
     # Prefill + one decode step: the cached-path logits equal the full
     # forward's logits at the same positions (masked window softmax ==
     # prefix softmax; exp(-inf) is exactly 0).
@@ -542,26 +547,24 @@ def test_kv_cache_logits_match_full_forward():
     cfg = dataclasses.replace(LLAMA_TINY, dtype=jnp.float32)
     model = LlamaLM(cfg)
     ids = _ids((2, 8), seed=4)
-    variables = model.init(jax.random.PRNGKey(0), ids)
 
-    full = model.apply(variables, ids)
+    full = jit_apply(model)(variables, ids)
     cache = init_kv_cache(cfg, 2, 16)
-    pre, cache = model.apply(variables, ids[:, :7], cache=cache,
-                             cache_index=0)
+    cached = jax.jit(lambda v, ids, cache, i: model.apply(
+        v, ids, cache=cache, cache_index=i))
+    pre, cache = cached(variables, ids[:, :7], cache, 0)
     np.testing.assert_allclose(np.asarray(pre), np.asarray(full[:, :7]),
                                rtol=1e-5, atol=1e-5)
-    step, cache = model.apply(variables, ids[:, 7:8], cache=cache,
-                              cache_index=7)
+    step, cache = cached(variables, ids[:, 7:8], cache, 7)
     np.testing.assert_allclose(np.asarray(step[:, 0]), np.asarray(full[:, 7]),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_generate_sampling_and_validation():
+def test_generate_sampling_and_validation(variables):
     from horovod_tpu.models import generate
 
     model = LlamaLM(LLAMA_TINY)
     prompt = _ids((1, 4), seed=5)
-    variables = model.init(jax.random.PRNGKey(0), prompt)
 
     # Temperature sampling: deterministic under a fixed key, right shape,
     # in-vocab tokens.
@@ -584,13 +587,12 @@ def test_generate_sampling_and_validation():
     assert one.shape == (1, 5)
 
 
-def test_generate_zero_tokens_and_temperature_shares_compile():
+def test_generate_zero_tokens_and_temperature_shares_compile(variables):
     from horovod_tpu.models import generate
     from horovod_tpu.models.llama import _decode
 
     model = LlamaLM(LLAMA_TINY)
     prompt = _ids((1, 4), seed=6)
-    variables = model.init(jax.random.PRNGKey(0), prompt)
 
     # max_new_tokens=0 is a no-op, not an extra token.
     out = generate(model, variables, prompt, max_new_tokens=0)
@@ -607,7 +609,7 @@ def test_generate_zero_tokens_and_temperature_shares_compile():
     assert _decode._cache_size() == one > before
 
 
-def test_generate_tensor_parallel_matches_single_device():
+def test_generate_tensor_parallel_matches_single_device(variables):
     # Multi-chip INFERENCE: generate() with params device_put under the
     # Megatron TP specs (llama_tp_param_specs) — GSPMD propagates the
     # shardings through prefill + scan and inserts the per-block psums —
@@ -622,7 +624,6 @@ def test_generate_tensor_parallel_matches_single_device():
     cfg = dataclasses.replace(LLAMA_TINY, dtype=jnp.float32)
     model = LlamaLM(cfg)
     prompt = _ids((2, 4), seed=11)
-    variables = model.init(jax.random.PRNGKey(0), prompt)
     base = generate(model, variables, prompt, max_new_tokens=5)
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("model",))

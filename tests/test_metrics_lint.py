@@ -19,11 +19,11 @@ import ast
 import json
 import os
 import re
-import subprocess
 import sys
 
 from horovod_tpu.analysis import run_lint
 from horovod_tpu.analysis.rules import MetricCatalogRule
+from mp_harness import child_env, run_cmd
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -250,12 +250,9 @@ def test_no_import_time_registration():
         "from horovod_tpu import metrics\n"
         "print(json.dumps({'names': metrics.default_registry().names(),\n"
         "                  'skipped': skipped}))\n")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     env["HOROVOD_METRICS"] = "1"
-    res = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=300)
+    res = run_cmd([sys.executable, "-c", code], timeout=180, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     report = json.loads(res.stdout.strip().splitlines()[-1])
     assert report["names"] == [], (

@@ -161,8 +161,8 @@ def test_moe_custom_vjp_grads_match_autodiff():
         y, aux = autodiff_twin(params, x, logits)
         return (y ** 2).sum() + 0.1 * aux
 
-    gf = jax.grad(loss_fast, argnums=(0, 1, 2))(params, x, logits)
-    gt = jax.grad(loss_twin, argnums=(0, 1, 2))(params, x, logits)
+    gf = jax.jit(jax.grad(loss_fast, argnums=(0, 1, 2)))(params, x, logits)
+    gt = jax.jit(jax.grad(loss_twin, argnums=(0, 1, 2)))(params, x, logits)
     for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gt)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5)

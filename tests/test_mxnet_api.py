@@ -1,5 +1,5 @@
 """MXNet adapter surface, size-1 semantics (reference test/test_mxnet.py
-scope, minus multi-rank which lives in test_multiprocess.py::mxnet).
+scope, minus multi-rank: test_multiprocess_frameworks.py, "mxnet").
 
 Runs against tests/fake_mxnet.py since mxnet is EOL and absent from CI; the
 fake implements only the surfaces the adapter touches, so these tests pin

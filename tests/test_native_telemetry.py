@@ -27,9 +27,6 @@ import ctypes
 import json
 import os
 import re
-import socket
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -38,6 +35,7 @@ from horovod_tpu import metrics
 from horovod_tpu.core import bindings
 from horovod_tpu.trace import merge_trace_dir
 from horovod_tpu.trace.tracer import PHASES
+from mp_harness import run_ring_ranks
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -56,53 +54,6 @@ def _fresh_metrics(monkeypatch):
     metrics.reset_for_tests()
     yield
     metrics.reset_for_tests()
-
-
-def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _run_engine_job(scenario, size, extra_env, timeout=180.0):
-    """Full-stack mp job (mp_worker scenarios) over the ring data plane;
-    engine picked by extra_env. Returns each rank's combined output."""
-    addr = f"127.0.0.1:{_free_port()}"
-    ring_addrs = ",".join(f"127.0.0.1:{_free_port()}" for _ in range(size))
-    procs = []
-    for rank in range(size):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["JAX_PLATFORMS"] = "cpu"
-        env.update({
-            "HOROVOD_RANK": str(rank), "HOROVOD_SIZE": str(size),
-            "HOROVOD_LOCAL_RANK": str(rank),
-            "HOROVOD_LOCAL_SIZE": str(size),
-            "HOROVOD_CONTROLLER_ADDR": addr,
-            "HOROVOD_RING_ADDRS": ring_addrs,
-            "HOROVOD_CYCLE_TIME": "1",
-        })
-        env.update(extra_env)
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "mp_worker.py"), scenario],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True))
-    outs = []
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            raise AssertionError(f"{scenario}: rank {rank} hung")
-        outs.append(out)
-    for rank, (proc, out) in enumerate(zip(procs, outs)):
-        assert proc.returncode == 0, (
-            f"{scenario}: rank {rank} failed (exit {proc.returncode}):\n"
-            f"{out}")
-    return outs
 
 
 # ---------------------------------------------------------------------------
@@ -296,7 +247,7 @@ def test_cross_engine_trace_parity(tmp_path):
     shapes = {}
     for engine in ("native", "python"):
         trace_dir = str(tmp_path / engine)
-        _run_engine_job("trace", 2, {
+        run_ring_ranks("trace", 2, extra_env={
             "HOROVOD_ENGINE": engine,
             "HOROVOD_TRACE_DIR": trace_dir,
             "HOROVOD_METRICS": "1",
@@ -330,7 +281,7 @@ def test_native_job_mergeable_offline(tmp_path):
     the stock merge (no offsets table -> workers flagged synced: false,
     visible not wrong)."""
     trace_dir = str(tmp_path / "t")
-    _run_engine_job("trace", 2, {
+    run_ring_ranks("trace", 2, extra_env={
         "HOROVOD_ENGINE": "native",
         "HOROVOD_TRACE_DIR": trace_dir,
     })
@@ -347,7 +298,7 @@ def test_native_telemetry_mp_bucket_sync_and_health(tmp_path):
     """2-rank native job: rank 0's tuned-bucket push arrives on BOTH
     ranks over the synced cycle reply, controller_health() reports live
     numbers, and the hvd_native_* series are present."""
-    outs = _run_engine_job("native_telemetry", 2, {
+    outs = run_ring_ranks("native_telemetry", 2, extra_env={
         "HOROVOD_ENGINE": "native",
         "HOROVOD_METRICS": "1",
     })

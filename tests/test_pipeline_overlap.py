@@ -27,7 +27,6 @@ import ctypes
 import hashlib
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -36,9 +35,8 @@ import pytest
 
 from horovod_tpu.core import bindings
 from horovod_tpu.controller.bucket_scheduler import BucketScheduler
+from mp_harness import run_script_ranks
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 QUANT_BLOCK = 4096  # kQuantBlock in ring.cc
 
 pytestmark = pytest.mark.skipif(
@@ -48,52 +46,12 @@ pytestmark = pytest.mark.skipif(
 PH_FUSE, PH_EXECUTE = 2, 3
 
 
-def _free_port():
-    import socket
-
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _run_two_rank(scenario, extra_env=None, timeout=180.0):
+def _run_two_rank(scenario, extra_env=None):
     """Spawn 2 ranks of this file's __main__ scenarios over a real TCP
     ring (the test_wire_compression harness); returns each rank's RESULT
     json."""
-    addrs = ",".join(f"127.0.0.1:{_free_port()}" for _ in range(2))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("HOROVOD_CYCLE_TIME", "1")
-    env.update(extra_env or {})
-    procs = [subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), scenario, str(rank),
-         "2", addrs],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        text=True) for rank in range(2)]
-    outs = []
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            raise AssertionError(f"{scenario}: rank {rank} hung")
-        outs.append(out)
-    results = []
-    for rank, (proc, out) in enumerate(zip(procs, outs)):
-        assert proc.returncode == 0, (
-            f"{scenario}: rank {rank} failed (exit {proc.returncode}):\n"
-            f"{out}")
-        payload = None
-        for line in out.splitlines():
-            if line.startswith("RESULT "):
-                payload = json.loads(line[len("RESULT "):])
-        assert payload is not None, f"{scenario}: no RESULT in:\n{out}"
-        results.append(payload)
-    return results
+    return run_script_ranks(os.path.abspath(__file__), scenario, 2,
+                            extra_env=extra_env)
 
 
 # ------------------------------------------------- fill-while-on-wire unit
@@ -192,7 +150,7 @@ def test_priority_tensor_completes_first_two_ranks():
             "HOROVOD_CYCLE_TIME": "300",
             "HOROVOD_FUSION_THRESHOLD": "4096",
             "HOROVOD_PIPELINE_TEST_DELAY_US": "50000",
-        }, timeout=240.0)
+        })
     for res in results:
         assert res["hi_ok"] and res["low_ok"], res
         # At the moment the priority tensor's wait() returned, at least

@@ -8,14 +8,13 @@ static×runtime join.
 import json
 import os
 import socket
-import subprocess
 import sys
-import time
 
 import pytest
 
 from mp_harness import (
     assert_protocheck_clean,
+    finish,
     free_port,
     launch_rank,
     protocheck_env,
@@ -290,16 +289,7 @@ def test_two_rank_job_is_conformant(tmp_path):
     procs = [launch_rank("allreduce", rank, 2, addr,
                          extra_env=protocheck_env(pc_dir))
              for rank in range(2)]
-    deadline = time.monotonic() + 120.0
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(
-                timeout=max(1.0, deadline - time.monotonic()))
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            raise AssertionError(f"rank {rank} hung")
-        assert proc.returncode == 0, f"rank {rank} failed:\n{out}"
+    finish(procs, 120.0, "allreduce")
     assert assert_protocheck_clean(pc_dir, "allreduce") == 2
     for rank in range(2):
         payload = json.loads(

@@ -88,8 +88,8 @@ def test_ring_attention_zigzag_gradient():
     def loss_ref(q, k, v):
         return (reference_attention(q, k, v, causal=True) ** 2).sum()
 
-    gr_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gr_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gr_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    gr_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr_ring, gr_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)
@@ -215,8 +215,8 @@ def test_ring_attention_flash_inner_gradient(path, monkeypatch):
     def loss_ref(q, k, v):
         return (reference_attention(q, k, v, causal=True) ** 2).sum()
 
-    gf = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)
@@ -266,8 +266,8 @@ def test_ring_attention_flash_zigzag_gradient():
     def loss_ref(q, k, v):
         return (reference_attention(q, k, v, causal=True) ** 2).sum()
 
-    gf = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-3, rtol=1e-3)

@@ -65,8 +65,8 @@ def test_ring_attention_gqa_gradient():
         return (reference_attention(q, k, v, causal=True)
                 .astype(jnp.float32) ** 2).sum()
 
-    g0 = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-    g1 = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    g0 = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+    g1 = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g0, g1):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=5e-4, rtol=1e-3)
@@ -132,10 +132,10 @@ def test_ring_attention_gqa_flash_inner(layout):
         out = reference_attention(q, k, v, causal=True)
         return (out.astype(jnp.float32) ** 2).sum(), out
 
-    (l0, out0), g0 = jax.value_and_grad(ring_loss, argnums=(0, 1, 2),
-                                        has_aux=True)(q, k, v)
-    (l1, out1), g1 = jax.value_and_grad(ref_loss, argnums=(0, 1, 2),
-                                        has_aux=True)(q, k, v)
+    (l0, out0), g0 = jax.jit(jax.value_and_grad(
+        ring_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (l1, out1), g1 = jax.jit(jax.value_and_grad(
+        ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1),
                                atol=2e-5, rtol=1e-4)
     for a, b_ in zip(g0, g1):

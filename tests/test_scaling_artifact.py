@@ -7,8 +7,9 @@ not the program's, and is not asserted (real numbers need chips)."""
 
 import json
 import os
-import subprocess
 import sys
+
+from mp_harness import run_cmd
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -16,12 +17,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_scaling_harness_curve_shape():
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    out = subprocess.run(
+    out = run_cmd(
         [sys.executable, os.path.join(REPO, "examples",
                                       "scaling_efficiency.py"),
          "--model", "mlp", "--steps", "5", "--warmup", "2",
          "--batch-per-chip", "32"],
-        env=env, capture_output=True, text=True, timeout=300)
+        timeout=180, env=env)
     assert out.returncode == 0, out.stderr[-2000:]
     record = json.loads(out.stdout.strip().splitlines()[-1])
 

@@ -66,7 +66,8 @@ SCFG = ServingConfig(max_batch=4, block_size=8, num_blocks=0,
 
 @pytest.fixture(scope="module")
 def tiny_variables():
-    return MODEL.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return jax.jit(MODEL.init)(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))
 
 
 @pytest.fixture(scope="module")

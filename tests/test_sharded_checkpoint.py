@@ -15,7 +15,7 @@ import time
 import numpy as np
 import pytest
 
-from mp_harness import run_ranks
+from mp_harness import child_env, run_cmd, run_ranks
 
 import horovod_tpu.elastic as elastic_mod
 from horovod_tpu.analysis import protocol
@@ -43,8 +43,6 @@ from horovod_tpu.utils.checkpoint import (
     write_manifest,
 )
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 
 SECRET = b"x" * 32
 
@@ -483,7 +481,6 @@ def test_state_construction_before_init_stays_local(tmp_path):
     """Review fix pin: commit() is purely local by contract — building
     (and committing) a State BEFORE hvd.init() must keep working, as it
     did pre-r15; only restore() needs the runtime."""
-    import subprocess
     import sys
 
     code = (
@@ -493,14 +490,11 @@ def test_state_construction_before_init_stays_local(tmp_path):
         "state.step = 5\n"
         "state.commit()\n"
         "print('PREINIT_OK', state._commit_world)\n")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     for scrub in ("HOROVOD_RANK", "HOROVOD_SIZE", "HOROVOD_CKPT_DIR",
                   "HOROVOD_CONTROLLER_ADDR"):
         env.pop(scrub, None)
-    res = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=120)
+    res = run_cmd([sys.executable, "-c", code], timeout=120, env=env)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "PREINIT_OK 1" in res.stdout
 
@@ -723,7 +717,7 @@ def test_elastic_ckpt_storm_with_slow_writer(tmp_path):
         {"site": "ckpt_save", "action": "delay", "at": 1, "times": 5,
          "seconds": 0.05, "rank": 1}]})
     outputs = run_ranks(
-        "elastic_ckpt_chaos_storm", size=3, timeout=200.0,
+        "elastic_ckpt_chaos_storm", size=3, timeout=180.0,
         extra_env={"HOROVOD_ELASTIC": "1", "HOROVOD_METRICS": "1",
                    "HOROVOD_CKPT_DIR": str(tmp_path)},
         per_rank_env={1: {"HOROVOD_FAULT_PLAN": join},

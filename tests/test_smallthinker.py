@@ -148,8 +148,8 @@ def test_held_layer_gradients_match_the_dense_form():
         return jnp.sum(_dense_experts(mine, x, weights[:, jnp.array(held)])
                        * target)
 
-    got = jax.grad(ours, argnums=(0, 1, 2))(mine, x, logits)
-    want = jax.grad(dense, argnums=(0, 1, 2))(mine, x, logits)
+    got = jax.jit(jax.grad(ours, argnums=(0, 1, 2)))(mine, x, logits)
+    want = jax.jit(jax.grad(dense, argnums=(0, 1, 2)))(mine, x, logits)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
 

@@ -6,10 +6,11 @@ for a pod: 2 processes x 2 virtual CPU devices, Gloo cross-process
 collectives."""
 
 import os
-import subprocess
 import sys
 
 import pytest
+
+from mp_harness import child_env, run_cmd
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -19,9 +20,7 @@ FAKE_SSH_DIR = os.path.join(HERE, "bin")
 
 
 def _env(ssh: bool = False, **extra):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
+    env = child_env()
     if ssh:
         # No sshd in this image: tests/bin/ssh executes the "remote"
         # command locally, so the launcher's whole remote path (preflight,
@@ -32,10 +31,10 @@ def _env(ssh: bool = False, **extra):
 
 
 def test_spmd_multihost_via_launcher():
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2", "--spmd",
          sys.executable, WORKER],
-        env=_env(), capture_output=True, text=True, timeout=240, cwd=REPO)
+        timeout=180, env=_env(), cwd=REPO)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "[0]: rank 0: spmd multihost" in res.stdout
     assert "[1]: rank 1: spmd multihost" in res.stdout
@@ -52,12 +51,11 @@ def test_remote_hosts_eager_ring_end_to_end():
     """horovodrun -H runsc:1,runsc:1 over (fake) ssh: preflight -> NIC
     discovery -> launch -> native TCP ring collectives -> shutdown
     (round-3 verdict item #6)."""
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
          "-H", "runsc:1,runsc:1", "--disable-cache",
          sys.executable, MP_WORKER, "allreduce"],
-        env=_env(ssh=True), capture_output=True, text=True, timeout=240,
-        cwd=REPO)
+        timeout=180, env=_env(ssh=True), cwd=REPO)
     assert res.returncode == 0, res.stdout + res.stderr
     for r in range(2):
         assert f"worker rank={r} scenario=allreduce: OK" in res.stdout
@@ -67,12 +65,11 @@ def test_remote_hosts_spmd_join_end_to_end():
     """--spmd over (fake) ssh: both ranks join one jax.distributed
     runtime (_maybe_init_jax_distributed) and train over the global
     4-device mesh."""
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
          "-H", "runsc:1,runsc:1", "--spmd", "--disable-cache",
          sys.executable, WORKER],
-        env=_env(ssh=True), capture_output=True, text=True, timeout=240,
-        cwd=REPO)
+        timeout=180, env=_env(ssh=True), cwd=REPO)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "devices=4 OK" in res.stdout
 
@@ -80,13 +77,12 @@ def test_remote_hosts_spmd_join_end_to_end():
 def test_remote_hosts_mixed_local_remote():
     """One local + one 'remote' entry: local rank spawns directly, remote
     rides ssh; the ring spans both spawn paths."""
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
          "-H", "localhost:1,runsc:1", "--disable-cache",
          "--disable-nic-discovery",
          sys.executable, MP_WORKER, "broadcast"],
-        env=_env(ssh=True), capture_output=True, text=True, timeout=240,
-        cwd=REPO)
+        timeout=180, env=_env(ssh=True), cwd=REPO)
     assert res.returncode == 0, res.stdout + res.stderr
     for r in range(2):
         assert f"worker rank={r} scenario=broadcast: OK" in res.stdout
@@ -95,12 +91,11 @@ def test_remote_hosts_mixed_local_remote():
 def test_preflight_failure_fails_fast():
     """Unreachable host (ssh exit 255): the launcher must abort with the
     preflight error naming the host, before spawning any rank."""
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
          "-H", "runsc:1,runsc:1", "--disable-cache",
          sys.executable, MP_WORKER, "allreduce"],
-        env=_env(ssh=True, FAKE_SSH_FAIL="1"), capture_output=True,
-        text=True, timeout=120, cwd=REPO)
+        timeout=120, env=_env(ssh=True, FAKE_SSH_FAIL="1"), cwd=REPO)
     assert res.returncode != 0
     err = res.stdout + res.stderr
     assert "ssh preflight failed" in err and "runsc" in err

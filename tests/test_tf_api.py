@@ -1,6 +1,6 @@
 """TF/Keras adapter, single-process semantics (reference test_tensorflow.py /
 test_keras.py size-independent parts). Cross-rank behavior: "tensorflow"
-scenario in tests/test_multiprocess.py."""
+scenario in tests/test_multiprocess_frameworks.py."""
 
 import numpy as np
 import pytest

@@ -5,7 +5,7 @@ coverage: the ops here are real graph nodes (AsyncOpKernels enqueueing into
 the native engine, ``horovod_tpu/tensorflow/src/tf_ops.cc``), so unlike the
 ``tf.py_function`` fallback they must survive graph serialization. Engine
 runs at size 1 (ring skipped); cross-rank semantics live in
-``tests/test_multiprocess.py::test_tf_custom_op_two_ranks``.
+``tests/test_multiprocess_frameworks.py::test_tf_custom_op_two_ranks``.
 """
 
 import ctypes

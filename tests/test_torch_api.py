@@ -1,6 +1,7 @@
 """Torch adapter, single-process semantics (size-1 fast paths + optimizer
 wiring). Cross-rank behavior is covered by the "torch" scenario in
-tests/test_multiprocess.py (reference test/test_torch.py runs under mpirun)."""
+tests/test_multiprocess_frameworks.py (reference test/test_torch.py runs
+under mpirun)."""
 
 import numpy as np
 import pytest

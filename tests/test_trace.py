@@ -8,13 +8,13 @@ timebase; FaultPlan delay chaos naming the delayed rank).
 import json
 import os
 import socket
-import subprocess
 import sys
 import time
 
 import numpy as np  # noqa: F401  (parity with the other mp test modules)
 import pytest
 
+from mp_harness import run_cmd
 from mp_harness import run_ranks as _run_ranks
 
 from horovod_tpu import metrics
@@ -30,7 +30,6 @@ from horovod_tpu.trace import (
 )
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 GOLDEN = os.path.join(HERE, "golden", "merged_trace.golden")
 
 
@@ -441,12 +440,10 @@ def test_wire_clock_ping_pong_roundtrip():
 
 def test_tools_straggler_cli_merges_and_reports(tmp_path):
     _write_golden_inputs(tmp_path)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    res = subprocess.run(
+    res = run_cmd(
         [sys.executable, "-m", "horovod_tpu.tools.straggler",
          str(tmp_path)],
-        env=env, capture_output=True, text=True, timeout=120)
+        timeout=120)
     assert res.returncode == 0, res.stdout + res.stderr
     report = json.loads(res.stdout)
     assert report["collectives"] == 2
@@ -457,10 +454,10 @@ def test_tools_straggler_cli_merges_and_reports(tmp_path):
     assert os.path.exists(os.path.join(str(tmp_path),
                                        "straggler_report.json"))
     assert os.path.exists(os.path.join(str(tmp_path), "merged_trace.json"))
-    res2 = subprocess.run(
+    res2 = run_cmd(
         [sys.executable, "-m", "horovod_tpu.tools.straggler",
          str(tmp_path / "nothing-here")],
-        env=env, capture_output=True, text=True, timeout=120)
+        timeout=120)
     assert res2.returncode != 0
 
 
@@ -528,20 +525,28 @@ def test_three_rank_run_produces_merged_trace_and_report(tmp_path):
 def test_chaos_delay_rule_names_the_delayed_rank(tmp_path):
     """Acceptance: a FaultPlan delay on rank 1's wire_send makes the
     straggler report AND hvd_straggler_cycles_total name rank 1 with
-    nonzero slack."""
+    nonzero slack. EVERY send of rank 1 after the clock handshake (its
+    first four: a delay there skews the offset the arrivals are corrected
+    by) is held 50 ms (26 collectives, under 2 s in all), so that rank 1
+    arrives last in all but one or two
+    cycles on an idle box and the counts still name it when the box holds
+    another rank up for a cycle or two; what one stall of another rank can
+    take is the single worst collective, so that is read as a majority of
+    the worst five (the flap under load: CHANGES.md, PR 39)."""
     trace_dir = tmp_path / "trace"
     outs = _run_ranks("trace", size=3, timeout=180.0, extra_env={
         "HOROVOD_TRACE_DIR": str(trace_dir),
         "HOROVOD_METRICS": "1",
         "HOROVOD_FAULT_PLAN": json.dumps({"seed": 3, "faults": [
             {"site": "wire_send", "action": "delay", "at": 5,
-             "times": 40, "seconds": 0.05, "rank": 1}]}),
+             "times": 400, "seconds": 0.05, "rank": 1}]}),
     })
     report = json.loads((trace_dir / "straggler_report.json").read_text())
     assert report["worst_rank"] == 1, report
     assert report["per_rank"]["1"]["straggler_cycles"] >= 3, report
     assert report["slack_max_seconds"] >= 0.03, report
-    assert report["worst_collectives"][0]["straggler"] == 1
+    assert sum(w["straggler"] == 1
+               for w in report["worst_collectives"][:5]) >= 3, report
     assert report["per_rank"]["1"]["lateness_max_seconds"] >= 0.03
     snap = _parse_snapshot(outs[0])
     cycles = dict((tuple(k), v) for k, v in

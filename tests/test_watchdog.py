@@ -3,12 +3,11 @@ rank reaps itself (reference ``spark/task/mpirun_exec_fn.py:25-35``)."""
 
 import os
 import signal
-import subprocess
 import sys
 import time
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
+from mp_harness import child_env, run_cmd, spawn
+
 
 _PARENT = r"""
 import subprocess, sys, time
@@ -18,6 +17,7 @@ import horovod_tpu.run.watchdog as w
 if not %r:
     w._set_pdeathsig = lambda s: False  # poll-thread-only path
 assert w.install(poll_interval=0.2, grace=1.0)
+print("armed", flush=True)
 import time
 time.sleep(120)
 ''' % prctl_ok
@@ -25,6 +25,12 @@ time.sleep(120)
 # diagnostic write hits a broken pipe and must still reap the child.
 child = subprocess.Popen([sys.executable, "-c", body],
                          stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+# The order of events under test: the watchdog is armed against THIS
+# parent, and then the parent dies. A child still importing when its
+# parent is killed arms against whoever adopted it and watches that
+# (the flap of a loaded box: CHANGES.md, PR 39), so the pid goes out only
+# once the child has said it is armed.
+assert child.stdout.readline().strip() == b"armed"
 print(child.pid, flush=True)
 time.sleep(120)
 """
@@ -43,21 +49,21 @@ import pytest
 
 @pytest.mark.parametrize("layer", ["prctl", "poll"])
 def test_orphaned_child_reaps_itself(layer):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    parent = subprocess.Popen([sys.executable, "-c", _PARENT, layer],
-                              env=env, stdout=subprocess.PIPE, text=True)
+    parent = spawn([sys.executable, "-c", _PARENT, layer], stderr=None)
     try:
         child_pid = int(parent.stdout.readline())
         assert _alive(child_pid)
         # SIGKILL: no cleanup chance — the exact orphaning the watchdog
         # exists for.
         parent.send_signal(signal.SIGKILL)
-        parent.wait(timeout=10)
-        deadline = time.monotonic() + 15.0
+        parent.wait(timeout=60)
+        # The child reaps itself within a poll or two (0.2 s) on an idle
+        # box; the deadline is what a child that never does costs, and it
+        # holds with every core of the box compiling beside the test.
+        deadline = time.monotonic() + 60.0
         while _alive(child_pid):
             assert time.monotonic() < deadline, (
-                "orphaned child still alive 15s after its parent died")
+                "orphaned child still alive 60s after its parent died")
             time.sleep(0.2)
     finally:
         if parent.poll() is None:
@@ -71,16 +77,12 @@ def test_orphaned_child_reaps_itself(layer):
 def _probe(env_value):
     """maybe_install_from_env() in a throwaway interpreter (arming a
     watchdog inside the pytest process would watch pytest's own parent)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("HOROVOD_PARENT_WATCHDOG", None)
-    if env_value is not None:
-        env["HOROVOD_PARENT_WATCHDOG"] = env_value
-    out = subprocess.run(
+    env = child_env({"HOROVOD_PARENT_WATCHDOG": env_value})
+    out = run_cmd(
         [sys.executable, "-c",
          "from horovod_tpu.run.watchdog import maybe_install_from_env;"
          "print(maybe_install_from_env())"],
-        env=env, capture_output=True, text=True, timeout=60)
+        timeout=60, env=env)
     assert out.returncode == 0, out.stderr
     return out.stdout.strip()
 
@@ -105,10 +107,7 @@ if pid == 0:  # child: no watchdog thread survived the fork
 _, status = os.waitpid(pid, 0)
 sys.exit(os.waitstatus_to_exitcode(status))
 """
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    res = subprocess.run([sys.executable, "-c", body], env=env,
-                         capture_output=True, text=True, timeout=60)
+    res = run_cmd([sys.executable, "-c", body], timeout=60)
     assert res.returncode == 0, res.stdout + res.stderr
 
 

@@ -24,8 +24,6 @@ import hashlib
 import json
 import os
 import re
-import socket
-import subprocess
 import sys
 
 import ml_dtypes
@@ -33,6 +31,8 @@ import numpy as np
 import pytest
 
 from horovod_tpu.core import bindings
+from mp_harness import free_port as _free_port
+from mp_harness import run_ring_ranks, run_script_ranks
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -42,48 +42,11 @@ pytestmark = pytest.mark.skipif(
     bindings.load() is None, reason="native core unavailable (no toolchain)")
 
 
-def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _run_ring_job(scenario, size, extra_env=None, timeout=180.0):
+def _run_ring_job(scenario, size, extra_env=None):
     """Spawn ``size`` ranks of this file's __main__ scenarios over a real
     TCP ring; returns each rank's RESULT json."""
-    addrs = ",".join(f"127.0.0.1:{_free_port()}" for _ in range(size))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.update(extra_env or {})
-    procs = [subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), scenario, str(rank),
-         str(size), addrs],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        text=True) for rank in range(size)]
-    outs = []
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            raise AssertionError(f"{scenario}: rank {rank} hung")
-        outs.append(out)
-    for rank, (proc, out) in enumerate(zip(procs, outs)):
-        assert proc.returncode == 0, (
-            f"{scenario}: rank {rank} failed (exit {proc.returncode}):\n"
-            f"{out}")
-    results = []
-    for out in outs:
-        payload = None
-        for line in out.splitlines():
-            if line.startswith("RESULT "):
-                payload = json.loads(line[len("RESULT "):])
-        assert payload is not None, f"{scenario}: no RESULT in:\n{out}"
-        results.append(payload)
-    return results
+    return run_script_ranks(os.path.abspath(__file__), scenario, size,
+                            extra_env=extra_env)
 
 
 # --------------------------------------------------------------- reference
@@ -245,38 +208,10 @@ def test_chunk_bytes_setter_clamps_and_rounds():
     assert bindings.wire_stats()["chunk_bytes"] == 256 * 1024
 
 
-def _run_engine_job(scenario, size, extra_env, timeout=120.0):
+def _run_engine_job(scenario, size, extra_env):
     """Full-stack job (mp_worker scenarios) with the ring data plane:
     rendezvous star + HOROVOD_RING_ADDRS, engine picked by extra_env."""
-    addr = f"127.0.0.1:{_free_port()}"
-    ring_addrs = ",".join(f"127.0.0.1:{_free_port()}" for _ in range(size))
-    procs = []
-    for rank in range(size):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["JAX_PLATFORMS"] = "cpu"
-        env.update({
-            "HOROVOD_RANK": str(rank), "HOROVOD_SIZE": str(size),
-            "HOROVOD_LOCAL_RANK": str(rank),
-            "HOROVOD_LOCAL_SIZE": str(size),
-            "HOROVOD_CONTROLLER_ADDR": addr,
-            "HOROVOD_RING_ADDRS": ring_addrs,
-        })
-        env.update(extra_env)
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "mp_worker.py"), scenario],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True))
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for p in procs:
-                p.kill()
-            raise AssertionError(f"{scenario}: rank {rank} hung")
-        assert proc.returncode == 0, (
-            f"{scenario}: rank {rank} failed (exit {proc.returncode}):\n"
-            f"{out}")
+    run_ring_ranks(scenario, size, timeout=120.0, extra_env=extra_env)
 
 
 @pytest.mark.parametrize("engine,wire", [
@@ -464,54 +399,24 @@ def test_per_link_wire_dtype_default_selection(monkeypatch):
         "local": "none", "ici": "none", "tcp": "int8", "dcn": "int8"}
 
 
-def _run_hier_native_job(scenario, extra_env, timeout=180.0):
+def _run_hier_native_job(scenario, extra_env):
     """4-rank 2x2 full-stack job on the NATIVE engine's two-level plane:
     per-rank local/cross env + group-specific local ring addresses, the
     exact surface hvd_eng_init reads."""
     hier = _hier_env()
     locals_by_group = hier["HVD_TEST_LOCAL_ADDRS"].split(";")
-    ring_addrs = ",".join(f"127.0.0.1:{_free_port()}" for _ in range(4))
-    procs = []
-    for rank in range(4):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["JAX_PLATFORMS"] = "cpu"
-        env.update({
-            "HOROVOD_RANK": str(rank), "HOROVOD_SIZE": "4",
+    return run_script_ranks(
+        os.path.abspath(__file__), scenario, 4,
+        extra_env={"HOROVOD_SIZE": "4", "HOROVOD_LOCAL_SIZE": "2",
+                   "HOROVOD_CROSS_SIZE": "2",
+                   "HOROVOD_CROSS_RING_ADDRS": hier["HVD_TEST_CROSS_ADDRS"],
+                   "HOROVOD_HIERARCHICAL_ALLREDUCE": "1", **extra_env},
+        per_rank_env={rank: {
+            "HOROVOD_RANK": str(rank),
             "HOROVOD_LOCAL_RANK": str(rank % 2),
-            "HOROVOD_LOCAL_SIZE": "2",
             "HOROVOD_CROSS_RANK": str(rank // 2),
-            "HOROVOD_CROSS_SIZE": "2",
-            "HOROVOD_RING_ADDRS": ring_addrs,
             "HOROVOD_LOCAL_RING_ADDRS": locals_by_group[rank // 2],
-            "HOROVOD_CROSS_RING_ADDRS": hier["HVD_TEST_CROSS_ADDRS"],
-            "HOROVOD_HIERARCHICAL_ALLREDUCE": "1",
-            "HOROVOD_CYCLE_TIME": "1",
-        })
-        env.update(extra_env)
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.abspath(__file__), scenario, str(rank),
-             "4", ring_addrs],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True))
-    results = []
-    for rank, proc in enumerate(procs):
-        try:
-            out, _ = proc.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            for pr in procs:
-                pr.kill()
-            raise AssertionError(f"{scenario}: rank {rank} hung")
-        assert proc.returncode == 0, (
-            f"{scenario}: rank {rank} failed (exit {proc.returncode}):\n"
-            f"{out}")
-        payload = None
-        for line in out.splitlines():
-            if line.startswith("RESULT "):
-                payload = json.loads(line[len("RESULT "):])
-        assert payload is not None, f"{scenario}: no RESULT in:\n{out}"
-        results.append(payload)
-    return results
+        } for rank in range(4)})
 
 
 def _check_hier_native_results(results):
@@ -776,6 +681,13 @@ def _child_hier_native(rank, size, addrs):
     topo = Topology(rank=rank, size=4, local_rank=rank % 2, local_size=2,
                     cross_rank=rank // 2, cross_size=2)
     ctl = NativeController(Config.from_env(), topo)
+    # Read while no rank can have shut down: every rank still owes the
+    # collectives below. After the last of them a rank that is done calls
+    # ``ctl.shutdown()``, the engines agree to stop, and the loop's exit
+    # takes the rings with it: a rank the box held up between its last
+    # ``wait`` and this read then saw False (the flap under load:
+    # CHANGES.md, PR 39).
+    hier_active = bool(ctl.hierarchical_active)
 
     # Exact-through-fusion payload: every 4096-block is the same integer
     # pattern with amax exactly 127, so each two-level stage quantizes
@@ -810,7 +722,7 @@ def _child_hier_native(rank, size, addrs):
     health = metrics.controller_health()
     stats = bindings.wire_stats()
     print("RESULT " + json.dumps({
-        "hier_active": bool(ctl.hierarchical_active),
+        "hier_active": hier_active,
         "fused_exact": fused_exact,
         "avg_rel_err": float(np.abs(avg - true).max() / np.abs(true).max()),
         "single_rel_err": single,
