@@ -64,6 +64,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "SCOPE_MOE_ROUTE", "SCOPE_MOE_DISPATCH", "SCOPE_MOE_EXPERTS",
            "SCOPE_MOE_COMBINE", "SCOPE_MOE_SHARED",
            "SCOPE_ATTN_FULL", "SCOPE_ATTN_WINDOW", "SCOPE_ATTN_POINTWISE",
+           "SCOPE_ATTN_LATENT", "SCOPE_ATTN_LATENT_PROJ", "SCOPE_MTP",
            "SCOPE_LINATTN_CONV", "SCOPE_LINATTN_SCAN", "SCOPE_LINATTN_GATE",
            "SCOPE_SHORTCONV", "SCOPE_SHORTCONV_POINTWISE",
            "SCOPE_LOSS_HEAD",
@@ -113,6 +114,20 @@ SCOPE_MOE_SHARED = "hvd.moe.shared"
 SCOPE_ATTN_FULL = "hvd.attn.full"
 SCOPE_ATTN_WINDOW = "hvd.attn.window"
 SCOPE_ATTN_POINTWISE = "hvd.attn.pointwise"
+
+#: Multi-head latent attention (``models/joyai.py`` ``LatentAttention``),
+#: forward and backward alike: the attention call on the expanded q, k and
+#: v (q and k 192 wide, v 128: the flash kernels land here by name); and
+#: everything else of the mixer but ``wo``: the two down-projections, their
+#: norms, the two up-projections, the rotation of the rotary parts and the
+#: copy of the one rotary key a token into every head.
+SCOPE_ATTN_LATENT = "hvd.attn.latent"
+SCOPE_ATTN_LATENT_PROJ = "hvd.attn.latent.proj"
+#: The multi-token-prediction module (``models/joyai.py``): its two norms
+#: and projection, its block, its norm and its pass through the main
+#: model's head (``joyai_lm_loss``), forward and backward alike. The
+#: block's own scopes (attention's, the expert layer's) lie inside it.
+SCOPE_MTP = "hvd.mtp"
 
 #: The parts of a gated delta-rule layer (``models/olmo_hybrid.py``
 #: ``LinearAttentionMixer`` over ``ops/linear_attention.py``), forward and

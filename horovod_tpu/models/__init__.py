@@ -27,6 +27,13 @@ from .llama import (  # noqa: F401
     token_nll,
 )
 from .inception import InceptionV3  # noqa: F401
+from .joyai import (  # noqa: F401
+    JOYAI_LLM_FLASH,
+    JOYAI_TINY,
+    JoyAIConfig,
+    JoyAILM,
+    joyai_lm_loss,
+)
 from .laguna import (  # noqa: F401
     LAGUNA_TINY,
     LAGUNA_XS2,

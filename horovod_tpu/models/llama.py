@@ -798,45 +798,50 @@ def causal_lm_loss(logits, input_ids):
     return token_nll(logits[:, :-1], input_ids[:, 1:]).mean()
 
 
-def _loss_chunks(hidden, head_kernel, input_ids, num_chunks):
+def _loss_chunks(hidden, head_kernel, input_ids, num_chunks, ahead):
     """The operands of one sweep over the sequence's chunks, chunk-major:
-    hidden states (n, B, c, D), shifted targets (n, B, c) and the head
-    kernel in ``hidden``'s dtype."""
+    hidden states (n, B, c, D), targets shifted by ``ahead`` (n, B, c) and
+    the head kernel in ``hidden``'s dtype."""
     b, s, d = hidden.shape
     c = s // num_chunks
-    # Shifted targets over the FULL sequence; the final position has no
-    # next token — it wraps to a garbage value and is masked out.
-    targets = jnp.concatenate([input_ids[:, 1:], input_ids[:, :1]], axis=1)
+    # Shifted targets over the FULL sequence; the final ``ahead`` positions
+    # have no target: they wrap to garbage values and are masked out.
+    targets = jnp.concatenate([input_ids[:, ahead:], input_ids[:, :ahead]],
+                              axis=1)
     h = hidden.reshape(b, num_chunks, c, d).transpose(1, 0, 2, 3)
     t = targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
     return h, t, head_kernel.astype(hidden.dtype)
 
 
-def _mean_nll(nll, b, s):
-    """The mean over every position but each sequence's last, of the
-    chunk-major (n, B, c) per-token nll."""
-    return nll.transpose(1, 0, 2).reshape(b, s)[:, :-1].mean()
+def _mean_nll(nll, b, s, ahead):
+    """The mean over every position that has a target (all but each
+    sequence's last ``ahead``), of the chunk-major (n, B, c) per-token
+    nll."""
+    return nll.transpose(1, 0, 2).reshape(b, s)[:, :-ahead].mean()
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_loss(hidden, head_kernel, input_ids, num_chunks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _chunked_loss(hidden, head_kernel, input_ids, num_chunks, ahead):
     # The call nobody differentiates: the loss alone, one product a chunk.
     with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
         b, s, _ = hidden.shape
-        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks)
+        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks,
+                               ahead)
         # Same matmul dtype as the in-model lm_head (MXU f32 accumulate).
         nll = jax.lax.map(lambda args: token_nll(args[0] @ w, args[1]),
                           (h, t))
-        return _mean_nll(nll, b, s)
+        return _mean_nll(nll, b, s, ahead)
 
 
-def _chunked_loss_fwd(hidden, head_kernel, input_ids, num_chunks):
+def _chunked_loss_fwd(hidden, head_kernel, input_ids, num_chunks, ahead):
     with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
         b, s, d = hidden.shape
-        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks)
+        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks,
+                               ahead)
         vocab = w.shape[1]
-        # d(mean)/d(nll) of every position: 0 at each sequence's last.
-        scale = (jnp.arange(s) < s - 1).astype(jnp.float32) / (b * (s - 1))
+        # d(mean)/d(nll) of every position: 0 where there is no target.
+        scale = (jnp.arange(s) < s - ahead).astype(jnp.float32) \
+            / (b * (s - ahead))
         scale = scale.reshape(num_chunks, 1, s // num_chunks)
 
         def chunk(dw, args):
@@ -859,11 +864,12 @@ def _chunked_loss_fwd(hidden, head_kernel, input_ids, num_chunks):
         dw, (nll, dh) = jax.lax.scan(
             chunk, jnp.zeros((d, vocab), jnp.float32), (h, t, scale))
         dh = dh.transpose(1, 0, 2, 3).reshape(b, s, d)
-        return _mean_nll(nll, b, s), (dh, dw.astype(head_kernel.dtype))
+        return _mean_nll(nll, b, s, ahead), (
+            dh, dw.astype(head_kernel.dtype))
 
 
-def _chunked_loss_bwd(num_chunks, residuals, g):
-    del num_chunks
+def _chunked_loss_bwd(num_chunks, ahead, residuals, g):
+    del num_chunks, ahead
     with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
         dh, dw = residuals
         return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
@@ -874,7 +880,7 @@ _chunked_loss.defvjp(_chunked_loss_fwd, _chunked_loss_bwd)
 
 
 def chunked_causal_lm_loss(hidden, head_kernel, input_ids,
-                           num_chunks: int = 8):
+                           num_chunks: int = 8, ahead: int = 1):
     """:func:`causal_lm_loss` with the lm_head fused in, applied one
     sequence chunk at a time in ONE sweep (a ``lax.scan``, one ``while``
     of the compiled step): the full (B, S, V) logits — and their
@@ -902,13 +908,21 @@ def chunked_causal_lm_loss(hidden, head_kernel, input_ids,
     float32 and, under bf16, to the rounding of the logits' cotangent
     (grad-norm deltas under 1%, ``tests/test_llama.py``). Called
     undifferentiated it computes the loss alone. Forward mode
-    (``jax.jvp``) is not defined."""
+    (``jax.jvp``) is not defined.
+
+    ``ahead``: how far ahead of a position its target lies. 1 is the next
+    token; a multi-token-prediction head at depth k passes k + 1
+    (``models/joyai.py``: 2), and the mean is over the ``S - ahead``
+    positions a sequence that have a target."""
     s = hidden.shape[1]
     if s % num_chunks:
         raise ValueError(
             f"chunked_causal_lm_loss: seq len {s} must be divisible by "
             f"num_chunks {num_chunks}")
-    return _chunked_loss(hidden, head_kernel, input_ids, num_chunks)
+    if not 1 <= ahead < s:
+        raise ValueError(
+            f"chunked_causal_lm_loss: ahead={ahead} must lie in [1, {s})")
+    return _chunked_loss(hidden, head_kernel, input_ids, num_chunks, ahead)
 
 
 def sp_causal_lm_loss(logits, input_ids, axis_name: str):

@@ -112,6 +112,11 @@ NEG_INF = -1e30
 
 
 def _check_gqa_heads(q, k, v, name: str) -> None:
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"{name}: q and k are contracted over one head width, got "
+            f"{q.shape[-1]} and {k.shape[-1]} (v's width, {v.shape[-1]}, "
+            "is its own: the output's)")
     if (v.shape[2] != k.shape[2]) or (q.shape[2] % k.shape[2]):
         raise ValueError(
             f"{name}: query heads ({q.shape[2]}) must be a multiple of "
@@ -176,9 +181,10 @@ def reference_attention(q, k, v, key_mask=None, causal=False,
                         window: Optional[int] = None):
     """Plain XLA attention; also the backward-path recompute.
 
-    Shapes: q (B, Sq, H, D); k/v (B, Sk, Hkv, D) with H % Hkv == 0
-    (grouped-query attention: K/V repeat across each group of
-    H // Hkv query heads); key_mask (B, Sk) bool. ``window`` (causal
+    Shapes: q (B, Sq, H, D); k (B, Sk, Hkv, D) and v (B, Sk, Hkv, Dv)
+    with H % Hkv == 0 (grouped-query attention: K/V repeat across each
+    group of H // Hkv query heads); the result is (B, Sq, H, Dv) and the
+    scale defaults to ``D ** -0.5``; key_mask (B, Sk) bool. ``window`` (causal
     only): the query at position i sees the keys ``i - window < j <= i``,
     itself and the ``window - 1`` before it."""
     d = q.shape[-1]
@@ -630,13 +636,17 @@ def _one_tile_heads(sq: int, sk: int, d: int, itemsize: int, group: int,
     return heads
 
 
-def _one_tile_path(q, k, block_q: int, block_k: int) -> int:
+def _one_tile_path(q, k, block_q: int, block_k: int, v=None) -> int:
     """``_one_tile_heads`` for (B, S, H, D) operands whose fitted blocks
     cover both sequence axes, else 0: the one place ``_flash_forward`` and
-    ``_flash_backward`` decide the path, so they cannot disagree."""
+    ``_flash_backward`` decide the path, so they cannot disagree. Where
+    ``v``'s head width is another than q's and k's, the rule sizes every
+    block at the wider of the two."""
     (_, sq, h, d), (_, sk, hkv, _) = q.shape, k.shape
     if (block_q, block_k) != (sq, sk):
         return 0
+    if v is not None:
+        d = max(d, v.shape[-1])
     return _one_tile_heads(sq, sk, d, q.dtype.itemsize, h // hkv, hkv)
 
 
@@ -687,7 +697,8 @@ def _one_tile_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                          window: Optional[int] = None):
     # Blocks, every operand with its sequence on the lanes: q/o
     # (1, heads*group*d, sq), k/v (1, heads*d, sk), a head the (d, s) band
-    # of rows ``_head`` slices; bias (1, 1, sk), lse (heads*group, 1, sq).
+    # of rows ``_head`` slices (d read from each block: v and o may be
+    # another width than q and k); bias (1, 1, sk), lse (heads*group, 1, sq).
     # Query head kh*group + j reads K/V head kh.
     # The tile is held TRANSPOSED, (sk, sq): the softmax statistics then
     # reduce over sublanes (plain VPU maxima and sums, no cross-lane
@@ -788,7 +799,9 @@ def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
     ``heads`` a step, each with its ``h // hkv`` query heads. ``ins`` are
     ``(kind, array)`` pairs and ``outs`` kinds; the kind gives the block:
     "q" the step's query heads, a (heads * group * d, sq) band of rows;
-    "k" its K/V heads, a (heads * d, sk) band; "row" a query head's
+    "k" its K/V heads, a (heads * d, sk) band; "o" and "v" the same two
+    bands at v's head width (o, do; v, dv), which may be another than
+    q's and k's; "row" a query head's
     (1, sq) f32 statistic, of a (B * H, 1, sq) array; "mask" a batch
     row's (1, sk) additive key mask. ``window`` reaches the kernel only
     where it can cut something (sk > window), so a call it cannot touch
@@ -796,9 +809,14 @@ def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
     arrays = dict(ins)
     (b, hd, sq), (_, kvd, sk) = arrays["q"].shape, arrays["k"].shape
     group, steps = h // hkv, hkv // heads
+    # v's and o's rows: k's and q's at v's head width.
+    vd = arrays["v"].shape[1]
+    od = hd * vd // kvd
     specs = {
         "q": pl.BlockSpec((1, hd // steps, sq), lambda n, i: (n, i, 0)),
         "k": pl.BlockSpec((1, kvd // steps, sk), lambda n, i: (n, i, 0)),
+        "o": pl.BlockSpec((1, od // steps, sq), lambda n, i: (n, i, 0)),
+        "v": pl.BlockSpec((1, vd // steps, sk), lambda n, i: (n, i, 0)),
         "row": pl.BlockSpec((heads * group, 1, sq),
                             lambda n, i: (n * steps + i, 0, 0)),
         "mask": pl.BlockSpec((1, 1, sk), lambda n, i: (n, 0, 0)),
@@ -806,6 +824,8 @@ def _one_tile_call(kernel, name, ins, outs, *, heads, h, hkv, scale, causal,
     shapes = {
         "q": jax.ShapeDtypeStruct((b, hd, sq), arrays["q"].dtype),
         "k": jax.ShapeDtypeStruct((b, kvd, sk), arrays["k"].dtype),
+        "o": jax.ShapeDtypeStruct((b, od, sq), arrays["q"].dtype),
+        "v": jax.ShapeDtypeStruct((b, vd, sk), arrays["v"].dtype),
         "row": jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
     }
     banded = ({"window": window}
@@ -833,7 +853,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
                    interpret, has_mask: bool = True,
                    window: Optional[int] = None):
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
@@ -843,13 +863,13 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             f"blocks ({block_q},{block_k}); pad to a block multiple")
 
     maskf = _mask_rows(key_mask, b, sk)
-    heads = _one_tile_path(q, k, block_q, block_k)
+    heads = _one_tile_path(q, k, block_q, block_k, v)
     if heads:
         out, lse = _one_tile_call(
             _one_tile_fwd_kernel, profiler.KERNEL_FLASH_FWD,
             [("q", _heads_to_rows(q)), ("k", _heads_to_rows(k)),
-             ("k", _heads_to_rows(v)), ("mask", _mask_bias(maskf))],
-            ["q", "row"], heads=heads, h=h, hkv=hkv, scale=scale,
+             ("v", _heads_to_rows(v)), ("mask", _mask_bias(maskf))],
+            ["o", "row"], heads=heads, h=h, hkv=hkv, scale=scale,
             causal=causal, has_mask=has_mask, interpret=interpret,
             window=window)
         return _rows_to_heads(out, h), lse
@@ -871,23 +891,23 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, dv),
                          lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh, i, j: (mask_row(bh), 0, keys.tile(i, j))),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         name=profiler.KERNEL_FLASH_FWD,
@@ -1009,7 +1029,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
                     block_q, block_k, interpret, dlse=None,
                     has_mask: bool = True, window: Optional[int] = None):
     b, sq, h, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv = k.shape[1], k.shape[2], v.shape[3]
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
@@ -1028,11 +1048,11 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         # since d lse_i / d s_ij = p_ij. dv is unaffected.
         delta = delta - dlse.reshape(b * h, 1, sq).astype(jnp.float32)
 
-    heads = _one_tile_path(q, k, block_q, block_k)
+    heads = _one_tile_path(q, k, block_q, block_k, v)
     if heads:
         ins = [("q", _heads_to_rows(q)), ("k", _heads_to_rows(k)),
-               ("k", _heads_to_rows(v)), ("mask", _mask_bias(maskf)),
-               ("q", _heads_to_rows(g)), ("row", lse), ("row", delta)]
+               ("v", _heads_to_rows(v)), ("mask", _mask_bias(maskf)),
+               ("o", _heads_to_rows(g)), ("row", lse), ("row", delta)]
         static = dict(heads=heads, h=h, hkv=hkv, scale=scale, causal=causal,
                       has_mask=has_mask, interpret=interpret, window=window)
         dq, = _one_tile_call(_one_tile_bwd_dq_kernel,
@@ -1040,7 +1060,7 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
                              **static)
         dk, dv = _one_tile_call(_one_tile_bwd_dkv_kernel,
                                 profiler.KERNEL_FLASH_BWD_DKV, ins,
-                                ["k", "k"], **static)
+                                ["k", "v"], **static)
         return (_rows_to_heads(dq, h), _rows_to_heads(dk, hkv),
                 _rows_to_heads(dv, hkv))
 
@@ -1058,11 +1078,11 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, dv),
                          lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh, i, j: (mask_row(bh), 0, keys.tile(i, j))),
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
@@ -1099,10 +1119,10 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
             pl.BlockSpec((1, block_q, d),
                          lambda bh, j, t: (q_row(bh, t), q_tile(j, t), 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, j, t: (bh, j, 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh, j, t: (bh // hkv, 0, j)),
-            pl.BlockSpec((1, block_q, d),
+            pl.BlockSpec((1, block_q, dv),
                          lambda bh, j, t: (q_row(bh, t), q_tile(j, t), 0)),
             pl.BlockSpec((1, 1, block_q),
                          lambda bh, j, t: (q_row(bh, t), 0, q_tile(j, t))),
@@ -1111,15 +1131,15 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, j, t: (bh, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * hkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hkv, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         name=profiler.KERNEL_FLASH_BWD_DKV,

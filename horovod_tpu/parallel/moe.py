@@ -458,18 +458,20 @@ def softmax_top_k(gate_logits: jax.Array, num_selected: int
     return top_ids, jax.nn.softmax(top_logits, axis=-1)
 
 
-#: In the normalisation of :func:`sigmoid_top_k`'s weights.
+#: In the normalisation of :func:`sigmoid_top_k`'s weights, where the
+#: model names no other.
 _WEIGHT_SUM_EPS = 1e-6
 
 
-def sigmoid_top_k(bias: jax.Array) -> Callable:
+def sigmoid_top_k(bias: jax.Array, eps: float = _WEIGHT_SUM_EPS) -> Callable:
     """A routing rule for :func:`moe_apply_held` whose choice is made on
     one array and whose weights are taken from another: the scores are
     ``s = sigmoid(gate_logits)``; a token chooses the ``num_selected``
     largest of ``s + bias`` (``bias`` ``[E]``, one float an expert, as an
     auxiliary-loss-free balancing rule keeps one: it enters the CHOICE
     only, takes no gradient and gives none); the weights are the chosen
-    experts' own ``s`` over their sum plus 1e-6."""
+    experts' own ``s`` over their sum plus ``eps``, which is the model's
+    (1e-6 by default, 1e-20 in ``models/joyai.py``)."""
     def rule(gate_logits, num_selected):
         scores = jax.nn.sigmoid(gate_logits)
         _, top_ids = jax.lax.top_k(
@@ -483,7 +485,7 @@ def sigmoid_top_k(bias: jax.Array) -> Callable:
         weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0),
                           axis=-1)
         return top_ids, weights / (
-            jnp.sum(weights, axis=-1, keepdims=True) + _WEIGHT_SUM_EPS)
+            jnp.sum(weights, axis=-1, keepdims=True) + eps)
 
     return rule
 

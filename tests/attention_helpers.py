@@ -67,7 +67,8 @@ def assert_matches_reference(flash, ref, q, k, v, *, cot=None, shared=None):
     want, want_grads = results[key]
     out, grads = out_and_grads(flash, q, k, v, cot)
     f32 = q.dtype == jnp.float32
-    assert out.dtype == q.dtype and out.shape == q.shape
+    # The output is as wide as v, which may be another width than q and k.
+    assert out.dtype == q.dtype and out.shape == q.shape[:-1] + v.shape[-1:]
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
         atol=2e-5 if f32 else 2e-2, rtol=1e-4 if f32 else 2e-2)

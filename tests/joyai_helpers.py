@@ -1,0 +1,99 @@
+"""What the JoyAI test files share: the tiny configuration, the
+benchmark's plain reference loaded by path, and seeded weights at scales
+where every path matters."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from horovod_tpu.models import JOYAI_TINY, JoyAILM
+from model_helpers import jit_init
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SEQ = 96
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The benchmark's reference file, loaded by path (its name holds
+    ``-``) with ``benchmarks`` on the path for its own import."""
+    import sys
+
+    bench = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "joyai_reference", os.path.join(
+                bench, "reference", "joyai-llm-flash.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.remove(bench)
+    return module
+
+
+def _config(held=None, **over):
+    return dataclasses.replace(JOYAI_TINY, dtype=jnp.float32,
+                               experts_held=held, **over)
+
+
+def _reference_config(cfg, mtp_weight=0.0, **optimizer):
+    """The model's sizes under the keys the configuration file has."""
+    return {
+        "num_layers": cfg.num_layers, "rms_norm_eps": cfg.norm_eps,
+        "first_k_dense_replace": cfg.num_dense_layers,
+        "num_nextn_predict_layers": cfg.mtp_layers,
+        "kv_lora_rank": cfg.kv_lora_rank,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim,
+        "rope_theta": cfg.rope_theta,
+        "num_experts_per_tok": cfg.num_selected,
+        "routed_scaling_factor": cfg.routed_scale,
+        "mtp_loss_weight": mtp_weight,
+        "deployment": {"experts_held": list(cfg.held())},
+        "optimizer": optimizer,
+    }
+
+
+def _blocks(params):
+    """The parameter trees of the blocks that may route: the layers' and
+    the module's."""
+    return [params[n] for n in sorted(params) if n.startswith("layer_")] \
+        + [params["mtp"]["block"]]
+
+
+def _share(params, held):
+    """``params`` of the model that holds every routed expert, cut to
+    ``held``; what every chip holds alike is left whole."""
+    out = jax.tree.map(lambda x: x, params)
+    for block in _blocks(out):
+        for w in ("w_gate", "w_up", "w_down"):
+            if w in block:
+                block[w] = {"kernel": block[w]["kernel"][
+                    jnp.array(held, jnp.int32)]}
+    return out
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    cfg = _config()
+    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
+                             cfg.vocab_size)
+    params = jit_init(JoyAILM(cfg), ids, rngs=jax.random.PRNGKey(3))[
+        "params"]
+
+    # Scales at which every path matters: a router that decides, a bias
+    # that moves the choice for some tokens and not for all, mixers and
+    # experts of the residual's own size.
+    def scaled(path, x):
+        names = {str(getattr(k, "key", k)) for k in path}
+        if "router" in names:
+            return x * 25.0
+        if "expert_bias" in names:
+            return x * 10.0
+        return x * 3.0 if x.ndim > 1 else x
+
+    return ids, jax.tree_util.tree_map_with_path(scaled, params)
