@@ -537,7 +537,9 @@ def phase_window_and_experts(sz, rehearsal):
     expert width 768) against every held expert applied densely in
     float32, once as a seeded router spreads the tokens and once with
     every token forced onto the held experts (PR 27: nothing dropped
-    where every sorted row belongs to a group). Both in bf16: a few ulps of bf16 of the largest value, and
+    where every sorted row belongs to a group), and that once more with
+    the 4 held of 32 (PR 41: an eighth held, so the rows are walked in
+    chunks, all of them live). Both in bf16: a few ulps of bf16 of the largest value, and
     never more than 2^-5 of it; the expert layer's count of what landed
     here must be the count of chosen ids that are held."""
     import re
@@ -640,10 +642,16 @@ def phase_window_and_experts(sz, rehearsal):
 
     dense_layer, held_layer = jax.jit(dense_layer), jax.jit(held_layer)
     # As the seeded router spreads them, a quarter lands here; with the
-    # held experts' logits raised every assignment does.
+    # held experts' logits raised every assignment does. The same under a
+    # router 32 wide: an eighth of the experts held, where the combine and
+    # the gradients of both row movements walk the landed rows in chunks
+    # (PR 41), and every chunk holds one.
+    wide = rand(tokens, 2 * experts, dtype=jnp.float32)
     for what, logits in (("a router's spread", logits),
                          ("every token forced here",
-                          logits.at[:, jnp.array(held)].add(20.0))):
+                          logits.at[:, jnp.array(held)].add(20.0)),
+                         ("every token forced here",
+                          wide.at[:, jnp.array(held)].add(20.0))):
         with jax.default_matmul_precision("highest"):
             want = timed(dense_layer, params, x.astype(jnp.float32), logits)
         *got, load = timed(held_layer, params, x, logits)
@@ -655,7 +663,7 @@ def phase_window_and_experts(sz, rehearsal):
         err = worst(got, want)
         checks.append(check(
             err <= tolerance,
-            f"experts {held} of {experts}, {what}: {landed} of "
+            f"experts {held} of {logits.shape[-1]}, {what}: {landed} of "
             f"{tokens * chosen} assignments here: y/dw/dx/dlogits within "
             f"{err:.2e} of max|reference|"))
     return {"compile_s": timed.compile_s, "run_s": timed.run_s,

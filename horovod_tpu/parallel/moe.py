@@ -35,6 +35,7 @@ exchanges; the exchange is not in it. ``MoeLM`` keeps the capacity path:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple, Sequence, Tuple
 
 import jax
@@ -382,42 +383,150 @@ class _Places(NamedTuple):
     live: jax.Array     # [k*T] whether the row belongs to a group
     place: jax.Array    # [k, T] the sorted row of each assignment
     here: jax.Array     # [k, T] whether the assignment landed here
+    landed: jax.Array   # [] how many rows belong to a group: the first
 
 
-@jax.custom_vjp
-def _rows_of_tokens(x, at):
+# ---------------------------------------------------------------------------
+# The two movements of rows, each with its gradient written by hand. Both
+# are sized for the worst case, every token choosing only experts held
+# here: ``k x T`` rows each way. On a device that holds a small part of
+# the experts most of those rows belong to no group (94% at a sixteenth
+# held), and the sort keeps the landed rows first. So where
+# :func:`walk_chunk` gives a chunk, the three rules that would gather and
+# mask all rows (dispatch backward, combine forward and backward) walk
+# the sorted order in static chunks instead and stop after the last
+# chunk that holds a landed row: a ``while`` whose trip count the device
+# reads from ``landed``, nothing chosen by a branch, the experts called
+# once on the buffer they had. If everything lands here they walk every
+# chunk: exact under any imbalance, as the one-pass rules are.
+
+
+def walk_chunk(rows: int, n_held: int, num_experts: int) -> int:
+    """Rows a chunk when :func:`moe_apply_held` walks a sorted order of
+    ``rows`` (tokens x chosen) on a device that holds ``n_held`` of
+    ``num_experts`` experts, or 0 where it does not walk but moves all
+    rows at once: above an eighth of the experts held. (At a quarter
+    held the whole prize was 0.7% of a step, and a router that collapses
+    onto the held experts makes every chunk live: a walked row adds into
+    its token by a scatter, at three times a gathered row's cost;
+    ``PERF.md`` section 6, PRs 27 and 41.) A chunk is a quarter of the
+    even share ``rows x n_held / num_experts``, in whole tiles of 8
+    rows: an even router walks five chunks at most, and less than a
+    quarter of a share for nothing."""
+    if 8 * n_held > num_experts:
+        return 0
+    quarter = max(1, -(-rows * n_held // (4 * num_experts)))
+    return min(rows, -(-quarter // 8) * 8)
+
+
+def chunks_walked(landed, chunk: int):
+    """How many chunks of ``chunk`` rows hold the ``landed`` first rows:
+    the trip count of every walk (a device scalar there; the benchmark's
+    ``moe_held_walked_pct`` calls it on the loads a step returned)."""
+    return (landed + chunk - 1) // chunk
+
+
+class _Chunk(NamedTuple):
+    """One chunk of the sorted order."""
+    start: jax.Array    # [] its first row
+    token: jax.Array    # [Q] ``_Places.token`` of its rows
+    mine: jax.Array     # [Q] ``_Places.mine`` of its rows
+    live: jax.Array     # [Q] whether the row belongs to a group
+    fresh: jax.Array    # [Q] and no earlier chunk held it
+
+
+def _walk(at, chunk, step, init):
+    """``step(_Chunk, carry) -> carry`` over the chunks that hold a
+    landed row, in order. The last chunk of an order that is no whole
+    number of chunks starts early enough to end with it and overlaps
+    the one before: what ``step`` writes there it writes twice, the
+    same; what it adds it adds where ``fresh``."""
+    size = at.token.shape[0]
+
+    def body(i, carry):
+        start = jnp.minimum(i * chunk, size - chunk)
+        rows = start + jnp.arange(chunk, dtype=jnp.int32)
+        live = rows < at.landed
+        return step(_Chunk(
+            start=start,
+            token=jax.lax.dynamic_slice(at.token, (start,), (chunk,)),
+            mine=jax.lax.dynamic_slice(at.mine, (start,), (chunk,)),
+            live=live, fresh=live & (rows >= i * chunk)), carry)
+
+    return jax.lax.fori_loop(0, chunks_walked(at.landed, chunk), body, init)
+
+
+def _rows_at(rows, c):
+    """The rows of chunk ``c`` of a sorted buffer."""
+    return jax.lax.dynamic_slice(
+        rows, (c.start, 0), (c.token.shape[0], rows.shape[1]))
+
+
+def _weights_at(weights, c):
+    """``[Q, 1]`` the weight of each row of chunk ``c``'s assignment."""
+    return weights.reshape(-1)[c.mine][:, None]
+
+
+def _walk_sum(rows, at, chunk, weights=None):
+    """``_gather_sum`` over the chunks that hold a landed row: each
+    landed row, times its assignment's weight where ``weights`` are
+    given, added into its token in float32 (the same terms in another
+    order), in ``rows``' dtype. What the other rows hold is not read for
+    its value."""
+    def add(c, acc):
+        terms = _rows_at(rows, c).astype(jnp.float32)
+        if weights is not None:
+            terms = terms * _weights_at(weights, c).astype(jnp.float32)
+        return acc.at[c.token].add(jnp.where(c.fresh[:, None], terms, 0))
+
+    return _walk(at, chunk, add, jnp.zeros(
+        (at.place.shape[1], rows.shape[1]), jnp.float32)).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of_tokens(chunk, x, at):
     """``[T, D]`` token rows -> the sorted rows: row i is token
-    ``at.token[i]``. Rows outside every group are read by no one."""
+    ``at.token[i]``. Rows outside every group are read by no one. One
+    gather whatever ``chunk``: from a source the chip keeps on chip it
+    runs at the speed of its writes, which a walk into a zeroed buffer
+    does not beat (``PERF.md`` section 6, PR 41)."""
     return x[at.token]
 
 
-def _rows_of_tokens_fwd(x, at):
+def _rows_of_tokens_fwd(chunk, x, at):
     return x[at.token], at
 
 
-def _rows_of_tokens_bwd(at, g):
+def _rows_of_tokens_bwd(chunk, at, g):
     # A token's rows add into it: as k gathers and a sum, not the
     # scatter-add autodiff would emit (row by row, and over three times a
-    # gather's cost on the v5e).
+    # gather's cost on the v5e); walked, as a scatter-add of the landed
+    # rows alone.
+    if chunk:
+        return _walk_sum(g, at, chunk), None
     return _gather_sum(g, at.place, at.here.astype(g.dtype)), None
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
-@jax.custom_vjp
-def _tokens_of_rows(out, weights, at):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _tokens_of_rows(chunk, out, weights, at):
     """The weighted sum back into token order: ``y[t]`` adds
     ``weights[j, t] * out[at.place[j, t]]`` over token t's assignments
     that are ``at.here``."""
+    if chunk:
+        return _walk_sum(out, at, chunk, weights)
     return _gather_sum(out, at.place, jnp.where(at.here, weights, 0))
 
 
-def _tokens_of_rows_fwd(out, weights, at):
-    return _tokens_of_rows(out, weights, at), (out, weights, at)
+def _tokens_of_rows_fwd(chunk, out, weights, at):
+    return _tokens_of_rows(chunk, out, weights, at), (out, weights, at)
 
 
-def _tokens_of_rows_bwd(res, dy):
+def _tokens_of_rows_bwd(chunk, res, dy):
+    if chunk:
+        return _walked_tokens_of_rows_bwd(chunk, res, dy)
     out, weights, at = res
     live = at.live[:, None]
     g = dy[at.token]
@@ -426,6 +535,31 @@ def _tokens_of_rows_bwd(res, dy):
         live, g.astype(jnp.float32) * out.astype(jnp.float32), 0), axis=-1)
     d_weights = jnp.where(at.here, d_row[at.place], 0).astype(weights.dtype)
     return d_out, d_weights, None
+
+
+def _walked_tokens_of_rows_bwd(chunk, res, dy):
+    """The same three results chunk by chunk: ``d_out`` written in place
+    into zeros, so it is zero wherever a row is not live; a row's sum
+    set at its assignment (a sorted row is one assignment's: the indices
+    are unique)."""
+    out, weights, at = res
+
+    def step(c, carry):
+        d_out, d_row = carry
+        live, g = c.live[:, None], dy[c.token]
+        d_out = jax.lax.dynamic_update_slice(d_out, jnp.where(
+            live, g * _weights_at(weights, c), 0).astype(out.dtype),
+            (c.start, 0))
+        d_row = d_row.at[c.mine].set(jnp.sum(jnp.where(
+            live, g.astype(jnp.float32)
+            * _rows_at(out, c).astype(jnp.float32), 0), axis=-1),
+            unique_indices=True)
+        return d_out, d_row
+
+    d_out, d_row = _walk(at, chunk, step, (
+        jnp.zeros_like(out), jnp.zeros((at.mine.shape[0],), jnp.float32)))
+    return (d_out, d_row.reshape(weights.shape).astype(weights.dtype),
+            None)
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -529,7 +663,20 @@ def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
     token rows of assignments that landed elsewhere (not zeros), what
     ``expert_fn`` returns for them is not read, and the gradient it is
     handed for them is zero. So ``expert_fn`` works row by row: a row's
-    result hangs on that row and its expert's parameters alone."""
+    result hangs on that row and its expert's parameters alone.
+
+    The sort keeps the landed rows first. Where ``held`` is an eighth of
+    the router's width or less (:func:`walk_chunk`, from the two static
+    sizes: no option), the rules that bring rows back to their tokens
+    (the gradient of the dispatch, the combine and its gradient) walk the
+    sorted order in static chunks of a quarter of the even share and stop
+    after the last chunk that holds a landed row, instead of gathering
+    all ``T * num_selected`` rows and masking most away; the dispatch
+    itself stays one gather, and ``expert_fn`` is called once on the
+    buffer it had. The trip count is read on the device from ``load``: no
+    branch, and every chunk is walked if everything lands here, so the
+    result is as exact. Above an eighth held the program is the one-pass
+    form, with no loop in it."""
     tokens, _ = x.shape
     num_experts = gate_logits.shape[-1]
     held = tuple(int(e) for e in held)
@@ -563,12 +710,13 @@ def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
         landed = jnp.sum(load)
         at = _Places(mine=order, token=order % tokens,
                      live=jnp.arange(order.shape[0]) < landed,
-                     place=place, here=place < landed)
-        rows = _rows_of_tokens(x, at)
+                     place=place, here=place < landed, landed=landed)
+        chunk = walk_chunk(order.shape[0], n_held, num_experts)
+        rows = _rows_of_tokens(chunk, x, at)
 
     with jax.named_scope(profiler.SCOPE_MOE_EXPERTS):
         out = expert_fn(expert_params, rows, load)
 
     with jax.named_scope(profiler.SCOPE_MOE_COMBINE):
-        y = _tokens_of_rows(out, weights, at)
+        y = _tokens_of_rows(chunk, out, weights, at)
     return y, load

@@ -5,6 +5,10 @@ weights (the model against the benchmark's plain float32 reference is
 up to the whole layer; nothing is dropped under a router forced onto one
 expert, nor whatever part of the assignments lands on a share."""
 
+import functools
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +20,7 @@ from horovod_tpu.models.smallthinker import SmallThinkerBlock
 from horovod_tpu.ops.attention import make_attention_fn
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
+from model_helpers import jit_apply
 from smallthinker_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                                   _share, reference, seeded)
 
@@ -66,8 +71,8 @@ def test_parts_of_the_four_shares_add_up_to_the_whole_layer(seeded,
                                   window=cfg.sliding_window)
 
     def block(held, p):
-        out, load = SmallThinkerBlock(
-            _config(held), rope=True, attention_fn=window_fn).apply(
+        out, load = jit_apply(SmallThinkerBlock(
+            _config(held), rope=True, attention_fn=window_fn))(
             {"params": p}, x)
         return out[0], load
 
@@ -119,7 +124,8 @@ def test_nothing_is_dropped_when_every_token_goes_to_one_expert(held):
                       (tokens, 1))
     params = _experts(jax.random.PRNGKey(1), experts)
     mine = jax.tree.map(lambda w: w[jnp.array(held)], params)
-    y, load = moe_apply_held(grouped_gated_mlp, mine, x, logits, held, k)
+    y, load = jax.jit(lambda mine, x, logits: moe_apply_held(
+        grouped_gated_mlp, mine, x, logits, held, k))(mine, x, logits)
     chosen = jnp.zeros((tokens, experts)).at[:, jnp.array([3, 1])].set(
         jax.nn.softmax(jnp.array([5.0, 4.0])))
     here = jnp.zeros((experts,)).at[jnp.array(held)].set(1.0)
@@ -155,8 +161,9 @@ def test_held_layer_gradients_match_the_dense_form():
 
 
 def test_held_ids_are_checked():
-    x, logits = jnp.zeros((4, 16)), jnp.zeros((4, 6))
-    params = _experts(jax.random.PRNGKey(0), 2)
+    x, logits = np.zeros((4, 16), np.float32), np.zeros((4, 6), np.float32)
+    params = {name: np.zeros((2,) + shape, np.float32) for name, shape in (
+        ("w_gate", (16, 24)), ("w_up", (16, 24)), ("w_down", (24, 16)))}
     with pytest.raises(ValueError, match="distinct expert ids"):
         moe_apply_held(grouped_gated_mlp, params, x, logits, (1, 1), 2)
     with pytest.raises(ValueError, match="distinct expert ids"):
@@ -164,6 +171,16 @@ def test_held_ids_are_checked():
 
 
 HELD, EXPERTS, CHOSEN, TOKENS = (5, 2), 32, 2, 64
+# A sixteenth of the experts held: the combine and the gradients of both
+# movements walk the sorted order in chunks of this many rows (the even
+# share, 8 of 128).
+CHUNK = moe.walk_chunk(TOKENS * CHOSEN, len(HELD), EXPERTS)
+# The sigmoid rule's bias: it takes experts 8 to 11 out of the choice of
+# the tokens that would have chosen them (no held expert among them nor
+# among what they choose next, so the landed count stands) and moves the
+# normalisation of their weights.
+BIAS = (1e-4 * jax.random.normal(jax.random.PRNGKey(8), (EXPERTS,))
+        ).at[8:12].set(-0.5)
 
 
 def _landing(landed, tokens=TOKENS, seed=0):
@@ -183,48 +200,156 @@ def _landing(landed, tokens=TOKENS, seed=0):
     return jnp.asarray(rng.permutation(logits), jnp.float32)
 
 
-def _held_and_dense(logits, dtype=jnp.float32):
-    """``(y, load, grads)`` of the layer with the share ``HELD`` and of
-    the plain form, for the gradients of the matrices, ``x`` and the
-    logits."""
-    tokens = logits.shape[0]
-    x = jax.random.normal(jax.random.PRNGKey(2), (tokens, 16), dtype)
-    params = _experts(jax.random.PRNGKey(4), EXPERTS)
-    mine = jax.tree.map(lambda w: w[jnp.array(HELD)], params)
-    target = jax.random.normal(jax.random.PRNGKey(5), (tokens, 16))
+def _softmax_choice(logits):
+    top, ids = jax.lax.top_k(logits, CHOSEN)
+    return ids, jax.nn.softmax(top, -1)
 
-    def ours(mine, x, logits):
-        y, load = moe_apply_held(grouped_gated_mlp, mine, x, logits, HELD,
-                                 CHOSEN)
-        return jnp.sum(y * target), (y, load)
 
-    def dense(mine, x, logits):
-        top, ids = jax.lax.top_k(logits, CHOSEN)
+def _sigmoid_choice(logits):
+    """The sigmoid rule with ``BIAS`` in the choice, written out plainly."""
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + BIAS, CHOSEN)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    return ids, chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+
+
+RULES = {"softmax": (moe.softmax_top_k, _softmax_choice),
+         "sigmoid": (moe.sigmoid_top_k(BIAS), _sigmoid_choice)}
+
+
+def _poisoned_experts(params, rows, group_sizes):
+    """The grouped products, and NaN for every row past the groups: what
+    an ``expert_fn`` may return where it is read by no one."""
+    out = grouped_gated_mlp(params, rows, group_sizes)
+    return jnp.where((jnp.arange(rows.shape[0])
+                      < jnp.sum(group_sizes))[:, None], out, jnp.nan)
+
+
+def _value_and_grads(f):
+    """``((loss, (y, load)), grads)`` of ``f(mine, x, logits, target)``
+    for the gradients of the matrices, ``x`` and the logits, jitted."""
+    return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_program(rule):
+    """The plain form under a routing rule: every held expert on every
+    token in float32, weighed by the rule written out."""
+    choice = RULES[rule][1]
+
+    def dense(mine, x, logits, target):
+        ids, chosen = choice(logits)
         weights = jnp.zeros_like(logits).at[
-            jnp.arange(tokens)[:, None], ids].set(jax.nn.softmax(top, -1))
+            jnp.arange(logits.shape[0])[:, None], ids].set(chosen)
         y = _dense_experts(mine, x, weights[:, jnp.array(HELD)])
         return jnp.sum(y * target), (y, jnp.sum(
             ids[:, :, None] == jnp.array(HELD), axis=(0, 1)))
 
-    return [jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))(
-        mine, x, logits) for f in (ours, dense)]
+    return _value_and_grads(dense)
 
 
-@pytest.mark.parametrize("landed", [0, 13, 16, 17, 32, 33, TOKENS * CHOSEN],
-                         ids=["none", "a-share", "a-tile", "a-tile-and-a-row",
-                              "a-quarter", "a-quarter-and-a-row",
-                              "every-assignment"])
-def test_a_share_is_exact_whatever_lands(landed):
-    """2 of 32 experts held: an even router lands 8 of the 128
-    assignments here. Whatever lands, up to all of them, ``y``, ``load``
-    and every gradient are the dense form's."""
+@functools.lru_cache(maxsize=None)
+def _held_program(rule, expert_fn):
+    """The layer with the share ``HELD`` under a routing rule and an
+    ``expert_fn``."""
+    def ours(mine, x, logits, target):
+        y, load = moe_apply_held(expert_fn, mine, x, logits, HELD, CHOSEN,
+                                 route=RULES[rule][0])
+        return jnp.sum(y * target), (y, load)
+
+    return _value_and_grads(ours)
+
+
+def _held_and_dense(logits, rule="softmax", dtype=jnp.float32,
+                    expert_fn=grouped_gated_mlp):
+    """``[ours, dense]``, each ``((loss, (y, load)), grads)``. One jitted
+    program a rule (and an ``expert_fn``) for the module, compiled once a
+    shape and a dtype of its arguments: the logits are an argument (PR 39's
+    rule 1). The plain form is given the same rows in float32."""
+    shape = (logits.shape[0], 16)
+    mine = jax.tree.map(lambda w: w[jnp.array(HELD)],
+                        _experts(jax.random.PRNGKey(4), EXPERTS))
+    x = jax.random.normal(jax.random.PRNGKey(2), shape, dtype)
+    target = jax.random.normal(jax.random.PRNGKey(5), shape)
+    return [_held_program(rule, expert_fn)(mine, x, logits, target),
+            _dense_program(rule)(mine, x.astype(jnp.float32), logits, target)]
+
+
+def _assert_the_dense_form(logits, landed, rule="softmax",
+                           expert_fn=grouped_gated_mlp):
+    """``y``, ``load`` and every gradient of the layer with the share
+    ``HELD`` are the plain form's, ``landed`` assignments on the share."""
     ((_, (y, load)), got), ((_, (want_y, want_load)), want) = \
-        _held_and_dense(_landing(landed))
+        _held_and_dense(logits, rule, expert_fn=expert_fn)
     assert int(load.sum()) == landed
     assert load.tolist() == want_load.tolist()
     np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-5)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+    return y, got
+
+
+@pytest.mark.parametrize("landed", [
+    0, 13, 16, 17, 32, 33, TOKENS * CHOSEN,
+    CHUNK - 1, CHUNK, CHUNK + 1, 5 * CHUNK + 3],
+    ids=["none", "a-share", "a-tile", "a-tile-and-a-row", "a-quarter",
+         "a-quarter-and-a-row", "every-assignment", "a-chunk-less-a-row",
+         "a-chunk", "a-chunk-and-a-row", "five-chunks-and-three-rows"])
+def test_a_share_is_exact_whatever_lands(landed):
+    """2 of 32 experts held: an even router lands 8 of the 128
+    assignments here, one chunk of the walk. Whatever lands, up to all of
+    them, ``y``, ``load`` and every gradient are the dense form's."""
+    _assert_the_dense_form(_landing(landed), landed)
+
+
+@pytest.mark.parametrize("landed", [93, 97, 100],
+                         ids=["the-last-whole-chunk", "a-row-of-the-last",
+                              "every-assignment"])
+def test_a_share_is_exact_where_the_order_is_no_whole_number_of_chunks(
+        landed):
+    """50 tokens: 100 sorted rows in chunks of 8. The last chunk starts
+    at row 92 to end with the order and overlaps the one before by four
+    rows, which are added once."""
+    assert (50 * CHOSEN) % moe.walk_chunk(50 * CHOSEN, len(HELD), EXPERTS)
+    _assert_the_dense_form(_landing(landed, tokens=50), landed)
+
+
+@pytest.mark.parametrize("landed", [13, TOKENS * CHOSEN],
+                         ids=["a-share", "every-assignment"])
+def test_a_share_is_exact_under_the_sigmoid_rule_with_its_bias(landed):
+    """Sigmoid scores, ``BIAS`` in the choice and not in the weights: the
+    bias moves the choice of tokens that land elsewhere, where there are
+    any, and with it what their weights are normalised by."""
+    logits = _landing(landed)
+    unbiased = jax.lax.top_k(jax.nn.sigmoid(logits), CHOSEN)[1]
+    moved = jnp.any(jnp.sort(_sigmoid_choice(logits)[0]) != jnp.sort(unbiased))
+    assert bool(moved) == (landed < TOKENS * CHOSEN)
+    _assert_the_dense_form(logits, landed, rule="sigmoid")
+
+
+def test_a_share_of_bf16_rows_is_the_dense_form_to_bf16s_rounding():
+    """The rows in bfloat16, as the models hand them over: the walk adds
+    the same products in float32 in another order and rounds once. The
+    plain form runs in float32 on the same rounded rows; the layer rounds
+    three products deep and its sums once: eight half units in the last
+    place of the largest entry, 2**-6 of it."""
+    ((_, (y, load)), got), ((_, (want_y, want_load)), want) = \
+        _held_and_dense(_landing(13), dtype=jnp.bfloat16)
+    assert load.tolist() == want_load.tolist() and int(load.sum()) == 13
+    for g, w in zip([y] + jax.tree.leaves(got),
+                    [want_y] + jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            g.astype(jnp.float32), w, rtol=0,
+            atol=2.0 ** -6 * float(jnp.max(jnp.abs(w))))
+
+
+def test_rows_no_one_reads_may_hold_anything():
+    """An ``expert_fn`` that returns NaN for the rows past the groups:
+    ``y`` and every gradient are finite and the dense form's."""
+    y, got = _assert_the_dense_form(_landing(13), 13,
+                                    expert_fn=_poisoned_experts)
+    assert all(bool(jnp.all(jnp.isfinite(a)))
+               for a in [y] + jax.tree.leaves(got))
 
 
 def _biased_experts(params, rows, group_sizes):
@@ -241,14 +366,36 @@ def _biased_experts(params, rows, group_sizes):
                     + params["b"][expert])
 
 
+@functools.lru_cache(maxsize=None)
+def _biased_programs():
+    def ours(params, x, logits, target):
+        y, _ = moe_apply_held(_biased_experts, params, x, logits, HELD,
+                              CHOSEN)
+        return jnp.sum(y * target)
+
+    def dense(params, x, logits, target):
+        ids, chosen = _softmax_choice(logits)
+        weights = jnp.zeros_like(logits).at[
+            jnp.arange(TOKENS)[:, None], ids].set(chosen)
+        every = jnp.full((1,), TOKENS)
+        return sum(jnp.sum(
+            weights[:, e, None] * target * _biased_experts(
+                jax.tree.map(lambda p: p[i:i + 1], params), x, every))
+            for i, e in enumerate(HELD))
+
+    return [jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+            for f in (ours, dense)]
+
+
 @pytest.mark.parametrize("landed", [0, 13, 64, TOKENS * CHOSEN],
                          ids=["none", "a-share", "half", "every-assignment"])
 def test_a_row_wise_expert_fn_with_a_bias_gets_its_gradients(landed):
     """The rows past the groups hold other experts' token rows, not zeros
     (PR 27). For any ``expert_fn`` that works row by row that changes
     nothing: what it returns there is not read and the gradient it is
-    handed there is zero, so its parameters' gradients, a bias's too, are
-    those of each held expert applied to its own tokens."""
+    handed there is zero (walked or not: PR 41), so its parameters'
+    gradients, a bias's too, are those of each held expert applied to its
+    own tokens."""
     logits = _landing(landed)
     x = jax.random.normal(jax.random.PRNGKey(2), (TOKENS, 16))
     params = {"w": 0.3 * jax.random.normal(jax.random.PRNGKey(6),
@@ -256,47 +403,70 @@ def test_a_row_wise_expert_fn_with_a_bias_gets_its_gradients(landed):
               "b": 0.5 + jax.random.normal(jax.random.PRNGKey(7),
                                            (len(HELD), 16))}
     target = jax.random.normal(jax.random.PRNGKey(5), (TOKENS, 16))
-
-    def ours(params, x, logits):
-        y, _ = moe_apply_held(_biased_experts, params, x, logits, HELD,
-                              CHOSEN)
-        return jnp.sum(y * target)
-
-    def dense(params, x, logits):
-        top, ids = jax.lax.top_k(logits, CHOSEN)
-        weights = jnp.zeros_like(logits).at[
-            jnp.arange(TOKENS)[:, None], ids].set(jax.nn.softmax(top, -1))
-        every = jnp.full((TOKENS,), TOKENS)
-        return sum(jnp.sum(
-            weights[:, e, None] * target * _biased_experts(
-                jax.tree.map(lambda p: p[i:i + 1], params), x,
-                every[:1])) for i, e in enumerate(HELD))
-
-    got, want = (jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(
-        params, x, logits) for f in (ours, dense))
+    got, want = (f(params, x, logits, target) for f in _biased_programs())
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("held", [
-    tuple(range(EXPERTS)), tuple(range(EXPERTS // 2)), HELD],
-    ids=["all-held", "half-held", "a-share"])
-def test_the_layer_lowers_to_one_path(held):
-    """Whatever part of the experts is held, the program has one path:
-    no branch chosen on the device, no loop, nothing lowered twice."""
-    x = jnp.zeros((TOKENS, 16))
-    logits = jnp.zeros((TOKENS, EXPERTS))
-    mine = _experts(jax.random.PRNGKey(4), len(held))
+@functools.lru_cache(maxsize=None)
+def _lowered(held):
+    """The layer's value and gradients with ``held`` of the 32 experts,
+    lowered for the TPU."""
+    x = jax.ShapeDtypeStruct((TOKENS, 16), jnp.float32)
+    logits = jax.ShapeDtypeStruct((TOKENS, EXPERTS), jnp.float32)
+    mine = {name: jax.ShapeDtypeStruct((len(held),) + shape, jnp.float32)
+            for name, shape in (("w_down", (24, 16)), ("w_gate", (16, 24)),
+                                ("w_up", (16, 24)))}
 
     def loss(mine, x, logits):
         y, _ = moe_apply_held(grouped_gated_mlp, mine, x, logits, held,
                               CHOSEN)
         return jnp.sum(y)
 
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        mine, x, logits).as_text()
+    # For the TPU, where a grouped product is one operation and not the
+    # masked dense products the CPU is given.
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).trace(
+        mine, x, logits).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def _grouped_products(text):
+    return len(re.findall(r"= \"?(?:stablehlo|chlo)\.ragged_dot", text))
+
+
+# The first 16 hexadecimal digits of the SHA-256 of ``_lowered(held)``
+# recorded on the parent commit 5d95678, whose layer had the one form.
+PARENTS_TEXT = {
+    "all-held": (tuple(range(EXPERTS)), "3df94f166fd0b8c8"),
+    "half-held": (tuple(range(EXPERTS // 2)), "52eab87544e6d318"),
+    "a-quarter-held": (tuple(range(EXPERTS // 4)), "8f697dd4e4e8501c"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENTS_TEXT))
+def test_the_layer_lowers_to_one_path(name):
+    """With more than an eighth of the experts held the program has one
+    path: no branch chosen on the device, no loop, nothing lowered twice;
+    the text is the parent's rule's, letter for letter."""
+    held, digest = PARENTS_TEXT[name]
+    text = _lowered(held)
     for branching in ("stablehlo.case", '"stablehlo.if"', "stablehlo.while"):
         assert branching not in text
+    assert not moe.walk_chunk(TOKENS * CHOSEN, len(held), EXPERTS)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_a_share_lowers_to_loops_and_no_branch():
+    """With an eighth held or less three of the four rules that move
+    rows are loops whose trip count the device reads (the dispatch stays
+    one gather); still no branch, and the
+    experts' grouped products are lowered as many times as with every
+    expert held: ``expert_fn`` is called once."""
+    text = _lowered(HELD)
+    assert text.count("stablehlo.while") == 3
+    for branching in ("stablehlo.case", '"stablehlo.if"'):
+        assert branching not in text
+    assert _grouped_products(text) == _grouped_products(
+        _lowered(tuple(range(EXPERTS)))) == 9
 
 
 @pytest.mark.parametrize("size,width,blocks", [
