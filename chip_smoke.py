@@ -15,11 +15,13 @@ calls, in ONE process (a chip belongs to one process at a time):
   flash_one_tile     ops.attention's one-tile path at seq 512 against
                      reference_attention in float32: causal GQA at head
                      width 128, float32 with a key mask, causal sq != sk
-  window_and_experts ops.attention's streamed kernels under a causal band
-                     with a window (two blocks a side, causal GQA at head
-                     width 128) and parallel.moe.moe_apply_held with a
-                     share of the experts at SmallThinker's widths, each
-                     against its float32 reference, forward and gradients
+  window_and_experts ops.attention's streamed kernels, two blocks a side:
+                     under a causal band with a window (GQA at head width
+                     128), plain causal at head width 64 and at 192 over
+                     128, and under a key mask; and
+                     parallel.moe.moe_apply_held with a share of the
+                     experts at SmallThinker's widths, each against its
+                     float32 reference, forward and gradients
   linear_attention   ops.linear_attention's chunked gated delta rule at
                      Olmo-Hybrid's head widths (15 heads, keys 96, values
                      192, chunks of 64) against the token-by-token
@@ -531,8 +533,12 @@ def phase_flash_one_tile(sz, rehearsal):
 def phase_window_and_experts(sz, rehearsal):
     """What PR 26 added to the step, off the benchmark's own shape: the
     streamed flash kernels with ``window`` (blocks the band skips, blocks
-    its edges cross, blocks wholly inside) against ``reference_attention``
-    on float32 copies at the highest matmul precision, and the dropless
+    its edges cross, blocks wholly inside), at the head widths the cells
+    run beside 128 (64; q and k 192 over v 128) and under a key mask, each
+    against ``reference_attention`` on float32 copies at the highest
+    matmul precision (the tolerance is ``tests/``' for bfloat16 or
+    tighter: ``attention_helpers`` holds gradients to 5e-2 of the
+    largest value), and the dropless
     expert layer holding 4 of 16 experts (3 chosen a token, hidden 2560,
     expert width 768) against every held expert applied densely in
     float32, once as a seeded router spreads the tokens and once with
@@ -565,40 +571,53 @@ def phase_window_and_experts(sz, rehearsal):
 
     tolerance = 2.0 ** -5
 
-    # -- the window: two default blocks a side, so the kernels stream.
+    # -- the streamed kernels: two default blocks a side, by band (a window
+    # that crosses blocks off their borders), by head width (128, 64, q
+    # and k 192 over v 128: a head is a band of that many rows of the
+    # (B, heads * d, S) arrays) and under a key mask (a padded batch).
     seq = 256 if rehearsal else 2048
     blocks = dict(block_q=64, block_k=128) if rehearsal else {}
-    window = seq // 2 + seq // 8        # crosses blocks off their borders
-    q, k, v, w = (rand(2, seq, 8, 128), rand(2, seq, 2, 128),
-                  rand(2, seq, 2, 128), rand(2, seq, 8, 128))
+    kernels = {"hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"}
+    rows = [   # name, query heads, K/V heads, q/k width, v width, window, mask
+        ("window-d128", 8, 2, 128, 128, seq // 2 + seq // 8, False),
+        ("causal-d64", 8, 2, 64, 64, None, False),
+        ("causal-192-over-128", 4, 4, 192, 128, None, False),
+        ("key-mask-d128", 8, 2, 128, 128, None, True),
+    ]
+    for name, h, hkv, d, dv, window, masked in rows:
+        q, k, v, w = (rand(2, seq, h, d), rand(2, seq, hkv, d),
+                      rand(2, seq, hkv, dv), rand(2, seq, h, dv))
+        mask = (jnp.asarray(np.arange(seq)[None, :] < rng.randint(
+            seq // 2, seq, (2, 1))) if masked else None)
 
-    def attention_grads(attn, **kw):
-        def fn(q, k, v):
-            def loss(q, k, v):
-                out = attn(q, k, v, causal=True, window=window, **kw)
-                return jnp.sum(out.astype(jnp.float32)
-                               * w.astype(jnp.float32)), out
-            (_, out), grads = jax.value_and_grad(
-                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-            return (out,) + grads
-        return jax.jit(fn)
+        def attention_grads(attn, **kw):
+            def fn(q, k, v):
+                def loss(q, k, v):
+                    out = attn(q, k, v, causal=True, window=window,
+                               key_mask=mask, **kw)
+                    return jnp.sum(out.astype(jnp.float32)
+                                   * w.astype(jnp.float32)), out
+                (_, out), grads = jax.value_and_grad(
+                    loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+                return (out,) + grads
+            return jax.jit(fn)
 
-    flash = attention_grads(flash_attention, **blocks)
-    names = set(re.findall(r"hvd_flash_\w+",
-                           flash.lower(q, k, v).as_text(debug_info=True)))
-    check(names == {"hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"}
-          and not attention._one_tile_path(
-              q, k, blocks.get("block_q", attention.FLASH_DEFAULT_BLOCK_Q),
-              blocks.get("block_k", attention.FLASH_DEFAULT_BLOCK_K)),
-          f"window: kernels {sorted(names)}, streamed by the shape rule")
-    with jax.default_matmul_precision("highest"):
-        want = timed(attention_grads(reference_attention),
-                     *(x.astype(jnp.float32) for x in (q, k, v)))
-    err = worst(timed(flash, q, k, v), want)
-    checks.append(check(
-        err <= tolerance,
-        f"window {window} of {seq}: out/dq/dk/dv within {err:.2e} of "
-        "max|reference|"))
+        flash = attention_grads(flash_attention, **blocks)
+        names = set(re.findall(r"hvd_flash_\w+", flash.lower(q, k, v).as_text(
+            debug_info=True)))
+        check(names == kernels and not attention._one_tile_path(
+            q, k, blocks.get("block_q", attention.FLASH_DEFAULT_BLOCK_Q),
+            blocks.get("block_k", attention.FLASH_DEFAULT_BLOCK_K), v),
+            f"{name}: kernels {sorted(names)}, streamed by the shape rule")
+        with jax.default_matmul_precision("highest"):
+            want = timed(attention_grads(reference_attention),
+                         *(x.astype(jnp.float32) for x in (q, k, v)))
+        err = worst(timed(flash, q, k, v), want)
+        checks.append(check(
+            err <= tolerance,
+            f"streamed {name} ({h}/{hkv} heads, "
+            f"{'window %d of ' % window if window else ''}{seq}): "
+            f"out/dq/dk/dv within {err:.2e} of max|reference|"))
 
     # -- the expert layer with a share.
     tokens, hidden, width = (512, 128, 96) if rehearsal else (4096, 2560, 768)

@@ -36,15 +36,32 @@ environment variable selects it):
   GQA a step covers a K/V head with its query group, so dk/dv are still
   written once per K/V head.
 * **Streamed** (every other shape): classic FlashAttention-2
-  online-softmax blocking over heads folded into batch (``_fold_heads``:
-  a transposed copy each way, (B * H, S, D)). The grid is (batch*heads,
-  q_blocks, the key blocks of a query block's band); Pallas streams one
-  (block_k, d) K/V tile per innermost grid step from HBM into VMEM
+  online-softmax blocking over the same (B, H * D, S) arrays
+  (``_heads_to_rows``; until PR 42 heads were folded into batch,
+  (B * H, S, D), by a transposed copy of every operand and result). The
+  grid is (batch*heads, q_blocks, the key blocks of a query block's
+  band); a head is the band of D rows the block index map picks (any D
+  that is whole sublanes: 64, 128, 192 alike, no lane padded), blocks
+  (1, d, block_q) and (1, d, block_k), a query head's K/V group routed
+  by the index map (``_gqa_index_maps``). Pallas streams one (d,
+  block_k) K/V tile per innermost grid step from HBM into VMEM
   (BlockSpec index_maps drive the double-buffered DMA pipeline), so VMEM
   holds O(block_q*d + block_k*d), not O(seq_k*d), and the ceiling on
-  sequence length is HBM, not VMEM. Running max / normalizer / output
-  accumulate in VMEM scratch across the innermost dimension (TPU grids
-  execute sequentially). Backward is two kernels (``hvd_flash_bwd_dq``
+  sequence length is HBM, not VMEM. The forward holds the score tile
+  (block_q, block_k): q is turned to (block_q, d) once a query block and
+  the accumulator turned into o's (dv, block_q) at the end
+  (``_transposed``: a product with an identity, not the transpose unit),
+  so k and v are taken as they lie and 512 query rows stream through the
+  matrix unit in both products. dq and dk/dv hold the tile transposed,
+  (block_k, block_q), as the one-tile kernels do: every streamed operand
+  is taken as it lies, the results accumulate in the (d, block) form they
+  are written in with only the head's d rows streaming (no dearer than
+  512 rows at d = 128, cheaper at 64 and 192, where a product d columns
+  wide leaves the unit part empty), dk/dv's sums over the query axis
+  contract the lanes of q, do, p and ds, and lse/delta are the lane rows
+  they are stored as. Running max / normalizer / output accumulate in
+  VMEM scratch across the innermost dimension (TPU grids execute
+  sequentially). Backward is two kernels (``hvd_flash_bwd_dq``
   streaming K/V, ``hvd_flash_bwd_dkv`` streaming Q/dO), each rebuilding
   the probabilities from the saved log-sum-exp instead of storing the
   S x S matrix. **The inner axis counts the blocks of the band, not of
@@ -214,24 +231,68 @@ def reference_attention(q, k, v, key_mask=None, causal=False,
 _LANES = 128
 
 
+def _dot(a, b, contract, precision=None):
+    """MXU product in the operands' dtype, accumulated in f32."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))   # a @ b.T
+_NN = ((1,), (0,))   # a @ b
+_TN = ((0,), (0,))   # a.T @ b
+
+
+def _transposed(x):
+    """A 2-D tile (r, c) as (c, r), made on the MXU: ``I . x^T``, 128
+    columns at a time. The matrix unit latches a right operand transposed
+    as it loads it, so the product with an identity is the transposition,
+    exact in x's dtype (one non-zero product an element, accumulated in
+    f32; float32 operands at the highest precision), at a few pushes a
+    tile, where ``x.T`` goes through the transpose unit: for what is
+    turned once a block (the streamed forward's q and accumulator, dk/dv's
+    k and v) the forward measured 3-7% shorter this way at four shapes of
+    five (PERF.md section 6, PR 42)."""
+    exact = lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    n = min(x.shape[1], _LANES)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)).astype(x.dtype)
+    parts = []
+    for i in range(0, x.shape[1], n):
+        part = x[:, i:i + n]
+        m = part.shape[1]       # the last part may be narrower
+        parts.append(_dot(eye[:m, :m], part, _NT, exact))
+    return jnp.concatenate(parts, axis=0).astype(x.dtype)
+
+
 def _allowed_mask(mask_ref, has_mask: bool, band: bool, qb, kb,
                   block_q: int, block_k: int, q_offset: int,
-                  window: Optional[int] = None):
-    """The (block_q, block_k) allowed-entry mask, or None when every entry
-    is allowed (no key mask given AND the block needs no ``band`` mask) so
-    the callers skip the where/zeroing VPU passes entirely. ``has_mask``
-    is static — the public entry knows at trace time whether a key mask
-    was supplied. ``band``: mask by the causal band, the diagonal above
-    and, with ``window``, the edge ``window`` keys below it."""
+                  window: Optional[int] = None, transposed: bool = False):
+    """The allowed-entry mask of a streamed kernel's score tile, (block_q,
+    block_k) or, ``transposed``, (block_k, block_q), or None when every
+    entry is allowed (no key mask given AND the block needs no ``band``
+    mask) so the callers skip the where/zeroing VPU passes entirely.
+    ``has_mask`` is static — the public entry knows at trace time whether
+    a key mask was supplied. The key mask's (block_k,) row of 32-bit
+    integers is broadcast over the tile, down the sublanes or, for the
+    transposed tile, as a column across the lanes, and compared there:
+    Mosaic turns a lane row of integers into a column, not one of
+    booleans (PR 29 met that), and compiles the tile's comparison in a
+    quarter of the time a row of booleans broadcast over it takes.
+    ``band``: mask by the causal band, the diagonal above and, with
+    ``window``, the edge ``window`` keys below it."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_axis = 1 if transposed else 0
     allowed = None
     if has_mask:
-        allowed = jnp.broadcast_to((mask_ref[0, 0] != 0)[None, :],
-                                   (block_q, block_k))
+        keys = mask_ref[0, 0]
+        allowed = jnp.broadcast_to(
+            keys[:, None] if transposed else keys[None, :], shape) != 0
     if band:
         q_pos = qb * block_q + q_offset + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+            jnp.int32, shape, q_axis)
         k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+            jnp.int32, shape, 1 - q_axis)
         tri = k_pos <= q_pos
         if window is not None:
             tri = tri & (k_pos > q_pos - window)
@@ -418,16 +479,24 @@ def _when_banded(causal: bool, window: Optional[int], qb, kb, block_q: int,
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                  m_scr, l_scr, acc_scr, *, block_k: int, sm_scale: float,
-                  causal: bool, keys: _BandAxis, block_q: int, q_offset: int,
-                  has_mask: bool, window: Optional[int] = None):
+                  m_scr, l_scr, acc_scr, q_scr, *, block_k: int,
+                  sm_scale: float, causal: bool, keys: _BandAxis,
+                  block_q: int, q_offset: int, has_mask: bool,
+                  window: Optional[int] = None):
     # Grid (bh, qb, j), j innermost over the key blocks of the query
     # block's band (``_band_grid``): step j is key block ``kb = first(qb)
-    # + j``. Block shapes: q (1, block_q, d) (constant across j — fetched
-    # once), k/v (1, block_k, d) (a NEW tile streams in from HBM each step
-    # inside the band, none past its end: the index maps clamp), mask
-    # (1, 1, block_k). Running softmax state persists in VMEM scratch
-    # across the j loop.
+    # + j``. Blocks of the (B, heads * d, S) arrays, the sequence on the
+    # lanes: q (1, d, block_q) (constant across j — fetched once), k
+    # (1, d, block_k) and v (1, dv, block_k) (a NEW tile streams in from
+    # HBM each step inside the band, none past its end: the index maps
+    # clamp), mask (1, 1, block_k), o (1, dv, block_q), lse (1, 1,
+    # block_q). The score tile is (block_q, block_k): q is turned to
+    # (block_q, d) into scratch once a query block, so that a step's two
+    # products take k and v as they lie (``q k`` plain, ``p v^T`` over the
+    # lanes of both) with the 512 query rows streaming through the matrix
+    # unit, and the accumulator is turned once into o's (dv, block_q) at
+    # the end (``_transposed``). Running softmax state persists in VMEM
+    # scratch across the j loop.
     # ``q_offset = sk - sq``: under the decode convention the sq query rows
     # are the LAST sq positions of the sk-long key axis, so query row i sits
     # on the causal diagonal at key column i + q_offset (matches
@@ -440,6 +509,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        q_scr[...] = _transposed(q_ref[0])
 
     # Causal: a step past the band's end runs no body, and only the blocks
     # an edge of the band crosses build its mask: ``_when_banded``.
@@ -449,13 +519,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         m = m_scr[:, :1]
         l = l_scr[:, :1]
         # MXU in the INPUT dtype with f32 accumulation: bf16 q/k run at
-        # full MXU rate (the previous astype(f32)-before-dot forced an
-        # f32 matmul at a fraction of it — measured 43.7% of the whole
-        # Llama-300M step inside these kernels); sm_scale applies to the
-        # f32 product, which is algebraically identical.
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+        # full MXU rate (an astype(f32) before the dot forces an f32
+        # matmul at a fraction of it); sm_scale applies to the f32
+        # product, which is algebraically identical.
+        s = _dot(q_scr[...], k_ref[0], _NN) * sm_scale   # (block_q, block_k)
         allowed = _allowed_mask(mask_ref, has_mask, band, qb, kb,
                                 block_q, block_k, q_offset, window)
         if allowed is not None:
@@ -472,9 +539,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         # p drops to the V dtype for the MXU (f32 inputs: no-op, tests
         # stay exact; bf16: full-rate matmul, the universal flash
         # convention — probabilities carry ~8 mantissa bits there).
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + _dot(
+            p.astype(v_ref.dtype), v_ref[0], _NT)              # (block_q, dv)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -484,28 +550,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     @pl.when(j == keys.extent - 1)
     def _finalize():
         m = m_scr[:, :1]
-        l = l_scr[:, :1]
+        l = jnp.maximum(l_scr[:, :1], 1e-30)
         # Fully-masked rows (l == 0) produce zeros, not NaNs.
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = _transposed((acc_scr[...] / l).astype(o_ref.dtype))
         # Log-sum-exp per row, saved for the backward pass
         # (FlashAttention-2): exp(s - lse) reconstitutes the softmax without
         # storing the S x S probs.
-        lse_ref[0, 0] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+        lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
 def _heads_to_rows(x):
-    """(B, S, N, D) -> (B, N * D, S), what the one-tile kernels take: a
-    head is a band of D rows with its sequence on the lanes, which a block
-    index map picks, whatever D is. No copy is made where XLA already
-    keeps the array so, and around attention it does: the TPU compiler
-    writes a projection's (B, S, N, D) result sequence-minor
-    (``{1,3,2,0}`` in the compiled steps of both flash cells, head width
-    64 and 128) and wants the output projection's operand and the three
-    gradients the same way, so the reshape and the swap of the last two
-    axes compile to bitcasts
-    (``tests/benchmark/test_aot_flash_layout.py`` reads that in the
-    compiled BERT step). Handing the kernels (B, S, N * D) instead, heads
-    as lane bands, left one transposing copy an operand in that step."""
+    """(B, S, N, D) -> (B, N * D, S), what every kernel here takes, the
+    file's one convention: a head is a band of D rows with its sequence
+    on the lanes, which a block index map picks, whatever D is. No copy
+    is made where XLA already keeps the array so, and around attention
+    it does: the TPU compiler writes a projection's (B, S, N, D) result
+    sequence-minor (``{1,3,2,0}`` in the compiled steps of the flash
+    cells, head widths 64, 128 and 192) and wants the output
+    projection's operand and the three gradients the same way, so the
+    reshape and the swap of the last two axes compile to bitcasts. That
+    leans on a layout XLA chooses, and only
+    ``tests/benchmark/test_aot_flash_layout.py`` sees which (it reads the
+    compiled BERT step; the jaxpr, which ``tests/test_flash_layouts.py``
+    holds to no rank-4 transposition, cannot): JoyAI's compiled step went
+    from 54 copies of its 134-201 MB attention arrays to 12 with the
+    streamed kernels on this form (PR 42; the twelve are v's gradient and
+    v out of ``[k_n | v]``, one each a block). Handing the kernels (B, S,
+    N * D) instead, heads as lane bands, left one transposing copy an
+    operand in the BERT step; folding heads into batch, (B * N, S, D),
+    cost a transposed copy each way."""
     b, s, n, d = x.shape
     return x.reshape(b, s, n * d).transpose(0, 2, 1)
 
@@ -513,24 +586,6 @@ def _heads_to_rows(x):
 def _rows_to_heads(x, n: int):
     b, nd, s = x.shape
     return x.transpose(0, 2, 1).reshape(b, s, n, nd // n)
-
-
-def _fold_heads(x):
-    """(B, S, N, D) -> (B * N, S, D), what the streamed kernels take: heads
-    folded into batch by a transposed copy, each head's (S, D) rows
-    contiguous MXU tiles (``_heads_to_rows``' form lost to it there:
-    PERF.md section 6, PR 29). Under GQA (Hkv < H) the K/V tiles are NOT
-    repeated — the pallas index_maps route each query head's grid row to
-    its group's K/V row, so the K/V HBM footprint stays at Hkv/H of the
-    repeated form (DMA traffic is unchanged: tiles are re-fetched per
-    query-head row)."""
-    b, s, n, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
-
-
-def _unfold_heads(x, n: int):
-    _, s, d = x.shape
-    return x.reshape(-1, n, s, d).transpose(0, 2, 1, 3)
 
 
 def _mask_rows(key_mask, b: int, sk: int):
@@ -543,18 +598,23 @@ def _mask_rows(key_mask, b: int, sk: int):
 
 
 def _gqa_index_maps(h: int, hkv: int):
-    """Index maps routing a (b*h) grid row to its K/V row (b*hkv) and its
-    mask row (b). ``bh = b*h + head``; the head's K/V group is
-    ``head // (h // hkv)``."""
+    """Index maps routing a (b*h) grid row of the streamed kernels to its
+    ``(batch row, head)`` in the (B, heads * d, S) arrays, a head the band
+    of rows its block is: ``q`` the query head's own, ``kv`` its group's
+    K/V head. ``bh = b*h + head``; the head's K/V group is
+    ``head // (h // hkv)``. The batch row is also the key mask's. Under
+    GQA (Hkv < H) the K/V tiles are NOT repeated: the K/V HBM footprint
+    stays at Hkv/H of the repeated form (DMA traffic is unchanged: tiles
+    are re-fetched per query-head row)."""
     group = h // hkv
 
+    def q(bh):
+        return bh // h, bh % h
+
     def kv(bh):
-        return (bh // h) * hkv + (bh % h) // group
+        return bh // h, (bh % h) // group
 
-    def mask(bh):
-        return bh // h
-
-    return kv, mask
+    return q, kv
 
 
 def _fit_block(block: int, seq: int) -> int:
@@ -648,17 +708,6 @@ def _one_tile_path(q, k, block_q: int, block_k: int, v=None) -> int:
     if v is not None:
         d = max(d, v.shape[-1])
     return _one_tile_heads(sq, sk, d, q.dtype.itemsize, h // hkv, hkv)
-
-
-def _dot(a, b, contract):
-    """MXU product in the operands' dtype, accumulated in f32."""
-    return jax.lax.dot_general(a, b, (contract, ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-_NT = ((1,), (1,))   # a @ b.T
-_NN = ((1,), (0,))   # a @ b
-_TN = ((0,), (0,))   # a.T @ b
 
 
 def _head(ref, g: int, n: int):
@@ -873,8 +922,7 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
             causal=causal, has_mask=has_mask, interpret=interpret,
             window=window)
         return _rows_to_heads(out, h), lse
-    qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
-    kv_row, mask_row = _gqa_index_maps(h, hkv)
+    q_head, kv_head = _gqa_index_maps(h, hkv)
     keys, _ = _band_grid(sq, sk, block_q, block_k, causal, window)
     # The band's key blocks innermost: K/V tiles stream HBM→VMEM one per
     # step inside the band; q block and the o/lse output blocks are
@@ -888,31 +936,34 @@ def _flash_forward(q, k, v, key_mask, causal, sm_scale, block_q, block_k,
                           window=window),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
-            pl.BlockSpec((1, block_k, dv),
-                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
+            pl.BlockSpec((1, d, block_q),
+                         lambda bh, i, j: (*q_head(bh), i)),
+            pl.BlockSpec((1, d, block_k),
+                         lambda bh, i, j: (*kv_head(bh), keys.tile(i, j))),
+            pl.BlockSpec((1, dv, block_k),
+                         lambda bh, i, j: (*kv_head(bh), keys.tile(i, j))),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, i, j: (mask_row(bh), 0, keys.tile(i, j))),
+                         lambda bh, i, j: (bh // h, 0, keys.tile(i, j))),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, dv, block_q),
+                         lambda bh, i, j: (*q_head(bh), i)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, h * dv, sq), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
+            pltpu.VMEM((block_q, d), q.dtype),
         ],
         interpret=interpret,
         name=profiler.KERNEL_FLASH_FWD,
-    )(qf, kf, vf, maskf)
-    return _unfold_heads(out, h), lse
+    )(_heads_to_rows(q), _heads_to_rows(k), _heads_to_rows(v), maskf)
+    return _rows_to_heads(out, h), lse
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
@@ -920,10 +971,17 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                          sm_scale: float, causal: bool, keys: _BandAxis,
                          block_q: int, q_offset: int, has_mask: bool,
                          window: Optional[int] = None):
-    # Grid (bh, qb, j) as the forward's, j innermost over the band's key
-    # blocks: K/V tiles stream from HBM while q/do/lse/delta stay resident.
-    # Recompute p block-by-block from q, k and the saved lse; no S x S
-    # materialization (FA-2 backward, dq pass).
+    # Grid (bh, qb, j) and blocks as the forward's, j innermost over the
+    # band's key blocks: K/V tiles stream from HBM while q/do/lse/delta
+    # stay resident (do as o, delta as lse). Recompute p block-by-block
+    # from q, k and the saved lse; no S x S materialization (FA-2
+    # backward, dq pass). The tile is held TRANSPOSED, (block_k, block_q),
+    # as the one-tile kernels hold it: every operand is taken as it lies
+    # (the scores and dp contract the rows of k and q, v and do; ``dq^T =
+    # k ds`` is plain, with only the head's d rows streaming, which costs
+    # the matrix unit no more at 128 and less at 64 and 192 than 512 rows
+    # of a product 128 columns wide), lse and delta are the (1, block_q)
+    # lane rows they are stored as, and dq's accumulator is its block.
     # q_offset: see _flash_kernel — decode-convention diagonal shift.
     qb, j = pl.program_id(1), pl.program_id(2)
     kb, _, ended = keys.block(qb, j)
@@ -933,28 +991,23 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def _body(band):
-        lse = lse_ref[0, 0][:, None]          # (block_q, 1)
-        delta = delta_ref[0, 0][:, None]      # (block_q, 1)
         # All dots in the INPUT dtype with f32 accumulation (see
         # _flash_kernel); sm_scale moves onto the f32 product / the
-        # finalize write.
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+        # finalize write. lse and delta are the (1, block_q) rows they
+        # are stored as.
+        s = _dot(k_ref[0], q_ref[0], _TN) * sm_scale     # (block_k, block_q)
         allowed = _allowed_mask(mask_ref, has_mask, band, qb, kb,
-                                block_q, block_k, q_offset, window)
+                                block_q, block_k, q_offset, window,
+                                transposed=True)
         # Explicit zeroing (not exp of -inf): fully-masked rows keep p = 0,
         # so their gradients vanish as they must (out is identically 0).
-        p = jnp.exp(s - lse)
+        p = jnp.exp(s - lse_ref[0])
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dp = _dot(v_ref[0], do_ref[0], _TN)              # (block_k, block_q)
+        ds = p * (dp - delta_ref[0])
+        dq_scr[...] = dq_scr[...] + _dot(
+            k_ref[0], ds.astype(k_ref.dtype), _NN)             # (d, block_q)
 
     _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, ended,
                  _body)
@@ -965,7 +1018,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                           k_scr, v_scr, *,
                            block_q: int, sm_scale: float, causal: bool,
                            queries: _BandAxis, block_k: int, q_offset: int,
                            inner_steps: int, has_mask: bool,
@@ -981,7 +1035,14 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     # per-query-head grid, with no full-H partial in HBM and no XLA
     # group-sum afterwards. MHA is the group == 1 case (inner_steps ==
     # extent). A key block no query sees (sq < sk under a window) runs no
-    # body and writes its zeros.
+    # body and writes its zeros. Blocks as the forward's; dk and dv are
+    # k's and v's, (1, d, block_k) and (1, dv, block_k). The score tile is
+    # held TRANSPOSED, (block_k, block_q), as dq's: the two sums over the
+    # query axis (``dv^T = do p``, ``dk^T = q ds``) contract the lanes of
+    # do, q, p and ds with no tile transposed and accumulate in the form
+    # they are written in, and lse and delta are lane rows. k and v, which
+    # the scores and dp want as (block_k, d), are resident over the sweep
+    # and turned into scratch once a key block (``_transposed``).
     # q_offset: see _flash_kernel — decode-convention diagonal shift.
     kb, t = pl.program_id(1), pl.program_id(2)
     qb, _, ended = queries.block(kb, t % queries.extent)
@@ -990,31 +1051,26 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        k_scr[...] = _transposed(k_ref[0])
+        v_scr[...] = _transposed(v_ref[0])
 
     def _body(band):
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
         # All dots in the INPUT dtype with f32 accumulation (see
         # _flash_kernel); sm_scale moves onto the f32 product here and
-        # onto dk at finalize (dk = scale * ds^T q).
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+        # onto dk at finalize (dk^T = scale * q^T ds).
+        s = _dot(k_scr[...], q_ref[0], _NN) * sm_scale   # (block_k, block_q)
         allowed = _allowed_mask(mask_ref, has_mask, band, qb, kb,
-                                block_q, block_k, q_offset, window)
-        p = jnp.exp(s - lse)
+                                block_q, block_k, q_offset, window,
+                                transposed=True)
+        p = jnp.exp(s - lse_ref[0])
         if allowed is not None:
             p = jnp.where(allowed, p, 0.0)
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_scr[...] = dv_scr[...] + _dot(
+            do_ref[0], p.astype(do_ref.dtype), _NT)            # (dv, block_k)
+        dp = _dot(v_scr[...], do_ref[0], _NN)            # (block_k, block_q)
+        ds = p * (dp - delta_ref[0])
+        dk_scr[...] = dk_scr[...] + _dot(
+            q_ref[0], ds.astype(q_ref.dtype), _NT)             # (d, block_k)
 
     _when_banded(causal, window, qb, kb, block_q, block_k, q_offset, ended,
                  _body)
@@ -1064,8 +1120,8 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
         return (_rows_to_heads(dq, h), _rows_to_heads(dk, hkv),
                 _rows_to_heads(dv, hkv))
 
-    qf, kf, vf, dof = (_fold_heads(x) for x in (q, k, v, g))
-    kv_row, mask_row = _gqa_index_maps(h, hkv)
+    qt, kt, vt, dot = (_heads_to_rows(x) for x in (q, k, v, g))
+    q_head, kv_head = _gqa_index_maps(h, hkv)
     block_k = _fit_band(block_k, causal, window)
     keys, queries = _band_grid(sq, sk, block_q, block_k, causal, window)
     dq = pl.pallas_call(
@@ -1075,38 +1131,50 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
                           has_mask=has_mask, window=window),
         grid=(b * h, sq // block_q, keys.extent),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
-            pl.BlockSpec((1, block_k, dv),
-                         lambda bh, i, j: (kv_row(bh), keys.tile(i, j), 0)),
+            pl.BlockSpec((1, d, block_q),
+                         lambda bh, i, j: (*q_head(bh), i)),
+            pl.BlockSpec((1, d, block_k),
+                         lambda bh, i, j: (*kv_head(bh), keys.tile(i, j))),
+            pl.BlockSpec((1, dv, block_k),
+                         lambda bh, i, j: (*kv_head(bh), keys.tile(i, j))),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, i, j: (mask_row(bh), 0, keys.tile(i, j))),
-            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
+                         lambda bh, i, j: (bh // h, 0, keys.tile(i, j))),
+            pl.BlockSpec((1, dv, block_q),
+                         lambda bh, i, j: (*q_head(bh), i)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_specs=pl.BlockSpec((1, d, block_q),
+                               lambda bh, i, j: (*q_head(bh), i)),
+        out_shape=jax.ShapeDtypeStruct((b, h * d, sq), q.dtype),
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         interpret=interpret,
         name=profiler.KERNEL_FLASH_BWD_DQ,
-    )(qf, kf, vf, maskf, dof, lse, delta)
+    )(qt, kt, vt, maskf, dot, lse, delta)
 
     # GQA-native dkdv: grid rows are K/V heads (b*hkv), the query group is
     # swept in-kernel (t = g * extent + j, innermost, over the query blocks
-    # of the key block's band), so dk/dv come out at (b*hkv, sk, d)
+    # of the key block's band), so dk/dv come out at (b, hkv * d, sk)
     # directly — no full-H partials in HBM, no XLA group-sum. Q/dO/lse/delta
     # index maps route the t step to query head kvh * group + t // extent
     # (group-contiguous, matching repeat_kv) and to the band's query tile.
     group = h // hkv
     inner = group * queries.extent
 
-    def q_row(bh, t):
-        return (bh // hkv) * h + (bh % hkv) * group + t // queries.extent
+    def q_head_of(bh, t):
+        return (bh % hkv) * group + t // queries.extent
 
     def q_tile(j, t):
         return queries.tile(j, t % queries.extent)
+
+    def q_block(bh, j, t):
+        return bh // hkv, q_head_of(bh, t), q_tile(j, t)
+
+    def q_stat(bh, j, t):
+        return (bh // hkv) * h + q_head_of(bh, t), 0, q_tile(j, t)
+
+    def kv_block(bh, j, t):
+        return bh // hkv, bh % hkv, j
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, block_q=block_q,
@@ -1116,37 +1184,35 @@ def _flash_backward(q, k, v, key_mask, out, lse, g, causal, sm_scale,
                           window=window),
         grid=(b * hkv, sk // block_k, inner),
         in_specs=[
-            pl.BlockSpec((1, block_q, d),
-                         lambda bh, j, t: (q_row(bh, t), q_tile(j, t), 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bh, j, t: (bh, j, 0)),
+            pl.BlockSpec((1, d, block_q), q_block),
+            pl.BlockSpec((1, d, block_k), kv_block),
+            pl.BlockSpec((1, dv, block_k), kv_block),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh, j, t: (bh // hkv, 0, j)),
-            pl.BlockSpec((1, block_q, dv),
-                         lambda bh, j, t: (q_row(bh, t), q_tile(j, t), 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bh, j, t: (q_row(bh, t), 0, q_tile(j, t))),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bh, j, t: (q_row(bh, t), 0, q_tile(j, t))),
+            pl.BlockSpec((1, dv, block_q), q_block),
+            pl.BlockSpec((1, 1, block_q), q_stat),
+            pl.BlockSpec((1, 1, block_q), q_stat),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, j, t: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bh, j, t: (bh, j, 0)),
+            pl.BlockSpec((1, d, block_k), kv_block),
+            pl.BlockSpec((1, dv, block_k), kv_block),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, sk, dv), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv * d, sk), k.dtype),
+            jax.ShapeDtypeStruct((b, hkv * dv, sk), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
+            pltpu.VMEM((d, block_k), jnp.float32),
+            pltpu.VMEM((dv, block_k), jnp.float32),
+            pltpu.VMEM((block_k, d), k.dtype),
+            pltpu.VMEM((block_k, dv), v.dtype),
         ],
         interpret=interpret,
         name=profiler.KERNEL_FLASH_BWD_DKV,
-    )(qf, kf, vf, maskf, dof, lse, delta)
+    )(qt, kt, vt, maskf, dot, lse, delta)
 
-    return (_unfold_heads(dq, h), _unfold_heads(dk, hkv),
-            _unfold_heads(dv, hkv))
+    return (_rows_to_heads(dq, h), _rows_to_heads(dk, hkv),
+            _rows_to_heads(dv, hkv))
 
 
 # The mask rides as a *differentiable* float32 argument with a zero
