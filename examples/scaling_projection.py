@@ -178,8 +178,7 @@ def _fsdp_llama_lowered(mesh):
 
     from horovod_tpu.jax.fsdp import (fsdp_param_specs, fsdp_shardings,
                                       fsdp_state_specs)
-    from horovod_tpu.models.llama import (LLAMA_300M, LlamaLM,
-                                          causal_lm_loss)
+    from horovod_tpu.models import LLAMA_300M, LlamaLM, causal_lm_loss
 
     model = LlamaLM(LLAMA_300M)
     n = len(mesh.devices.ravel())

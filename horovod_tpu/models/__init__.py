@@ -17,12 +17,14 @@ from .llama import (  # noqa: F401
     DecodePath,
     LlamaConfig,
     LlamaLM,
-    causal_lm_loss,
-    chunked_causal_lm_loss,
     classify_decode_sharding,
     generate,
     init_kv_cache,
     llama_tp_param_specs,
+)
+from .losses import (  # noqa: F401
+    causal_lm_loss,
+    chunked_causal_lm_loss,
     sp_causal_lm_loss,
     token_nll,
 )

@@ -18,7 +18,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 import numpy as np
 
-from .llama import token_nll
+from .losses import token_nll
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,7 +170,7 @@ def mlm_loss(logits, labels, label_mask):
 
     Uses the lse formulation (``lse(logits) - logits[label]``) so no
     (B, S, V) f32 array is materialized — see
-    ``horovod_tpu.models.llama.token_nll``."""
+    ``horovod_tpu.models.losses.token_nll``."""
     nll = token_nll(logits, labels)
     label_mask = label_mask.astype(jnp.float32)
     return (nll * label_mask).sum() / jnp.maximum(label_mask.sum(), 1.0)

@@ -3,9 +3,8 @@ and a multi-token-prediction module.
 
 The architecture of ``jdopensource/JoyAI-LLM-Flash`` (48B parameters,
 2.7B active; widths from its public ``config.json``, whose keys are
-DeepSeek-V3's), beside ``LagunaLM`` and ``Lfm2LM`` and built from the
-same parts (``RMSNorm``, ``rotary_embedding``, ``GatedMLP``,
-``moe_apply_held``, ``sigmoid_top_k``). What sets it apart:
+DeepSeek-V3's), built from ``models/decoder.py``'s parts. What sets it
+apart:
 
 * **Multi-head latent attention** on every layer. Queries and keys come
   through low-rank paths, each normed in the middle: ``c_q =
@@ -63,12 +62,12 @@ import jax
 import jax.numpy as jnp
 
 from ..common import profiler
-from ..ops.attention import make_attention_fn
-from ..parallel.moe import grouped_gated_mlp, moe_apply_held, sigmoid_top_k
-from .laguna import GatedMLP
-from .llama import RMSNorm, chunked_causal_lm_loss, rotary_embedding
-from .olmo_hybrid import _Leaf
-from .smallthinker import _Kernel
+from ..parallel.moe import grouped_gated_mlp, sigmoid_top_k
+from .decoder import (GatedMLP, Leaf, RMSNorm, decoder_layers, held_experts,
+                      linear, lm_head, project_heads, project_out,
+                      rematerialised, rotary_embedding, router_logits,
+                      stack_loads, token_embedding, xla_attention)
+from .losses import chunked_causal_lm_loss
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,22 +142,21 @@ class LatentAttention(nn.Module):
         cfg = self.config
         nope, rope, heads = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                              cfg.num_heads)
-        dense = lambda features, name: nn.DenseGeneral(  # noqa: E731
-            features=features, axis=-1, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name=name)
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype,  # noqa: E731
                                     name=name)
         rotate = lambda r: rotary_embedding(  # noqa: E731
             deinterleave(r), cfg.rope_theta, positions)
         with jax.named_scope(profiler.SCOPE_ATTN_LATENT_PROJ):
-            q = dense((heads, cfg.qk_head_dim), "wq_b")(
-                norm("q_a_norm")(dense(cfg.q_lora_rank, "wq_a")(x)))
+            q = project_heads(heads, cfg.qk_head_dim, cfg.dtype, "wq_b")(
+                norm("q_a_norm")(
+                    linear(cfg.q_lora_rank, cfg.dtype, "wq_a")(x)))
             c_kv, k_rope = jnp.split(
-                dense(cfg.kv_lora_rank + rope, "wkv_a")(x),
+                linear(cfg.kv_lora_rank + rope, cfg.dtype, "wkv_a")(x),
                 [cfg.kv_lora_rank], axis=-1)
             k_nope, v = jnp.split(
-                dense((heads, nope + cfg.v_head_dim), "wkv_b")(
-                    norm("kv_a_norm")(c_kv)), [nope], axis=-1)
+                project_heads(heads, nope + cfg.v_head_dim, cfg.dtype,
+                              "wkv_b")(norm("kv_a_norm")(c_kv)),
+                [nope], axis=-1)
             q = jnp.concatenate(
                 [q[..., :nope], rotate(q[..., nope:])], axis=-1)
             # One rotary key a token, rotated once and read by every head.
@@ -168,9 +166,7 @@ class LatentAttention(nn.Module):
                     k_rope, k_nope.shape[:-1] + (rope,))], axis=-1)
         with jax.named_scope(profiler.SCOPE_ATTN_LATENT):
             ctx = self.attention_fn(q, k, v, None)
-        return nn.DenseGeneral(features=cfg.dim, axis=(-2, -1),
-                               use_bias=False, dtype=cfg.dtype,
-                               param_dtype=jnp.float32, name="wo")(ctx)
+        return project_out(cfg.dim, cfg.dtype)(ctx)
 
 
 class JoyAIBlock(nn.Module):
@@ -193,27 +189,15 @@ class JoyAIBlock(nn.Module):
         if not self.sparse:
             return h + GatedMLP(cfg.mlp_hidden, cfg.dtype, name="mlp")(z), \
                 None
-        held = cfg.held()
         rows = z.reshape(b * s, d)
-        # The router in float32: which experts a token gets is decided on
-        # small differences between scores.
-        logits = rows.astype(jnp.float32) @ _Kernel(
-            (d, cfg.num_experts), name="router")()
-        bias = _Leaf("kernel", (cfg.num_experts,),
-                     nn.initializers.normal(0.01), name="expert_bias")()
+        logits = router_logits(rows, cfg.num_experts)
+        bias = Leaf("kernel", (cfg.num_experts,),
+                    nn.initializers.normal(0.01), name="expert_bias")()
         with jax.named_scope(profiler.SCOPE_MOE_SHARED):
             shared = GatedMLP(cfg.shared_hidden, cfg.dtype, name="shared")(z)
-        experts = {
-            "w_gate": _Kernel((len(held), d, cfg.expert_hidden),
-                              name="w_gate")(),
-            "w_up": _Kernel((len(held), d, cfg.expert_hidden),
-                            name="w_up")(),
-            "w_down": _Kernel((len(held), cfg.expert_hidden, d),
-                              name="w_down")(),
-        }
-        routed, load = moe_apply_held(
+        routed, load = held_experts(
             functools.partial(grouped_gated_mlp, activation=jax.nn.silu),
-            experts, rows, logits, held, cfg.num_selected,
+            rows, logits, cfg.held(), cfg.expert_hidden, cfg.num_selected,
             route=sigmoid_top_k(bias, eps=cfg.weight_sum_eps))
         return h + shared + cfg.routed_scale * routed.reshape(b, s, d), load
 
@@ -232,12 +216,11 @@ class MTPModule(nn.Module):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.norm_eps, cfg.dtype,  # noqa: E731
                                     name=name)
-        u = nn.Dense(cfg.dim, use_bias=False, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="eh_proj")(
+        u = linear(cfg.dim, cfg.dtype, "eh_proj")(
             jnp.concatenate([norm("enorm")(e), norm("hnorm")(g)], axis=-1))
-        block_cls = nn.remat(JoyAIBlock) if cfg.remat else JoyAIBlock
-        u, load = block_cls(cfg, sparse=True, attention_fn=self.attention_fn,
-                            name="block")(u, positions)
+        u, load = rematerialised(cfg, JoyAIBlock)(
+            cfg, sparse=True, attention_fn=self.attention_fn, name="block")(
+            u, positions)
         return norm("norm")(u), load
 
 
@@ -265,20 +248,13 @@ class JoyAILM(nn.Module):
         if cfg.mtp_layers not in (0, 1):
             raise ValueError("JoyAILM: mtp_layers is 0 or 1, got "
                              f"{cfg.mtp_layers}")
-        attention_fn = self.attention_fn or make_attention_fn(
-            causal=True, use_flash=False)
-        embed = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                         name="tok_embeddings")
-        x = embed(input_ids).astype(cfg.dtype)
-        block_cls = nn.remat(JoyAIBlock) if cfg.remat else JoyAIBlock
-        loads = []
-        for i in range(cfg.num_layers):
-            sparse = i >= cfg.num_dense_layers
-            x, load = block_cls(cfg, sparse=sparse, attention_fn=attention_fn,
-                                name=f"layer_{i}")(x, positions)
-            if sparse:
-                loads.append(load)
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        attention_fn = self.attention_fn or xla_attention()
+        layers = [dict(sparse=i >= cfg.num_dense_layers,
+                       attention_fn=attention_fn)
+                  for i in range(cfg.num_layers)]
+        embed = token_embedding(cfg)
+        x, loads = decoder_layers(cfg, JoyAIBlock, layers, embed(input_ids),
+                                  positions)
         mtp = None
         if cfg.mtp_layers:
             with jax.named_scope(profiler.SCOPE_MTP):
@@ -289,12 +265,10 @@ class JoyAILM(nn.Module):
                 mtp, load = MTPModule(cfg, attention_fn, name="mtp")(
                     following, x, positions)
             loads.append(load)
-        load = jnp.stack(loads) if loads else jnp.zeros(
-            (0, len(cfg.held())), jnp.int32)
+        load = stack_loads(loads, cfg.held())
         if return_hidden:
             return x, mtp, load
-        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=jnp.float32, name="lm_head")
+        head = lm_head(cfg)
         return head(x), None if mtp is None else head(mtp), load
 
 

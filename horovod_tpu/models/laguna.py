@@ -1,9 +1,8 @@
 """Laguna: a causal expert LM whose layers differ in kind.
 
 The architecture of ``poolside/Laguna-XS.2`` (33.4B parameters, 3B active;
-widths from its public ``config.json``), beside ``SmallThinkerLM`` and
-built from the same parts (``RMSNorm``, ``rotary_embedding``,
-``moe_apply_held``). What sets its block apart:
+widths from its public ``config.json``), built from
+``models/decoder.py``'s parts. What sets its block apart:
 
 * **Attention takes its shape from the layer's type** (``layer_types``,
   period [full, sliding, sliding, sliding]). A full layer has 48 query
@@ -44,11 +43,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import profiler
-from ..ops.attention import make_attention_fn
-from ..parallel.moe import (grouped_gated_mlp, moe_apply_held,
-                            softmax_top_k)
-from .llama import RMSNorm, rotary_embedding
-from .smallthinker import _Kernel
+from ..parallel.moe import grouped_gated_mlp, softmax_top_k
+from .decoder import (GatedMLP, RMSNorm, decoder_layers, held_experts,
+                      linear, lm_head, one_entry_a_layer, project_heads,
+                      project_out, rotary_embedding, router_logits,
+                      stack_loads, token_embedding, xla_attention)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -162,22 +161,6 @@ LAGUNA_TINY = LagunaConfig(
     shared_hidden=48)
 
 
-class GatedMLP(nn.Module):
-    """``w_down(silu(w_gate h) * (w_up h))``, no bias: the dense layer's
-    MLP and the shared expert."""
-    hidden: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, h):
-        dense = lambda features, name: nn.Dense(  # noqa: E731
-            features, use_bias=False, dtype=self.dtype,
-            param_dtype=jnp.float32, name=name)
-        return dense(h.shape[-1], "w_down")(
-            nn.silu(dense(self.hidden, "w_gate")(h))
-            * dense(self.hidden, "w_up")(h))
-
-
 def head_gate(ctx, gate_logits):
     """The attention's output gate: context (B, S, H, D) times the
     sigmoid of one logit a head and token (B, S, H)."""
@@ -198,14 +181,10 @@ class LagunaAttention(nn.Module):
     @nn.compact
     def __call__(self, x, positions=None):
         cfg = self.config
-        dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
-            features=(heads, cfg.head_dim), axis=-1, use_bias=False,
-            dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
-        q = dense(self.heads, "wq")(x)
-        k = dense(cfg.num_kv_heads, "wk")(x)
-        v = dense(cfg.num_kv_heads, "wv")(x)
-        gate = nn.Dense(self.heads, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=jnp.float32, name="wg")(x)
+        q = project_heads(self.heads, cfg.head_dim, cfg.dtype, "wq")(x)
+        k = project_heads(cfg.num_kv_heads, cfg.head_dim, cfg.dtype, "wk")(x)
+        v = project_heads(cfg.num_kv_heads, cfg.head_dim, cfg.dtype, "wv")(x)
+        gate = linear(self.heads, cfg.dtype, "wg")(x)
         rotate = functools.partial(
             rotary_embedding, positions=positions,
             **rotary_arguments(self.rotary, cfg.head_dim))
@@ -215,9 +194,7 @@ class LagunaAttention(nn.Module):
             ctx = self.attention_fn(q, k, v, None)
         with jax.named_scope(profiler.SCOPE_ATTN_POINTWISE):
             ctx = head_gate(ctx, gate)
-        return nn.DenseGeneral(features=cfg.dim, axis=(-2, -1),
-                               use_bias=False, dtype=cfg.dtype,
-                               param_dtype=jnp.float32, name="wo")(ctx)
+        return project_out(cfg.dim, cfg.dtype)(ctx)
 
 
 class LagunaBlock(nn.Module):
@@ -247,25 +224,13 @@ class LagunaBlock(nn.Module):
         if self.mlp_kind == DENSE:
             return a + GatedMLP(cfg.mlp_hidden, cfg.dtype,
                                 name="mlp")(h), None
-        held = cfg.held()
         rows = h.reshape(b * s, d)
-        # The router in float32: which experts a token gets is decided on
-        # small differences between logits.
-        logits = rows.astype(jnp.float32) @ _Kernel(
-            (d, cfg.num_experts), name="router")()
+        logits = router_logits(rows, cfg.num_experts)
         with jax.named_scope(profiler.SCOPE_MOE_SHARED):
             shared = GatedMLP(cfg.shared_hidden, cfg.dtype, name="shared")(h)
-        experts = {
-            "w_gate": _Kernel((len(held), d, cfg.expert_hidden),
-                              name="w_gate")(),
-            "w_up": _Kernel((len(held), d, cfg.expert_hidden),
-                            name="w_up")(),
-            "w_down": _Kernel((len(held), cfg.expert_hidden, d),
-                              name="w_down")(),
-        }
-        routed, load = moe_apply_held(
+        routed, load = held_experts(
             functools.partial(grouped_gated_mlp, activation=jax.nn.silu),
-            experts, rows, logits, held, cfg.num_selected,
+            rows, logits, cfg.held(), cfg.expert_hidden, cfg.num_selected,
             route=softmax_top_k)
         return a + shared + cfg.routed_scale * routed.reshape(b, s, d), load
 
@@ -290,32 +255,18 @@ class LagunaLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, return_hidden=False):
         cfg = self.config
-        for name in ("layer_types", "heads_per_layer", "mlp_layer_types"):
-            if len(getattr(cfg, name)) < cfg.num_layers:
-                raise ValueError(f"LagunaLM: {name} needs an entry for each "
-                                 f"of {cfg.num_layers} layers")
-        plain = self.attention_fn or make_attention_fn(
-            causal=True, use_flash=False)
-        windowed = self.window_attention_fn or make_attention_fn(
-            causal=True, use_flash=False, window=cfg.sliding_window)
-        x = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                     name="tok_embeddings")(input_ids).astype(cfg.dtype)
-        block_cls = nn.remat(LagunaBlock) if cfg.remat else LagunaBlock
-        loads = []
-        for i in range(cfg.num_layers):
-            kind = cfg.layer_types[i]
-            x, load = block_cls(
-                cfg, kind=kind, heads=cfg.heads_per_layer[i],
-                mlp_kind=cfg.mlp_layer_types[i],
-                attention_fn=plain if kind == FULL else windowed,
-                name=f"layer_{i}")(x, positions)
-            if cfg.mlp_layer_types[i] == SPARSE:
-                loads.append(load)
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
-        load = jnp.stack(loads) if loads else jnp.zeros(
-            (0, len(cfg.held())), jnp.int32)
+        one_entry_a_layer("LagunaLM", cfg, "layer_types", "heads_per_layer",
+                          "mlp_layer_types")
+        plain = self.attention_fn or xla_attention()
+        windowed = self.window_attention_fn or xla_attention(
+            cfg.sliding_window)
+        layers = [dict(kind=kind, heads=cfg.heads_per_layer[i],
+                       mlp_kind=cfg.mlp_layer_types[i],
+                       attention_fn=plain if kind == FULL else windowed)
+                  for i, kind in enumerate(cfg.layer_types[:cfg.num_layers])]
+        x, loads = decoder_layers(cfg, LagunaBlock, layers,
+                                  token_embedding(cfg)(input_ids), positions)
+        load = stack_loads(loads, cfg.held())
         if return_hidden:
             return x, load
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, name="lm_head")(x)
-        return logits, load
+        return lm_head(cfg)(x), load

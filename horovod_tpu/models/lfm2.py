@@ -2,9 +2,8 @@
 
 The architecture of ``LiquidAI/LFM2-24B-A2B`` (``model_type``
 ``lfm2_moe``; 24B parameters, 2B active; widths from its public
-``config.json``), beside ``SmallThinkerLM``, ``LagunaLM`` and
-``OlmoHybridLM`` and built from their parts (``RMSNorm``,
-``rotary_embedding``, ``causal_conv``, ``moe_apply_held``). The block is
+``config.json``), built from ``models/decoder.py``'s parts and
+``ops.linear_attention.causal_conv``. The block is
 ``h = x + Mixer(norm(x))``, ``y = h + FFN(norm(h))``. What sets it apart:
 
 * **Three mixers in four are gated short convolutions** (``layer_types``,
@@ -54,13 +53,12 @@ import jax
 import jax.numpy as jnp
 
 from ..common import profiler
-from ..ops.attention import make_attention_fn
 from ..ops.linear_attention import causal_conv
-from ..parallel.moe import grouped_gated_mlp, moe_apply_held, sigmoid_top_k
-from .laguna import GatedMLP
-from .llama import RMSNorm, rotary_embedding
-from .olmo_hybrid import _Leaf
-from .smallthinker import _Kernel
+from ..parallel.moe import grouped_gated_mlp, sigmoid_top_k
+from .decoder import (GatedMLP, Leaf, RMSNorm, decoder_layers, held_experts,
+                      linear, one_entry_a_layer, project_heads, project_out,
+                      rotary_embedding, router_logits, stack_loads,
+                      token_embedding, xla_attention)
 
 CONV, FULL = "conv", "full_attention"
 _PERIOD = (CONV, CONV, FULL, CONV)
@@ -139,17 +137,14 @@ class ShortConvMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        dense = lambda features, name: nn.Dense(  # noqa: E731
-            features, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name=name)
         with jax.named_scope(profiler.SCOPE_SHORTCONV):
             b_gate, c_gate, u = jnp.split(
-                dense(3 * cfg.dim, "in_proj")(x), 3, axis=-1)
-            taps = _Leaf("kernel", (cfg.conv_taps, cfg.dim), _taps_init,
-                         name="taps")()
+                linear(3 * cfg.dim, cfg.dtype, "in_proj")(x), 3, axis=-1)
+            taps = Leaf("kernel", (cfg.conv_taps, cfg.dim), _taps_init,
+                        name="taps")()
             with jax.named_scope(profiler.SCOPE_SHORTCONV_POINTWISE):
                 y = gated_short_conv(b_gate, c_gate, u, taps)
-            return dense(cfg.dim, "out_proj")(y)
+            return linear(cfg.dim, cfg.dtype, "out_proj")(y)
 
 
 class Lfm2Attention(nn.Module):
@@ -162,21 +157,16 @@ class Lfm2Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions=None):
         cfg = self.config
-        dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
-            features=(heads, cfg.head_dim), axis=-1, use_bias=False,
-            dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
         q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_norm")(
-            dense(cfg.num_heads, "wq")(x))
+            project_heads(cfg.num_heads, cfg.head_dim, cfg.dtype, "wq")(x))
         k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_norm")(
-            dense(cfg.num_kv_heads, "wk")(x))
-        v = dense(cfg.num_kv_heads, "wv")(x)
+            project_heads(cfg.num_kv_heads, cfg.head_dim, cfg.dtype, "wk")(x))
+        v = project_heads(cfg.num_kv_heads, cfg.head_dim, cfg.dtype, "wv")(x)
         q = rotary_embedding(q, cfg.rope_theta, positions)
         k = rotary_embedding(k, cfg.rope_theta, positions)
         with jax.named_scope(profiler.SCOPE_ATTN_FULL):
             ctx = self.attention_fn(q, k, v, None)
-        return nn.DenseGeneral(features=cfg.dim, axis=(-2, -1),
-                               use_bias=False, dtype=cfg.dtype,
-                               param_dtype=jnp.float32, name="wo")(ctx)
+        return project_out(cfg.dim, cfg.dtype)(ctx)
 
 
 class Lfm2Block(nn.Module):
@@ -207,25 +197,13 @@ class Lfm2Block(nn.Module):
         if not self.sparse:
             return h + GatedMLP(cfg.mlp_hidden, cfg.dtype, name="mlp")(z), \
                 None
-        held = cfg.held()
         rows = z.reshape(b * s, d)
-        # The router in float32: which experts a token gets is decided on
-        # small differences between scores.
-        logits = rows.astype(jnp.float32) @ _Kernel(
-            (d, cfg.num_experts), name="router")()
-        bias = _Leaf("kernel", (cfg.num_experts,),
-                     nn.initializers.normal(0.01), name="expert_bias")()
-        experts = {
-            "w_gate": _Kernel((len(held), d, cfg.expert_hidden),
-                              name="w_gate")(),
-            "w_up": _Kernel((len(held), d, cfg.expert_hidden),
-                            name="w_up")(),
-            "w_down": _Kernel((len(held), cfg.expert_hidden, d),
-                              name="w_down")(),
-        }
-        routed, load = moe_apply_held(
+        logits = router_logits(rows, cfg.num_experts)
+        bias = Leaf("kernel", (cfg.num_experts,),
+                    nn.initializers.normal(0.01), name="expert_bias")()
+        routed, load = held_experts(
             functools.partial(grouped_gated_mlp, activation=jax.nn.silu),
-            experts, rows, logits, held, cfg.num_selected,
+            rows, logits, cfg.held(), cfg.expert_hidden, cfg.num_selected,
             route=sigmoid_top_k(bias))
         return h + routed.reshape(b, s, d), load
 
@@ -250,27 +228,15 @@ class Lfm2LM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, return_hidden=False):
         cfg = self.config
-        if len(cfg.layer_types) < cfg.num_layers:
-            raise ValueError("Lfm2LM: layer_types needs an entry for each "
-                             f"of {cfg.num_layers} layers")
-        attention_fn = self.attention_fn or make_attention_fn(
-            causal=True, use_flash=False)
-        embed = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                         name="tok_embeddings")
-        x = embed(input_ids).astype(cfg.dtype)
-        block_cls = nn.remat(Lfm2Block) if cfg.remat else Lfm2Block
-        loads = []
-        for i, kind in enumerate(cfg.layer_types[:cfg.num_layers]):
-            sparse = i >= cfg.num_dense_layers
-            x, load = block_cls(
-                cfg, kind=kind, sparse=sparse,
-                attention_fn=attention_fn if kind == FULL else None,
-                name=f"layer_{i}")(x, positions)
-            if sparse:
-                loads.append(load)
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
-        load = jnp.stack(loads) if loads else jnp.zeros(
-            (0, len(cfg.held())), jnp.int32)
+        one_entry_a_layer("Lfm2LM", cfg, "layer_types")
+        attention_fn = self.attention_fn or xla_attention()
+        layers = [dict(kind=kind, sparse=i >= cfg.num_dense_layers,
+                       attention_fn=attention_fn if kind == FULL else None)
+                  for i, kind in enumerate(cfg.layer_types[:cfg.num_layers])]
+        embed = token_embedding(cfg)
+        x, loads = decoder_layers(cfg, Lfm2Block, layers, embed(input_ids),
+                                  positions)
+        load = stack_loads(loads, cfg.held())
         if return_hidden:
             return x, load
         return jnp.einsum("bsd,vd->bsv", x, embed.embedding.astype(

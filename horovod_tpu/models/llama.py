@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import profiler
+from .decoder import (RMSNorm, gated_mlp, linear, project_heads, project_out,
+                      rematerialised, rotary_embedding, token_embedding)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,75 +66,6 @@ LLAMA_TINY = LlamaConfig(vocab_size=512, dim=64, num_layers=2, num_heads=4,
                          num_kv_heads=2, ffn_hidden=128, max_seq_len=256)
 
 
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        # All-f32 chain, deliberately: a bf16-application variant (f32
-        # stats, bf16 multiply) measured SLOWER on v5e (56.0k vs 59.3k
-        # tok/s Llama-300M — it splits the fused norm chain) and loosened
-        # sp-parity tolerances. XLA fuses this form fully.
-        x32 = x.astype(jnp.float32)
-        norm = x32 * jnp.reciprocal(
-            jnp.sqrt(jnp.mean(x32 ** 2, axis=-1, keepdims=True) + self.eps))
-        return (norm * scale).astype(self.dtype)
-
-
-def rotary_embedding(x, theta: float, positions=None, rotary_dim=None,
-                     inv_freq=None, scale=None):
-    """Apply RoPE to (B, S, H, D). ``positions`` are the GLOBAL token
-    positions of the rows — defaults to 0..S-1. Shape (S,) rotates every
-    batch row alike (training, whole-batch decode); shape (B, S) gives
-    each sequence its own positions (the serving tier's continuous
-    batches mix sequences at heterogeneous decode positions). Under
-    sequence parallelism each shard must pass its own global offsets
-    (e.g. ``axis_index * S_local + arange(S_local)``) or every shard
-    would rotate as if it held the sequence start.
-
-    The defaults rotate the whole head at ``theta ** (-i / half)``. A
-    partial rotary embedding gives ``rotary_dim`` < D: the first
-    ``rotary_dim`` entries of each head are rotated (rotate-half inside
-    them), the rest pass through. ``inv_freq`` (``rotary_dim // 2``
-    numbers) takes the place of the frequencies ``theta`` gives, for a
-    scaled embedding whose frequencies are blended (YaRN:
-    ``models/laguna.py``); ``scale`` multiplies cos and sin (YaRN's
-    attention factor)."""
-    b, s, h, d = x.shape
-    rotated = d if rotary_dim is None else rotary_dim
-    half = rotated // 2
-    if inv_freq is None:
-        freqs = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
-    else:
-        freqs = np.asarray(inv_freq, np.float32)
-    if positions is None:
-        positions = jnp.arange(s, dtype=jnp.float32)
-    # Angles/cos/sin in f32 (positional phase must not quantize: at
-    # position 64k a bf16 angle would be off by whole radians), then the
-    # APPLICATION runs in the activation dtype — the rotation factors are
-    # in [-1, 1] where bf16 is at its densest, and the f32 elementwise
-    # over (B, S, H, D) this replaces was ~8% of the Llama-300M step
-    # (XProf round 3).
-    angles = positions.astype(jnp.float32)[..., :, None] * freqs
-    # (S, half) rotates every batch row alike, (B, S, half) each its own.
-    at = (None, slice(None), None) if angles.ndim == 2 \
-        else (slice(None), slice(None), None)
-
-    def table(fn):
-        values = fn(angles) if scale is None else fn(angles) * scale
-        return values[at].astype(x.dtype)
-
-    cos, sin = table(jnp.cos), table(jnp.sin)
-    x1, x2 = x[..., :half], x[..., half:rotated]
-    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
-    if rotated < d:
-        parts.append(x[..., rotated:])
-    return jnp.concatenate(parts, axis=-1)
-
-
 class LlamaAttention(nn.Module):
     config: LlamaConfig
     attention_fn: Optional[Callable] = None
@@ -147,17 +80,14 @@ class LlamaAttention(nn.Module):
         """
         cfg = self.config
         head_dim = cfg.dim // cfg.num_heads
-        dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
-            features=(heads, head_dim), axis=-1, use_bias=False,
-            dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
-        q = rotary_embedding(dense(cfg.num_heads, "wq")(x), cfg.rope_theta,
-                             positions)
-        k = rotary_embedding(dense(cfg.num_kv_heads, "wk")(x),
-                             cfg.rope_theta, positions)
-        v = dense(cfg.num_kv_heads, "wv")(x)
-        out_proj = nn.DenseGeneral(features=cfg.dim, axis=(-2, -1),
-                                   use_bias=False, dtype=cfg.dtype,
-                                   param_dtype=jnp.float32, name="wo")
+        q = rotary_embedding(
+            project_heads(cfg.num_heads, head_dim, cfg.dtype, "wq")(x),
+            cfg.rope_theta, positions)
+        k = rotary_embedding(
+            project_heads(cfg.num_kv_heads, head_dim, cfg.dtype, "wk")(x),
+            cfg.rope_theta, positions)
+        v = project_heads(cfg.num_kv_heads, head_dim, cfg.dtype, "wv")(x)
+        out_proj = project_out(cfg.dim, cfg.dtype)
 
         if cache is not None:
             ctx, new_cache = _cached_attention(q, k, v, cache, cache_index)
@@ -402,12 +332,7 @@ class LlamaBlock(nn.Module):
         x, new_cache = attention_sublayer(cfg, self.attention_fn, x,
                                           positions, cache, cache_index)
         h = RMSNorm(cfg.norm_eps, cfg.dtype, name="ffn_norm")(x)
-        dense = lambda f, name: nn.Dense(  # noqa: E731
-            f, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32,
-            name=name)
-        gated = nn.silu(dense(cfg.ffn_hidden, "w_gate")(h)) * \
-            dense(cfg.ffn_hidden, "w_up")(h)
-        out = x + dense(cfg.dim, "w_down")(gated)
+        out = x + gated_mlp(h, cfg.ffn_hidden, cfg.dtype)
         return out if cache is None else (out, new_cache)
 
 
@@ -441,10 +366,9 @@ class LlamaLM(nn.Module):
                 positions = cache_index[:, None] + steps
             else:
                 positions = cache_index + steps
-        x = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                     name="tok_embeddings")(input_ids).astype(cfg.dtype)
+        x = token_embedding(cfg)(input_ids).astype(cfg.dtype)
         new_cache = {}
-        block_cls = nn.remat(LlamaBlock) if cfg.remat else LlamaBlock
+        block_cls = rematerialised(cfg, LlamaBlock)
         for i in range(cfg.num_layers):
             if cache is None:
                 x = block_cls(cfg, attention_fn=self.attention_fn,
@@ -462,9 +386,8 @@ class LlamaLM(nn.Module):
             return x
         # Head matmul in head_dtype (default: model compute dtype; MXU
         # accumulates f32 internally) — see LlamaConfig.head_dtype.
-        logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.head_dtype or cfg.dtype,
-                          param_dtype=jnp.float32, name="lm_head")(x)
+        logits = linear(cfg.vocab_size, cfg.head_dtype or cfg.dtype,
+                        "lm_head")(x)
         return logits if cache is None else (logits, new_cache)
 
 
@@ -775,172 +698,3 @@ def llama_tp_param_specs(params, axis: str = "model"):
         return P()
 
     return jax.tree_util.tree_map_with_path(spec, params)
-
-
-def token_nll(logits, targets):
-    """Per-token negative log-likelihood via the lse formulation:
-    ``lse(logits) - logits[target]``. Unlike ``log_softmax`` +
-    ``take_along_axis`` this never materializes a (..., V) f32 array —
-    the f32 upcast fuses into the logsumexp reduction and the target
-    logit is a gather — which cuts ~1 GiB of peak HBM at
-    (B=8, S=1024, V=32000) and is what lets larger batches fit."""
-    # Gather BEFORE the upcast: astype-then-gather would force the f32
-    # copy this formulation exists to avoid (the upcast inside logsumexp
-    # fuses into the reduction; a gather consumer would not).
-    lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-    target_logit = jnp.take_along_axis(
-        logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
-    return lse - target_logit
-
-
-def causal_lm_loss(logits, input_ids):
-    """Next-token cross entropy (shifted)."""
-    return token_nll(logits[:, :-1], input_ids[:, 1:]).mean()
-
-
-def _loss_chunks(hidden, head_kernel, input_ids, num_chunks, ahead):
-    """The operands of one sweep over the sequence's chunks, chunk-major:
-    hidden states (n, B, c, D), targets shifted by ``ahead`` (n, B, c) and
-    the head kernel in ``hidden``'s dtype."""
-    b, s, d = hidden.shape
-    c = s // num_chunks
-    # Shifted targets over the FULL sequence; the final ``ahead`` positions
-    # have no target: they wrap to garbage values and are masked out.
-    targets = jnp.concatenate([input_ids[:, ahead:], input_ids[:, :ahead]],
-                              axis=1)
-    h = hidden.reshape(b, num_chunks, c, d).transpose(1, 0, 2, 3)
-    t = targets.reshape(b, num_chunks, c).transpose(1, 0, 2)
-    return h, t, head_kernel.astype(hidden.dtype)
-
-
-def _mean_nll(nll, b, s, ahead):
-    """The mean over every position that has a target (all but each
-    sequence's last ``ahead``), of the chunk-major (n, B, c) per-token
-    nll."""
-    return nll.transpose(1, 0, 2).reshape(b, s)[:, :-ahead].mean()
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _chunked_loss(hidden, head_kernel, input_ids, num_chunks, ahead):
-    # The call nobody differentiates: the loss alone, one product a chunk.
-    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
-        b, s, _ = hidden.shape
-        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks,
-                               ahead)
-        # Same matmul dtype as the in-model lm_head (MXU f32 accumulate).
-        nll = jax.lax.map(lambda args: token_nll(args[0] @ w, args[1]),
-                          (h, t))
-        return _mean_nll(nll, b, s, ahead)
-
-
-def _chunked_loss_fwd(hidden, head_kernel, input_ids, num_chunks, ahead):
-    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
-        b, s, d = hidden.shape
-        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks,
-                               ahead)
-        vocab = w.shape[1]
-        # d(mean)/d(nll) of every position: 0 where there is no target.
-        scale = (jnp.arange(s) < s - ahead).astype(jnp.float32) \
-            / (b * (s - ahead))
-        scale = scale.reshape(num_chunks, 1, s // num_chunks)
-
-        def chunk(dw, args):
-            h_c, t_c, scale_c = args
-            logits = h_c @ w
-            # token_nll's sweep (the gather before the upcast), with the
-            # softmax's gradient taken while the logits are in hand.
-            z = logits.astype(jnp.float32)
-            lse = jax.scipy.special.logsumexp(z, axis=-1)
-            target_logit = jnp.take_along_axis(
-                logits, t_c[..., None], axis=-1)[..., 0].astype(jnp.float32)
-            onehot = jnp.arange(vocab) == t_c[..., None]
-            dlogits = ((jnp.exp(z - lse[..., None]) - onehot)
-                       * scale_c[..., None]).astype(logits.dtype)
-            dh_c = jnp.einsum("bcv,dv->bcd", dlogits, w)
-            dw = dw + jnp.einsum("bcd,bcv->dv", h_c, dlogits,
-                                 preferred_element_type=jnp.float32)
-            return dw, (lse - target_logit, dh_c)
-
-        dw, (nll, dh) = jax.lax.scan(
-            chunk, jnp.zeros((d, vocab), jnp.float32), (h, t, scale))
-        dh = dh.transpose(1, 0, 2, 3).reshape(b, s, d)
-        return _mean_nll(nll, b, s, ahead), (
-            dh, dw.astype(head_kernel.dtype))
-
-
-def _chunked_loss_bwd(num_chunks, ahead, residuals, g):
-    del num_chunks, ahead
-    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
-        dh, dw = residuals
-        return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
-                (g * dw.astype(jnp.float32)).astype(dw.dtype), None)
-
-
-_chunked_loss.defvjp(_chunked_loss_fwd, _chunked_loss_bwd)
-
-
-def chunked_causal_lm_loss(hidden, head_kernel, input_ids,
-                           num_chunks: int = 8, ahead: int = 1):
-    """:func:`causal_lm_loss` with the lm_head fused in, applied one
-    sequence chunk at a time in ONE sweep (a ``lax.scan``, one ``while``
-    of the compiled step): the full (B, S, V) logits — and their
-    same-sized cotangent — never exist; peak extra HBM is
-    O(B * S/num_chunks * V). At Llama-300M S=16384 that's the ~2 GiB that
-    makes single-chip training fit where the fused-head path OOMs.
-
-    ``hidden``: final-norm hidden states from
-    ``model.apply(..., return_hidden=True)``, shape (B, S, dim);
-    ``head_kernel``: ``params["lm_head"]["kernel"]`` (dim, V).
-    The LOSS matches ``causal_lm_loss`` on the full logits exactly (each
-    logit row is the same dot product; the mean is reassembled exactly).
-
-    Differentiated (a ``jax.custom_vjp``), the same sweep also computes
-    both GRADIENTS: the loss ends the step, so with a chunk's logits in
-    hand ``dlogits = (softmax - onehot) / count`` is known (zero at each
-    sequence's last position) and the chunk does its three
-    vocabulary-wide products at once — ``h_c @ w``, ``dlogits @ w.T``,
-    ``h_c.T @ dlogits`` — in the operands' dtype with float32
-    accumulation; nothing is recomputed. Stored for the backward rule,
-    which only multiplies them by the incoming scalar: ``dh`` (B, S, dim)
-    in ``hidden``'s dtype and ``dW`` (dim, V), summed over the chunks in
-    float32 and rounded once to ``head_kernel``'s dtype. Against autodiff
-    of ``causal_lm_loss`` on the full logits both agree to 1e-6 in
-    float32 and, under bf16, to the rounding of the logits' cotangent
-    (grad-norm deltas under 1%, ``tests/test_llama.py``). Called
-    undifferentiated it computes the loss alone. Forward mode
-    (``jax.jvp``) is not defined.
-
-    ``ahead``: how far ahead of a position its target lies. 1 is the next
-    token; a multi-token-prediction head at depth k passes k + 1
-    (``models/joyai.py``: 2), and the mean is over the ``S - ahead``
-    positions a sequence that have a target."""
-    s = hidden.shape[1]
-    if s % num_chunks:
-        raise ValueError(
-            f"chunked_causal_lm_loss: seq len {s} must be divisible by "
-            f"num_chunks {num_chunks}")
-    if not 1 <= ahead < s:
-        raise ValueError(
-            f"chunked_causal_lm_loss: ahead={ahead} must lie in [1, {s})")
-    return _chunked_loss(hidden, head_kernel, input_ids, num_chunks, ahead)
-
-
-def sp_causal_lm_loss(logits, input_ids, axis_name: str):
-    """Sequence-parallel twin of :func:`causal_lm_loss`: ``logits`` /
-    ``input_ids`` are the LOCAL (contiguous-layout) sequence shards inside
-    ``shard_map``. The next-token shift crosses shard boundaries, so each
-    shard fetches its right neighbor's first token over one ``ppermute``
-    (riding ICI) and the global final position is masked out; the result
-    is the same global mean on every shard — numerically identical to the
-    single-device loss on the gathered sequence."""
-    n = jax.lax.psum(1, axis_name)
-    idx = jax.lax.axis_index(axis_name)
-    nxt = jax.lax.ppermute(
-        input_ids[:, :1], axis_name,
-        [(i, (i - 1) % n) for i in range(n)])
-    targets = jnp.concatenate([input_ids[:, 1:], nxt], axis=1)
-    nll = token_nll(logits, targets)
-    valid = jnp.ones(input_ids.shape, bool).at[:, -1].set(idx != n - 1)
-    total = jax.lax.psum(jnp.where(valid, nll, 0.0).sum(), axis_name)
-    count = jax.lax.psum(valid.sum(), axis_name)
-    return total / count

@@ -19,10 +19,11 @@ which experts (several, any subset) a device holds, and
 ``models/smallthinker.py`` is built on it. The capacity path here keeps
 its fixed ``[E, C, D]`` buffers because ``all_to_all`` needs them.
 
-The MLM/causal losses and non-MoE machinery are shared with the Llama
-family. Aux (load-balancing) losses from every MoE layer are summed into
-the ``"aux_loss"`` collection — fold ``sum(aux) * aux_weight`` into the
-objective.
+The non-MoE machinery is shared with the Llama family (the one decoder
+file that imports another: this one extends ``llama.py``); the losses are
+``models/losses.py``'s. Aux (load-balancing) losses from every MoE layer
+are summed into the ``"aux_loss"`` collection — fold ``sum(aux) *
+aux_weight`` into the objective.
 """
 
 from __future__ import annotations
@@ -34,13 +35,8 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..parallel.moe import moe_apply, moe_apply_dense
-from .llama import (  # noqa: F401
-    LlamaAttention,
-    LlamaBlock,
-    LlamaConfig,
-    RMSNorm,
-    causal_lm_loss,
-)
+from .decoder import RMSNorm, linear, rematerialised, token_embedding
+from .llama import LlamaBlock, LlamaConfig, attention_sublayer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,8 +151,6 @@ class MoeBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions=None, cache=None, cache_index=None):
         cfg = self.config
-        from .llama import attention_sublayer
-
         x, new_cache = attention_sublayer(cfg.llama(), self.attention_fn, x,
                                           positions, cache, cache_index)
         h = RMSNorm(cfg.norm_eps, cfg.dtype, name="ffn_norm")(x)
@@ -212,11 +206,10 @@ class MoeLM(nn.Module):
         cfg = self.config
         if cache is not None and positions is None:
             positions = cache_index + jnp.arange(input_ids.shape[1])
-        x = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                     name="tok_embeddings")(input_ids).astype(cfg.dtype)
+        x = token_embedding(cfg)(input_ids).astype(cfg.dtype)
         new_cache = {}
-        moe_cls = nn.remat(MoeBlock) if cfg.remat else MoeBlock
-        dense_cls = nn.remat(LlamaBlock) if cfg.remat else LlamaBlock
+        moe_cls = rematerialised(cfg, MoeBlock)
+        dense_cls = rematerialised(cfg, LlamaBlock)
         for i in range(cfg.num_layers):
             # Every moe_every-th layer is routed (moe_every=1: all layers);
             # the rest are plain LlamaBlocks (shared implementation).
@@ -244,7 +237,6 @@ class MoeLM(nn.Module):
             return x
         # Head matmul in head_dtype (default: model compute dtype),
         # matching LlamaLM — see LlamaConfig.head_dtype.
-        logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                          dtype=cfg.head_dtype or cfg.dtype,
-                          param_dtype=jnp.float32, name="lm_head")(x)
+        logits = linear(cfg.vocab_size, cfg.head_dtype or cfg.dtype,
+                        "lm_head")(x)
         return logits if cache is None else (logits, new_cache)

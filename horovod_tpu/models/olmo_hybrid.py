@@ -3,9 +3,8 @@ three gated delta-rule layers to every full-attention layer.
 
 The architecture of ``allenai/Olmo-Hybrid-7B`` (widths from its public
 ``config.json``; the linear layers' keys are those of
-flash-linear-attention's ``GatedDeltaNet``), beside ``LlamaLM`` and
-``SmallThinkerLM`` and built from their parts (``RMSNorm``,
-``make_attention_fn``). What sets it apart:
+flash-linear-attention's ``GatedDeltaNet``), built from
+``models/decoder.py``'s parts. What sets it apart:
 
 * **The block norms its sublayers' outputs**, the OLMo 2/3 family's
   convention: ``h = x + RMSNorm(Mixer(x))``, ``y = h + RMSNorm(MLP(h))``,
@@ -58,8 +57,9 @@ from jax import lax
 
 from ..common import profiler
 from ..ops import linear_attention
-from ..ops.attention import make_attention_fn
-from .llama import RMSNorm
+from .decoder import (Leaf, RMSNorm, decoder_layers, gated_mlp, linear,
+                      lm_head, one_entry_a_layer, token_embedding,
+                      xla_attention)
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -98,11 +98,6 @@ OLMO_HYBRID_TINY = OlmoHybridConfig(
     linear_value_dim=32)
 
 
-def _dense(features, cfg, name):
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=jnp.float32, name=name)
-
-
 def _a_log_init(key, shape, dtype=jnp.float32):
     """``log`` of a decay rate uniform in [1, 16], flash-linear-attention's
     default."""
@@ -118,18 +113,6 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 def _taps_init(key, shape, dtype=jnp.float32):
     return jax.random.uniform(key, shape, dtype, -0.5, 0.5)
-
-
-class _Leaf(nn.Module):
-    """One float32 parameter under a leaf name of the usual vocabulary
-    (``kernel``, ``scale``), as ``nn.Dense`` and ``RMSNorm`` name theirs."""
-    leaf: str
-    shape: Tuple[int, ...]
-    init: Callable
-
-    @nn.compact
-    def __call__(self):
-        return self.param(self.leaf, self.init, self.shape, jnp.float32)
 
 
 class _WholeNorm(nn.Module):
@@ -166,12 +149,12 @@ class FullAttentionMixer(nn.Module):
         b, s, _ = x.shape
         heads = len(cfg.held())
         width = heads * cfg.head_dim
-        q = _WholeNorm(cfg, name="q_norm")(_dense(width, cfg, "wq")(x))
-        k = _WholeNorm(cfg, name="k_norm")(_dense(width, cfg, "wk")(x))
-        v = _dense(width, cfg, "wv")(x)
+        q = _WholeNorm(cfg, name="q_norm")(linear(width, cfg.dtype, "wq")(x))
+        k = _WholeNorm(cfg, name="k_norm")(linear(width, cfg.dtype, "wk")(x))
+        v = linear(width, cfg.dtype, "wv")(x)
         split = lambda a: a.reshape(b, s, heads, cfg.head_dim)  # noqa: E731
         ctx = self.attention_fn(split(q), split(k), split(v), None)
-        return _dense(cfg.dim, cfg, "wo")(ctx.reshape(b, s, width))
+        return linear(cfg.dim, cfg.dtype, "wo")(ctx.reshape(b, s, width))
 
 
 class LinearAttentionMixer(nn.Module):
@@ -191,14 +174,14 @@ class LinearAttentionMixer(nn.Module):
         d_k, d_v = cfg.linear_key_dim, cfg.linear_value_dim
 
         def conv(a, name):
-            return linear_attention.causal_conv_silu(a, _Leaf(
+            return linear_attention.causal_conv_silu(a, Leaf(
                 "kernel", (cfg.conv_kernel, a.shape[-1]), _taps_init,
                 name=name)())
 
-        q = _dense(heads * d_k, cfg, "wq")(x)
-        k = _dense(heads * d_k, cfg, "wk")(x)
-        v = _dense(heads * d_v, cfg, "wv")(x)
-        gate = _dense(heads * d_v, cfg, "wg")(x)
+        q = linear(heads * d_k, cfg.dtype, "wq")(x)
+        k = linear(heads * d_k, cfg.dtype, "wk")(x)
+        v = linear(heads * d_v, cfg.dtype, "wv")(x)
+        gate = linear(heads * d_v, cfg.dtype, "wg")(x)
         with jax.named_scope(profiler.SCOPE_LINATTN_CONV):
             q = linear_attention.l2_normalize(
                 conv(q, "conv_q").reshape(b, s, heads, d_k)) * d_k ** -0.5
@@ -206,25 +189,23 @@ class LinearAttentionMixer(nn.Module):
                 conv(k, "conv_k").reshape(b, s, heads, d_k))
             v = conv(v, "conv_v").reshape(b, s, heads, d_v)
         x32 = x.astype(jnp.float32)
-        beta = 2.0 * jax.nn.sigmoid(nn.Dense(
-            heads, use_bias=False, dtype=jnp.float32, name="wb")(x32))
+        beta = 2.0 * jax.nn.sigmoid(linear(heads, jnp.float32, "wb")(x32))
         a_log = self.param("A_log", _a_log_init, (heads,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (heads,), jnp.float32)
-        g = -jnp.exp(a_log) * jax.nn.softplus(nn.Dense(
-            heads, use_bias=False, dtype=jnp.float32, name="wa")(x32)
-            + dt_bias)
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            linear(heads, jnp.float32, "wa")(x32) + dt_bias)
         with jax.named_scope(profiler.SCOPE_LINATTN_SCAN):
             o, state = linear_attention.gated_delta_rule(
                 q, k, v, g, beta, output_final_state=True)
         with jax.named_scope(profiler.SCOPE_LINATTN_GATE):
             o = linear_attention.gated_head_norm(
                 o, gate.reshape(b, s, heads, d_v),
-                _Leaf("scale", (d_v,), nn.initializers.ones,
+                Leaf("scale", (d_v,), nn.initializers.ones,
                       name="o_norm")(), cfg.norm_eps)
         stats = lax.stop_gradient(jnp.stack([
             jnp.min(g), jnp.mean(g),
             jnp.sqrt(jnp.max(jnp.sum(state * state, axis=(-2, -1))))]))
-        return _dense(cfg.dim, cfg, "wo")(
+        return linear(cfg.dim, cfg.dtype, "wo")(
             o.reshape(b, s, heads * d_v)), stats
 
 
@@ -252,9 +233,7 @@ class OlmoHybridBlock(nn.Module):
         if cfg.heads_axis is not None:
             mixed = lax.psum(mixed, cfg.heads_axis)
         h = x + RMSNorm(cfg.norm_eps, cfg.dtype, name="mixer_norm")(mixed)
-        mlp = _dense(cfg.dim, cfg, "w_down")(
-            jax.nn.silu(_dense(cfg.mlp_hidden, cfg, "w_gate")(h))
-            * _dense(cfg.mlp_hidden, cfg, "w_up")(h))
+        mlp = gated_mlp(h, cfg.mlp_hidden, cfg.dtype)
         return h + RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(mlp), \
             stats
 
@@ -277,25 +256,15 @@ class OlmoHybridLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, return_hidden=False):
         cfg = self.config
-        if len(cfg.layer_types) < cfg.num_layers:
-            raise ValueError("OlmoHybridLM: layer_types needs an entry for "
-                             f"each of {cfg.num_layers} layers")
-        attention_fn = self.attention_fn or make_attention_fn(
-            causal=True, use_flash=False)
-        x = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                     name="tok_embeddings")(input_ids).astype(cfg.dtype)
-        block_cls = nn.remat(OlmoHybridBlock) if cfg.remat \
-            else OlmoHybridBlock
-        stats = []
-        for i, kind in enumerate(cfg.layer_types[:cfg.num_layers]):
-            x, layer_stats = block_cls(cfg, kind, attention_fn,
-                                       name=f"layer_{i}")(x)
-            if kind == LINEAR:
-                stats.append(layer_stats)
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        one_entry_a_layer("OlmoHybridLM", cfg, "layer_types")
+        attention_fn = self.attention_fn or xla_attention()
+        kinds = cfg.layer_types[:cfg.num_layers]
+        layers = [dict(layer_type=kind, attention_fn=attention_fn)
+                  for kind in kinds]
+        x, stats = decoder_layers(cfg, OlmoHybridBlock, layers,
+                                  token_embedding(cfg)(input_ids))
+        stats = [row for row, kind in zip(stats, kinds) if kind == LINEAR]
         stats = jnp.stack(stats) if stats else jnp.zeros((0, 3), jnp.float32)
         if return_hidden:
             return x, stats
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, name="lm_head")(x)
-        return logits, stats
+        return lm_head(cfg)(x), stats

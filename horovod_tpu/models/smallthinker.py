@@ -2,8 +2,8 @@
 
 The architecture of ``PowerInfer/SmallThinker-21BA3B-Instruct`` (the
 SmallThinker report, arXiv:2507.20984; widths from its public
-``config.json``), beside ``LlamaLM`` and ``MoeLM`` and built from their
-parts (``RMSNorm``, ``rotary_embedding``). What sets its block apart:
+``config.json``), built from ``models/decoder.py``'s parts. What sets
+its block apart:
 
 * **Every layer is an expert layer**, 6 of 64 experts a token, no shared
   expert, no capacity and no dropped assignment; an expert is a
@@ -20,15 +20,15 @@ parts (``RMSNorm``, ``rotary_embedding``). What sets its block apart:
   hidden width of 2560, so q is 3584 wide).
 
 **The experts held.** ``experts_held`` names the expert ids whose weights
-this device has; the block hands them to
-``parallel.moe.moe_apply_held``, which routes over all
-``num_experts`` and returns the part of the layer's result that the held
-experts give. ``None`` holds all of them and is the published layer. With
-a share (16 of 64: one chip of four that share each layer) the block's
-output is ``a + (that part)``, and that partial result goes on to the next
-layer: nothing stands in for the other devices or their exchange. The
-parts of disjoint shares, with attention and the residual counted once,
-add up to the whole layer (``tests/test_smallthinker.py``).
+this device has; the block hands them to ``decoder.held_experts``
+(``parallel.moe.moe_apply_held``), which routes over all ``num_experts``
+and returns the part of the layer's result that the held experts give.
+``None`` holds all of them and is the published layer. With a share (16
+of 64: one chip of four that share each layer) the block's output is ``a
++ (that part)``, and that partial result goes on to the next layer:
+nothing stands in for the other devices or their exchange. The parts of
+disjoint shares, with attention and the residual counted once, add up to
+the whole layer (``tests/test_smallthinker.py``).
 """
 
 from __future__ import annotations
@@ -39,10 +39,11 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ..ops.attention import make_attention_fn
-from ..parallel.moe import (grouped_gated_mlp, moe_apply_held,
-                            softmax_top_k)
-from .llama import RMSNorm, rotary_embedding
+from ..parallel.moe import grouped_gated_mlp, softmax_top_k
+from .decoder import (RMSNorm, decoder_layers, held_experts, lm_head,
+                      one_entry_a_layer, project_heads, project_out,
+                      rotary_embedding, router_logits, stack_loads,
+                      token_embedding, xla_attention)
 
 _PERIOD = (0, 1, 1, 1)      # global without RoPE, then three window layers
 
@@ -83,17 +84,6 @@ SMALLTHINKER_TINY = SmallThinkerConfig(
     sliding_window=48, window_layout=_PERIOD * 2, rope_layout=_PERIOD * 2)
 
 
-class _Kernel(nn.Module):
-    """One float32 matrix under the leaf name ``kernel``, as ``nn.Dense``
-    names its own."""
-    shape: Tuple[int, ...]
-
-    @nn.compact
-    def __call__(self):
-        return self.param("kernel", nn.initializers.normal(0.02), self.shape,
-                          jnp.float32)
-
-
 class SmallThinkerAttention(nn.Module):
     """Causal grouped-query attention at an explicit head width, rotary
     embedding on or off. ``attention_fn(q, k, v, None)`` carries the band
@@ -106,19 +96,14 @@ class SmallThinkerAttention(nn.Module):
     @nn.compact
     def __call__(self, x, positions=None):
         cfg = self.config
-        dense = lambda heads, name: nn.DenseGeneral(  # noqa: E731
-            features=(heads, cfg.head_dim), axis=-1, use_bias=False,
-            dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
-        q = dense(cfg.num_heads, "wq")(x)
-        k = dense(cfg.num_kv_heads, "wk")(x)
-        v = dense(cfg.num_kv_heads, "wv")(x)
+        q = project_heads(cfg.num_heads, cfg.head_dim, cfg.dtype, "wq")(x)
+        k = project_heads(cfg.num_kv_heads, cfg.head_dim, cfg.dtype, "wk")(x)
+        v = project_heads(cfg.num_kv_heads, cfg.head_dim, cfg.dtype, "wv")(x)
         if self.rope:
             q = rotary_embedding(q, cfg.rope_theta, positions)
             k = rotary_embedding(k, cfg.rope_theta, positions)
         ctx = self.attention_fn(q, k, v, None)
-        return nn.DenseGeneral(features=cfg.dim, axis=(-2, -1),
-                               use_bias=False, dtype=cfg.dtype,
-                               param_dtype=jnp.float32, name="wo")(ctx)
+        return project_out(cfg.dim, cfg.dtype)(ctx)
 
 
 class SmallThinkerBlock(nn.Module):
@@ -133,28 +118,16 @@ class SmallThinkerBlock(nn.Module):
     def __call__(self, x, positions=None):
         cfg = self.config
         b, s, d = x.shape
-        held = cfg.held()
-        # The router, ahead of attention and on the un-normed input, in
-        # float32: which experts a token gets is decided on small
-        # differences between logits.
-        logits = x.reshape(b * s, d).astype(jnp.float32) @ _Kernel(
-            (d, cfg.num_experts), name="router")()
+        # The router reads the un-normed input, ahead of attention.
+        logits = router_logits(x.reshape(b * s, d), cfg.num_experts)
         a = x + SmallThinkerAttention(
             cfg, self.rope, self.attention_fn, name="attention")(
             RMSNorm(cfg.norm_eps, cfg.dtype, name="attention_norm")(x),
             positions)
         h = RMSNorm(cfg.norm_eps, cfg.dtype, name="ffn_norm")(a)
-        experts = {
-            "w_gate": _Kernel((len(held), d, cfg.expert_hidden),
-                              name="w_gate")(),
-            "w_up": _Kernel((len(held), d, cfg.expert_hidden),
-                            name="w_up")(),
-            "w_down": _Kernel((len(held), cfg.expert_hidden, d),
-                              name="w_down")(),
-        }
-        y, load = moe_apply_held(grouped_gated_mlp, experts,
-                                 h.reshape(b * s, d), logits, held,
-                                 cfg.num_selected, route=softmax_top_k)
+        y, load = held_experts(grouped_gated_mlp, h.reshape(b * s, d),
+                               logits, cfg.held(), cfg.expert_hidden,
+                               cfg.num_selected, route=softmax_top_k)
         return a + y.reshape(b, s, d), load
 
 
@@ -178,30 +151,17 @@ class SmallThinkerLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, return_hidden=False):
         cfg = self.config
-        if len(cfg.window_layout) < cfg.num_layers or len(
-                cfg.rope_layout) < cfg.num_layers:
-            raise ValueError("SmallThinkerLM: window_layout and rope_layout "
-                             f"need an entry for each of {cfg.num_layers} "
-                             "layers")
-        plain = self.attention_fn or make_attention_fn(
-            causal=True, use_flash=False)
-        windowed = self.window_attention_fn or make_attention_fn(
-            causal=True, use_flash=False, window=cfg.sliding_window)
-        x = nn.Embed(cfg.vocab_size, cfg.dim, param_dtype=jnp.float32,
-                     name="tok_embeddings")(input_ids).astype(cfg.dtype)
-        block_cls = nn.remat(SmallThinkerBlock) if cfg.remat \
-            else SmallThinkerBlock
-        loads = []
-        for i in range(cfg.num_layers):
-            x, load = block_cls(
-                cfg, rope=bool(cfg.rope_layout[i]),
-                attention_fn=windowed if cfg.window_layout[i] else plain,
-                name=f"layer_{i}")(x, positions)
-            loads.append(load)
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
-        load = jnp.stack(loads)
+        one_entry_a_layer("SmallThinkerLM", cfg, "window_layout",
+                          "rope_layout")
+        plain = self.attention_fn or xla_attention()
+        windowed = self.window_attention_fn or xla_attention(
+            cfg.sliding_window)
+        layers = [dict(rope=bool(cfg.rope_layout[i]),
+                       attention_fn=windowed if cfg.window_layout[i]
+                       else plain) for i in range(cfg.num_layers)]
+        x, loads = decoder_layers(cfg, SmallThinkerBlock, layers,
+                                  token_embedding(cfg)(input_ids), positions)
+        load = stack_loads(loads, cfg.held())
         if return_hidden:
             return x, load
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, name="lm_head")(x)
-        return logits, load
+        return lm_head(cfg)(x), load

@@ -23,7 +23,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from .bert import SelfAttention
-from .llama import token_nll
+from .losses import token_nll
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,5 +128,5 @@ class VisionTransformer(nn.Module):
 
 def classification_loss(logits, labels):
     """Mean cross entropy over the batch, lse-formulated (no (B, C) f32
-    log-softmax materialization — ``llama.token_nll``)."""
+    log-softmax materialization — ``losses.token_nll``)."""
     return token_nll(logits, labels).mean()

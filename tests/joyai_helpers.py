@@ -3,37 +3,19 @@ benchmark's plain reference loaded by path, and seeded weights at scales
 where every path matters."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import JOYAI_TINY, JoyAILM
+from decoder_helpers import reference_fixture
 from model_helpers import jit_init
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 96
 
 
-@pytest.fixture(scope="module")
-def reference():
-    """The benchmark's reference file, loaded by path (its name holds
-    ``-``) with ``benchmarks`` on the path for its own import."""
-    import sys
-
-    bench = os.path.join(ROOT, "benchmarks")
-    sys.path.insert(0, bench)
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "joyai_reference", os.path.join(
-                bench, "reference", "joyai-llm-flash.py"))
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(bench)
-    return module
+reference = reference_fixture("joyai-llm-flash")
 
 
 def _config(held=None, **over):
@@ -56,25 +38,6 @@ def _reference_config(cfg, mtp_weight=0.0, **optimizer):
         "deployment": {"experts_held": list(cfg.held())},
         "optimizer": optimizer,
     }
-
-
-def _blocks(params):
-    """The parameter trees of the blocks that may route: the layers' and
-    the module's."""
-    return [params[n] for n in sorted(params) if n.startswith("layer_")] \
-        + [params["mtp"]["block"]]
-
-
-def _share(params, held):
-    """``params`` of the model that holds every routed expert, cut to
-    ``held``; what every chip holds alike is left whole."""
-    out = jax.tree.map(lambda x: x, params)
-    for block in _blocks(out):
-        for w in ("w_gate", "w_up", "w_down"):
-            if w in block:
-                block[w] = {"kernel": block[w]["kernel"][
-                    jnp.array(held, jnp.int32)]}
-    return out
 
 
 @pytest.fixture(scope="module")

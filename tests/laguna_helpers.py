@@ -3,39 +3,20 @@ benchmark's plain reference loaded by path, and seeded weights at scales
 where every path matters."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import LAGUNA_TINY, LagunaLM
+from decoder_helpers import reference_fixture
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # The tiny window is 48 and YaRN's original context 32: both shorter than
 # the sequence.
 SEQ = 128
 
 
-@pytest.fixture(scope="module")
-def reference():
-    """The benchmark's reference file, loaded by path (its name holds a
-    ``-`` and a ``.``) with ``benchmarks`` on the path for its own
-    import."""
-    import sys
-
-    bench = os.path.join(ROOT, "benchmarks")
-    sys.path.insert(0, bench)
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "laguna_reference", os.path.join(
-                bench, "reference", "laguna-xs.2.py"))
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(bench)
-    return module
+reference = reference_fixture("laguna-xs.2")
 
 
 def _config(held=None, **over):
@@ -72,19 +53,6 @@ def _reference_config(cfg):
             "sliding_attention": _rope_parameters(cfg.sliding_rotary)},
         "deployment": {"experts_held": list(cfg.held())},
     }
-
-
-def _share(params, held):
-    """``params`` of the model that holds every routed expert, cut to
-    ``held``; what every chip holds alike is left whole."""
-    out = jax.tree.map(lambda x: x, params)
-    for name in sorted(n for n in out if n.startswith("layer_")):
-        for w in ("w_gate", "w_up", "w_down"):
-            if w in out[name]:
-                out[name][w] = {
-                    "kernel": out[name][w]["kernel"][
-                        jnp.array(held, jnp.int32)]}
-    return out
 
 
 @pytest.fixture(scope="module")

@@ -3,36 +3,18 @@ benchmark's plain reference loaded by path, seeded weights, and the cut of
 a whole model's weights to a share of its heads."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import OLMO_HYBRID_TINY, OlmoHybridLM
+from decoder_helpers import reference_fixture
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 160       # three chunks of 64, the last one padded
 
 
-@pytest.fixture(scope="module")
-def reference():
-    """The benchmark's reference file, loaded by path (its name holds a
-    ``-``) with ``benchmarks`` on the path for its own import."""
-    import sys
-
-    bench = os.path.join(ROOT, "benchmarks")
-    sys.path.insert(0, bench)
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "olmo_hybrid_reference", os.path.join(
-                bench, "reference", "olmo-hybrid-7b.py"))
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(bench)
-    return module
+reference = reference_fixture("olmo-hybrid-7b")
 
 
 def _config(held=None, **over):

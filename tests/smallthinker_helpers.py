@@ -3,36 +3,18 @@ benchmark's plain reference loaded by path, and seeded weights at scales
 where every path matters."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import SMALLTHINKER_TINY, SmallThinkerLM
+from decoder_helpers import reference_fixture
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 128       # the tiny window is 48: shorter than the sequence
 
 
-@pytest.fixture(scope="module")
-def reference():
-    """The benchmark's reference file, loaded by path (its name holds a
-    ``-``) with ``benchmarks`` on the path for its own import."""
-    import sys
-
-    bench = os.path.join(ROOT, "benchmarks")
-    sys.path.insert(0, bench)
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "smallthinker_reference", os.path.join(
-                bench, "reference", "smallthinker-21b-a3b.py"))
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-    finally:
-        sys.path.remove(bench)
-    return module
+reference = reference_fixture("smallthinker-21b-a3b")
 
 
 def _config(held=None, **over):
@@ -50,16 +32,6 @@ def _reference_config(cfg):
         "rope_layout": list(cfg.rope_layout), "rope_theta": cfg.rope_theta,
         "deployment": {"experts_held": list(cfg.held())},
     }
-
-
-def _share(params, held):
-    """``params`` of the model that holds every expert, cut to ``held``."""
-    out = jax.tree.map(lambda x: x, params)
-    for name in sorted(n for n in out if n.startswith("layer_")):
-        for w in ("w_gate", "w_up", "w_down"):
-            out[name][w] = {
-                "kernel": out[name][w]["kernel"][jnp.array(held)]}
-    return out
 
 
 @pytest.fixture(scope="module")

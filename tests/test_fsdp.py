@@ -19,9 +19,9 @@ from horovod_tpu.jax.fsdp import (
 from horovod_tpu.models.llama import (
     LLAMA_TINY,
     LlamaLM,
-    causal_lm_loss,
     llama_tp_param_specs,
 )
+from horovod_tpu.models.losses import causal_lm_loss
 from horovod_tpu.parallel import make_mesh
 
 N_DEV = 8

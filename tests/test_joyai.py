@@ -17,11 +17,13 @@ import pytest
 
 from horovod_tpu.models import (JoyAILM, chunked_causal_lm_loss,
                                 joyai_lm_loss)
+from horovod_tpu.models.decoder import rotary_embedding
 from horovod_tpu.models.joyai import JoyAIBlock, deinterleave
 from horovod_tpu.models.lfm2 import decay_mask
-from horovod_tpu.models.llama import rotary_embedding, token_nll
+from horovod_tpu.models.losses import token_nll
 from horovod_tpu.ops.attention import make_attention_fn
-from joyai_helpers import (SEQ, _config, _reference_config, _share,  # noqa: F401
+from decoder_helpers import share
+from joyai_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                            reference, seeded)
 
 OPTIMIZER = dict(learning_rate=1e-3, b1=0.9, b2=0.999, eps=1e-8,
@@ -49,7 +51,7 @@ def test_three_adamw_steps_match_the_plain_reference(mtp_weight, seeded,
     ids, params = seeded
     held = (0, 5, 7)
     cfg = _config(held)
-    params = _share(params, held)
+    params = share(params, held)
     model = JoyAILM(cfg)
     tx = optax.adamw(mask=decay_mask, **OPTIMIZER)
 

@@ -14,11 +14,12 @@ import pytest
 
 from horovod_tpu.models import (LAGUNA_TINY, LAGUNA_XS2, LagunaLM,
                                 causal_lm_loss, chunked_causal_lm_loss)
+from horovod_tpu.models.decoder import rotary_embedding
 from horovod_tpu.models.laguna import (FULL, SLIDING, SPARSE, LagunaBlock,
                                        rotary_arguments)
-from horovod_tpu.models.llama import rotary_embedding
 from horovod_tpu.ops.attention import make_attention_fn
-from laguna_helpers import (SEQ, _config, _reference_config, _share,  # noqa: F401
+from decoder_helpers import share
+from laguna_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                             reference, seeded)
 
 
@@ -79,12 +80,12 @@ def test_routed_parts_of_all_the_shares_add_up_to_the_whole_layer(
     whole = reference._layer(lambda a: a, p, x[0], rcfg, kind, True)
     # Attention, the residual and the shared expert: what every chip adds.
     alike = reference._layer(
-        lambda a: a, _share({layer: p}, ())[layer], x[0],
+        lambda a: a, share({layer: p}, ())[layer], x[0],
         {**rcfg, "deployment": {"experts_held": []}}, kind, True)
     shares = [(0, 1), (2, 3), (4, 5), (6, 7)]
     parts, landed = 0.0, 0
     for held in shares:
-        out, load = block(held, _share({layer: p}, held)[layer])
+        out, load = block(held, share({layer: p}, held)[layer])
         parts = parts + (out - alike)
         landed += int(load.sum())
     assert landed == SEQ * cfg.num_selected     # every assignment, once

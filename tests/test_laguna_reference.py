@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import LagunaLM, causal_lm_loss
-from laguna_helpers import (SEQ, _config, _reference_config, _share,  # noqa: F401
+from decoder_helpers import share
+from laguna_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                             reference, seeded)
 
 
@@ -24,7 +25,7 @@ def test_logits_loss_and_gradients_match_the_plain_reference(held, seeded,
     assert SEQ > cfg.sliding_window and \
         SEQ > cfg.full_rotary.original_positions
     assert {"full_attention", "sliding_attention"} == set(cfg.layer_types)
-    params = params if held is None else _share(params, held)
+    params = params if held is None else share(params, held)
     model = LagunaLM(cfg)
     rcfg = _reference_config(cfg)
 

@@ -19,7 +19,8 @@ from horovod_tpu.models.lfm2 import (CONV, Lfm2Block, decay_mask,
                                      gated_short_conv)
 from horovod_tpu.ops.linear_attention import causal_conv, causal_conv_silu
 from horovod_tpu.parallel.moe import sigmoid_top_k, softmax_top_k
-from lfm2_helpers import (SEQ, _config, _reference_config, _share,  # noqa: F401
+from decoder_helpers import share
+from lfm2_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                           reference, seeded)
 
 OPTIMIZER = dict(learning_rate=1e-3, b1=0.9, b2=0.999, eps=1e-8,
@@ -36,7 +37,7 @@ def test_three_adamw_steps_match_the_plain_reference(seeded, reference):
     held = (0, 5, 7)
     cfg = _config(held)
     assert {"conv", "full_attention"} == set(cfg.layer_types)
-    params = _share(params, held)
+    params = share(params, held)
     model = Lfm2LM(cfg)
     tx = optax.adamw(mask=decay_mask, **OPTIMIZER)
 
@@ -199,12 +200,12 @@ def test_routed_parts_of_the_eight_shares_add_up_to_the_whole_layer(
     whole = reference._layer(lambda a: a, p, x[0], rcfg, CONV, True)
     # The mixer and the residual: what every chip adds.
     alike = reference._layer(
-        lambda a: a, _share({"layer_2": p}, ())["layer_2"], x[0],
+        lambda a: a, share({"layer_2": p}, ())["layer_2"], x[0],
         {**rcfg, "deployment": {"experts_held": []}}, CONV, True)
     parts, landed = 0.0, 0
     for expert in range(cfg.num_experts):
         out, load = block((expert,),
-                          _share({"layer_2": p}, (expert,))["layer_2"])
+                          share({"layer_2": p}, (expert,))["layer_2"])
         parts = parts + (out - alike)
         landed += int(load.sum())
     assert landed == SEQ * cfg.num_selected     # every assignment, once

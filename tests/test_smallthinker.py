@@ -20,9 +20,10 @@ from horovod_tpu.models.smallthinker import SmallThinkerBlock
 from horovod_tpu.ops.attention import make_attention_fn
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
+from decoder_helpers import share
 from model_helpers import jit_apply
 from smallthinker_helpers import (SEQ, _config, _reference_config,  # noqa: F401
-                                  _share, reference, seeded)
+                                  reference, seeded)
 
 
 def test_flash_kernels_remat_and_chunked_loss_change_nothing(seeded):
@@ -85,7 +86,7 @@ def test_parts_of_the_four_shares_add_up_to_the_whole_layer(seeded,
     shares = [(0, 1), (2, 3), (4, 5), (6, 7)]
     parts, landed = 0.0, 0
     for held in shares:
-        out, load = block(held, _share({"layer_1": layer}, held)["layer_1"])
+        out, load = block(held, share({"layer_1": layer}, held)["layer_1"])
         parts = parts + (out - nothing_held)
         landed += int(load.sum())
     assert landed == SEQ * cfg.num_selected     # every assignment, once

@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import SmallThinkerLM, causal_lm_loss
-from smallthinker_helpers import (_config, _reference_config, _share,  # noqa: F401
+from decoder_helpers import share
+from smallthinker_helpers import (_config, _reference_config,  # noqa: F401
                                   reference, seeded)
 
 
@@ -19,7 +20,7 @@ def test_loss_and_gradients_match_the_plain_reference(held, seeded,
                                                       reference):
     ids, params = seeded
     cfg = _config(held)
-    params = params if held is None else _share(params, held)
+    params = params if held is None else share(params, held)
     model = SmallThinkerLM(cfg)
 
     def loss(p):
