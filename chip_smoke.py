@@ -272,9 +272,13 @@ def build_resnet50_step(batch_per_chip, image_size):
         batch, image_size, image_size, 3).astype(jnp.bfloat16)
     labels_host = np.random.RandomState(1).randint(0, 1000, size=(batch,))
 
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.ones((1, image_size, image_size, 3)),
-                           train=True)
+    # One program: eagerly, flax hands XLA a program an operation, and
+    # few of a ResNet's repeat (22-30 s of 94 on a cold v5e). A stack of
+    # like layers is the other way round: its eager programs repeat, and
+    # the 300M Llama's init as one program took 6-11 s longer a phase.
+    variables = jax.jit(model.init, static_argnames="train")(
+        jax.random.PRNGKey(0), jnp.ones((1, image_size, image_size, 3)),
+        train=True)
     params, batch_stats = variables["params"], variables["batch_stats"]
 
     tx = hvd.DistributedOptimizer(

@@ -70,9 +70,9 @@ def main():
         [args.warmup_steps])
 
     model = ResNet50(num_classes=NUM_CLASSES, dtype=jnp.bfloat16)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.ones((1, image_size, image_size, 3)),
-                           train=True)
+    variables = jax.jit(model.init, static_argnames="train")(
+        jax.random.PRNGKey(0), jnp.ones((1, image_size, image_size, 3)),
+        train=True)
     params, batch_stats = variables["params"], variables["batch_stats"]
     tx = hvd.DistributedOptimizer(
         optax.sgd(schedule, momentum=0.9), axis_name="data")

@@ -52,7 +52,7 @@ def main():
     y = hvd.parallel.shard_batch(
         jnp.asarray(np.random.RandomState(1).randint(0, 1000, batch)), mesh)
 
-    variables = model.init(
+    variables = jax.jit(model.init, static_argnames="train")(
         {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
         jnp.ones((1, size, size, 3)), train=True)
     # VGG has no BatchNorm (stats stays an empty pytree); VGG and Inception

@@ -61,9 +61,10 @@ def main():
 
     def measure(n):
         mesh = hvd.parallel.make_mesh(devices=devices[:n])
-        variables = model.init(jax.random.PRNGKey(0), sample, train=True) \
+        init = jax.jit(model.init, static_argnames="train")
+        variables = init(jax.random.PRNGKey(0), sample, train=True) \
             if args.model == "resnet50" \
-            else model.init(jax.random.PRNGKey(0), sample)
+            else init(jax.random.PRNGKey(0), sample)
         tx = hvd.DistributedOptimizer(
             optax.sgd(0.01, momentum=0.9), axis_name="data")
 

@@ -101,3 +101,15 @@ def kernel_grids(fn, *args):
 # Both paths call their kernels by these names (the benchmark's per-kernel
 # metrics read them).
 KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
+
+# The streamed calls of the benchmark's cells:
+# name: (batch, sequence, query heads, K/V heads, q/k width, v width, window)
+CELLS = {
+    "smallthinker-global": (2, 8192, 28, 4, 128, 128, None),
+    "smallthinker-window-4096": (2, 8192, 28, 4, 128, 128, 4096),
+    "olmo-hybrid": (1, 8192, 15, 15, 128, 128, None),
+    "laguna-full": (2, 8192, 48, 8, 128, 128, None),
+    "laguna-window-512": (2, 8192, 64, 8, 128, 128, 512),
+    "lfm2-head-64": (4, 8192, 32, 8, 64, 64, None),
+    "joyai-192-over-128": (2, 8192, 32, 32, 192, 128, None),
+}

@@ -4,13 +4,11 @@ where every path matters."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import JOYAI_TINY, JoyAILM
-from decoder_helpers import reference_fixture
-from model_helpers import jit_init
+from decoder_helpers import reference_fixture, seeded_ids_and_params
 
 SEQ = 96
 
@@ -42,12 +40,6 @@ def _reference_config(cfg, mtp_weight=0.0, **optimizer):
 
 @pytest.fixture(scope="module")
 def seeded():
-    cfg = _config()
-    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
-                             cfg.vocab_size)
-    params = jit_init(JoyAILM(cfg), ids, rngs=jax.random.PRNGKey(3))[
-        "params"]
-
     # Scales at which every path matters: a router that decides, a bias
     # that moves the choice for some tokens and not for all, mixers and
     # experts of the residual's own size.
@@ -59,4 +51,4 @@ def seeded():
             return x * 10.0
         return x * 3.0 if x.ndim > 1 else x
 
-    return ids, jax.tree_util.tree_map_with_path(scaled, params)
+    return seeded_ids_and_params(JoyAILM(_config()), SEQ, scaled)

@@ -4,12 +4,12 @@ where every path matters."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import LAGUNA_TINY, LagunaLM
-from decoder_helpers import reference_fixture
+from horovod_tpu.models.laguna import FULL, SLIDING
+from decoder_helpers import reference_fixture, seeded_ids_and_params
 
 # The tiny window is 48 and YaRN's original context 32: both shorter than
 # the sequence.
@@ -20,8 +20,12 @@ reference = reference_fixture("laguna-xs.2")
 
 
 def _config(held=None, **over):
-    return dataclasses.replace(LAGUNA_TINY, dtype=jnp.float32,
-                               experts_held=held, **over)
+    """``LAGUNA_TINY`` as deep as a test needs: the dense full layer, a
+    sparse sliding layer and a sparse full one (the MLP kinds are the
+    constant's first three)."""
+    return dataclasses.replace(
+        LAGUNA_TINY, dtype=jnp.float32, experts_held=held, num_layers=3,
+        layer_types=(FULL, SLIDING, FULL), heads_per_layer=(6, 8, 6), **over)
 
 
 def _rope_parameters(spec):
@@ -57,15 +61,11 @@ def _reference_config(cfg):
 
 @pytest.fixture(scope="module")
 def seeded():
-    cfg = _config()
-    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
-                             cfg.vocab_size)
-    params = jax.jit(LagunaLM(cfg).init)(jax.random.PRNGKey(3), ids)["params"]
     # Scales at which every path matters: a router that decides, a gate
     # that is not one half everywhere, experts and attention of the
     # residual's own size.
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x * (25.0 if "router" in str(path)
-                             or "wg" in str(path) else 3.0)
-        if x.ndim > 1 else x, params)
-    return ids, params
+    def scaled(path, x):
+        return x * (25.0 if "router" in str(path) or "wg" in str(path)
+                    else 3.0) if x.ndim > 1 else x
+
+    return seeded_ids_and_params(LagunaLM(_config()), SEQ, scaled)

@@ -4,12 +4,12 @@ where every path matters."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import LFM2_TINY, Lfm2LM
-from decoder_helpers import reference_fixture
+from horovod_tpu.models.lfm2 import CONV, FULL
+from decoder_helpers import reference_fixture, seeded_ids_and_params
 
 SEQ = 96
 
@@ -18,8 +18,11 @@ reference = reference_fixture("lfm2-24b-a2b")
 
 
 def _config(held=None, **over):
-    return dataclasses.replace(LFM2_TINY, dtype=jnp.float32,
-                               experts_held=held, **over)
+    """``LFM2_TINY`` as deep as a test needs: the dense convolution
+    layer, a sparse attention layer and a sparse convolution one."""
+    return dataclasses.replace(
+        LFM2_TINY, dtype=jnp.float32, experts_held=held, num_layers=3,
+        layer_types=(CONV, FULL, CONV), **over)
 
 
 def _reference_config(cfg, **optimizer):
@@ -41,11 +44,6 @@ def _reference_config(cfg, **optimizer):
 
 @pytest.fixture(scope="module")
 def seeded():
-    cfg = _config()
-    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
-                             cfg.vocab_size)
-    params = jax.jit(Lfm2LM(cfg).init)(jax.random.PRNGKey(3), ids)["params"]
-
     # Scales at which every path matters: a router that decides, a bias
     # that moves the choice for some tokens and not for all, mixers and
     # experts of the residual's own size (a convolution mixer is cubic in
@@ -61,4 +59,4 @@ def seeded():
             return x
         return x * 3.0 if x.ndim > 1 and "taps" not in names else x
 
-    return ids, jax.tree_util.tree_map_with_path(scaled, params)
+    return seeded_ids_and_params(Lfm2LM(_config()), SEQ, scaled)

@@ -4,12 +4,11 @@ a whole model's weights to a share of its heads."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import OLMO_HYBRID_TINY, OlmoHybridLM
-from decoder_helpers import reference_fixture
+from decoder_helpers import reference_fixture, seeded_ids_and_params
 
 SEQ = 160       # three chunks of 64, the last one padded
 
@@ -74,12 +73,6 @@ def _share(params, held, cfg):
 
 @pytest.fixture(scope="module")
 def seeded():
-    cfg = _config()
-    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
-                             cfg.vocab_size)
-    params = jax.jit(OlmoHybridLM(cfg).init)(jax.random.PRNGKey(3),
-                                             ids)["params"]
-
     # Scales at which every path matters: norm scales that differ by
     # column (a q/k norm over the wrong columns shows), decays that keep a
     # state alive over chunks (flax's own scale on ``wa`` forgets it within
@@ -90,4 +83,4 @@ def seeded():
             return 1.0 + 0.3 * jnp.sin(jnp.arange(x.shape[0], dtype=x.dtype))
         return x * 0.2 if "wa" in names else x
 
-    return ids, jax.tree_util.tree_map_with_path(leaf, params)
+    return seeded_ids_and_params(OlmoHybridLM(_config()), SEQ, leaf)

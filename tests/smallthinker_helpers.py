@@ -4,12 +4,11 @@ where every path matters."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import pytest
 
 from horovod_tpu.models import SMALLTHINKER_TINY, SmallThinkerLM
-from decoder_helpers import reference_fixture
+from decoder_helpers import reference_fixture, seeded_ids_and_params
 
 SEQ = 128       # the tiny window is 48: shorter than the sequence
 
@@ -18,6 +17,7 @@ reference = reference_fixture("smallthinker-21b-a3b")
 
 
 def _config(held=None, **over):
+    over.setdefault("num_layers", 4)        # one period
     return dataclasses.replace(SMALLTHINKER_TINY, dtype=jnp.float32,
                                experts_held=held, **over)
 
@@ -36,14 +36,10 @@ def _reference_config(cfg):
 
 @pytest.fixture(scope="module")
 def seeded():
-    cfg = _config()
-    ids = jax.random.randint(jax.random.PRNGKey(7), (2, SEQ), 0,
-                             cfg.vocab_size)
-    params = jax.jit(SmallThinkerLM(cfg).init)(jax.random.PRNGKey(3),
-                                               ids)["params"]
     # Scales at which every path matters: a router that decides, experts
     # and attention of the residual's own size.
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x * (25.0 if "router" in str(path) else 3.0)
-        if x.ndim > 1 else x, params)
-    return ids, params
+    def scaled(path, x):
+        return x * (25.0 if "router" in str(path) else 3.0) \
+            if x.ndim > 1 else x
+
+    return seeded_ids_and_params(SmallThinkerLM(_config()), SEQ, scaled)

@@ -165,14 +165,9 @@ def _lowered_resnet_step(n):
     return text, state, (x, y), mesh
 
 
-# The mesh of one is @slow in both tests: 65 s under the driver's command on
-# an idle box (130 on a loaded one) for a second eager ResNet-50 init
-# (``chip_smoke.build_resnet50_step``, once a mesh); the mesh of four keeps
-# placement and exchange in tier-1.
-_MESHES = [pytest.param(1, marks=pytest.mark.slow), 4]
-
-
-@pytest.mark.parametrize("n", _MESHES)
+# Both meshes in tier-1 since the smoke draws its weights in one program
+# (PR 45): 12 s a mesh, where the eager ResNet-50 init took 65.
+@pytest.mark.parametrize("n", [1, 4])
 def test_resnet_step_is_placed_as_the_smoke_asserts(n):
     import jax
     from jax.sharding import PartitionSpec as P
@@ -192,8 +187,7 @@ def test_resnet_step_is_placed_as_the_smoke_asserts(n):
 
 
 @pytest.mark.parametrize("n,group", [
-    pytest.param(1, "dense<0> : tensor<1x1xi64>", marks=pytest.mark.slow),
-    (4, "dense<[[0, 1, 2, 3]]>")])
+    (1, "dense<0> : tensor<1x1xi64>"), (4, "dense<[[0, 1, 2, 3]]>")])
 def test_resnet_step_lowers_with_its_exchange(n, group):
     import jax
 

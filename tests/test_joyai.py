@@ -22,7 +22,8 @@ from horovod_tpu.models.joyai import JoyAIBlock, deinterleave
 from horovod_tpu.models.lfm2 import decay_mask
 from horovod_tpu.models.losses import token_nll
 from horovod_tpu.ops.attention import make_attention_fn
-from decoder_helpers import share
+from decoder_helpers import (assert_shares_add_up,
+                             assert_three_adamw_steps_match, share)
 from joyai_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                            reference, seeded)
 
@@ -53,48 +54,18 @@ def test_three_adamw_steps_match_the_plain_reference(mtp_weight, seeded,
     cfg = _config(held)
     params = share(params, held)
     model = JoyAILM(cfg)
-    tx = optax.adamw(mask=decay_mask, **OPTIMIZER)
 
-    @jax.jit
-    def step(p, opt_state):
-        value, grads = jax.value_and_grad(
-            lambda p: _full_loss(model, p, ids, mtp_weight))(p)
-        updates, opt_state = tx.update(grads, opt_state, p)
-        return optax.apply_updates(p, updates), opt_state, value, grads
-
-    ours, opt_state, losses, first = params, tx.init(params), [], None
-    for _ in range(3):
-        ours, opt_state, value, grads = step(ours, opt_state)
-        losses.append(float(value))
-        first = grads if first is None else first
-    their_losses, their_first, theirs = reference.follow(
-        params, [(np.asarray(row)[None],) for row in ids], 3,
-        _reference_config(cfg, mtp_weight, **OPTIMIZER))
-    # One replica a sequence: Horovod's mean of the replicas' means.
-    np.testing.assert_allclose(
-        losses, [np.mean(step) for step in their_losses], rtol=2e-5)
-    flat, _ = jax.tree_util.tree_flatten_with_path(params)
-    for (path, start), g, r, a, b in zip(
-            flat, *map(jax.tree.leaves, (first, their_first, ours, theirs))):
-        name = jax.tree_util.keystr(path)
-        # float32 through three blocks of weights scaled up: a gradient
-        # agrees to a part in a thousand of its leaf.
-        scale = float(np.max(np.abs(r))) + 1e-12
-        assert float(jnp.max(jnp.abs(g - r))) <= 3e-3 * scale, name
-        if "expert_bias" in name:
-            assert not np.any(np.asarray(g)) and not np.any(r)
-            np.testing.assert_array_equal(a, start)
-            np.testing.assert_array_equal(b, start)
-            continue
+    def only_the_modules_loss_reaches(name, r):
         if "'mtp'" in name:
-            # Only the module's loss reaches these.
             assert bool(np.any(r)) is bool(mtp_weight), name
-        # By norms: AdamW moves an entry whose gradient is all but zero
-        # by its sign, which float32 does not settle; a leaf has a few.
-        moved = float(np.linalg.norm(np.asarray(b) - np.asarray(start)))
-        assert moved > 0, name
-        assert float(np.linalg.norm(np.asarray(a) - np.asarray(b))) \
-            <= 0.05 * moved, name
+
+    # By norms: AdamW moves an entry whose gradient is all but zero by
+    # its sign, which float32 does not settle; a leaf has a few.
+    assert_three_adamw_steps_match(
+        lambda p: _full_loss(model, p, ids, mtp_weight), params, ids,
+        reference, _reference_config(cfg, mtp_weight, **OPTIMIZER),
+        optax.adamw(mask=decay_mask, **OPTIMIZER), size=np.linalg.norm,
+        check=only_the_modules_loss_reaches)
 
 
 def test_rotating_halves_after_deinterleaving_gives_the_pairs_scores(
@@ -105,10 +76,10 @@ def test_rotating_halves_after_deinterleaving_gives_the_pairs_scores(
     theta, width = 3.2e7, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 40, 3, width))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, 40, 1, width))
-    ours_q = rotary_embedding(deinterleave(q), theta)
-    ours_k = rotary_embedding(deinterleave(k), theta)
-    pairs_q = reference.rotate_pairs(q[0], theta)
-    pairs_k = reference.rotate_pairs(k[0, :, 0], theta)    # no head axis
+    ours = jax.jit(lambda x: rotary_embedding(deinterleave(x), theta))
+    pairs = jax.jit(lambda x: reference.rotate_pairs(x, theta))
+    ours_q, ours_k = ours(q), ours(k)
+    pairs_q, pairs_k = pairs(q[0]), pairs(k[0, :, 0])      # no head axis
     np.testing.assert_allclose(ours_q[0], deinterleave(pairs_q), atol=1e-5)
     np.testing.assert_allclose(ours_k[0, :, 0], deinterleave(pairs_k),
                                atol=1e-5)
@@ -116,9 +87,9 @@ def test_rotating_halves_after_deinterleaving_gives_the_pairs_scores(
         jnp.einsum("qhd,kd->hqk", ours_q[0], ours_k[0, :, 0]),
         jnp.einsum("qhd,kd->hqk", pairs_q, pairs_k), atol=1e-4)
     # Rotating halves WITHOUT the de-interleaving is another function.
-    wrong = jnp.einsum("qhd,kd->hqk", rotary_embedding(q, theta)[0],
-                       rotary_embedding(k, theta)[0, :, 0])
-    assert float(jnp.max(jnp.abs(wrong - jnp.einsum(
+    halves = jax.jit(lambda x: rotary_embedding(x, theta))
+    wrong = jnp.einsum("qhd,kd->hqk", halves(q)[0], halves(k)[0, :, 0])
+    assert float(np.max(np.abs(wrong - jnp.einsum(
         "qhd,kd->hqk", pairs_q, pairs_k)))) > 0.1
     # The permutation: evens, then odds.
     np.testing.assert_array_equal(deinterleave(jnp.arange(8)),
@@ -187,13 +158,13 @@ def test_the_benchmarks_step_is_the_plain_model_and_the_head_has_two_sources(
     np.testing.assert_allclose(got_value, want_value, rtol=1e-5)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree.leaves(want)):
-        scale = float(jnp.max(jnp.abs(b))) + 1e-12
+        scale = float(np.max(np.abs(b))) + 1e-12
         np.testing.assert_allclose(a, b, rtol=0, atol=2e-4 * scale,
                                    err_msg=jax.tree_util.keystr(path))
     both = want["lm_head"]["kernel"]
-    scale = float(jnp.max(jnp.abs(both)))
+    scale = float(np.max(np.abs(both)))
     for part in (by_main, by_mtp):
-        assert float(jnp.max(jnp.abs(part))) > 0.01 * scale
+        assert float(np.max(np.abs(part))) > 0.01 * scale
     np.testing.assert_allclose(by_main + by_mtp, both, rtol=0,
                                atol=2e-5 * scale)
     # Without the module the loss is the main one, and the module's
@@ -212,40 +183,12 @@ def test_routed_parts_of_the_eight_shares_add_up_to_the_whole_layer(
     (one expert each; thirty-two shares of eight at the published sizes),
     with attention, the shared expert and the residual counted once, are
     the uncut reference's layer."""
-    ids, params = seeded
     cfg = _config()
-    p = params["layer_1"]
-    x = 3.0 * jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, cfg.dim))
-
-    def block(held, p):
-        out, load = jax.jit(lambda p, x: JoyAIBlock(
-            _config(held), sparse=True, attention_fn=make_attention_fn(
-                causal=True, use_flash=False)).apply(
-            {"params": p}, x))(p, x)
-        return out[0], load
-
-    def cut(held):
-        return {**p, **{w: {"kernel": p[w]["kernel"][
-            jnp.array(held, jnp.int32)]}
-            for w in ("w_gate", "w_up", "w_down")}}
-
-    rcfg = _reference_config(cfg)
-    whole = reference._layer(lambda a: a, p, x[0], rcfg, True)
-    # Attention, the shared expert and the residual: what every chip adds.
-    alike = reference._layer(
-        lambda a: a, cut(()), x[0],
-        {**rcfg, "deployment": {"experts_held": []}}, True)
-    parts, landed = 0.0, 0
-    for expert in range(cfg.num_experts):
-        out, load = block((expert,), cut((expert,)))
-        parts = parts + (out - alike)
-        landed += int(load.sum())
-    assert landed == SEQ * cfg.num_selected     # every assignment, once
-    scale = float(jnp.max(jnp.abs(whole)))
-    # The routed parts are far above the tolerance they are added up to.
-    assert float(jnp.max(jnp.abs(parts))) > 100 * 2e-5 * scale
-    np.testing.assert_allclose(alike + parts, whole, rtol=0,
-                               atol=2e-5 * scale)
-    # The same from the layer that holds all eight.
-    np.testing.assert_allclose(block(None, p)[0], whole, rtol=0,
-                               atol=2e-5 * scale)
+    attention_fn = make_attention_fn(causal=True, use_flash=False)
+    # Alike on every chip: attention, the shared expert and the residual.
+    assert_shares_add_up(
+        lambda held: JoyAIBlock(_config(held), sparse=True,
+                                attention_fn=attention_fn),
+        seeded[1]["layer_1"], lambda p, rows, rcfg: reference._layer(
+            lambda a: a, p, rows, rcfg, True), _reference_config(cfg),
+        [(expert,) for expert in range(cfg.num_experts)], cfg, SEQ)

@@ -145,15 +145,16 @@ def test_the_module_reads_the_next_token_and_scores_the_one_after(
         return -sum(float(logp[i, ids[i + ahead]])
                     for i in range(seq - ahead))
 
-    main, mtp = jax.jit(lambda ids: reference.sequence_nll_sums(
-        params, ids, SAME, rcfg))(jnp.asarray(ids))
+    def sums(rcfg):
+        return jax.jit(lambda ids: reference.sequence_nll_sums(
+            params, ids, SAME, rcfg))(jnp.asarray(ids))
+
+    main, mtp = sums(rcfg)
     np.testing.assert_allclose(main, by_hand(g, 1), rtol=1e-5)
     np.testing.assert_allclose(mtp, by_hand(m, 2), rtol=1e-5)
     # One ahead would be another number.
     assert abs(by_hand(m, 1) * (seq - 2) / (seq - 1) - float(mtp)) > 1e-3
     # A configuration without the module has no second loss.
-    none = reference.sequence_nll_sums(
-        params, jnp.asarray(ids), SAME,
-        {**rcfg, "num_nextn_predict_layers": 0})
+    none = sums({**rcfg, "num_nextn_predict_layers": 0})
     np.testing.assert_allclose(none[0], main, rtol=1e-6)
     assert float(none[1]) == 0.0

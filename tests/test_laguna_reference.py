@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import LagunaLM, causal_lm_loss
-from decoder_helpers import share
+from horovod_tpu.models import LagunaLM
+from decoder_helpers import assert_matches_the_plain_reference, share
 from laguna_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                             reference, seeded)
 
@@ -25,35 +25,20 @@ def test_logits_loss_and_gradients_match_the_plain_reference(held, seeded,
     assert SEQ > cfg.sliding_window and \
         SEQ > cfg.full_rotary.original_positions
     assert {"full_attention", "sliding_attention"} == set(cfg.layer_types)
-    params = params if held is None else share(params, held)
+    params = share(params, held)
     model = LagunaLM(cfg)
     rcfg = _reference_config(cfg)
 
-    logits = model.apply({"params": params}, ids)[0]
-    hidden = reference.sequence_hidden(params, ids[0], lambda a: a, rcfg)
-    theirs = hidden @ params["lm_head"]["kernel"]
-    np.testing.assert_allclose(logits[0], theirs, rtol=0, atol=1e-3 * float(
-        jnp.max(jnp.abs(theirs))))
-
-    def loss(p):
-        return causal_lm_loss(model.apply({"params": p}, ids)[0], ids)
-
-    ours, grads = jax.jit(jax.value_and_grad(loss))(params)
-
-    def reference_loss(p):
-        total = sum(reference.sequence_nll_sum(
-            p, row, rnd=lambda a: a, config=rcfg) for row in ids)
-        return total / (ids.shape[0] * (ids.shape[1] - 1))
-
-    theirs, reference_grads = jax.jit(
-        jax.value_and_grad(reference_loss))(params)
-    np.testing.assert_allclose(ours, theirs, rtol=1e-5)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), r in zip(flat, jax.tree.leaves(reference_grads)):
-        # float32 through five layers of weights scaled up: the loss
-        # agrees to 1e-5, a gradient to a part in a thousand of its leaf.
-        scale = float(jnp.max(jnp.abs(r))) + 1e-12
-        assert float(jnp.max(jnp.abs(g - r))) <= 3e-3 * scale, path
+    logits, theirs = jax.jit(lambda p: (
+        model.apply({"params": p}, ids)[0][0],
+        reference.sequence_hidden(p, ids[0], lambda a: a, rcfg)
+        @ p["lm_head"]["kernel"]))(params)
+    np.testing.assert_allclose(logits, theirs, rtol=0, atol=1e-3 * float(
+        np.max(np.abs(theirs))))
+    # float32 through three layers of weights scaled up: the loss agrees
+    # to 1e-5, a gradient to a part in a thousand of its leaf.
+    assert_matches_the_plain_reference(model, params, ids, reference,
+                                       rcfg, 3e-3)
 
 
 @pytest.mark.parametrize("window", [None, 48])
@@ -68,15 +53,19 @@ def test_reference_attention_in_blocks_is_the_masked_softmax(
     q = jax.random.normal(jax.random.PRNGKey(0), (256, 8, 16))
     k, v = (jax.random.normal(jax.random.PRNGKey(i), (256, 2, 16))
             for i in (1, 2))
-    ours = reference._attention(lambda a: a, q, k, v, window)
-    want = reference_attention(q[None], k[None], v[None], causal=True,
-                               window=window)[0]
+    def in_blocks(q, k, v):
+        out = reference._attention(lambda a: a, q, k, v, window)
+        return jnp.sum(out ** 2), out
+
+    def masked(q, k, v):
+        out = reference_attention(q[None], k[None], v[None], causal=True,
+                                  window=window)[0]
+        return jnp.sum(out ** 2), out
+
+    (_, ours), grads = jax.jit(jax.value_and_grad(
+        in_blocks, (0, 1, 2), has_aux=True))(q, k, v)
+    (_, want), want_grads = jax.jit(jax.value_and_grad(
+        masked, (0, 1, 2), has_aux=True))(q, k, v)
     np.testing.assert_allclose(ours, want, rtol=0, atol=2e-6)
-    grads = jax.grad(lambda *a: jnp.sum(
-        reference._attention(lambda x: x, *a, window) ** 2), (0, 1, 2))(
-        q, k, v)
-    want_grads = jax.grad(lambda q, k, v: jnp.sum(reference_attention(
-        q[None], k[None], v[None], causal=True, window=window) ** 2),
-        (0, 1, 2))(q, k, v)
     for g, w in zip(grads, want_grads):
         np.testing.assert_allclose(g, w, rtol=0, atol=2e-5)

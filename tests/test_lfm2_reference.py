@@ -23,8 +23,10 @@ def test_reference_attention_in_blocks_is_the_masked_softmax(
     q = jax.random.normal(jax.random.PRNGKey(0), (256, 8, 16))
     k, v = (jax.random.normal(jax.random.PRNGKey(i), (256, 2, 16))
             for i in (1, 2))
-    ours = reference._attention(lambda a: a, q, k, v)
-    want = reference_attention(q[None], k[None], v[None], causal=True)[0]
+    ours = jax.jit(lambda q, k, v: reference._attention(
+        lambda a: a, q, k, v))(q, k, v)
+    want = jax.jit(lambda q, k, v: reference_attention(
+        q[None], k[None], v[None], causal=True)[0])(q, k, v)
     np.testing.assert_allclose(ours, want, rtol=0, atol=2e-6)
 
 

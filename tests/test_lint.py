@@ -702,3 +702,24 @@ def test_tests_launch_processes_only_through_the_harness():
             with open(os.path.join(HERE, name), encoding="utf-8") as f:
                 found += _launch_findings(f.read(), f"tests/{name}")
     assert not found, "\n".join(found)
+
+
+# The modules that ``tests/conftest.py`` lets compile more XLA programs a
+# test than ``MAX_XLA_PROGRAMS_A_TEST`` (rule 1 beside rule 2 above), each
+# by a module-level ``EAGER_BY_DESIGN = "<why>"``. A new name here is a
+# reviewer's decision, and five are the most there may be.
+EAGER_MODULES = set()
+
+
+def test_the_modules_excepted_from_the_compile_cap_are_listed_here():
+    import ast
+
+    found = set()
+    for name in sorted(os.listdir(HERE)):
+        if name.endswith(".py"):
+            with open(os.path.join(HERE, name), encoding="utf-8") as f:
+                body = ast.parse(f.read(), name).body
+            found |= {name for node in body if isinstance(node, ast.Assign)
+                      and any(getattr(t, "id", "") == "EAGER_BY_DESIGN"
+                              for t in node.targets)}
+    assert found == EAGER_MODULES and len(found) <= 5

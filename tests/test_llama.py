@@ -340,15 +340,16 @@ def _rel(a, b):
 def test_chunked_loss_and_both_gradients_match_autodiff_of_full_logits(
         num_chunks, batch, dtype, tol):
     hidden, kernel, ids = _head_problem(batch, dtype)
-    l0, g0 = jax.value_and_grad(_full_head_loss(ids), argnums=(0, 1))(
+    def chunked(h, w):
+        return chunked_causal_lm_loss(h, w, ids, num_chunks=num_chunks)
+
+    l0, g0 = jax.jit(jax.value_and_grad(
+        _full_head_loss(ids), argnums=(0, 1)))(hidden, kernel)
+    l1, g1 = jax.jit(jax.value_and_grad(chunked, argnums=(0, 1)))(
         hidden, kernel)
-    l1, g1 = jax.value_and_grad(
-        lambda h, w: chunked_causal_lm_loss(h, w, ids, num_chunks=num_chunks),
-        argnums=(0, 1))(hidden, kernel)
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
     # Differentiated or not, the same loss to the bit.
-    assert float(l1) == float(
-        chunked_causal_lm_loss(hidden, kernel, ids, num_chunks=num_chunks))
+    assert float(l1) == float(jax.jit(chunked)(hidden, kernel))
     for a, b in zip(g0, g1):
         assert a.dtype == b.dtype and a.shape == b.shape
         assert _rel(a, b) < tol, (_rel(a, b), tol)
@@ -360,13 +361,14 @@ def test_chunked_loss_bf16_gradients_do_not_hang_on_the_chunk_count():
     # their cotangent, the same at every chunk count (the sum of bf16
     # partials this replaces grew with it).
     hidden, kernel, ids = _head_problem(2, jnp.bfloat16)
-    _, exact = jax.value_and_grad(_full_head_loss(ids), argnums=(0, 1))(
+    _, exact = jax.jit(jax.value_and_grad(
+        _full_head_loss(ids), argnums=(0, 1)))(
         hidden.astype(jnp.float32), kernel)
     gaps = []
     for num_chunks in (1, 8):
-        got = jax.grad(
+        got = jax.jit(jax.grad(
             lambda h, w: chunked_causal_lm_loss(
-                h, w, ids, num_chunks=num_chunks), argnums=(0, 1))(
+                h, w, ids, num_chunks=num_chunks), argnums=(0, 1)))(
                     hidden, kernel)
         gaps.append([_rel(a, b) for a, b in zip(exact, got)])
     assert max(gaps[0] + gaps[1]) < 1e-2, gaps
@@ -385,10 +387,10 @@ def test_chunked_loss_takes_a_cotangent_that_is_not_one(wrap):
     def chunked(h, w):
         return chunked_causal_lm_loss(h, w, ids, num_chunks=4)
 
-    g0 = jax.grad(lambda h, w: wrap(_full_head_loss(ids), h, w),
-                  argnums=(0, 1))(hidden, kernel)
-    g1 = jax.grad(lambda h, w: wrap(chunked, h, w),
-                  argnums=(0, 1))(hidden, kernel)
+    g0 = jax.jit(jax.grad(lambda h, w: wrap(_full_head_loss(ids), h, w),
+                          argnums=(0, 1)))(hidden, kernel)
+    g1 = jax.jit(jax.grad(lambda h, w: wrap(chunked, h, w),
+                          argnums=(0, 1)))(hidden, kernel)
     for a, b in zip(g0, g1):
         assert _rel(a, b) < 1e-6, _rel(a, b)
 
@@ -396,8 +398,8 @@ def test_chunked_loss_takes_a_cotangent_that_is_not_one(wrap):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_chunked_loss_last_position_gets_no_gradient(dtype):
     hidden, kernel, ids = _head_problem(2, dtype)
-    dh = jax.grad(lambda h: chunked_causal_lm_loss(
-        h, kernel, ids, num_chunks=4))(hidden)
+    dh = jax.jit(jax.grad(lambda h: chunked_causal_lm_loss(
+        h, kernel, ids, num_chunks=4)))(hidden)
     assert dh.dtype == dtype
     assert not np.asarray(dh[:, -1], np.float32).any()
     assert np.asarray(dh[:, :-1], np.float32).any(axis=-1).all()
@@ -407,10 +409,11 @@ def test_chunked_loss_last_position_gets_no_gradient(dtype):
 def test_chunked_loss_kernel_gradient_comes_back_in_the_kernels_dtype(
         kernel_dtype):
     hidden, kernel, ids = _head_problem(2, jnp.bfloat16, kernel_dtype)
-    dw = jax.grad(lambda w: chunked_causal_lm_loss(
-        hidden, w, ids, num_chunks=4))(kernel)
+    dw = jax.jit(jax.grad(lambda w: chunked_causal_lm_loss(
+        hidden, w, ids, num_chunks=4)))(kernel)
     assert dw.dtype == kernel_dtype and dw.shape == kernel.shape
-    want = jax.grad(lambda w: _full_head_loss(ids)(hidden, w))(kernel)
+    want = jax.jit(jax.grad(lambda w: _full_head_loss(ids)(hidden, w)))(
+        kernel)
     assert _rel(want, dw) < 2e-2
 
 

@@ -78,21 +78,22 @@ def test_inception_v3_forward():
     # exercising every block type (A/B/C/D/E + stem).
     model = InceptionV3(num_classes=7, dtype=jnp.float32)
     x = jnp.ones((1, 75, 75, 3))
-    variables = jit_init(model, x, train=False)
-    out = jit_apply(model, train=False)(variables, x)
-    assert out.shape == (1, 7)
+    # The forward pass that draws the weights is the one looked at: one
+    # program of the whole network, not two.
+    out, variables = jax.jit(lambda x: model.init_with_output(
+        jax.random.PRNGKey(0), x, train=False))(x)
+    assert out.shape == (1, 7) and "batch_stats" in variables
     assert np.isfinite(np.asarray(out)).all()
 
 
 def test_inception_v3_aux_logits():
     model = InceptionV3(num_classes=5, aux_logits=True, dtype=jnp.float32)
     x = jnp.ones((1, 75, 75, 3))
-    variables = jit_init(model, x, train=True, rngs={
-        "params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)})
-    (logits, aux), _ = jit_apply(
-        model, train=True, mutable=["batch_stats"],
-        rngs={"dropout": jax.random.PRNGKey(2)})(variables, x)
+    (logits, aux), variables = jax.jit(lambda x: model.init_with_output(
+        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
+        x, train=True))(x)
     assert logits.shape == (1, 5) and aux.shape == (1, 5)
+    assert "aux_head" in variables["params"]
 
 
 def test_mnist_mlp():

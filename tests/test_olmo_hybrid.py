@@ -9,10 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import (OlmoHybridLM, causal_lm_loss,
-                                chunked_causal_lm_loss)
+from horovod_tpu.models import OlmoHybridLM
 from horovod_tpu.models.olmo_hybrid import FULL, LINEAR, OlmoHybridBlock
 from horovod_tpu.ops.attention import make_attention_fn
+from decoder_helpers import assert_the_benchmarks_step_is_the_plain_model
 from olmo_hybrid_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                                  _share, reference, seeded)
 
@@ -58,21 +58,7 @@ def test_flash_kernels_remat_and_chunked_loss_change_nothing(seeded):
                         attention_fn=make_attention_fn(
                             causal=True, use_flash=True, block_q=64,
                             block_k=64))
-
-    def plain_loss(p):
-        return causal_lm_loss(plain.apply({"params": p}, ids)[0], ids)
-
-    def fast_loss(p):
-        hidden, _ = fast.apply({"params": p}, ids, return_hidden=True)
-        return chunked_causal_lm_loss(hidden, p["lm_head"]["kernel"], ids,
-                                      num_chunks=4)
-
-    a, ga = jax.jit(jax.value_and_grad(plain_loss))(params)
-    b, gb = jax.jit(jax.value_and_grad(fast_loss))(params)
-    np.testing.assert_allclose(a, b, rtol=1e-5)
-    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
-        np.testing.assert_allclose(x, y, rtol=0, atol=5e-3 * float(
-            jnp.max(jnp.abs(x)) + 1e-12))
+    assert_the_benchmarks_step_is_the_plain_model(plain, fast, params, ids)
 
 
 @pytest.mark.parametrize("layer,kind", [("layer_1", LINEAR),
@@ -86,13 +72,13 @@ def test_the_two_shares_joined_are_the_whole_layer(layer, kind, seeded,
     _, params = seeded
     cfg = _config()
     x = jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, cfg.dim))
-    whole = reference._layer(lambda a: a, params[layer], x[0],
-                             _reference_config(cfg), kind)
+    whole = jax.jit(lambda p: reference._layer(
+        lambda a: a, p, x[0], _reference_config(cfg), kind))(params[layer])
     shares = [(0, 2), (1, 3)]
-    stacked = jax.tree.map(
+    stacked = jax.jit(lambda p: jax.tree.map(
         lambda *leaves: jnp.stack(leaves),
-        *(_share({layer: params[layer]}, held, cfg)[layer]
-          for held in shares))
+        *(_share({layer: p}, held, cfg)[layer] for held in shares)))(
+        params[layer])
     block = OlmoHybridBlock(_config(shares[0], heads_axis="heads"), kind,
                             make_attention_fn(causal=True, use_flash=False))
     out, _ = jax.jit(jax.vmap(lambda p: block.apply({"params": p}, x),
@@ -100,15 +86,15 @@ def test_the_two_shares_joined_are_the_whole_layer(layer, kind, seeded,
     for device in range(2):
         np.testing.assert_allclose(
             out[device, 0], whole, rtol=0,
-            atol=2e-5 * float(jnp.max(jnp.abs(whole))))
+            atol=2e-5 * float(np.max(np.abs(whole))))
     # One share alone, with nothing joined, is another function.
     attention_fn = make_attention_fn(causal=True, use_flash=False)
     alone, _ = jax.jit(lambda p: OlmoHybridBlock(
         _config(shares[0]), kind, attention_fn).apply({"params": p}, x))(
         jax.tree.map(lambda a: a[0], stacked))
-    assert float(jnp.max(jnp.abs(alone[0] - whole))) > 1e-2
+    assert float(np.max(np.abs(alone[0] - whole))) > 1e-2
     # The same from the layer that holds all four.
     full, _ = jax.jit(lambda p: OlmoHybridBlock(
         cfg, kind, attention_fn).apply({"params": p}, x))(params[layer])
     np.testing.assert_allclose(full[0], whole, rtol=0,
-                               atol=2e-5 * float(jnp.max(jnp.abs(whole))))
+                               atol=2e-5 * float(np.max(np.abs(whole))))

@@ -4,23 +4,12 @@ kernel, no chunk algebra, the recurrence token by token), whole and with a
 share of the heads: loss and every gradient."""
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from horovod_tpu.models import OlmoHybridLM, causal_lm_loss
+from horovod_tpu.models import OlmoHybridLM
+from decoder_helpers import assert_matches_the_plain_reference
 from olmo_hybrid_helpers import (_config, _reference_config, _share,  # noqa: F401
                                  reference, seeded)
-
-
-def _reference_loss(reference, cfg, ids):
-    def loss(p):
-        total = sum(reference.sequence_nll_sum(
-            p, row, rnd=lambda a: a, config=_reference_config(cfg))
-            for row in ids)
-        return total / (ids.shape[0] * (ids.shape[1] - 1))
-
-    return loss
 
 
 @pytest.mark.parametrize("held", [None, (0, 1), (1, 3)],
@@ -33,19 +22,11 @@ def test_loss_and_gradients_match_the_plain_reference(held, seeded,
     ids, params = seeded
     ids = ids[:1]
     cfg = _config(held)
-    params = params if held is None else _share(params, held, cfg)
+    params = params if held is None else jax.jit(
+        lambda p: _share(p, held, cfg))(params)
     model = OlmoHybridLM(cfg)
 
-    def loss(p):
-        return causal_lm_loss(model.apply({"params": p}, ids)[0], ids)
-
-    ours, grads = jax.jit(jax.value_and_grad(loss))(params)
-    theirs, reference_grads = jax.jit(jax.value_and_grad(
-        _reference_loss(reference, cfg, ids)))(params)
-    np.testing.assert_allclose(ours, theirs, rtol=1e-5)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    for (path, g), r in zip(flat, jax.tree.leaves(reference_grads)):
-        # float32 through four layers, the chunked form against the
-        # token-by-token one: a part in a thousand of the leaf's largest.
-        scale = float(jnp.max(jnp.abs(r))) + 1e-12
-        assert float(jnp.max(jnp.abs(g - r))) <= 1e-3 * scale, path
+    # float32 through four layers, the chunked form against the
+    # token-by-token one: a part in a thousand of the leaf's largest.
+    assert_matches_the_plain_reference(model, params, ids, reference,
+                                       _reference_config(cfg), 1e-3)

@@ -262,8 +262,9 @@ def test_paged_matches_reference(hkv, h):
     lens = jnp.asarray([5, 17, 30], jnp.int32)
     q, kp, vp, tables, k_win, v_win = _paged_fixture(0, b, hkv, h, d, bs,
                                                      nb, lens)
-    out = paged_decode_attention(q, kp, vp, tables, lens, hkv)
-    ref = _reference(q, k_win, v_win, lens, hkv)
+    out = jax.jit(paged_decode_attention, static_argnums=5)(
+        q, kp, vp, tables, lens, hkv)
+    ref = jax.jit(_reference, static_argnums=4)(q, k_win, v_win, lens, hkv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 
@@ -305,8 +306,10 @@ def test_paged_gather_fallback_matches_kernel():
     b, hkv, h, d, bs, nb = 2, 2, 8, 16, 8, 3
     lens = jnp.asarray([3, 20], jnp.int32)
     q, kp, vp, tables, _, _ = _paged_fixture(3, b, hkv, h, d, bs, nb, lens)
-    out_k = paged_decode_attention(q, kp, vp, tables, lens, hkv)
-    out_g = paged_gather_attention(q, kp, vp, tables, lens, hkv)
+    out_k = jax.jit(paged_decode_attention, static_argnums=5)(
+        q, kp, vp, tables, lens, hkv)
+    out_g = jax.jit(paged_gather_attention, static_argnums=5)(
+        q, kp, vp, tables, lens, hkv)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_g),
                                atol=2e-5, rtol=1e-4)
 

@@ -14,14 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import (SMALLTHINKER_TINY, SmallThinkerLM,
-                                causal_lm_loss, chunked_causal_lm_loss)
+from horovod_tpu.models import SMALLTHINKER_TINY, SmallThinkerLM
 from horovod_tpu.models.smallthinker import SmallThinkerBlock
 from horovod_tpu.ops.attention import make_attention_fn
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.moe import grouped_gated_mlp, moe_apply_held
-from decoder_helpers import share
-from model_helpers import jit_apply
+from decoder_helpers import (assert_shares_add_up,
+                             assert_the_benchmarks_step_is_the_plain_model)
 from smallthinker_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                                   reference, seeded)
 
@@ -32,31 +31,15 @@ def test_flash_kernels_remat_and_chunked_loss_change_nothing(seeded):
     model: one function."""
     ids, params = seeded
     ids = jnp.concatenate([ids] * 4, axis=1)      # 512: four blocks a side
-    plain = SmallThinkerLM(_config(num_layers=4))   # one period
+    plain = SmallThinkerLM(_config())
     fast = SmallThinkerLM(
-        _config(num_layers=4, remat=True),
+        _config(remat=True),
         attention_fn=make_attention_fn(causal=True, use_flash=True,
                                        block_q=128, block_k=128),
         window_attention_fn=make_attention_fn(
             causal=True, use_flash=True, block_q=128, block_k=128,
             window=SMALLTHINKER_TINY.sliding_window))
-    params = {k: params[k] for k in sorted(params)
-              if k not in ("layer_4", "layer_5", "layer_6", "layer_7")}
-
-    def plain_loss(p):
-        return causal_lm_loss(plain.apply({"params": p}, ids)[0], ids)
-
-    def fast_loss(p):
-        hidden, _ = fast.apply({"params": p}, ids, return_hidden=True)
-        return chunked_causal_lm_loss(hidden, p["lm_head"]["kernel"], ids,
-                                      num_chunks=4)
-
-    a, ga = jax.jit(jax.value_and_grad(plain_loss))(params)
-    b, gb = jax.jit(jax.value_and_grad(fast_loss))(params)
-    np.testing.assert_allclose(a, b, rtol=1e-5)
-    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
-        np.testing.assert_allclose(x, y, rtol=0, atol=5e-3 * float(
-            jnp.max(jnp.abs(x)) + 1e-12))
+    assert_the_benchmarks_step_is_the_plain_model(plain, fast, params, ids)
 
 
 def test_parts_of_the_four_shares_add_up_to_the_whole_layer(seeded,
@@ -64,37 +47,17 @@ def test_parts_of_the_four_shares_add_up_to_the_whole_layer(seeded,
     """One layer: each share's output is ``a + (its experts' part)``, so
     the four parts, with attention and the residual counted once, are the
     whole-layer reference."""
-    ids, params = seeded
     cfg = _config()
-    layer = params["layer_1"]           # a windowed, rotated layer
-    x = 3.0 * jax.random.normal(jax.random.PRNGKey(11), (1, SEQ, cfg.dim))
     window_fn = make_attention_fn(causal=True, use_flash=False,
                                   window=cfg.sliding_window)
-
-    def block(held, p):
-        out, load = jit_apply(SmallThinkerBlock(
-            _config(held), rope=True, attention_fn=window_fn))(
-            {"params": p}, x)
-        return out[0], load
-
-    whole = reference._layer(lambda a: a, layer, x[0],
-                             _reference_config(cfg), True, True)
-    nothing_held = reference._layer(
-        lambda a: a, layer, x[0],
-        {**_reference_config(cfg), "deployment": {"experts_held": []}},
-        True, True)                     # a: attention and the residual
-    shares = [(0, 1), (2, 3), (4, 5), (6, 7)]
-    parts, landed = 0.0, 0
-    for held in shares:
-        out, load = block(held, share({"layer_1": layer}, held)["layer_1"])
-        parts = parts + (out - nothing_held)
-        landed += int(load.sum())
-    assert landed == SEQ * cfg.num_selected     # every assignment, once
-    np.testing.assert_allclose(nothing_held + parts, whole, rtol=0,
-                               atol=2e-5 * float(jnp.max(jnp.abs(whole))))
-    # The same from the layer that holds all eight.
-    np.testing.assert_allclose(block(None, layer)[0], whole, rtol=0,
-                               atol=2e-5 * float(jnp.max(jnp.abs(whole))))
+    # Layer 1 is windowed and rotated; alike on every chip: a, attention
+    # and the residual.
+    assert_shares_add_up(
+        lambda held: SmallThinkerBlock(_config(held), rope=True,
+                                       attention_fn=window_fn),
+        seeded[1]["layer_1"], lambda p, rows, rcfg: reference._layer(
+            lambda a: a, p, rows, rcfg, True, True), _reference_config(cfg),
+        [(0, 1), (2, 3), (4, 5), (6, 7)], cfg, SEQ)
 
 
 def _experts(key, n, d=16, f=24):
@@ -131,7 +94,8 @@ def test_nothing_is_dropped_when_every_token_goes_to_one_expert(held):
         jax.nn.softmax(jnp.array([5.0, 4.0])))
     here = jnp.zeros((experts,)).at[jnp.array(held)].set(1.0)
     np.testing.assert_allclose(
-        y, _dense_experts(params, x, chosen * here), rtol=2e-5, atol=2e-5)
+        y, jax.jit(_dense_experts)(params, x, chosen * here), rtol=2e-5,
+        atol=2e-5)
     assert load.tolist() == [tokens if e in (1, 3) else 0 for e in held]
 
 
@@ -341,7 +305,7 @@ def test_a_share_of_bf16_rows_is_the_dense_form_to_bf16s_rounding():
                     [want_y] + jax.tree.leaves(want)):
         np.testing.assert_allclose(
             g.astype(jnp.float32), w, rtol=0,
-            atol=2.0 ** -6 * float(jnp.max(jnp.abs(w))))
+            atol=2.0 ** -6 * float(np.max(np.abs(w))))
 
 
 def test_rows_no_one_reads_may_hold_anything():
