@@ -348,13 +348,16 @@ def moe_apply_dense(expert_fn: Callable[[Any, jax.Array], jax.Array],
 #: alone, bf16 rows 2560 wide, the step between 110 and 120 MiB
 #: (``PERF.md`` section 6, PR 27): on another generation measure it again,
 #: a block more than needed costs a slice and a concatenation of the block.
+#: Two read it, through :func:`_gather_blocks`: :func:`_gather_sum` for the
+#: sorted rows and :func:`_gather_rows` for the token rows (PR 46).
 _GATHER_SOURCE_BYTES = 96 * 2 ** 20
 
 
 def _gather_blocks(size: int, width: int) -> int:
-    """Into how many blocks of columns :func:`_gather_sum` splits a source
-    of ``size`` bytes whose rows are ``width`` wide: the fewest whole
-    128-lane blocks that bring each under ``_GATHER_SOURCE_BYTES``."""
+    """Into how many blocks of columns :func:`_gather_sum` and
+    :func:`_gather_rows` split a source of ``size`` bytes whose rows are
+    ``width`` wide: the fewest whole 128-lane blocks that bring each under
+    ``_GATHER_SOURCE_BYTES``."""
     lanes = width // 128 if width % 128 == 0 else 1
     return next((n for n in range(1, lanes + 1) if lanes % n == 0
                  and size <= n * _GATHER_SOURCE_BYTES), lanes)
@@ -372,6 +375,29 @@ def _gather_sum(rows, place, scale):
         jnp.einsum("ktd,kt->td", jnp.where(used, block[place], 0), scale,
                    preferred_element_type=jnp.float32).astype(rows.dtype)
         for block in jnp.split(rows, blocks, axis=-1)], axis=-1)
+
+
+def _gather_rows(source, index):
+    """``source[index]`` for rows ``source`` ``[T, D]`` and ``index``
+    ``[R]``, the same values to the bit. A source the chip keeps on chip
+    is gathered whole. A larger one is gathered in blocks of columns small
+    enough for the fast gather, each by the same ``index`` and written at
+    its static column offset into one result that is never zeroed. A
+    block is sliced only after the block before it is written (the
+    barrier): sliced together, the blocks come out of one fusion of which
+    the compiler keeps one on chip and leaves the others in HBM
+    (``PERF.md`` section 6, PR 46)."""
+    size, width = index.shape[0], source.shape[1]
+    blocks = _gather_blocks(source.size * source.dtype.itemsize, width)
+    if blocks == 1:
+        return source[index]
+    rows = jax.lax.empty((size, width), source.dtype)
+    for start in range(0, width, width // blocks):
+        if start:
+            rows, source = jax.lax.optimization_barrier((rows, source))
+        block = source[:, start:start + width // blocks]
+        rows = jax.lax.dynamic_update_slice(rows, block[index], (0, start))
+    return rows
 
 
 class _Places(NamedTuple):
@@ -486,15 +512,17 @@ def _walk_sum(rows, at, chunk, weights=None):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _rows_of_tokens(chunk, x, at):
     """``[T, D]`` token rows -> the sorted rows: row i is token
-    ``at.token[i]``. Rows outside every group are read by no one. One
-    gather whatever ``chunk``: from a source the chip keeps on chip it
-    runs at the speed of its writes, which a walk into a zeroed buffer
-    does not beat (``PERF.md`` section 6, PR 41)."""
-    return x[at.token]
+    ``at.token[i]``. Rows outside every group are read by no one. All
+    rows in one pass whatever ``chunk``, by :func:`_gather_rows`: one
+    gather from token rows the chip keeps on chip, one a block of columns
+    from larger ones. Such a gather runs at the speed of its writes, which
+    a walk into a zeroed buffer does not beat (``PERF.md`` section 6, PRs
+    41 and 46)."""
+    return _gather_rows(x, at.token)
 
 
 def _rows_of_tokens_fwd(chunk, x, at):
-    return x[at.token], at
+    return _gather_rows(x, at.token), at
 
 
 def _rows_of_tokens_bwd(chunk, at, g):
@@ -529,7 +557,7 @@ def _tokens_of_rows_bwd(chunk, res, dy):
         return _walked_tokens_of_rows_bwd(chunk, res, dy)
     out, weights, at = res
     live = at.live[:, None]
-    g = dy[at.token]
+    g = _gather_rows(dy, at.token)
     d_out = jnp.where(live, g * weights.reshape(-1)[at.mine][:, None], 0)
     d_row = jnp.sum(jnp.where(
         live, g.astype(jnp.float32) * out.astype(jnp.float32), 0), axis=-1)
@@ -672,7 +700,9 @@ def moe_apply_held(expert_fn: Callable[[Any, jax.Array, jax.Array],
     sorted order in static chunks of a quarter of the even share and stop
     after the last chunk that holds a landed row, instead of gathering
     all ``T * num_selected`` rows and masking most away; the dispatch
-    itself stays one gather, and ``expert_fn`` is called once on the
+    itself stays one pass over all rows (one gather, or one a block of
+    columns where the token rows are more than the chip keeps on chip:
+    :func:`_gather_rows`), and ``expert_fn`` is called once on the
     buffer it had. The trip count is read on the device from ``load``: no
     branch, and every chunk is walked if everything lands here, so the
     result is as exact. Above an eighth held the program is the one-pass

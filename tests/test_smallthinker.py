@@ -453,3 +453,88 @@ def test_a_share_lowers_to_loops_and_no_branch():
 ])
 def test_gather_blocks_by_hand(size, width, blocks):
     assert moe._gather_blocks(size, width) == blocks
+
+
+def _sorted_places(slots, n_held):
+    """``moe._Places`` of the assignments ``slots`` ``[k, T]`` (the held
+    slot of each, ``n_held`` where it landed elsewhere), as
+    ``moe_apply_held`` makes them."""
+    flat = slots.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32).reshape(slots.shape)
+    landed = jnp.sum(flat < n_held)
+    return moe._Places(
+        mine=order, token=order % slots.shape[1],
+        live=jnp.arange(order.shape[0]) < landed, place=place,
+        here=place < landed, landed=landed)
+
+
+def _with_no_limit(program):
+    """``program()`` traced as if every source fitted on chip."""
+    with pytest.MonkeyPatch.context() as unlimited:
+        unlimited.setattr(moe, "_GATHER_SOURCE_BYTES", 2 ** 40)
+        return program()
+
+
+def _gathered(width, index):
+    """``_gather_rows`` of 40 float32 rows ``width`` wide by ``index``
+    beside ``source[index]``."""
+    source = jnp.asarray(np.random.RandomState(3).randn(40, width),
+                         jnp.float32)
+    return moe._gather_rows(source, index), source[index]
+
+
+def _dispatched(chunk):
+    """Value and gradient through ``_rows_of_tokens`` for 24 tokens of 256
+    float32 (24 KiB), two choices each over two held slots and elsewhere,
+    under the limit in force beside the same under no limit."""
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(24, 256), jnp.float32)
+    slots = jnp.asarray(rng.randint(0, 3, (2, 24)), jnp.int32)
+    cot = jnp.asarray(rng.randn(48, 256), jnp.float32)
+
+    def value_and_grad():
+        return jax.value_and_grad(lambda x: jnp.sum(
+            moe._rows_of_tokens(chunk, x, _sorted_places(slots, 2)) * cot)
+        )(x)
+
+    return value_and_grad(), _with_no_limit(value_and_grad)
+
+
+OUT_OF_ORDER = np.random.RandomState(5).permutation(40)[:33]
+REPEATED = np.random.RandomState(6).randint(0, 40, 100)
+
+
+@pytest.mark.parametrize("limit,blocks,case", [
+    # 40 rows of 768 float32 are 122,880 bytes in six lanes: whole, in
+    # halves and in thirds by the limit alone.
+    (122_880, 1, functools.partial(_gathered, 768, REPEATED)),
+    (122_879, 2, functools.partial(_gathered, 768, REPEATED)),
+    (61_439, 3, functools.partial(_gathered, 768, OUT_OF_ORDER)),
+    (61_440, 2, functools.partial(_gathered, 768, OUT_OF_ORDER)),
+    # Rows that are no whole lanes wide are not split, however large.
+    (1_000, 1, functools.partial(_gathered, 200, REPEATED)),
+    (1_000, 1, functools.partial(_gathered, 200, OUT_OF_ORDER)),
+    # Through the dispatch and its gradient, not walked and walked.
+    (24 * 256 * 4 - 1, 2, functools.partial(_dispatched, 0)),
+    (24 * 256 * 4 - 1, 2, functools.partial(_dispatched, 8)),
+], ids=["whole-on-the-limit", "halves", "thirds", "halves-on-the-limit",
+        "no-whole-lanes-repeated", "no-whole-lanes-out-of-order",
+        "dispatch-and-gradient", "dispatch-and-gradient-walked"])
+def test_rows_gathered_in_blocks_are_the_rows_gathered_whole(
+        limit, blocks, case, monkeypatch):
+    """``_gather_rows`` moves rows and computes nothing: in however many
+    blocks of columns the limit asks for, the result is ``source[index]``
+    to the bit, and so are the dispatch's rows and their gradient. One
+    compiled program a case: what the limit makes beside what no limit
+    makes."""
+    monkeypatch.setattr(moe, "_GATHER_SOURCE_BYTES", limit)
+    lowered = jax.jit(case).lower()
+    # The row gathers alone, a ``[rows, width]`` result each (the walked
+    # gradient's gathers of chunk indices give vectors): one a block, and
+    # the one gathered whole beside them.
+    assert len(re.findall(r"stablehlo\.gather.*-> tensor<\d+x\d+xf32>",
+                          lowered.as_text())) == blocks + 1
+    got, want = lowered.compile()()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(g, w)
