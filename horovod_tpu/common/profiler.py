@@ -71,6 +71,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
+           "KERNEL_SHORTCONV_FWD", "KERNEL_SHORTCONV_BWD",
            "Span", "span", "record_span", "spans", "spans_dropped",
            "CompileEvent", "compile_events", "install_compile_listeners",
            "COMPILE_EVENTS",
@@ -144,7 +145,8 @@ SCOPE_LINATTN_GATE = "hvd.linattn.gate"
 #: ``ShortConvMixer``), forward and backward alike: the whole mixer, both
 #: projections inside; and inside it the pointwise part between them, the
 #: two gates and the causal convolution
-#: (``ops/linear_attention.causal_conv``).
+#: (``ops/short_conv.gated_short_conv_packed``'s two kernels where the
+#: shapes tile, ``ops/linear_attention.causal_conv`` where not).
 SCOPE_SHORTCONV = "hvd.shortconv"
 SCOPE_SHORTCONV_POINTWISE = "hvd.shortconv.pointwise"
 
@@ -160,6 +162,8 @@ KERNEL_FLASH_BWD_DQ = "hvd_flash_bwd_dq"
 KERNEL_FLASH_BWD_DKV = "hvd_flash_bwd_dkv"
 KERNEL_DECODE = "hvd_decode"
 KERNEL_PAGED_DECODE = "hvd_paged_decode"
+KERNEL_SHORTCONV_FWD = "hvd_shortconv_fwd"
+KERNEL_SHORTCONV_BWD = "hvd_shortconv_bwd"
 
 
 #: The decode attention paths of ``models/llama.py``, each under

@@ -2,15 +2,19 @@
 
 The architecture of ``LiquidAI/LFM2-24B-A2B`` (``model_type``
 ``lfm2_moe``; 24B parameters, 2B active; widths from its public
-``config.json``), built from ``models/decoder.py``'s parts and
-``ops.linear_attention.causal_conv``. The block is
+``config.json``), built from ``models/decoder.py``'s parts,
+``ops.short_conv``'s two kernels and ``ops.linear_attention.causal_conv``.
+The block is
 ``h = x + Mixer(norm(x))``, ``y = h + FFN(norm(h))``. What sets it apart:
 
 * **Three mixers in four are gated short convolutions** (``layer_types``,
   period [conv, conv, attention, conv]): ``[B, C, u] = split3(z W_in)``,
   ``v = conv3(B * u)`` (causal, depthwise, 3 taps, no bias and no
   activation), ``out = (C * v) W_out``: a gate on either side of a
-  convolution that sees two tokens back.
+  convolution that sees two tokens back. Where the width is a whole
+  number of 128-lane tiles and the sequence of row blocks, ``C * conv3(B *
+  u)`` is one Pallas kernel forward and one backward over the projection's
+  packed result (``ops.short_conv``); elsewhere XLA's plain form.
 * **The fourth is grouped-query attention** with an RMSNorm over each q
   and k head (one scale of the head's width, shared by the heads) BEFORE
   the rotary embedding, which rotates the whole head; 32 query heads over
@@ -54,6 +58,7 @@ import jax.numpy as jnp
 
 from ..common import profiler
 from ..ops.linear_attention import causal_conv
+from ..ops.short_conv import block_rows, gated_short_conv_packed
 from ..parallel.moe import grouped_gated_mlp, sigmoid_top_k
 from .decoder import (GatedMLP, Leaf, RMSNorm, decoder_layers, held_experts,
                       linear, one_entry_a_layer, project_heads, project_out,
@@ -115,8 +120,12 @@ def _taps_init(key, shape, dtype=jnp.float32):
 
 def gated_short_conv(b_gate, c_gate, u, taps):
     """``C * conv(B * u)``: the pointwise part of the short-convolution
-    mixer, (B, S, dim) arrays and ``taps`` (K, dim). The convolution is
-    ``ops.linear_attention.causal_conv`` with no activation."""
+    mixer in its plain form, (B, S, dim) arrays and ``taps`` (K, dim). The
+    convolution is ``ops.linear_attention.causal_conv`` with no
+    activation. What the mixer runs where
+    ``ops.short_conv.block_rows`` names no block for its shapes (a width
+    off the 128-lane tile, a sequence the block does not divide); the
+    others go through ``ops.short_conv.gated_short_conv_packed``."""
     return c_gate * causal_conv(b_gate * u, taps)
 
 
@@ -131,19 +140,31 @@ def decay_mask(params):
 class ShortConvMixer(nn.Module):
     """``[B, C, u] = split3(z W_in)``; ``out = (C * conv(B * u)) W_out``,
     no bias anywhere. All of it under ``hvd.shortconv``, the part between
-    the projections under ``hvd.shortconv.pointwise``."""
+    the projections under ``hvd.shortconv.pointwise``.
+
+    Which form the pointwise part takes is read from the shapes: where
+    ``ops.short_conv.block_rows`` names a block (the width a whole number
+    of 128-lane tiles, the sequence a whole number of blocks: 256 rows at
+    width 2048), one Pallas kernel forward and one backward read the
+    in-projection's result where it lies and write ``y``, and the three
+    gates' gradients as one array; anything else (the tiny models at
+    width 64, a ragged sequence) splits it and runs
+    :func:`gated_short_conv`."""
     config: Lfm2Config
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
         with jax.named_scope(profiler.SCOPE_SHORTCONV):
-            b_gate, c_gate, u = jnp.split(
-                linear(3 * cfg.dim, cfg.dtype, "in_proj")(x), 3, axis=-1)
+            gates = linear(3 * cfg.dim, cfg.dtype, "in_proj")(x)
             taps = Leaf("kernel", (cfg.conv_taps, cfg.dim), _taps_init,
                         name="taps")()
+            fused = block_rows(x.shape[1], cfg.dim, cfg.conv_taps)
+            if not fused:
+                b_gate, c_gate, u = jnp.split(gates, 3, axis=-1)
             with jax.named_scope(profiler.SCOPE_SHORTCONV_POINTWISE):
-                y = gated_short_conv(b_gate, c_gate, u, taps)
+                y = (gated_short_conv_packed(gates, taps) if fused
+                     else gated_short_conv(b_gate, c_gate, u, taps))
             return linear(cfg.dim, cfg.dtype, "out_proj")(y)
 
 

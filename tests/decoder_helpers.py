@@ -99,8 +99,9 @@ def assert_the_benchmarks_step_is_the_plain_model(plain, fast, params, ids):
 
 
 def assert_three_adamw_steps_match(loss, params, ids, reference, rcfg, tx,
-                                   size, check=lambda name, r: None):
-    """Three steps of ``tx`` on ``loss``, ONE jitted step, against
+                                   size, check=lambda name, r: None,
+                                   steps=3):
+    """Three steps (or ``steps``) of ``tx`` on ``loss``, ONE jitted step, against
     ``reference.follow`` with a replica a row of ``ids`` (Horovod's mean
     of the replicas' means): each step's loss, every leaf's first gradient
     to a part in a thousand of its largest entry (float32 through a few
@@ -115,12 +116,12 @@ def assert_three_adamw_steps_match(loss, params, ids, reference, rcfg, tx,
         return optax.apply_updates(p, updates), opt_state, value, grads
 
     ours, opt_state, losses, first = params, jax.jit(tx.init)(params), [], None
-    for _ in range(3):
+    for _ in range(steps):
         ours, opt_state, value, grads = step(ours, opt_state)
         losses.append(float(value))
         first = grads if first is None else first
     their_losses, their_first, theirs = reference.follow(
-        params, [(np.asarray(row)[None],) for row in ids], 3, rcfg)
+        params, [(np.asarray(row)[None],) for row in ids], steps, rcfg)
     np.testing.assert_allclose(
         losses, [np.mean(step) for step in their_losses], rtol=2e-5)
     flat, _ = jax.tree_util.tree_flatten_with_path(jax.device_get(params))

@@ -8,6 +8,8 @@ as it was; the tied matrix's gradient is the sum of its two paths; the
 routed parts of the eight disjoint shares add up to the uncut reference's
 layer."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,9 +20,11 @@ from horovod_tpu.models import (Lfm2LM, causal_lm_loss,
 from horovod_tpu.models.lfm2 import (CONV, Lfm2Block, decay_mask,
                                      gated_short_conv)
 from horovod_tpu.ops.linear_attention import causal_conv, causal_conv_silu
+from horovod_tpu.ops.short_conv import block_rows
 from horovod_tpu.parallel.moe import sigmoid_top_k, softmax_top_k
 from decoder_helpers import (assert_shares_add_up,
-                             assert_three_adamw_steps_match, share)
+                             assert_three_adamw_steps_match,
+                             seeded_ids_and_params, share)
 from lfm2_helpers import (SEQ, _config, _reference_config,  # noqa: F401
                           reference, seeded)
 
@@ -45,6 +49,27 @@ def test_three_adamw_steps_match_the_plain_reference(seeded, reference):
         params, ids, reference, _reference_config(cfg, **OPTIMIZER),
         optax.adamw(mask=decay_mask, **OPTIMIZER),
         size=lambda x: np.max(np.abs(x)))
+
+
+def test_a_step_through_the_fused_mixers_matches_the_plain_reference(
+        reference):
+    """At a width and a sequence that take the kernels (128 lanes, two
+    blocks of 512 rows), each block recomputed as the benchmark's step
+    recomputes it: a dense and a sparse convolution layer against the
+    reference, loss, first gradient and the parameters after a step."""
+    seq = 1024
+    cfg = dataclasses.replace(_config((0, 5, 7)), dim=128, num_layers=2,
+                              layer_types=(CONV, CONV), remat=True)
+    assert block_rows(seq, cfg.dim, cfg.conv_taps) == seq // 2
+    model = Lfm2LM(cfg)
+    ids, params = seeded_ids_and_params(
+        model, seq, lambda path, x: x * 25.0 if "router" in {
+            str(getattr(k, "key", k)) for k in path} else x)
+    assert_three_adamw_steps_match(
+        lambda p: causal_lm_loss(model.apply({"params": p}, ids)[0], ids),
+        params, ids, reference, _reference_config(cfg, **OPTIMIZER),
+        optax.adamw(mask=decay_mask, **OPTIMIZER),
+        size=lambda x: np.max(np.abs(x)), steps=1)
 
 
 def test_the_convolution_sees_no_later_token_and_is_three_shifted_sums(
