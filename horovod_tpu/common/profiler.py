@@ -67,7 +67,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "SCOPE_ATTN_LATENT", "SCOPE_ATTN_LATENT_PROJ", "SCOPE_MTP",
            "SCOPE_LINATTN_CONV", "SCOPE_LINATTN_SCAN", "SCOPE_LINATTN_GATE",
            "SCOPE_SHORTCONV", "SCOPE_SHORTCONV_POINTWISE",
-           "SCOPE_LOSS_HEAD",
+           "SCOPE_LOSS_HEAD", "SCOPE_LOOP_PASS", "SCOPE_LOOP_EXIT",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
@@ -154,6 +154,17 @@ SCOPE_SHORTCONV_POINTWISE = "hvd.shortconv.pointwise"
 #: sequence's chunks (one ``while``) that applies the head and computes
 #: the loss and, when differentiated, both of its gradients.
 SCOPE_LOSS_HEAD = "hvd.loss.head"
+
+#: A looped decoder (``models/ouro.py`` over
+#: ``models/decoder.looped_decoder_layers``), forward and backward alike:
+#: one pass of the shared stack, every layer and the final norm that ends
+#: it, under the same name in every pass (the passes are unrolled, so each
+#: kernel and scope inside is an operation of the compiled step); and what
+#: makes the passes' exits one loss: the gate's product on every pass's
+#: normed states, the exit distribution, its entropy and the stacking of
+#: states and weights for the head's one sweep.
+SCOPE_LOOP_PASS = "hvd.loop.pass"
+SCOPE_LOOP_EXIT = "hvd.loop.exit"
 
 #: ``name=`` of the Pallas kernels: what the Mosaic custom calls are
 #: called in the compiled program and the device trace.

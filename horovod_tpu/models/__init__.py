@@ -27,6 +27,7 @@ from .losses import (  # noqa: F401
     chunked_causal_lm_loss,
     sp_causal_lm_loss,
     token_nll,
+    weighted_chunked_causal_lm_loss,
 )
 from .inception import InceptionV3  # noqa: F401
 from .joyai import (  # noqa: F401
@@ -59,6 +60,14 @@ from .olmo_hybrid import (  # noqa: F401
     OLMO_HYBRID_TINY,
     OlmoHybridConfig,
     OlmoHybridLM,
+)
+from .ouro import (  # noqa: F401
+    OURO_2_6B,
+    OURO_TINY,
+    OuroConfig,
+    OuroLM,
+    exit_distribution,
+    ouro_lm_loss,
 )
 from .smallthinker import (  # noqa: F401
     SMALLTHINKER_21B,

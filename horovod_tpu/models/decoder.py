@@ -1,11 +1,9 @@
 """The parts every decoder configuration is built from.
 
 ``LlamaLM``, ``MoeLM``, ``SmallThinkerLM``, ``OlmoHybridLM``, ``LagunaLM``,
-``Lfm2LM`` and ``JoyAILM`` import this module and ``losses``, and no
-model file imports another (``moe_lm.py`` extends ``llama.py`` by design).
-A configuration's own file holds its configuration, its mixers, its
-block's wiring and what only it has; ``docs/decoder-configurations.md``
-says what a new one writes and what it imports.
+``Lfm2LM``, ``JoyAILM`` and ``OuroLM`` import this module and ``losses``,
+and no model file imports another (``moe_lm.py`` extends ``llama.py``). What
+a configuration's own file holds: ``docs/decoder-configurations.md``.
 
 Apart from the four classes, everything here is a **plain function
 called inside the caller's ``@nn.compact`` method**: the flax modules it
@@ -20,7 +18,7 @@ the benchmark's readers and the compiled programs do not see it.
 * the held sparse layer: :func:`router_logits`, :func:`held_experts`;
 * the LM's skeleton: :func:`one_entry_a_layer`, :func:`xla_attention`,
   :func:`token_embedding`, :func:`rematerialised`, :func:`decoder_layers`,
-  :func:`stack_loads`, :func:`lm_head`.
+  :func:`looped_decoder_layers`, :func:`stack_loads`, :func:`lm_head`.
 """
 
 from __future__ import annotations
@@ -28,9 +26,11 @@ from __future__ import annotations
 from typing import Any, Callable, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import profiler
 from ..ops.attention import make_attention_fn
 from ..parallel.moe import moe_apply_held
 
@@ -243,6 +243,39 @@ def decoder_layers(cfg, block, layers, x, *args):
             x, *args)
         aux.append(layer_aux)
     return RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x), aux
+
+
+def looped_decoder_layers(cfg, block, layers, x, passes, *args):
+    """A stack that is BUILT ONCE AND RUN ``passes`` TIMES: the embedded
+    tokens ``x`` cast to ``cfg.dtype``, then ``passes`` times through
+    ``block`` as ``layer_0`` .. ``layer_{n-1}`` and ``final_norm``, each
+    pass reading the normed states the pass before it wrote. ``layers`` and
+    ``args`` as :func:`decoder_layers` has them (a block's ``aux`` is
+    dropped). Returns the list of every pass's normed states.
+
+    A parameter's path is ``layer_i/...`` or ``final_norm/scale`` whatever
+    ``passes`` is: one leaf a shared parameter, whose gradient autodiff
+    sums over the passes. With ``cfg.remat`` each application of a block
+    is recomputed in the backward pass on its own.
+
+    The passes are UNROLLED IN PYTHON, not a ``lax.scan`` / ``nn.scan``:
+    the device trace shows a ``while`` as one operation, so a scanned loop
+    would hide the flash kernels and every scope inside it from every
+    reader of the benchmark. Each pass runs under ``hvd.loop.pass``, the
+    same name every pass."""
+    x = x.astype(cfg.dtype)
+    block_cls = rematerialised(cfg, block)
+    blocks = [block_cls(cfg, name=f"layer_{i}", **built_with)
+              for i, built_with in enumerate(layers)]
+    final_norm = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")
+    states = []
+    for _ in range(passes):
+        with jax.named_scope(profiler.SCOPE_LOOP_PASS):
+            for layer in blocks:
+                x, _ = layer(x, *args)
+            x = final_norm(x)
+        states.append(x)
+    return states
 
 
 def stack_loads(loads, held):

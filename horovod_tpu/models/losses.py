@@ -2,9 +2,9 @@
 
 ``token_nll`` (the lse form BERT's, ViT's and the LMs' losses share),
 ``causal_lm_loss`` on full logits, ``chunked_causal_lm_loss`` with the
-head fused in and both gradients computed in its one sweep (every
-decoder cell's loss head; the ``hvd.loss.head`` scope is here), and
-``sp_causal_lm_loss`` across sequence shards.
+head fused in and both gradients computed in its one sweep (under
+``hvd.loss.head``), ``weighted_chunked_causal_lm_loss`` (that sweep under
+weights that are differentiated), ``sp_causal_lm_loss`` across shards.
 """
 
 from __future__ import annotations
@@ -163,6 +163,124 @@ def chunked_causal_lm_loss(hidden, head_kernel, input_ids,
         raise ValueError(
             f"chunked_causal_lm_loss: ahead={ahead} must lie in [1, {s})")
     return _chunked_loss(hidden, head_kernel, input_ids, num_chunks, ahead)
+
+
+def _weighted_sum(nll, weights, sequences, ahead):
+    """``sum(weights * nll)`` over every position that has a target, over
+    the count of such positions in ``sequences`` sequences."""
+    s = nll.shape[1]
+    return (weights.astype(jnp.float32) * nll)[:, :-ahead].sum() \
+        / (sequences * (s - ahead))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _weighted_chunked_loss(hidden, head_kernel, input_ids, weights,
+                           num_chunks, ahead, sequences):
+    # The call nobody differentiates: the loss alone, one product a chunk.
+    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
+        n, s, _ = hidden.shape
+        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks,
+                               ahead)
+        nll = jax.lax.map(lambda args: token_nll(args[0] @ w, args[1]),
+                          (h, t))
+        return _weighted_sum(nll.transpose(1, 0, 2).reshape(n, s), weights,
+                             sequences, ahead)
+
+
+def _weighted_chunked_loss_fwd(hidden, head_kernel, input_ids, weights,
+                               num_chunks, ahead, sequences):
+    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
+        n, s, d = hidden.shape
+        h, t, w = _loss_chunks(hidden, head_kernel, input_ids, num_chunks,
+                               ahead)
+        vocab = w.shape[1]
+        # d(loss)/d(weight * nll) of every position: 0 where there is no
+        # target; d(loss)/d(nll) is the position's weight times it.
+        counted = (jnp.arange(s) < s - ahead).astype(jnp.float32) \
+            / (sequences * (s - ahead))
+        scale = (weights.astype(jnp.float32) * counted).reshape(
+            n, num_chunks, s // num_chunks).transpose(1, 0, 2)
+
+        def chunk(dw, args):
+            # ``_chunked_loss_fwd``'s chunk, a scale a row and position.
+            h_c, t_c, scale_c = args
+            logits = h_c @ w
+            z = logits.astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(z, axis=-1)
+            target_logit = jnp.take_along_axis(
+                logits, t_c[..., None], axis=-1)[..., 0].astype(jnp.float32)
+            onehot = jnp.arange(vocab) == t_c[..., None]
+            dlogits = ((jnp.exp(z - lse[..., None]) - onehot)
+                       * scale_c[..., None]).astype(logits.dtype)
+            dh_c = jnp.einsum("bcv,dv->bcd", dlogits, w)
+            dw = dw + jnp.einsum("bcd,bcv->dv", h_c, dlogits,
+                                 preferred_element_type=jnp.float32)
+            return dw, (lse - target_logit, dh_c)
+
+        dw, (nll, dh) = jax.lax.scan(
+            chunk, jnp.zeros((d, vocab), jnp.float32), (h, t, scale))
+        dh = dh.transpose(1, 0, 2, 3).reshape(n, s, d)
+        nll = nll.transpose(1, 0, 2).reshape(n, s)
+        # A weight's gradient is its position's own nll over the count.
+        return _weighted_sum(nll, weights, sequences, ahead), (
+            dh, dw.astype(head_kernel.dtype),
+            (nll * counted).astype(weights.dtype))
+
+
+def _weighted_chunked_loss_bwd(num_chunks, ahead, sequences, residuals, g):
+    del num_chunks, ahead, sequences
+    with jax.named_scope(profiler.SCOPE_LOSS_HEAD):
+        dh, dw, dweights = residuals
+        return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
+                (g * dw.astype(jnp.float32)).astype(dw.dtype), None,
+                (g * dweights.astype(jnp.float32)).astype(dweights.dtype))
+
+
+_weighted_chunked_loss.defvjp(_weighted_chunked_loss_fwd,
+                              _weighted_chunked_loss_bwd)
+
+
+def weighted_chunked_causal_lm_loss(hidden, head_kernel, input_ids, weights,
+                                    num_chunks: int = 8, ahead: int = 1):
+    """:func:`chunked_causal_lm_loss` under PER-POSITION WEIGHTS THAT ARE
+    DIFFERENTIATED, for one or several exits through one head:
+    ``sum(weights * nll) / (B (S - ahead))`` over the positions that have
+    a target, ``B, S = input_ids.shape``.
+
+    ``hidden`` is ``(E B, S, dim)`` and ``weights`` ``(E B, S)``, the
+    ``E`` exits stacked on the batch axis, exit-major (row ``e B + b`` is
+    exit ``e`` of sequence ``b``; ``E = 1`` is one weighted loss); every
+    exit has ``input_ids``' targets. The count stays ``B (S - ahead)``:
+    with an exit distribution as the weights the value is the expected
+    task loss. All exits go through the one sweep, so the head kernel is
+    read once a chunk and its gradient summed once.
+
+    The one sweep also gives the three GRADIENTS, nothing recomputed:
+    ``d/d hidden = weight * (softmax - onehot) / count`` and ``d/d
+    head_kernel`` as the unweighted rule has them, and ``d/d weights``,
+    which is the position's own nll over the count (zero where there is
+    no target): the sweep has it in hand. Weights of all ones over ``B``
+    rows give the unweighted loss and gradients bit for bit
+    (``tests/test_losses_weighted.py``). Forward mode is not defined."""
+    b, s = input_ids.shape
+    exits, left = divmod(hidden.shape[0], b)
+    if left or not exits or hidden.shape[1] != s \
+            or weights.shape != hidden.shape[:2]:
+        raise ValueError(
+            "weighted_chunked_causal_lm_loss: hidden "
+            f"{hidden.shape} and weights {weights.shape} must hold a whole "
+            f"number of exits of input_ids {input_ids.shape}")
+    if s % num_chunks:
+        raise ValueError(
+            f"weighted_chunked_causal_lm_loss: seq len {s} must be "
+            f"divisible by num_chunks {num_chunks}")
+    if not 1 <= ahead < s:
+        raise ValueError(
+            f"weighted_chunked_causal_lm_loss: ahead={ahead} must lie in "
+            f"[1, {s})")
+    return _weighted_chunked_loss(
+        hidden, head_kernel, jnp.tile(input_ids, (exits, 1)), weights,
+        num_chunks, ahead, b)
 
 
 def sp_causal_lm_loss(logits, input_ids, axis_name: str):

@@ -37,7 +37,7 @@ EXTENDS = {"moe_lm": {"llama"}, "vit": {"bert"}}
 
 def test_no_model_file_imports_another():
     files = sorted(f[:-3] for f in os.listdir(MODELS) if f.endswith(".py"))
-    assert {"decoder", "losses", "llama", "joyai"} <= set(files)
+    assert {"decoder", "losses", "llama", "joyai", "ouro"} <= set(files)
     found = {}
     for name in files:
         if name == "__init__":
@@ -106,6 +106,12 @@ LATENT = {"attention/wq_a/kernel": (64, 48),
           **scales("attention_norm", "ffn_norm")}
 JOYAI_SPARSE = {**LATENT, **HELD, **BIAS, **gated(48, "shared/")}
 OLMO_MLP = {**gated(96), **scales("mixer_norm", "mlp_norm")}
+# A norm before and after each sublayer, named as the public code names
+# them; the MLP's kernels in the block's own scope.
+OURO_LAYER = {**attention(2, 2, 32), **gated(160),
+              **scales("input_layernorm", "input_layernorm_2",
+                       "post_attention_layernorm",
+                       "post_attention_layernorm_2")}
 
 TREES = {
     "LlamaLM": (models.LlamaLM, models.LLAMA_TINY, TOP,
@@ -154,6 +160,11 @@ TREES = {
         **under("mtp/block", JOYAI_SPARSE)}, {
             (0,): {**LATENT, **gated(160, "mlp/")},
             (1,): JOYAI_SPARSE}),
+    # Two layers run four times: one ``layer_i`` a layer, not one a pass,
+    # and one gate for every pass.
+    "OuroLM": (models.OuroLM, models.OURO_TINY, {
+        **TOP, "early_exit_gate/kernel": (64, 1),
+        "early_exit_gate/bias": (1,)}, {(0, 1): OURO_LAYER}),
 }
 
 
