@@ -71,7 +71,7 @@ __all__ = ["trace", "start_trace", "stop_trace", "annotate", "step",
            "DECODE_PATHS", "decode_scope",
            "KERNEL_FLASH_FWD", "KERNEL_FLASH_BWD_DQ", "KERNEL_FLASH_BWD_DKV",
            "KERNEL_DECODE", "KERNEL_PAGED_DECODE",
-           "KERNEL_SHORTCONV_FWD", "KERNEL_SHORTCONV_BWD",
+           "KERNEL_SHORTCONV_FWD", "KERNEL_SHORTCONV_BWD", "KERNELS",
            "Span", "span", "record_span", "spans", "spans_dropped",
            "CompileEvent", "compile_events", "install_compile_listeners",
            "COMPILE_EVENTS",
@@ -175,6 +175,11 @@ KERNEL_DECODE = "hvd_decode"
 KERNEL_PAGED_DECODE = "hvd_paged_decode"
 KERNEL_SHORTCONV_FWD = "hvd_shortconv_fwd"
 KERNEL_SHORTCONV_BWD = "hvd_shortconv_bwd"
+#: All of them (``utils.comm_accounting.mosaic_calls_by_kernel`` counts
+#: each in a lowered or compiled program).
+KERNELS = (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD_DQ, KERNEL_FLASH_BWD_DKV,
+           KERNEL_DECODE, KERNEL_PAGED_DECODE,
+           KERNEL_SHORTCONV_FWD, KERNEL_SHORTCONV_BWD)
 
 
 #: The decode attention paths of ``models/llama.py``, each under

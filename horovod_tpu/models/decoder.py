@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common import profiler
-from ..ops.attention import make_attention_fn
+from ..ops.attention import FLASH_RESIDUAL_NAMES, make_attention_fn
 from ..parallel.moe import moe_apply_held
 
 
@@ -223,9 +223,20 @@ def token_embedding(cfg):
 
 
 def rematerialised(cfg, block):
-    """``block``, recomputed in the backward pass where ``cfg.remat``
-    (``jax.checkpoint`` a block)."""
-    return nn.remat(block) if cfg.remat else block
+    """``block``, checkpointed where ``cfg.remat`` (``jax.checkpoint`` a
+    block). A checkpoint keeps the block's input and, of what the block
+    computes, the flash kernel's output and row log-sum-exp
+    (``ops.attention.FLASH_RESIDUAL_NAMES``: (B, S, H * Dv) in the
+    block's dtype and (B, H, S) float32 a call); everything else (norms,
+    projections, rotary, q, k and v, gates, the expert layer, the MLP) is
+    computed again in the backward pass. With both of the forward
+    kernel's results kept, the recomputation does not call it: a step
+    holds one forward call beside each dq / dk-dv pair."""
+    if not cfg.remat:
+        return block
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUAL_NAMES)
+    return nn.remat(block, policy=keep)
 
 
 def decoder_layers(cfg, block, layers, x, *args):
@@ -256,7 +267,9 @@ def looped_decoder_layers(cfg, block, layers, x, passes, *args):
     A parameter's path is ``layer_i/...`` or ``final_norm/scale`` whatever
     ``passes`` is: one leaf a shared parameter, whose gradient autodiff
     sums over the passes. With ``cfg.remat`` each application of a block
-    is recomputed in the backward pass on its own.
+    is a checkpoint of its own (:func:`rematerialised`): it keeps its
+    input and its flash kernel's output and row log-sum-exp, ``passes``
+    times a layer, and recomputes the rest in the backward pass.
 
     The passes are UNROLLED IN PYTHON, not a ``lax.scan`` / ``nn.scan``:
     the device trace shows a ``while`` as one operation, so a scanned loop

@@ -87,6 +87,21 @@ accumulation, ``p`` cast to the V dtype for the MXU, fully-masked rows
 emit zeros (and zero gradients) and a finite ``lse``, a cotangent on
 ``lse`` is a shift of ``delta``.
 
+**What a checkpoint can keep.** ``_flash``'s forward rule marks the two
+results of the forward kernel with ``jax.ad_checkpoint.checkpoint_name``
+before it hands them on as the primal output and as residuals: the output
+``hvd_flash_out`` (:data:`FLASH_OUT_NAME`) and the row log-sum-exp
+``hvd_flash_lse`` (:data:`FLASH_LSE_NAME`; both in
+:data:`FLASH_RESIDUAL_NAMES`). Every caller below the ``custom_vjp``
+carries them (``flash_attention``, ``make_attention_fn``, ring attention,
+the serving prefill), on either path. Outside a ``jax.checkpoint`` a name
+is an identity that lowers to no operation; inside one whose policy is
+``save_only_these_names(*FLASH_RESIDUAL_NAMES)``
+(``models.decoder.rematerialised``) the backward pass finds both results
+saved and does not call ``hvd_flash_fwd`` a second time (q, k and v are
+still recomputed for the backward kernels). Both must be saved: with one
+of them missing the recomputation keeps the call.
+
 What was measured where (``PERF.md`` sections 5 and 6 have the tables): on
 one TPU v5e, jax 0.9.0, B=64 H=12 S=512 D=64 bf16 with a key mask
 (BERT-base's shape in the benchmark cell ``bert-base-s512-dp1``), the
@@ -120,12 +135,22 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import profiler
 
 NEG_INF = -1e30
+
+#: ``jax.ad_checkpoint.checkpoint_name`` of what ``_flash``'s forward rule
+#: hands its backward rule beside q, k, v and the mask: the kernel's output
+#: and its row log-sum-exp. A ``jax.checkpoint`` whose policy saves both
+#: (``models.decoder.rematerialised``) does not call the forward kernel
+#: again in the backward pass; outside a checkpoint a name is nothing.
+FLASH_OUT_NAME = "hvd_flash_out"
+FLASH_LSE_NAME = "hvd_flash_lse"
+FLASH_RESIDUAL_NAMES = (FLASH_OUT_NAME, FLASH_LSE_NAME)
 
 
 def _check_gqa_heads(q, k, v, name: str) -> None:
@@ -1232,6 +1257,8 @@ def _flash_fwd_rule(q, k, v, maskf, causal, sm_scale, block_q, block_k,
     out, lse = _flash_forward(q, k, v, maskf != 0, causal, sm_scale, block_q,
                               block_k, interpret, has_mask=has_mask,
                               window=window)
+    out = checkpoint_name(out, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, maskf, out, lse)
 
 

@@ -195,6 +195,37 @@ def decode_path_markers(compiled_or_text) -> Dict[str, int]:
 
 
 # ---------------------------------------------------------------------------
+# Kernel attribution.
+
+_MOSAIC_CALL = "tpu_custom_call"
+_KERNEL_WORDS = {kernel: re.compile(r"(?<![\w.])" + re.escape(kernel)
+                                    + r"(?![\w.])")
+                 for kernel in profiler.KERNELS}
+
+
+def mosaic_calls_by_kernel(program_or_text) -> Dict[str, int]:
+    """Count a program's Mosaic custom calls by the ``pallas_call``'s
+    ``name=`` (``profiler.KERNELS``; a call of none of them, such as a
+    product XLA hands to Mosaic itself, under ``"(unnamed)"``). Pass a
+    ``jit(f).lower(...)`` (StableHLO: the call carries ``kernel_name =
+    "hvd_flash_fwd"``), its ``.compile()`` (HLO: the call's ``op_name``
+    ends ``.../hvd_flash_fwd/pallas_call``) or either's ``as_text()``. A
+    step whose blocks are checkpointed (``models.decoder.rematerialised``)
+    shows as many ``hvd_flash_fwd`` as ``hvd_flash_bwd_dq``: the forward
+    kernel is not called again in the backward pass."""
+    text = (program_or_text if isinstance(program_or_text, str)
+            else program_or_text.as_text())
+    found = dict.fromkeys(profiler.KERNELS + ("(unnamed)",), 0)
+    for line in text.splitlines():
+        if _MOSAIC_CALL not in line:
+            continue
+        found[next((kernel for kernel in profiler.KERNELS
+                    if _KERNEL_WORDS[kernel].search(line)),
+                   "(unnamed)")] += 1
+    return found
+
+
+# ---------------------------------------------------------------------------
 # Ring-model wire bytes (per device, send direction).
 
 
